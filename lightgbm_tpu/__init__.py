@@ -8,7 +8,12 @@ lightgbm-compatible Python API.
 from .config import Config
 from .models.tree import Tree
 from .models.serialize import GBDTModel
+from .utils.backend import configure_compile_cache
 from .utils.log import register_log_callback, LightGBMError
+
+# the one place every entry point passes through (lgb.train, the CLI,
+# bench.py, the serving layer): see utils/backend.py for the rule
+configure_compile_cache()
 
 __version__ = "0.1.0"
 
@@ -23,8 +28,8 @@ __all__ = [
 
 
 def __getattr__(name):
-    # Lazy imports: keep `import lightgbm_tpu` cheap and avoid initializing
-    # JAX until a training/inference entry point is touched.
+    # Lazy imports: keep `import lightgbm_tpu` cheap and initialize no JAX
+    # backend until a training/inference entry point is touched.
     if name in ("Dataset", "Booster"):
         from . import basic
 

@@ -278,8 +278,10 @@ class Config:
         # config.h:690); defaulting a TPU-native framework to the host path
         # would leave the attached accelerator idle. Unset device_type means
         # "auto": the tree-learner factory picks the on-device learner when
-        # an accelerator backend is live. An EXPLICIT device_type=cpu (or
-        # device=cpu alias) still forces the host-driven path.
+        # the default device is a TPU and says why when it does not. An
+        # EXPLICIT device_type=cpu (or device=cpu alias) forces the
+        # host-driven path; an explicit device_type=tpu with no TPU attached
+        # is fatal (treelearner/serial.py device_growth_applies).
         if "device_type" not in self.raw_params:
             self.device_type = "auto"
         # mirrors Config::CheckParamConflict essentials

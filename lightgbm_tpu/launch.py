@@ -4,8 +4,8 @@ The reference ships parallel orchestration through its socket machinery plus
 external wrappers (Dask in python-package/lightgbm/dask.py, MPI via mpirun);
 the TPU-native equivalent is one JAX process per host joined through
 `jax.distributed`. This launcher covers the single-machine multi-process
-case (simulating a multi-host cluster, or driving multiple local
-accelerator processes):
+case on the CPU backend (a multi-host cluster simulated as a gang of
+`--devices-per-proc` CPU processes):
 
     python -m lightgbm_tpu.launch -n 4 -- config=train.conf
 
@@ -14,6 +14,12 @@ JAX_PROCESS_ID set; each worker runs the normal CLI (lightgbm_tpu.cli), and
 parallel/dist.py picks the env vars up in init_distributed. For a REAL
 multi-host pod, run the same CLI once per host with those env vars (or a
 machine-list conf) instead.
+
+A chip belongs to one process, and every worker spawned here would claim
+ALL of its host's chips, so the launcher is not how one host's chips are
+used: there the multi-chip path is the in-process mesh — one process,
+`tree_learner=data num_machines=k`, which parallel/mesh.py maps onto k
+local devices. One process per HOST, never one per chip.
 
 The gang is *supervised* (parallel/elastic.py): the moment one worker exits
 nonzero or misses its liveness deadline, every sibling is reaped — a dead
@@ -59,7 +65,12 @@ def main(argv: List[str] = None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m lightgbm_tpu.launch",
         description="Spawn N jax.distributed worker processes running the "
-                    "lightgbm_tpu CLI")
+                    "lightgbm_tpu CLI: a CPU gang on one machine "
+                    "(--devices-per-proc), or the shape of one process per "
+                    "HOST of a pod. Not for the chips of one host: each "
+                    "worker would claim every local chip. There, run ONE "
+                    "process with tree_learner=data num_machines=k (the "
+                    "in-process mesh, parallel/mesh.py).")
     parser.add_argument("-n", "--nproc", type=int, default=2,
                         help="number of worker processes")
     parser.add_argument("--port", type=int, default=0,

@@ -3,9 +3,11 @@
 The reference ships its parser stack as C++ (src/io/parser.cpp); here the
 native module is compiled once per interpreter ABI with plain g++ against
 the CPython headers (no pybind11 dependency) into this package directory,
-then dlopen'd as a normal extension module. Every caller treats a missing
-toolchain or failed build as "no native parser" and falls back to the
-pure-numpy path in io/parser.py.
+then dlopen'd as a normal extension module. The .so is a build product
+(git-ignored): a fresh checkout holds none and builds its own on first
+use. A missing toolchain or failed build is logged once as a Warning;
+callers then parse with the pure-numpy path in io/parser.py, which reads
+the same files to the same values, only slower.
 """
 from __future__ import annotations
 
@@ -15,6 +17,8 @@ import subprocess
 import sys
 import sysconfig
 from typing import Optional
+
+from ..utils.log import Log
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "parser.cpp")
@@ -40,7 +44,12 @@ def _build() -> Optional[str]:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, so)
-    except Exception:  # noqa: BLE001 - toolchain missing/failed: no native
+    except (OSError, subprocess.SubprocessError) as exc:
+        detail = getattr(exc, "stderr", b"") or b""
+        Log.warning("native parser not built (%r%s); text files are parsed "
+                    "by the slower pure-numpy path", exc,
+                    ": " + detail.decode(errors="replace")[-300:]
+                    if detail else "")
         try:
             os.unlink(tmp)
         except OSError:

@@ -29,8 +29,9 @@ global scatter, so the same data movement is phrased as dense tile algebra:
 
 Exactness: values transit the MXU as 8-bit limbs of their raw bits (bf16
 operands — 0/1 one-hot and limbs <= 255 are exact in bf16, and each output
-row receives exactly ONE source row), so payloads are moved bit-exactly at
-full bf16 MXU rate: f32 rows as four limbs, the bin plane as two limbs for
+row receives exactly ONE source row), and the limbs recombine and accumulate
+as integers, so payloads are moved bit-exactly (-0.0 and denormals included)
+at full bf16 MXU rate: f32 rows as four limbs, the bin plane as two limbs for
 int32 (values < 2**16) or ONE limb when the plane is already 8-bit (uint8
 bins, values <= 255) — a 2x cut in the plane's transport matmuls on top of
 the 4x HBM cut of the narrow plane itself. The only lax.sort is the single
@@ -46,10 +47,12 @@ from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import perfmodel, telemetry
+from ..utils import sanitize
 
 # Compaction tile: independent of the histogram tile (DEFAULT_TILE_ROWS);
 # the one-hot P is [tile, tile] so smaller tiles keep VMEM + per-pair FLOPs
@@ -110,13 +113,31 @@ def range_partition_dst(go_left: jax.Array, match: jax.Array,
 
 
 def max_pairs_bound(n_tiles: int, n_classes: int) -> int:
-    """Static upper bound on the pair-list length for DISJOINT class masks.
+    """Static upper bound on the pair-list length (skip pairs included) for
+    the left|right class masks of disjoint position ranges.
 
-    identity pairs: n_tiles. Per class, (in_tile, out_tile) adjacencies of a
-    contiguous destination run <= tiles_touched + out_tiles; summed over
-    disjoint classes both terms are <= n_tiles + 2*n_classes.
+    identity pairs: n_tiles. A class whose destinations are one contiguous
+    run lists, per input tile it touches, one pair plus one more where the
+    run crosses an output-tile boundary inside that tile: <= tiles_touched +
+    out_tiles of them. The two classes of a range are disjoint in ROWS but
+    interleaved in every tile the range overlaps, so both touch all of its
+    tiles: over all ranges tiles_touched sums to <= 2 * (n_tiles +
+    n_classes), and out_tiles to <= n_tiles + n_classes. (Counting each
+    tile once, 3 * n_tiles, overflows as soon as one range spans more than
+    ~4 * n_classes tiles — the root split of any tree over ~90k rows — and
+    the truncated list drops the last output tiles' rows.)
     """
-    return 3 * n_tiles + 4 * n_classes + 8
+    return 4 * n_tiles + 4 * n_classes + 8
+
+
+def _check_pairs_fit(mp: int, n_pairs) -> None:
+    # graftlint: disable=R1 -- host side of jax.debug.callback: n_pairs arrives as a concrete value, and the callback exists only under LGBM_TPU_SANITIZE
+    needed = int(np.asarray(n_pairs))  # not a counted sync (utils/sanitize)
+    if needed > mp:
+        raise ValueError(
+            f"compaction pair list needs {needed} pairs, "
+            f"max_pairs_bound allows {mp}: the truncated list drops rows "
+            "(class masks must be disjoint, per-tile-contiguous ranges)")
 
 
 def build_pair_tables(dst: jax.Array, class_masks: Sequence[jax.Array],
@@ -172,6 +193,10 @@ def build_pair_tables(dst: jax.Array, class_masks: Sequence[jax.Array],
     # share both blocks with their predecessor, so they cost no extra DMA.
     dup = jnp.concatenate([jnp.zeros(1, bool), key[1:] == key[:-1]])
     mp = max_pairs_bound(T, len(class_masks))
+    if sanitize.enabled():
+        # the bound is derived for range_partition_dst's masks; any other
+        # caller that outgrows it would lose rows below without a sign
+        jax.debug.callback(partial(_check_pairs_fit, mp), n_pairs)
     if key.shape[0] < mp:
         pad_n = mp - key.shape[0]
         key = jnp.concatenate([key, jnp.full(pad_n, big, jnp.int32)])
@@ -240,14 +265,23 @@ def _make_compact_kernel(tile: int, gp: int, rc: int, plane8: bool):
             rl = _limbs(rbits, 4, axis=1).astype(jnp.bfloat16)  # [tile, 4*rc]
             orl = jax.lax.dot_general(
                 P, rl, dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32).astype(jnp.int32)
-            obits = (orl[:, :rc]
-                     | (orl[:, rc:2 * rc] << 8)
-                     | (orl[:, 2 * rc:3 * rc] << 16)
-                     | (orl[:, 3 * rc:] << 24))
-            # rows not sourced by this pair recombine to bits 0 == +0.0f;
-            # f32 += 0.0 is exact, so cross-pair accumulation is bit-exact
-            row_out[...] += jax.lax.bitcast_convert_type(obits, jnp.float32)
+                preferred_element_type=jnp.float32)
+            # The low three limbs recombine in f32 (a sum below 2**24 is
+            # exact), the top one by the only integer shift. NOT
+            # `limb2 << 16`: on a v5e (my chip run, PR 22) Mosaic's i32
+            # shift-left by 16 zeroes every value under 128 — what a
+            # bf16 -> f32 widening does to a bf16 denormal — so payloads
+            # lost bits 16..22 wherever bit 23 was clear, and interpret
+            # mode never showed it.
+            low = (orl[:, :rc] + 256.0 * orl[:, rc:2 * rc]
+                   + 65536.0 * orl[:, 2 * rc:3 * rc]).astype(jnp.int32)
+            obits = low | (orl[:, 3 * rc:].astype(jnp.int32) << 24)
+            # rows not sourced by this pair recombine to bits 0, and the
+            # accumulate ORs bits, so no float operation ever touches a
+            # payload: -0.0 and denormals ride along exactly
+            row_out[...] = jax.lax.bitcast_convert_type(
+                jax.lax.bitcast_convert_type(row_out[...], jnp.int32) | obits,
+                jnp.float32)
             if plane8:
                 # single limb: values <= 255 are exact bf16 operands
                 bl = bins_ref[...].astype(jnp.int32).astype(jnp.bfloat16)

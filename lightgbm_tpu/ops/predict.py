@@ -20,7 +20,7 @@ Serving-path machinery on top of the traversal:
   * `predict_raw_early_stop` — device-resident: scores and the active-row
     mask stay on device; the only per-block host sync is one scalar.
   * optional Pallas row-tile traversal behind LGBM_TPU_PREDICT_PALLAS=1
-    (ops/predict_pallas.py, interpret-tested like hist_pallas.py).
+    (ops/predict_pallas.py: interpret-tested; Mosaic refuses it today).
 """
 from __future__ import annotations
 
@@ -38,6 +38,7 @@ import numpy as np
 from .. import perfmodel, telemetry
 from ..common import MISSING_NAN, MISSING_ZERO, K_ZERO_THRESHOLD
 from ..models.tree import Tree
+from ..utils.backend import pallas_interpret
 from ..utils.log import Log
 from ..utils.timer import global_timer
 
@@ -348,12 +349,9 @@ def predict_raw(packed: PackedEnsemble, X: jax.Array,
     if predict_pallas_enabled() and not packed.linear:
         from .predict_pallas import pallas_predict_raw
 
-        # Mosaic compiles on TPU only; elsewhere (CPU tests, GPU) the
-        # opt-in still works end to end through interpret mode
-        interp = jax.default_backend() != "tpu"
         with global_timer.scope("predict_traverse"):
             return pallas_predict_raw(packed, X, num_tree_per_iteration,
-                                      interpret=interp)
+                                      interpret=pallas_interpret())
     with global_timer.scope("predict_traverse"):
         if packed.linear:
             # under jit XLA contracts the linear mul+sum into fmas, a 1-ulp
@@ -505,8 +503,13 @@ def aot_load_bundle(blob: bytes, model_sha256: Optional[str] = None):
     with global_timer.scope("predict_aot_load"):
         for ent in obj.get("entries", ()):
             try:
+                # aot_compile lowers for the default device alone; without
+                # execution_devices the loader binds the executable to
+                # EVERY local device and the first call fails on its shard
+                # count
                 out[ent["key"]] = deserialize_and_load(
-                    ent["payload"], ent["in_tree"], ent["out_tree"])
+                    ent["payload"], ent["in_tree"], ent["out_tree"],
+                    execution_devices=jax.devices()[:1])
             except Exception as exc:  # noqa: BLE001 - refuse the bundle
                 return {}, [f"executable for {ent.get('rows')} rows failed "
                             f"to deserialize: {exc!r}"]

@@ -17,9 +17,13 @@ table for serving-size ensembles (T*I ints) is a few MB — comfortably
 VMEM-resident next to a 512-row X tile. Linear-tree ensembles keep the
 XLA path (the [N, T, K] coefficient gather does not tile this way).
 
-Enabled by LGBM_TPU_PREDICT_PALLAS=1 (ops/predict.py:predict_raw);
-correctness pinned by interpret-mode tests against the XLA path, like
-hist_pallas.py.
+Enabled by LGBM_TPU_PREDICT_PALLAS=1 (ops/predict.py:predict_raw), an
+explicit opt-in; correctness pinned by interpret-mode tests
+(LGBM_TPU_PALLAS_INTERPRET=1) against the XLA path. The v5e compiler
+refuses the body today ("Only 2D gather is supported": the node-table
+gathers), so opting in on a TPU raises that error — it does not fall back
+and is never interpreted unasked (tests/test_chip_compile.py holds it to
+that).
 """
 from __future__ import annotations
 

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common import MISSING_NAN, MISSING_NONE, MISSING_ZERO
+from ..utils.backend import pallas_interpret
 
 K_EPSILON = 1e-15
 K_MIN_GAIN = -np.inf
@@ -185,26 +186,6 @@ def pad_feature_meta(meta: FeatureMeta, f_pad: int) -> FeatureMeta:
     )
 
 
-def _register_barrier_batching() -> None:
-    # jaxlib (as of 0.4.37) ships no vmap rule for optimization_barrier, but
-    # the device learner vmaps find_best_split over leaves and that path
-    # reaches the threshold_l1 barrier below. The barrier is the identity on
-    # values, so batching is trivial: bind on the batched operands and keep
-    # each operand's batch dim unchanged.
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except ImportError:  # future jax: internals moved — assume rule exists
-        return
-    if optimization_barrier_p not in batching.primitive_batchers:
-        def _batcher(args, dims):
-            return optimization_barrier_p.bind(*args), dims
-        batching.primitive_batchers[optimization_barrier_p] = _batcher
-
-
-_register_barrier_batching()
-
-
 def threshold_l1(s, l1):
     # The barrier pins the soft-thresholded gradient to a rounded f32 before
     # it feeds the output division and the gain products. Without it, XLA's
@@ -350,17 +331,18 @@ def per_feature_best(fh: jax.Array, totals: jax.Array, meta: FeatureMeta,
     parallel learners (the reference runs FindBestThresholdSequentially per
     rank feature block, data_parallel_tree_learner.cpp:305+).
 
-    On TPU backends the numeric lanes route to the fused Pallas kernel
-    (ops/scan_pallas.py, bit-identical; LGBM_TPU_SCAN_PALLAS=0 restores this
-    XLA body byte-for-byte). Monotone-constrained scans — the clamped-output
-    gain variant below — always take the XLA body.
+    LGBM_TPU_SCAN_PALLAS=1 routes the numeric lanes to the fused Pallas
+    kernel instead (ops/scan_pallas.py: bit-identical interpreted, refused
+    by Mosaic compiled — an opt-in that raises, not a default).
+    Monotone-constrained scans — the clamped-output gain variant below —
+    always take the XLA body.
     """
     from . import scan_pallas  # local import: scan_pallas has no split dep
     if (constraint is None and fh.dtype == jnp.float32
             and scan_pallas.use_scan_pallas()):
         return scan_pallas.per_feature_best_fused(
             fh, totals, meta, params, feature_mask, penalty,
-            interpret=scan_pallas.interpret_mode())
+            interpret=pallas_interpret())
     l1, l2, min_data, min_hess, min_gain, max_delta = (
         params[0], params[1], params[2], params[3], params[4], params[5])
     F, Bmax, _ = fh.shape

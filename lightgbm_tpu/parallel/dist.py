@@ -113,10 +113,7 @@ def init_distributed(config=None,
         # process computations aren't implemented on the CPU backend");
         # gloo gives the CPU gang real psums — essential for the chaos
         # harness, harmless for the TPU path (knob only affects CPU)
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 - older jaxlib: knob absent
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     try:
         # the coordinator join can block for the whole cluster spin-up;
         # make that visible in perf reports

@@ -305,7 +305,7 @@ class ElasticRuntime:
         import numpy as np
         from jax.sharding import PartitionSpec as P
 
-        from ..utils.compat import shard_map
+        from jax import shard_map
         from .dist import put_global
         from .mesh import data_mesh
 

@@ -32,6 +32,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..config import Config
@@ -51,7 +52,6 @@ from ..treelearner.device import (REC, DeviceTreeLearner, _PendingTree,
 from ..treelearner.serial import (SerialTreeLearner, _LeafState,
                                   device_growth_applies)
 from ..utils import sanitize
-from ..utils.compat import shard_map
 from ..utils.log import Log
 from ..utils.timer import global_timer
 from .dist import (host_value, init_distributed, put_global, put_global_tree,

@@ -21,9 +21,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from ..ops.predict import PackedEnsemble, _predict_raw_fused, validate_tree_count
-from ..utils.compat import shard_map
 from ..utils.timer import global_timer
 from .dist import put_global, put_replicated
 from .mesh import data_mesh, padded_row_count

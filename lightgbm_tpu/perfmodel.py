@@ -207,10 +207,10 @@ PEAK_HBM_BYTES_PER_S: Tuple[Tuple[str, float], ...] = (
 
 # VMEM capacity per core by device kind (bytes), substring-matched like
 # the bandwidth table. graftlint R14 reads this file's AST (no import) to
-# bound every pallas_call's worst-case footprint; the runtime helper below
-# serves bench attribution. The DEFAULT is the floor every kernel must
-# fit: the smallest VMEM of any device the kernels are expected to run on
-# (see /opt guide numbers cited in docs/PERF_NOTES.md).
+# bound every pallas_call's worst-case footprint; nothing reads them at
+# run time. The DEFAULT is the lint's floor every kernel must fit: the
+# smallest VMEM of any device the kernels are expected to run on (see /opt
+# guide numbers cited in docs/PERF_NOTES.md).
 PALLAS_VMEM_BYTES: Tuple[Tuple[str, int], ...] = (
     ("v5 lite", 134217728), ("v5e", 134217728),   # 128 MiB
     ("v7x", 67108864),                            # 64 MiB
@@ -229,24 +229,6 @@ PALLAS_DIM_BOUNDS: Tuple[Tuple[str, int], ...] = (
     ("Gp", 512), ("tile", 1024), ("rc", 16),  # compact planes / row tile / cols
     ("F", 1024), ("C", 32),               # predict feature row / tree outputs
 )
-
-
-def pallas_vmem_bytes(device_kind: str = "") -> int:
-    """VMEM capacity in bytes for a device kind (floor default when the
-    kind is unknown). $LGBM_TPU_VMEM_MIB overrides for calibration."""
-    import os
-
-    env = os.environ.get("LGBM_TPU_VMEM_MIB", "")
-    if env:
-        try:
-            return int(float(env) * 1048576)
-        except ValueError:
-            pass
-    kind = (device_kind or "").lower()
-    for marker, cap in PALLAS_VMEM_BYTES:
-        if marker in kind:
-            return cap
-    return PALLAS_VMEM_DEFAULT_BYTES
 
 
 def peak_bandwidth_bytes_per_s(device_kind: str = "") -> Optional[float]:

@@ -44,10 +44,10 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from ..io.dataset import Dataset as CoreDataset
 from ..parallel.mesh import data_mesh
-from ..utils.compat import shard_map
 from ..utils.log import Log
 from ..utils.timer import global_timer
 from .. import telemetry

@@ -168,7 +168,7 @@ class _RecompileWatcher(logging.Handler):
     """Counts jit cache misses per (function, input shapes) by listening to
     jax's `jax_log_compiles` log line; warns once per function on churn.
 
-    The pxla logger emits "Compiling <fn> with global shapes and types
+    The pxla logger emits "Compiling jit(<fn>) with global shapes and types
     [...]. Argument mapping: ..." per cache miss — the only public hook that
     carries function identity (jax._src.monitoring events do not)."""
 
@@ -222,6 +222,10 @@ class _RecompileWatcher(logging.Handler):
         head, _, rest = msg[len("Compiling "):].partition(
             " with global shapes and types ")
         fn = head.strip() or "<unknown>"
+        if fn.startswith("jit(") and fn.endswith(")"):
+            # jax logs the lowered module's name, "jit(<fn>)"; the registry
+            # and the churn warning key on the function's own name
+            fn = fn[4:-1]
         shapes = rest.split(". Argument mapping", 1)[0].strip()
         self.per_key[(fn, shapes)] += 1
         self.per_fn[fn] += 1

@@ -52,6 +52,7 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from ..models.sample_strategy import DeviceBag
 from ..models.tree import Tree
@@ -62,7 +63,7 @@ from ..ops.split import (SPLIT_FIELDS, ScanMeta, SplitInfo, find_best_split,
                          per_feature_best, reduce_best_record)
 from .. import perfmodel, telemetry
 from ..utils import sanitize
-from ..utils.compat import shard_map
+from ..utils.backend import pallas_interpret
 from ..utils.log import Log
 from ..utils.timer import global_timer
 from .serial import SerialTreeLearner, _leaf_output_host
@@ -250,12 +251,11 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
     if Gp != G:
         bins_p = jnp.pad(bins_p, ((0, Gp - G), (0, 0)), constant_values=0)
     T_hist = Np // DEFAULT_TILE_ROWS
-    # Pallas kernels on TPU backends; the XLA fallback (CPU tests) shares
-    # the forward-map/range logic and differs only in kernel dispatch.
+    # Pallas kernels on a TPU; the XLA bodies (CPU tests) share the
+    # forward-map/range logic and differ only in kernel dispatch.
     # LGBM_TPU_PALLAS_INTERPRET=1 runs the TPU kernel path in interpret
     # mode — CPU-runnable end-to-end coverage of the ragged machinery.
-    interp = os.environ.get("LGBM_TPU_PALLAS_INTERPRET", "").lower() in (
-        "1", "true", "on")
+    interp = pallas_interpret()
     use_kernels = (_use_pallas() or interp) and os.environ.get(
         "LGBM_TPU_HIST_SLOTS", "1").lower() not in ("0", "false", "off")
     pool_dtype = jnp.int32 if quantized else jnp.float32
@@ -1288,8 +1288,12 @@ class DeviceTreeLearner(SerialTreeLearner):
         wave-width controller: each wave partitions + histograms K candidate
         splits but the replay commits only as many as stay globally
         best-first — the measured ratio drives the next tree's K
-        (ROADMAP item 1; split decisions are K-invariant, so only the
-        amount of speculative work changes, never the model)."""
+        (ROADMAP item 1; split decisions are K-invariant given the same
+        histogram sums, so only the amount of speculative work changes,
+        never the model: exact with use_quantized_grad, and in float up to
+        the summation order of a backend whose histogram contraction
+        depends on the 3*K output width — XLA:CPU's does,
+        tests/test_device_learner.py)."""
         from .. import telemetry, tracing
         n_waves = int(pending.n_waves)
         wave_k = pending.wave_k or self.wave_k

@@ -40,6 +40,7 @@ from ..ops.split import (FeatureMeta, SplitInfo, bins_to_bitset,
 from .cegb import CEGB
 from .col_sampler import ColSampler
 from .. import perfmodel, telemetry
+from ..utils.backend import on_tpu
 from ..utils.log import Log
 from ..utils.timer import global_timer
 
@@ -610,33 +611,53 @@ def _leaf_output_host(sum_g: float, sum_h: float, l1: float, l2: float,
 
 def device_growth_applies(device_type: str, config: Config,
                           dataset: Dataset) -> bool:
-    """Whether the on-device whole-tree wave learner can serve this config.
+    """Whether the on-device whole-tree wave learner grows this config's
+    trees, said out loud when it does not.
 
     The wave learner trades O(leaf) index gathers for O(N) static-shape
     masked histograms — near-free on the MXU, slow on the CPU backend — so
-    it is selected on accelerators only; device_type=cpu forces the
-    host-driven learner regardless of the attached backend (device_type
-    defaults to "auto": see Config._post_process). Shared by the serial
-    factory below and the data-parallel factory (parallel/learners.py),
-    which stacks its sharded grower on the same device-growth conditions.
+    it is for a TPU only. device_type=cpu forces the host-driven learner;
+    an explicit device_type=tpu on a machine without one is fatal, never a
+    quiet host run; unset ("auto": see Config._post_process) chooses, and
+    logs why whenever the answer is the host loop. A backend that fails to
+    initialise raises out of on_tpu(). Shared by the serial factory below
+    and the data-parallel factory (parallel/learners.py), which stacks its
+    sharded grower on the same device-growth conditions.
     """
-    try:
-        on_accelerator = jax.default_backend() not in ("cpu",)
-    except RuntimeError:
-        on_accelerator = False
-    has_cat = any(dataset.mappers[f].bin_type == 1
-                  for f in dataset.used_features)
+    if device_type == "cpu":
+        return False
+    if not on_tpu():
+        if device_type == "tpu":
+            Log.fatal("device_type=tpu was asked for but the default JAX "
+                      "device is %r: no TPU is attached to this process "
+                      "(device_type=cpu runs the host learner)",
+                      jax.devices()[0].platform)
+        Log.info("device_type=%s: no TPU attached, growing trees with the "
+                 "host-driven learner", device_type)
+        return False
+    reasons = []
+    if any(dataset.mappers[f].bin_type == 1 for f in dataset.used_features):
+        reasons.append("categorical features")
     # per-node feature masks / per-leaf bounds and penalties need the
     # host-driven loop for now
-    needs_host = (config.feature_fraction_bynode < 1.0
-                  or bool(config.interaction_constraints)
-                  or bool(dataset.monotone_constraints
-                          and any(dataset.monotone_constraints))
-                  or CEGB.enabled(config)
-                  or config.linear_tree
-                  or bool(config.forcedsplits_filename))
-    return (device_type != "cpu" and on_accelerator and not has_cat
-            and not needs_host)
+    if config.feature_fraction_bynode < 1.0:
+        reasons.append("feature_fraction_bynode")
+    if config.interaction_constraints:
+        reasons.append("interaction_constraints")
+    if dataset.monotone_constraints and any(dataset.monotone_constraints):
+        reasons.append("monotone_constraints")
+    if CEGB.enabled(config):
+        reasons.append("cegb_*")
+    if config.linear_tree:
+        reasons.append("linear_tree")
+    if config.forcedsplits_filename:
+        reasons.append("forcedsplits_filename")
+    if reasons:
+        say = Log.warning if device_type == "tpu" else Log.info
+        say("device_type=%s: the device learner does not support %s yet, "
+            "growing trees with the host-driven learner", device_type,
+            ", ".join(reasons))
+    return not reasons
 
 
 def create_tree_learner(learner_type: str, device_type: str, config: Config,

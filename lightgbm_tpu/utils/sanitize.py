@@ -33,6 +33,11 @@ on a real run —
   re-executed cached jit does not re-trace, so sequences are compared per
   distinct traced program, not per dispatch.
 
+* **Compaction pair-list overflow.** ops/compact_pallas.py sizes its pair
+  list from a static bound derived for the learner's range masks; with the
+  sanitizer on, a list that outgrows it raises from a host callback
+  instead of being truncated (and rows dropped) in silence.
+
 Known gap: `np.asarray(arr)` reaches the host through the buffer protocol
 without calling any patchable `jax.Array` method (patching `__array__` on
 ArrayImpl does not intercept it), so asarray pulls are invisible to the

@@ -1,0 +1,187 @@
+"""Ask the v5e's compiler, without a chip, whether the training path lowers.
+
+Interpret mode cannot see what Mosaic and XLA:TPU refuse (an unimplemented
+primitive, a misaligned block, a program that does not fit 16 GB); these
+ahead-of-time compiles for a DESCRIBED chip can, at HIGGS's widths: 28
+features -> a uint8[32, N] plane, max_bin=255, 3 gh channels, the default
+wave width 21, N = 2^20. Nothing runs, so they say nothing about results or
+times — `python chip_smoke.py` on a chip does.
+
+The topology is described inside a module-scoped fixture of THIS file and
+nowhere else (only one process may hold the TPU library; see the
+on-chip-measurement guide), every compile happens in the test's own process,
+and the persistent compilation cache is off around them: an executable
+compiled for an absent chip cannot be read back.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from lightgbm_tpu.config import Config
+from lightgbm_tpu.io.dataset import Dataset as CoreDataset
+from lightgbm_tpu.ops import histogram, scan_pallas
+from lightgbm_tpu.ops.compact_pallas import (COMPACT_TILE, _pallas_compact_call,
+                                             max_pairs_bound)
+from lightgbm_tpu.ops.hist_pallas import (DEFAULT_TILE_ROWS, pallas_histogram,
+                                          pallas_histogram_slots_ragged)
+from lightgbm_tpu.ops.predict import PackedEnsemble, _predict_raw_fused
+from lightgbm_tpu.ops.predict_pallas import pallas_predict_raw
+from lightgbm_tpu.treelearner import device as device_mod
+
+N = 1 << 20
+FEATURES, GROUPS_PADDED, BINS, WAVE_K = 28, 32, 255, 21
+HBM_BYTES = int(15.75 * 2 ** 30)  # what the v5e compiler admits a program
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def on_chip(topo):
+    """shape, dtype -> an abstract array placed on one described chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def _mosaic_calls(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_dense_histogram_kernel_compiles(on_chip, quantized):
+    gh = jnp.int8 if quantized else jnp.float32
+    compiled = pallas_histogram.lower(
+        on_chip((FEATURES, N), jnp.uint8), on_chip((N, 3), gh),
+        num_bins=BINS, quantized=quantized, interpret=False).compile()
+    assert _mosaic_calls(compiled) == 1
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("n_slots", [1, WAVE_K, 2 * WAVE_K])
+def test_ragged_histogram_kernel_compiles(on_chip, n_slots, quantized):
+    """1 slot is the root pass, WAVE_K the wave's smaller children, and
+    2 * WAVE_K the widest slot block the 4 MiB output budget still keeps on
+    the uint8 plane at 255 bins."""
+    tiles = N // DEFAULT_TILE_ROWS
+    compiled = pallas_histogram_slots_ragged.lower(
+        on_chip((GROUPS_PADDED, N), jnp.uint8), on_chip((N, 3), jnp.float32),
+        on_chip((N,), jnp.int32), on_chip((tiles,), jnp.int32),
+        on_chip((1,), jnp.int32), num_bins=BINS, n_slots=n_slots,
+        quantized=quantized, interpret=False).compile()
+    assert _mosaic_calls(compiled) == 1
+
+
+@pytest.mark.parametrize("plane", [jnp.uint8, jnp.int32])
+def test_compaction_kernel_compiles(on_chip, plane):
+    """uint8 is HIGGS's plane; int32 (two limbs) is what a dataset whose
+    EFB bundles pass 256 bins a group gets with the same default settings."""
+    pairs = max_pairs_bound(N // COMPACT_TILE, 2 * WAVE_K)
+    compiled = _pallas_compact_call.lower(
+        on_chip((GROUPS_PADDED, N), plane), on_chip((N, 5), jnp.float32),
+        on_chip((N,), jnp.int32), on_chip((pairs,), jnp.int32),
+        on_chip((pairs,), jnp.int32), on_chip((pairs,), jnp.int32),
+        on_chip((1,), jnp.int32), tile=COMPACT_TILE,
+        interpret=False).compile()
+    assert _mosaic_calls(compiled) == 1
+
+
+def _packed_500x255(on_chip) -> PackedEnsemble:
+    T, L = 500, 255
+
+    def node(dtype):
+        return on_chip((T, L - 1), dtype)
+
+    return PackedEnsemble(
+        split_feature=node(jnp.int32), threshold=node(jnp.float32),
+        decision_type=node(jnp.int32), left_child=node(jnp.int32),
+        right_child=node(jnp.int32), leaf_value=on_chip((T, L), jnp.float32),
+        cat_words=on_chip((1,), jnp.uint32), cat_offset=node(jnp.int32),
+        cat_n_words=node(jnp.int32), num_leaves=on_chip((T,), jnp.int32),
+        max_depth=24, num_trees=T)
+
+
+def test_predict_program_fits_at_the_streaming_chunk(on_chip):
+    """Booster.predict streams 2^18-row chunks; the fused XLA traversal of a
+    500-tree x 255-leaf ensemble must fit the chip at that size (at 2^20
+    rows in one shot the compiler refuses it: 20.4 GiB)."""
+    compiled = _predict_raw_fused.lower(
+        _packed_500x255(on_chip), on_chip((1 << 18, FEATURES), jnp.float32),
+        num_tree_per_iteration=1).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+
+
+# The two opt-in kernels Mosaic refuses today. Neither is reachable with
+# default settings; each raises, compiled, instead of falling back. When a
+# JAX release lowers one of them this test fails: turn it into a compile
+# test like the ones above and revisit the kernel's default.
+
+def test_opt_in_scan_kernel_is_refused_not_hidden(on_chip):
+    with pytest.raises(NotImplementedError, match="cumsum"):
+        scan_pallas.fused_split_scan.lower(
+            on_chip((3, GROUPS_PADDED, 256), jnp.float32),
+            on_chip((GROUPS_PADDED, scan_pallas.REC_PAD), jnp.float32),
+            on_chip((GROUPS_PADDED, 256), jnp.float32),
+            interpret=False).compile()
+
+
+def test_opt_in_predict_kernel_is_refused_not_hidden(on_chip):
+    with pytest.raises(NotImplementedError, match="gather"):
+        pallas_predict_raw.lower(
+            _packed_500x255(on_chip), on_chip((1 << 16, FEATURES),
+                                              jnp.float32),
+            num_tree_per_iteration=1, interpret=False).compile()
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("quantized", [False, True])
+def test_whole_tree_program_compiles_and_fits(on_chip, monkeypatch, quantized):
+    """grow_tree_on_device whole, ~45 s a compile. The learner asks
+    on_tpu() — the CPU, in this process — so the test answers for it."""
+    monkeypatch.setattr(histogram, "on_tpu", lambda: True)
+    monkeypatch.delenv("LGBM_TPU_PALLAS_INTERPRET", raising=False)
+    rng = np.random.default_rng(0)
+    X = rng.standard_normal((4096, FEATURES), dtype=np.float32)
+    cfg = Config({"objective": "binary", "num_leaves": 255, "max_bin": BINS,
+                  "min_data_in_leaf": 100, "verbosity": -1})
+    ds = CoreDataset.from_matrix(X, label=(X[:, 0] > 0).astype(np.float64),
+                                 config=cfg)
+    learner = device_mod.DeviceTreeLearner(cfg, ds)
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: on_chip(a.shape, a.dtype),
+                                      tree)
+
+    compiled = device_mod.grow_tree_on_device.lower(
+        on_chip((FEATURES, N), jnp.uint8),
+        on_chip((N, 3), jnp.int8 if quantized else jnp.float32),
+        on_chip((N,), jnp.int32), abstract(learner.meta),
+        abstract(learner.tables), abstract(learner.params_dev),
+        on_chip((FEATURES,), jnp.bool_), num_leaves=255,
+        num_bins=learner.group_bin_padded, max_depth=cfg.max_depth,
+        quantized=quantized,
+        scale_vec=on_chip((3,), jnp.float32) if quantized else None,
+        batch=WAVE_K, bagged=False).compile()
+    # root histogram, wave histogram, wave compaction
+    assert _mosaic_calls(compiled) == 3
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES

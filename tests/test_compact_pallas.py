@@ -3,6 +3,7 @@
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from lightgbm_tpu.ops.compact_pallas import (
@@ -66,6 +67,8 @@ def test_compact_pallas_bit_exact(rng, name, ranges, tile):
     bins = rng.randint(0, 60000, size=(gp, n)).astype(np.int32)
     row = rng.randn(n, rc).astype(np.float32)
     row[:, 3] = np.arange(n)  # a perm-style integer column rides along
+    # bit patterns a float accumulate would not carry: the kernel ORs bits
+    row[::5, 0], row[1::5, 0] = -0.0, 1e-39
     moved = match.any(axis=1)
     ours_b, ours_r = compact_rows(
         jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst),
@@ -77,6 +80,34 @@ def test_compact_pallas_bit_exact(rng, name, ranges, tile):
     ref_r[dst] = row
     np.testing.assert_array_equal(np.asarray(ours_b), ref_b)
     # bit-exact: limb transport must preserve f32 payloads exactly
+    np.testing.assert_array_equal(
+        np.asarray(ours_r).view(np.uint32), ref_r.view(np.uint32))
+
+
+def test_pair_list_holds_a_range_spanning_many_tiles(rng):
+    """A tree's root split is ONE range over every tile, its left and right
+    rows interleaved in each: close to 4 pairs per tile. A pair list sized
+    3 per tile truncated there, dropping the last output tiles' rows — on
+    every tree over ~90k rows, and in no test, since none spanned more
+    than 8 tiles."""
+    n, gp, rc, tile = 16384, 32, 5, 256
+    go_left = rng.rand(n) < 0.5
+    dst, _, cm, match = _dst(go_left, [(0, n)], n)
+    masks = [jnp.asarray(m) for m in cm]
+    moved = jnp.asarray(match.any(axis=1))
+    *_, n_pairs = build_pair_tables(jnp.asarray(dst), masks, moved, tile)
+    assert 3 * (n // tile) < int(n_pairs[0]) <= max_pairs_bound(n // tile, 2)
+    bins = rng.randint(0, 256, size=(gp, n)).astype(np.uint8)
+    row = rng.randn(n, rc).astype(np.float32)
+    row[:, 3] = np.arange(n)
+    ours_b, ours_r = compact_rows(
+        jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst), masks, moved,
+        tile=tile, use_pallas=True, interpret=True)
+    ref_b = np.zeros_like(bins)
+    ref_b[:, dst] = bins
+    ref_r = np.zeros_like(row)
+    ref_r[dst] = row
+    np.testing.assert_array_equal(np.asarray(ours_b), ref_b)
     np.testing.assert_array_equal(
         np.asarray(ours_r).view(np.uint32), ref_r.view(np.uint32))
 
@@ -197,3 +228,19 @@ def test_pair_table_bound_and_coverage(rng):
     keep = live_copy < 2
     pairs = list(zip(live_in[keep].tolist(), live[keep].tolist()))
     assert len(pairs) == len(set(pairs))
+
+
+def test_pair_list_overflow_is_loud_under_sanitize(rng, monkeypatch):
+    """Masks that break the per-tile-contiguity contract (three classes
+    scattered by a random permutation: 7 pairs per tile) outgrow the static
+    bound. The list is truncated either way; LGBM_TPU_SANITIZE=1 says so
+    instead of dropping the rows in silence."""
+    n, tile = 8192, 256
+    dst = jnp.asarray(rng.permutation(n).astype(np.int32))
+    masks = [jnp.arange(n) % 3 == c for c in range(3)]
+    moved = jnp.ones(n, bool)
+    *_, n_pairs = build_pair_tables(dst, masks, moved, tile)
+    assert int(n_pairs[0]) > max_pairs_bound(n // tile, len(masks))
+    monkeypatch.setenv("LGBM_TPU_SANITIZE", "1")
+    with pytest.raises(Exception, match="the truncated list drops rows"):
+        jax.block_until_ready(build_pair_tables(dst, masks, moved, tile))

@@ -321,17 +321,31 @@ def _adaptive_run(X, y, params, n_iters, adaptive, monkeypatch):
 
 
 @pytest.mark.slow  # tier-1 budget triage: heavy full-training driver, runs in the slow tier
-def test_adaptive_wave_width_byte_identical_and_cheaper(rng, monkeypatch):
+@pytest.mark.parametrize("plane", ["float", "quantized"])
+def test_adaptive_wave_width_byte_identical_and_cheaper(rng, monkeypatch,
+                                                        plane):
     """The wave-width controller only changes how much speculative work a
     wave dispatches, never which splits win: split decisions are replayed
     exact best-first from the same records, so the adaptive run must
     produce byte-identical trees while histogramming measurably fewer
-    rows on a low-commit-rate workload (ISSUE round-8 acceptance)."""
+    rows on a low-commit-rate workload (ISSUE round-8 acceptance).
+
+    Byte-identical wherever the histogram sums do not depend on K: always
+    with use_quantized_grad (integer sums are exact). In float the XLA
+    body is one one-hot contraction whose output is 3*K columns wide, and
+    XLA:CPU (jax 0.9) sums the rows in another order at width 63 (K = 21)
+    than at 12/24/48 (K = 4/8/16) — 678 of 6120 sums differ, up to 4e-5
+    relative, for the same rows in slot 0. So there the trees agree to
+    that rounding only: ULPs on gains and outputs, a near-tie threshold
+    between empty bins may flip, predictions within the device-vs-serial
+    tolerance. (The Pallas kernel on a TPU accumulates per slot; whether
+    it is K-invariant in float there: PERF.md, open questions.)"""
     n = 1200
     X = rng.randn(n, 8)
     y = 2 * X[:, 0] - X[:, 1] + np.sin(3 * X[:, 2]) + 0.1 * rng.randn(n)
     params = {"objective": "regression", "num_leaves": 31,
-              "min_data_in_leaf": 5, "verbosity": -1}
+              "min_data_in_leaf": 5, "verbosity": -1,
+              "use_quantized_grad": plane == "quantized"}
     b_on, l_on, ks_on, rows_on = _adaptive_run(
         X, y, params, 6, True, monkeypatch)
     b_off, l_off, ks_off, rows_off = _adaptive_run(
@@ -345,10 +359,15 @@ def test_adaptive_wave_width_byte_identical_and_cheaper(rng, monkeypatch):
                for k in ks_on), ks_on
     # fewer speculative leaves per wave -> fewer rows histogrammed
     assert rows_on < rows_off, (rows_on, rows_off)
-    _assert_same_models(b_on, b_off)
-    np.testing.assert_array_equal(
-        np.asarray(b_on.predict(X, raw_score=True)),
-        np.asarray(b_off.predict(X, raw_score=True)))
+    p_on = np.asarray(b_on.predict(X, raw_score=True))
+    p_off = np.asarray(b_off.predict(X, raw_score=True))
+    if plane == "quantized":
+        _assert_same_models(b_on, b_off)
+        np.testing.assert_array_equal(p_on, p_off)
+    else:
+        assert ([t.num_leaves for t in b_on.models]
+                == [t.num_leaves for t in b_off.models])
+        np.testing.assert_allclose(p_on, p_off, rtol=1e-4, atol=1e-5)
     # the controller publishes its state as a gauge
     from lightgbm_tpu.utils.timer import global_timer
     assert global_timer.counters.get("wave_k") == l_off.wave_k
