@@ -8,12 +8,15 @@ lightgbm-compatible Python API.
 from .config import Config
 from .models.tree import Tree
 from .models.serialize import GBDTModel
+from .tracing import install_compile_listener
 from .utils.backend import configure_compile_cache
 from .utils.log import register_log_callback, LightGBMError
 
 # the one place every entry point passes through (lgb.train, the CLI,
 # bench.py, the serving layer): see utils/backend.py for the rule
 configure_compile_cache()
+# every compile and cache load of the process becomes a `compile` flight note
+install_compile_listener()
 
 __version__ = "0.1.0"
 

@@ -20,7 +20,7 @@ from .basic import Booster, Dataset
 from .callback import CallbackEnv, EarlyStopException
 from .config import key_alias_transform
 from .utils.log import Log, LightGBMError
-from .utils.timer import global_timer
+from .utils.timer import SPAN_ITERATION, global_timer
 
 
 _INIT_SCORE_CHUNK = 262_144  # rows densified at a time for sparse inputs
@@ -167,7 +167,7 @@ def _train_impl(params, train_set, num_boost_round, valid_sets, valid_names,
     booster.best_iteration = -1
     is_finished = False
     # §5 tracing: _train_loop wraps the boosting loop in a jax.profiler
-    # trace when LGBM_TPU_PROFILE(_DIR) is set (utils/profile.maybe_trace),
+    # trace when LGBM_TPU_PROFILE is set (utils/profile.maybe_trace),
     # composing with LGBM_TPU_TIMETAG per-scope TraceAnnotations
     try:
         is_finished = _train_loop(
@@ -210,31 +210,35 @@ def _train_loop_inner(booster, params, feval, fobj, init_iteration,
         it_span.attrs["iteration"] = int(i)
         counters_before = (dict(global_timer.counters)
                            if telemetry.enabled() else None)
-        for cb in callbacks_before:
-            cb(CallbackEnv(model=booster, params=params, iteration=i,
-                           begin_iteration=init_iteration,
-                           end_iteration=init_iteration + num_boost_round,
-                           evaluation_result_list=None))
-        is_finished = booster.update(fobj=fobj)
-        t_boost_end = time.perf_counter()
-        it_span.add_stage("boost", t_boost_end - it_t0)
-
-        evaluation_result_list = []
-        if booster._gbdt.valid_sets or booster._gbdt.train_metrics:
-            if booster._train_data_name == "training" and _wants_train_metric(params):
-                evaluation_result_list.extend(booster.eval_train(feval))
-            evaluation_result_list.extend(booster.eval_valid(feval))
-        try:
-            for cb in callbacks_after:
+        # the Span's interval is the `iteration` host span too: the one root
+        # `boosting`, `bagging`, `tree_train` and `update_score` nest under,
+        # whatever the boosting type (GBDT, DART, RF; sync or async path)
+        with global_timer.scope(SPAN_ITERATION):
+            for cb in callbacks_before:
                 cb(CallbackEnv(model=booster, params=params, iteration=i,
                                begin_iteration=init_iteration,
                                end_iteration=init_iteration + num_boost_round,
-                               evaluation_result_list=evaluation_result_list))
-        except EarlyStopException as earlyStopException:
-            booster.best_iteration = earlyStopException.best_iteration + 1
-            evaluation_result_list = earlyStopException.best_score
-            is_finished = True
-        it_span.add_stage("eval", time.perf_counter() - t_boost_end)
+                               evaluation_result_list=None))
+            is_finished = booster.update(fobj=fobj)
+            t_boost_end = time.perf_counter()
+            it_span.add_stage("boost", t_boost_end - it_t0)
+
+            evaluation_result_list = []
+            if booster._gbdt.valid_sets or booster._gbdt.train_metrics:
+                if booster._train_data_name == "training" and _wants_train_metric(params):
+                    evaluation_result_list.extend(booster.eval_train(feval))
+                evaluation_result_list.extend(booster.eval_valid(feval))
+            try:
+                for cb in callbacks_after:
+                    cb(CallbackEnv(model=booster, params=params, iteration=i,
+                                   begin_iteration=init_iteration,
+                                   end_iteration=init_iteration + num_boost_round,
+                                   evaluation_result_list=evaluation_result_list))
+            except EarlyStopException as earlyStopException:
+                booster.best_iteration = earlyStopException.best_iteration + 1
+                evaluation_result_list = earlyStopException.best_score
+                is_finished = True
+            it_span.add_stage("eval", time.perf_counter() - t_boost_end)
         it_span.finish()
         if counters_before is not None:
             _emit_iteration_record(booster, i, evaluation_result_list,

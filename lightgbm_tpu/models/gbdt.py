@@ -32,7 +32,10 @@ from ..parallel import elastic
 from ..treelearner import create_tree_learner
 from ..utils import faults, sanitize
 from ..utils.log import Log
-from ..utils.timer import global_timer
+from ..utils.timer import (SCOPE_GRADIENTS, SCOPE_UPDATE_SCORE,
+                           SPAN_PREDICT_CALL, SPAN_PREDICT_FETCH,
+                           SPAN_PREDICT_TRAVERSE, SPAN_PREDICT_UPLOAD,
+                           global_timer)
 from .sample_strategy import DeviceBag, create_sample_strategy
 from .serialize import GBDTModel
 from .tree import Tree
@@ -74,11 +77,12 @@ def _apply_split_log_to_score(score: jax.Array, rec_store: jax.Array,
         wn = jnp.where(valid, t + 1, L)
         return lv.at[wb].set(row[14]).at[wn].set(row[15])
 
-    lv = jax.lax.fori_loop(0, rec_store.shape[0], body,
-                           jnp.zeros(L + 1, jnp.float32))
-    lv = lv[:L] * rate
-    return score + jnp.where(
-        leaf_ids >= 0, lv[jnp.clip(leaf_ids, 0, L - 1)], 0.0)
+    with jax.named_scope(SCOPE_UPDATE_SCORE):
+        lv = jax.lax.fori_loop(0, rec_store.shape[0], body,
+                               jnp.zeros(L + 1, jnp.float32))
+        lv = lv[:L] * rate
+        return score + jnp.where(
+            leaf_ids >= 0, lv[jnp.clip(leaf_ids, 0, L - 1)], 0.0)
 
 
 def _colocate(arr: jax.Array, ref: jax.Array) -> jax.Array:
@@ -202,7 +206,8 @@ class GBDT:
         """score [N] (C==1) or [C, N] -> (grad, hess) matching shapes — the
         whole-iteration gradient pass (kept unpacked so the sample strategy
         can rescale GOSS's small-gradient rows before packing)."""
-        return self.objective.get_gradients(score)
+        with jax.named_scope(SCOPE_GRADIENTS):
+            return self.objective.get_gradients(score)
 
     def prepare_training_score(self) -> None:
         """Hook run before custom gradients read the training score
@@ -616,48 +621,61 @@ class GBDT:
         C = self.num_tree_per_iteration
         n = X.shape[0]
         chunk = stream_chunk_rows(n, chunk_rows)
-        if early_stop is not None and packed.num_trees > 0:
-            from ..ops.predict import predict_raw_early_stop
 
-            freq, margin = early_stop
-            out = predict_raw_early_stop(
-                packed, jnp.asarray(X, dtype=dtype), C, freq, margin)
-        elif packed.num_trees > 0 and chunk_rows is not None and chunk > 0:
-            # explicit pred_chunk_rows wins over auto-sharding
-            out = predict_raw_streamed(
-                packed, np.asarray(X, dtype=np.dtype(dtype)), C, chunk, dtype)
-        elif packed.num_trees > 0 and not packed.linear \
-                and self._sharded_predict_enabled(n, shard_rows):
-            # linear ensembles keep single-chip dispatch: their score math
-            # runs eagerly for bit-stability (ops/predict.predict_raw)
-            from ..parallel.predict import predict_raw_sharded
+        def upload() -> jax.Array:
+            with global_timer.scope(SPAN_PREDICT_UPLOAD):
+                return jnp.asarray(X, dtype=dtype)
 
-            out = predict_raw_sharded(
-                packed, np.asarray(X, dtype=np.dtype(dtype)), C)
-        elif chunk > 0 and packed.num_trees > 0:
-            out = predict_raw_streamed(
-                packed, np.asarray(X, dtype=np.dtype(dtype)), C, chunk, dtype)
-        else:
-            # serving warm start: a key-matched AOT executable answers
-            # without consulting (or populating) the jit cache — a cold
-            # replica's first bucket-shaped request skips the XLA compile
-            fn = None
-            if packed.num_trees > 0 and not packed.linear:
-                from ..ops.predict import predict_pallas_enabled
+        # the device part of the call: upload, traverse, fetch (the streamed
+        # path opens the three per chunk, under its predict_chunk spans)
+        with global_timer.scope(SPAN_PREDICT_CALL):
+            if early_stop is not None and packed.num_trees > 0:
+                from ..ops.predict import predict_raw_early_stop
 
-                if not predict_pallas_enabled():
-                    fn = self._predictor.aot_get(
-                        packed, n, X.shape[1], C, np.dtype(dtype))
-            if fn is not None:
-                with global_timer.scope("predict_traverse"):
-                    out = fn(packed, jnp.asarray(X, dtype=dtype))
+                freq, margin = early_stop
+                out = predict_raw_early_stop(packed, upload(), C, freq,
+                                             margin)
+            elif packed.num_trees > 0 and chunk_rows is not None \
+                    and chunk > 0:
+                # explicit pred_chunk_rows wins over auto-sharding
+                out = predict_raw_streamed(
+                    packed, np.asarray(X, dtype=np.dtype(dtype)), C, chunk,
+                    dtype)
+            elif packed.num_trees > 0 and not packed.linear \
+                    and self._sharded_predict_enabled(n, shard_rows):
+                # linear ensembles keep single-chip dispatch: their score
+                # math runs eagerly for bit-stability (ops/predict.predict_raw)
+                from ..parallel.predict import predict_raw_sharded
+
+                out = predict_raw_sharded(
+                    packed, np.asarray(X, dtype=np.dtype(dtype)), C)
+            elif chunk > 0 and packed.num_trees > 0:
+                out = predict_raw_streamed(
+                    packed, np.asarray(X, dtype=np.dtype(dtype)), C, chunk,
+                    dtype)
             else:
-                out = predict_raw(packed, jnp.asarray(X, dtype=dtype), C)
-        if self.average_output and packed.num_trees > 0:
-            out = out / (packed.num_trees // C)
-        if not raw_score and self.objective is not None:
-            out = self.objective.convert_output(out)
-        res = np.asarray(out)
+                # serving warm start: a key-matched AOT executable answers
+                # without consulting (or populating) the jit cache — a cold
+                # replica's first bucket-shaped request skips the XLA compile
+                fn = None
+                if packed.num_trees > 0 and not packed.linear:
+                    from ..ops.predict import predict_pallas_enabled
+
+                    if not predict_pallas_enabled():
+                        fn = self._predictor.aot_get(
+                            packed, n, X.shape[1], C, np.dtype(dtype))
+                xd = upload()
+                if fn is not None:
+                    with global_timer.scope(SPAN_PREDICT_TRAVERSE):
+                        out = fn(packed, xd)
+                else:
+                    out = predict_raw(packed, xd, C)
+            if self.average_output and packed.num_trees > 0:
+                out = out / (packed.num_trees // C)
+            if not raw_score and self.objective is not None:
+                out = self.objective.convert_output(out)
+            with global_timer.scope(SPAN_PREDICT_FETCH):
+                res = np.asarray(out)
         return res[:, 0] if res.shape[1] == 1 else res
 
     def predict_leaf_index(self, X: np.ndarray, num_iteration: int = 0,

@@ -339,6 +339,10 @@ def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
             jax.ShapeDtypeStruct((N, rc), jnp.float32),
         ],
         interpret=interpret,
+        # the jitted wrapper's own name, not compact_rows': the trace names
+        # the custom call after it, and the benchmark's
+        # train.compact_kernel_ms_per_tree finds it by ^_pallas_compact_call
+        name="_pallas_compact_call",
         **kwargs,
     )(pair_in, pair_out, is_copy, n_pairs, bins_p, row_p,
       dst.reshape(N, 1))

@@ -174,6 +174,7 @@ def pallas_histogram(bins: jax.Array, gh: jax.Array, num_bins: int,
         out_shape=jax.ShapeDtypeStruct((g_blocks * GB, CH, num_bins),
                                        acc_dtype),
         interpret=interpret,
+        name="pallas_histogram",
     )(bins, gh)
     return out[:G].transpose(0, 2, 1)  # [G, B, CH]; 172KB, free vs the dot
 
@@ -276,6 +277,7 @@ def pallas_histogram_slots(bins: jax.Array, gh: jax.Array, slot: jax.Array,
         out_shape=jax.ShapeDtypeStruct((g_blocks * GB, SC, num_bins),
                                        acc_dtype),
         interpret=interpret,
+        name="pallas_histogram_slots",
     )(bins, gh, slot)
     return out[:G].transpose(0, 2, 1)  # [G, B, SC]
 
@@ -407,6 +409,7 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
         out_shape=jax.ShapeDtypeStruct((g_blocks * GB, SC, num_bins),
                                        acc_dtype),
         interpret=interpret,
+        name="pallas_histogram_slots_ragged",
     )(tiles.astype(jnp.int32), n_active.astype(jnp.int32),
       bins, gh.astype(jnp.float32), slot)
     return out[:G].transpose(0, 2, 1)  # [G, B, SC]

@@ -40,7 +40,11 @@ from ..common import MISSING_NAN, MISSING_ZERO, K_ZERO_THRESHOLD
 from ..models.tree import Tree
 from ..utils.backend import pallas_interpret
 from ..utils.log import Log
-from ..utils.timer import global_timer
+from ..utils.timer import (SCOPE_ACCUMULATE, SCOPE_DECIDE,
+                           SCOPE_FEATURE_GATHER, SCOPE_LEAF_VALUES,
+                           SCOPE_NODE_GATHER, SPAN_PREDICT_CHUNK,
+                           SPAN_PREDICT_FETCH, SPAN_PREDICT_TRAVERSE,
+                           SPAN_PREDICT_UPLOAD, global_timer)
 
 _EPS = K_ZERO_THRESHOLD
 
@@ -224,33 +228,43 @@ def forest_level_step(X: jax.Array, node: jax.Array, sf: jax.Array,
     path and the Pallas row-tile kernel (ops/predict_pallas.py)."""
     I = sf.shape[1]
     T = sf.shape[0]
-    tree_base = jnp.arange(T, dtype=jnp.int32)[None, :] * I
-    active = node >= 0
-    nd = tree_base + jnp.maximum(node, 0)  # flat [N, T] into [T*I] tables
-    feat = sf.reshape(-1)[nd]
-    d = dt.reshape(-1)[nd]
-    fval = jnp.take_along_axis(X, feat, axis=1)  # ONE X gather per level
-    is_cat = (d & 1) > 0
-    default_left = (d & 2) > 0
-    missing_type = (d >> 2) & 3
-    # --- numerical decision (tree.h:338-355)
-    is_nan = jnp.isnan(fval)
-    fval_num = jnp.where(is_nan & (missing_type != MISSING_NAN), 0.0, fval)
-    is_missing = ((missing_type == MISSING_ZERO) & (jnp.abs(fval_num) <= _EPS)) | (
-        (missing_type == MISSING_NAN) & jnp.isnan(fval_num))
-    go_left_num = jnp.where(is_missing, default_left,
-                            fval_num <= th.reshape(-1)[nd])
-    # --- categorical decision (tree.h:375-388)
-    int_fval = jnp.where(is_nan, -1, fval.astype(jnp.int32))
-    word_idx = jnp.clip(int_fval, 0, None) // 32
-    bit_idx = jnp.clip(int_fval, 0, None) % 32
-    in_range = (int_fval >= 0) & (word_idx < cn.reshape(-1)[nd])
-    word = cat_words[jnp.clip(co.reshape(-1)[nd] + word_idx, 0,
-                              cat_words.shape[0] - 1)]
-    go_left_cat = in_range & (((word >> bit_idx.astype(jnp.uint32)) & 1) > 0)
-    go_left = jnp.where(is_cat, go_left_cat, go_left_num)
-    nxt = jnp.where(go_left, lc.reshape(-1)[nd], rc.reshape(-1)[nd])
-    return jnp.where(active, nxt, node)
+    with jax.named_scope(SCOPE_NODE_GATHER):
+        tree_base = jnp.arange(T, dtype=jnp.int32)[None, :] * I
+        nd = tree_base + jnp.maximum(node, 0)  # flat [N, T] into [T*I] tables
+        feat = sf.reshape(-1)[nd]
+        d = dt.reshape(-1)[nd]
+        thr = th.reshape(-1)[nd]
+        n_words = cn.reshape(-1)[nd]
+        word_off = co.reshape(-1)[nd]
+        left = lc.reshape(-1)[nd]
+        right = rc.reshape(-1)[nd]
+    with jax.named_scope(SCOPE_FEATURE_GATHER):
+        fval = jnp.take_along_axis(X, feat, axis=1)  # ONE X gather per level
+    with jax.named_scope(SCOPE_DECIDE):
+        active = node >= 0
+        is_cat = (d & 1) > 0
+        default_left = (d & 2) > 0
+        missing_type = (d >> 2) & 3
+        # --- numerical decision (tree.h:338-355)
+        is_nan = jnp.isnan(fval)
+        fval_num = jnp.where(is_nan & (missing_type != MISSING_NAN), 0.0, fval)
+        is_missing = ((missing_type == MISSING_ZERO)
+                      & (jnp.abs(fval_num) <= _EPS)) | (
+            (missing_type == MISSING_NAN) & jnp.isnan(fval_num))
+        go_left_num = jnp.where(is_missing, default_left, fval_num <= thr)
+        # --- categorical decision (tree.h:375-388)
+        int_fval = jnp.where(is_nan, -1, fval.astype(jnp.int32))
+        word_idx = jnp.clip(int_fval, 0, None) // 32
+        bit_idx = jnp.clip(int_fval, 0, None) % 32
+        in_range = (int_fval >= 0) & (word_idx < n_words)
+        with jax.named_scope(SCOPE_NODE_GATHER):
+            word = cat_words[jnp.clip(word_off + word_idx, 0,
+                                      cat_words.shape[0] - 1)]
+        go_left_cat = in_range & (
+            ((word >> bit_idx.astype(jnp.uint32)) & 1) > 0)
+        go_left = jnp.where(is_cat, go_left_cat, go_left_num)
+        nxt = jnp.where(go_left, left, right)
+        return jnp.where(active, nxt, node)
 
 
 def _traverse_leaves(packed: PackedEnsemble, X: jax.Array) -> jax.Array:
@@ -258,7 +272,8 @@ def _traverse_leaves(packed: PackedEnsemble, X: jax.Array) -> jax.Array:
     whole forest."""
     n = X.shape[0]
     T = packed.split_feature.shape[0]
-    node0 = jnp.zeros((n, T), dtype=jnp.int32)
+    with jax.named_scope(SCOPE_DECIDE):
+        node0 = jnp.zeros((n, T), dtype=jnp.int32)
 
     def body(_, node):
         return forest_level_step(
@@ -269,9 +284,11 @@ def _traverse_leaves(packed: PackedEnsemble, X: jax.Array) -> jax.Array:
     node = jax.lax.fori_loop(0, packed.max_depth, body, node0)
     # a leaf id is the bitwise complement of the (negative) frozen node;
     # single-leaf (constant) trees sit at leaf 0
-    return jnp.where(packed.num_leaves[None, :] <= 1, 0, ~node)
+    with jax.named_scope(SCOPE_LEAF_VALUES):
+        return jnp.where(packed.num_leaves[None, :] <= 1, 0, ~node)
 
 
+@jax.named_scope(SCOPE_LEAF_VALUES)
 def _leaf_scores(packed: PackedEnsemble, X: jax.Array,
                  leaf: jax.Array) -> jax.Array:
     """Per-(row, tree) scores [N, T] from leaf assignments. Linear-tree
@@ -306,8 +323,9 @@ def _predict_raw_fused(packed: PackedEnsemble, X: jax.Array,
     leaf = _traverse_leaves(packed, X)
     vals = _leaf_scores(packed, X, leaf)
     n, T = vals.shape
-    return vals.reshape(n, T // num_tree_per_iteration,
-                        num_tree_per_iteration).sum(axis=1)
+    with jax.named_scope(SCOPE_ACCUMULATE):
+        return vals.reshape(n, T // num_tree_per_iteration,
+                            num_tree_per_iteration).sum(axis=1)
 
 
 _leaf_indices_fused = jax.jit(_traverse_leaves)
@@ -317,7 +335,7 @@ def predict_leaf_indices(packed: PackedEnsemble, X: jax.Array) -> jax.Array:
     """[N, T] leaf index per row per tree."""
     if packed.num_trees == 0:
         return jnp.zeros((X.shape[0], 0), dtype=jnp.int32)
-    with global_timer.scope("predict_traverse"):
+    with global_timer.scope(SPAN_PREDICT_TRAVERSE):
         return _leaf_indices_fused(packed, X)
 
 
@@ -341,18 +359,19 @@ def predict_pallas_enabled() -> bool:
 
 def predict_raw(packed: PackedEnsemble, X: jax.Array,
                 num_tree_per_iteration: int = 1) -> jax.Array:
-    """Raw scores [N, num_tree_per_iteration] summed over iterations."""
+    """Raw scores [N, num_tree_per_iteration] summed over iterations. The
+    one boundary of the `predict_traverse` span in this module: whichever
+    program traverses, its dispatch is inside it once."""
     T = packed.num_trees
     if T == 0:
         return jnp.zeros((X.shape[0], num_tree_per_iteration), dtype=X.dtype)
     validate_tree_count(packed, num_tree_per_iteration)
-    if predict_pallas_enabled() and not packed.linear:
-        from .predict_pallas import pallas_predict_raw
+    with global_timer.scope(SPAN_PREDICT_TRAVERSE):
+        if predict_pallas_enabled() and not packed.linear:
+            from .predict_pallas import pallas_predict_raw
 
-        with global_timer.scope("predict_traverse"):
             return pallas_predict_raw(packed, X, num_tree_per_iteration,
                                       interpret=pallas_interpret())
-    with global_timer.scope("predict_traverse"):
         if packed.linear:
             # under jit XLA contracts the linear mul+sum into fmas, a 1-ulp
             # drift vs the eager reference arithmetic; keep the score math
@@ -651,28 +670,34 @@ def predict_raw_streamed(packed: PackedEnsemble, X: np.ndarray,
     n_chunks = -(-n // chunk)
     out_parts: List[Optional[np.ndarray]] = [None] * n_chunks
     inflight: deque = deque()
-    with global_timer.scope("predict_stream"):
-        for i in range(n_chunks):
-            start = i * chunk
-            stop = min(start + chunk, n)
-            rows = stop - start
-            xc = X[start:stop]
-            pad = chunk if rows == chunk else bucket_size(rows, 256)
-            if rows < pad:  # tail chunk: pad to its own bucket
-                xc = np.concatenate(
-                    [xc, np.zeros((pad - rows, X.shape[1]), dtype=X.dtype)])
-            xd = jnp.asarray(xc, dtype=dtype)
-            yd = predict_raw(packed, xd, num_tree_per_iteration)
-            yd.copy_to_host_async()
-            if telemetry.enabled():
-                telemetry.emit("predict_chunk", index=i, rows=rows, pad=pad)
-            inflight.append((i, rows, yd))
-            while len(inflight) > 2:
+
+    def fetch(keep: int) -> None:
+        with global_timer.scope(SPAN_PREDICT_FETCH):
+            while len(inflight) > keep:
                 j, r, y = inflight.popleft()
                 out_parts[j] = np.asarray(y)[:r]
-        while inflight:
-            j, r, y = inflight.popleft()
-            out_parts[j] = np.asarray(y)[:r]
+
+    with global_timer.scope("predict_stream"):
+        for i in range(n_chunks):
+            with global_timer.scope(SPAN_PREDICT_CHUNK):
+                start = i * chunk
+                stop = min(start + chunk, n)
+                rows = stop - start
+                xc = X[start:stop]
+                pad = chunk if rows == chunk else bucket_size(rows, 256)
+                if rows < pad:  # tail chunk: pad to its own bucket
+                    xc = np.concatenate([xc, np.zeros(
+                        (pad - rows, X.shape[1]), dtype=X.dtype)])
+                with global_timer.scope(SPAN_PREDICT_UPLOAD):
+                    xd = jnp.asarray(xc, dtype=dtype)
+                yd = predict_raw(packed, xd, num_tree_per_iteration)
+                yd.copy_to_host_async()
+                if telemetry.enabled():
+                    telemetry.emit("predict_chunk", index=i, rows=rows,
+                                   pad=pad)
+                inflight.append((i, rows, yd))
+                fetch(keep=2)
+        fetch(keep=0)
         global_timer.add_count("predict_stream_chunks", n_chunks)
     return np.concatenate(out_parts, axis=0)
 

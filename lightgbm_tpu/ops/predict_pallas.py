@@ -113,6 +113,7 @@ def pallas_predict_raw(packed: PackedEnsemble, X: jax.Array,
         out_specs=pl.BlockSpec((tile_rows, C), lambda t: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, C), packed.leaf_value.dtype),
         interpret=interpret,
+        name="pallas_predict_raw",
     )(packed.split_feature, packed.threshold, packed.decision_type,
       packed.left_child, packed.right_child, packed.cat_offset,
       packed.cat_n_words, packed.cat_words, packed.num_leaves,

@@ -247,6 +247,7 @@ def fused_split_scan(hist3: jax.Array, meta_cols: jax.Array,
                                lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((f_pad, REC_PAD), jnp.float32),
         interpret=interpret,
+        name="fused_split_scan",
     )(hist3, meta_cols, valid)
 
 

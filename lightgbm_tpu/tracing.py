@@ -301,6 +301,35 @@ def note(kind: str, **fields: Any) -> None:
     _recorder.note(kind, fields)
 
 
+# every program the process compiles or loads from JAX's persistent cache,
+# not only grow_tree_on_device (all its `_cache_size()` sees). jax 0.9.0
+# times the whole of `compile_or_get_cached` as BACKEND_COMPILE (a compile
+# or a cache load, whichever it was) and fires CACHE_RETRIEVAL inside it on
+# a hit, over seconds the outer event counts again: so the outer event is
+# the one note per program, and the inner only marks it a hit.
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_cache_hit = threading.local()
+
+
+def _note_compile(event: str, seconds: float, **_: Any) -> None:
+    if event == CACHE_RETRIEVAL:
+        _cache_hit.pending = True
+    elif event == BACKEND_COMPILE:
+        hit = getattr(_cache_hit, "pending", False)
+        _cache_hit.pending = False
+        note("compile", seconds=float(seconds), cache_hit=hit)
+
+
+def install_compile_listener() -> None:
+    """Register the one jax.monitoring listener that turns compiles into
+    `compile` flight notes. Called once, at package import; it runs only
+    when something compiles."""
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_note_compile)
+
+
 def _finish_span(span: Span) -> None:
     if not _enabled:
         return

@@ -65,7 +65,10 @@ from .. import perfmodel, telemetry
 from ..utils import sanitize
 from ..utils.backend import pallas_interpret
 from ..utils.log import Log
-from ..utils.timer import global_timer
+from ..utils.timer import (SCOPE_ALLREDUCE, SCOPE_COMMIT, SCOPE_COMPACT,
+                           SCOPE_FINISH, SCOPE_HIST, SCOPE_REPLAY,
+                           SCOPE_ROUTE, SCOPE_SCAN, SCOPE_SELECT,
+                           SCOPE_TREE_SETUP, global_timer)
 from .serial import SerialTreeLearner, _leaf_output_host
 
 REC = len(SPLIT_FIELDS)
@@ -232,61 +235,62 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
     # pad rows ONCE to a common multiple of the histogram and compaction
     # tiles; padded rows carry leaf_id -1 and zero gh and (like bagged-out
     # rows) sit after every leaf range, contributing nothing anywhere
-    unit = max(DEFAULT_TILE_ROWS, COMPACT_TILE)
-    assert unit % COMPACT_TILE == 0 and unit % DEFAULT_TILE_ROWS == 0
-    Np = -(-N // unit) * unit
-    if Np != N:
-        bins = jnp.pad(bins, ((0, 0), (0, Np - N)), constant_values=0)
-        gh = jnp.pad(gh, ((0, Np - N), (0, 0)))
-        leaf_id0 = jnp.pad(leaf_id0, (0, Np - N), constant_values=-1)
-    # 8-bit planes (uint8 bins, every group <= 256 bins) are carried
-    # UNWIDENED through the wave loop — 4x less HBM traffic on the dominant
-    # [Gp, Np] array, single-limb compaction transport. Mosaic tiles 8-bit
-    # as (32, 128), so the group dim pads to 32 instead of 8. Wider planes
-    # (uint16 groups, or the LGBM_TPU_BINS_I32 escape hatch upstream)
-    # widen to int32 here as before.
-    plane8 = bins.dtype.itemsize == 1
-    Gp = -(-G // 32) * 32 if plane8 else -(-G // 8) * 8
-    bins_p = bins if plane8 else bins.astype(jnp.int32)
-    if Gp != G:
-        bins_p = jnp.pad(bins_p, ((0, Gp - G), (0, 0)), constant_values=0)
-    T_hist = Np // DEFAULT_TILE_ROWS
-    # Pallas kernels on a TPU; the XLA bodies (CPU tests) share the
-    # forward-map/range logic and differ only in kernel dispatch.
-    # LGBM_TPU_PALLAS_INTERPRET=1 runs the TPU kernel path in interpret
-    # mode — CPU-runnable end-to-end coverage of the ragged machinery.
-    interp = pallas_interpret()
-    use_kernels = (_use_pallas() or interp) and os.environ.get(
-        "LGBM_TPU_HIST_SLOTS", "1").lower() not in ("0", "false", "off")
-    pool_dtype = jnp.int32 if quantized else jnp.float32
-    pos = jnp.arange(Np, dtype=jnp.int32)
+    with jax.named_scope(SCOPE_TREE_SETUP):
+        unit = max(DEFAULT_TILE_ROWS, COMPACT_TILE)
+        assert unit % COMPACT_TILE == 0 and unit % DEFAULT_TILE_ROWS == 0
+        Np = -(-N // unit) * unit
+        if Np != N:
+            bins = jnp.pad(bins, ((0, 0), (0, Np - N)), constant_values=0)
+            gh = jnp.pad(gh, ((0, Np - N), (0, 0)))
+            leaf_id0 = jnp.pad(leaf_id0, (0, Np - N), constant_values=-1)
+        # 8-bit planes (uint8 bins, every group <= 256 bins) are carried
+        # UNWIDENED through the wave loop — 4x less HBM traffic on the dominant
+        # [Gp, Np] array, single-limb compaction transport. Mosaic tiles 8-bit
+        # as (32, 128), so the group dim pads to 32 instead of 8. Wider planes
+        # (uint16 groups, or the LGBM_TPU_BINS_I32 escape hatch upstream)
+        # widen to int32 here as before.
+        plane8 = bins.dtype.itemsize == 1
+        Gp = -(-G // 32) * 32 if plane8 else -(-G // 8) * 8
+        bins_p = bins if plane8 else bins.astype(jnp.int32)
+        if Gp != G:
+            bins_p = jnp.pad(bins_p, ((0, Gp - G), (0, 0)), constant_values=0)
+        T_hist = Np // DEFAULT_TILE_ROWS
+        # Pallas kernels on a TPU; the XLA bodies (CPU tests) share the
+        # forward-map/range logic and differ only in kernel dispatch.
+        # LGBM_TPU_PALLAS_INTERPRET=1 runs the TPU kernel path in interpret
+        # mode — CPU-runnable end-to-end coverage of the ragged machinery.
+        interp = pallas_interpret()
+        use_kernels = (_use_pallas() or interp) and os.environ.get(
+            "LGBM_TPU_HIST_SLOTS", "1").lower() not in ("0", "false", "off")
+        pool_dtype = jnp.int32 if quantized else jnp.float32
+        pos = jnp.arange(Np, dtype=jnp.int32)
 
-    # leaf-contiguous payload: gh channels + original position + leaf id,
-    # all exact in f32 (positions < 2**24, ids < 2**8; quantized int8 gh
-    # values are exact too) and moved bit-exactly by the compaction kernel.
-    # LGBM_TPU_GH_BF16=1 (opt-in, float path only): gh rides as bf16 PAIRS
-    # bitcast into f32 payload columns — half the gh carry bytes. The
-    # packed bits survive compaction unchanged (the kernel moves f32 limbs
-    # exactly) and are unpacked per histogram pass; bit-identity with the
-    # f32 path is NOT guaranteed (the learner warns once).
-    pack_bf16 = (not quantized) and os.environ.get(
-        "LGBM_TPU_GH_BF16", "").lower() in ("1", "true", "on")
-    if pack_bf16:
-        CHp = CH + (CH % 2)
-        ghb = gh.astype(jnp.float32).astype(jnp.bfloat16)
-        if CHp != CH:
-            ghb = jnp.pad(ghb, ((0, 0), (0, CHp - CH)))
-        gh_cols = jax.lax.bitcast_convert_type(
-            ghb.reshape(Np, CHp // 2, 2), jnp.float32)  # [Np, CHp//2]
-        n_gh = CHp // 2
-    else:
-        gh_cols = gh.astype(jnp.float32)
-        n_gh = CH
-    row_p = jnp.concatenate([
-        gh_cols, pos.astype(jnp.float32)[:, None],
-        leaf_id0.astype(jnp.float32)[:, None]], axis=1)  # [Np, n_gh+2]
-    POS_COL = n_gh
-    LEAF_COL = n_gh + 1
+        # leaf-contiguous payload: gh channels + original position + leaf id,
+        # all exact in f32 (positions < 2**24, ids < 2**8; quantized int8 gh
+        # values are exact too) and moved bit-exactly by the compaction kernel.
+        # LGBM_TPU_GH_BF16=1 (opt-in, float path only): gh rides as bf16 PAIRS
+        # bitcast into f32 payload columns — half the gh carry bytes. The
+        # packed bits survive compaction unchanged (the kernel moves f32 limbs
+        # exactly) and are unpacked per histogram pass; bit-identity with the
+        # f32 path is NOT guaranteed (the learner warns once).
+        pack_bf16 = (not quantized) and os.environ.get(
+            "LGBM_TPU_GH_BF16", "").lower() in ("1", "true", "on")
+        if pack_bf16:
+            CHp = CH + (CH % 2)
+            ghb = gh.astype(jnp.float32).astype(jnp.bfloat16)
+            if CHp != CH:
+                ghb = jnp.pad(ghb, ((0, 0), (0, CHp - CH)))
+            gh_cols = jax.lax.bitcast_convert_type(
+                ghb.reshape(Np, CHp // 2, 2), jnp.float32)  # [Np, CHp//2]
+            n_gh = CHp // 2
+        else:
+            gh_cols = gh.astype(jnp.float32)
+            n_gh = CH
+        row_p = jnp.concatenate([
+            gh_cols, pos.astype(jnp.float32)[:, None],
+            leaf_id0.astype(jnp.float32)[:, None]], axis=1)  # [Np, n_gh+2]
+        POS_COL = n_gh
+        LEAF_COL = n_gh + 1
 
     def payload_gh(row_c):
         """gh channels of a payload slice as f32 [rows, CH] (unpacks the
@@ -319,21 +323,22 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
         leaf-contiguous ranges (slot must be the dump value outside).
         bins_c/row_c passed explicitly: inside the wave loop they are the
         CARRY arrays, not the pre-loop closure values."""
-        ghc = payload_gh(row_c)
-        if use_kernels:
-            tiles, nact = active_tile_table(starts, ends, valid, T_hist,
-                                            DEFAULT_TILE_ROWS)
-            h = pallas_histogram_slots_ragged(
-                bins_c, ghc, slot, tiles, nact, num_bins,
-                n_slots, quantized=quantized, f32=hist_force_f32(),
-                interpret=interp)
-            return h[:G]
-        # XLA fallback: flat slot-expanded build over the full row set
-        col_slot = jnp.arange(n_slots * CH, dtype=jnp.int32) // CH
-        ghK = jnp.where(slot[:, None] == col_slot[None, :],
-                        jnp.tile(ghc, (1, n_slots)), 0.0)
-        h = build_histogram(bins_c[:G], ghK, num_bins)
-        return h.astype(pool_dtype)  # quantized: exact ints below 2**24
+        with jax.named_scope(SCOPE_HIST):
+            ghc = payload_gh(row_c)
+            if use_kernels:
+                tiles, nact = active_tile_table(starts, ends, valid, T_hist,
+                                                DEFAULT_TILE_ROWS)
+                h = pallas_histogram_slots_ragged(
+                    bins_c, ghc, slot, tiles, nact, num_bins,
+                    n_slots, quantized=quantized, f32=hist_force_f32(),
+                    interpret=interp)
+                return h[:G]
+            # XLA fallback: flat slot-expanded build over the full row set
+            col_slot = jnp.arange(n_slots * CH, dtype=jnp.int32) // CH
+            ghK = jnp.where(slot[:, None] == col_slot[None, :],
+                            jnp.tile(ghc, (1, n_slots)), 0.0)
+            h = build_histogram(bins_c[:G], ghK, num_bins)
+            return h.astype(pool_dtype)  # quantized: exact ints below 2**24
 
     if data_par:
         gidx, vslot, sm = meta.gather_index, meta.valid_slot, meta.scan
@@ -354,8 +359,9 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
                 lambda h: gather_feature_hist_raw(h, gidx, vslot))(hists_k)
             if narrow:
                 fh = fh.astype(jnp.int16)
-            blk = jax.lax.psum_scatter(fh, "data", scatter_dimension=1,
-                                       tiled=True)
+            with jax.named_scope(SCOPE_ALLREDUCE):
+                blk = jax.lax.psum_scatter(fh, "data", scatter_dimension=1,
+                                           tiled=True)
             return blk.astype(pool_dtype)
 
         def scan_blocks(blk_raw, tot_raw, depths):
@@ -378,7 +384,8 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             feat = recs[:, :, 1]
             recs = recs.at[:, :, 1].set(
                 jnp.where(feat >= 0, feat + shard_off, -1.0))
-            recs = jax.lax.all_gather(recs, "data", axis=1, tiled=True)
+            with jax.named_scope(SCOPE_ALLREDUCE):
+                recs = jax.lax.all_gather(recs, "data", axis=1, tiled=True)
             best = jax.vmap(reduce_best_record)(recs)
             return jax.vmap(guard)(best, tot[:, 2], tot[:, 1], depths)
 
@@ -431,8 +438,9 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
                            ) % F_pad
                 nom = jnp.where(hit, jnp.broadcast_to(garbage[None, :],
                                                       nom.shape), nom)
-            votes = jax.lax.all_gather(nom, "data", axis=1,
-                                       tiled=True)  # [k, D*kl]
+            with jax.named_scope(SCOPE_ALLREDUCE):
+                votes = jax.lax.all_gather(nom, "data", axis=1,
+                                           tiled=True)  # [k, D*kl]
             counts = jax.vmap(lambda v: jnp.zeros(
                 (F_pad,), jnp.int32).at[v].add(1))(votes)
             # phase 2 (GlobalVoting): elect the top-2k by vote count —
@@ -443,7 +451,8 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
                 fh_raw, selected[:, :, None, None], axis=1)
             if narrow:
                 sel_raw = sel_raw.astype(jnp.int16)
-            sel_red = jax.lax.psum(sel_raw, "data").astype(pool_dtype)
+            with jax.named_scope(SCOPE_ALLREDUCE):
+                sel_red = jax.lax.psum(sel_raw, "data").astype(pool_dtype)
             tot = _scaled(tot_raw)
 
             def rescan(blk, idx, t):
@@ -465,7 +474,8 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             # the vote avoided and count best-feature disagreements (the
             # documented approximation: the exact best can be un-nominated)
             full_raw = fh_raw.astype(jnp.int16) if narrow else fh_raw
-            full = jax.lax.psum(full_raw, "data").astype(pool_dtype)
+            with jax.named_scope(SCOPE_ALLREDUCE):
+                full = jax.lax.psum(full_raw, "data").astype(pool_dtype)
             frecs = _fix_scan(_scaled(full),
                               jnp.broadcast_to(tot, (kk, CH)))
             fbest = jax.vmap(reduce_best_record)(frecs)
@@ -496,32 +506,34 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             recs = recs.at[:, :, 1].set(
                 jnp.where(feat >= 0, feat + shard_off, -1.0))
             best = jax.vmap(reduce_best_record)(recs)  # [k, REC] local
-            allr = jax.lax.all_gather(best[:, None], "data", axis=1,
-                                      tiled=True)  # [k, D, REC]
+            with jax.named_scope(SCOPE_ALLREDUCE):
+                allr = jax.lax.all_gather(best[:, None], "data", axis=1,
+                                          tiled=True)  # [k, D, REC]
             best = jax.vmap(reduce_best_record)(allr)
             return jax.vmap(guard)(best, tots[:, 2], tots[:, 1], depths)
 
     # --- initial compaction: in-bag rows to the front, root = [0, n_in)
-    if bagged:
-        in_bag = leaf_id0 == 0
-        n_in = in_bag.sum().astype(jnp.int32)
-        dst0, _ = range_partition_dst(
-            in_bag, jnp.ones((Np, 1), bool), jnp.zeros(1, jnp.int32),
-            jnp.full(1, Np, jnp.int32), jnp.ones(1, bool))
-        bins_p, row_p = compact_rows(
-            bins_p, row_p, dst0, [in_bag, ~in_bag],
-            jnp.ones(Np, bool), tile=COMPACT_TILE,
-            use_pallas=use_kernels, interpret=interp)
-    elif row_sharded:
-        # the learner's global row padding trails the real rows, so every
-        # shard's real rows are already contiguous from 0 — count, don't
-        # compact
-        n_in = (leaf_id0 == 0).sum().astype(jnp.int32)
-    else:
-        n_in = jnp.int32(N)
+    with jax.named_scope(SCOPE_TREE_SETUP):
+        if bagged:
+            in_bag = leaf_id0 == 0
+            n_in = in_bag.sum().astype(jnp.int32)
+            dst0, _ = range_partition_dst(
+                in_bag, jnp.ones((Np, 1), bool), jnp.zeros(1, jnp.int32),
+                jnp.full(1, Np, jnp.int32), jnp.ones(1, bool))
+            bins_p, row_p = compact_rows(
+                bins_p, row_p, dst0, [in_bag, ~in_bag],
+                jnp.ones(Np, bool), tile=COMPACT_TILE,
+                use_pallas=use_kernels, interpret=interp)
+        elif row_sharded:
+            # the learner's global row padding trails the real rows, so every
+            # shard's real rows are already contiguous from 0 — count, don't
+            # compact
+            n_in = (leaf_id0 == 0).sum().astype(jnp.int32)
+        else:
+            n_in = jnp.int32(N)
 
-    start = jnp.zeros(L + 1, jnp.int32)
-    count = jnp.zeros(L + 1, jnp.int32).at[0].set(n_in)
+        start = jnp.zeros(L + 1, jnp.int32)
+        count = jnp.zeros(L + 1, jnp.int32).at[0].set(n_in)
 
     # --- root histogram through the ragged slots kernel (satellite: the
     # thin-CH masked dot cost ~183 ms/tree; this path is O(n_in) and warm)
@@ -530,45 +542,56 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
         jnp.zeros(1, jnp.int32), n_in[None], jnp.ones(1, bool))
     hist_rows = n_in  # instrumentation: rows histogrammed this tree
 
-    depth = jnp.zeros(L + 1, jnp.int32)
-    leaf_best = jnp.full((L + 1, REC), neg_inf, jnp.float32)
-    if data_par:
-        root_tot_raw = jax.lax.psum(root_hist[0].sum(axis=0), "data")
-        n_in_g = jax.lax.psum(n_in, "data")
-        pool = jnp.zeros((L + 1, f_local, Bmax, CH), pool_dtype).at[0].set(
-            raw_blocks(root_hist[None])[0])
-        tpool = jnp.zeros((L + 1, CH), pool_dtype).at[0].set(root_tot_raw)
-        count_g = jnp.zeros(L + 1, jnp.int32).at[0].set(n_in_g)
-        root_rec = scan_blocks(pool[0][None], root_tot_raw[None],
-                               jnp.zeros(1, jnp.int32))[0]
-    elif voting:
-        # the pool keeps the LOCAL raw group layout — no feature-blocked
-        # histogram crosses the wire until the vote elects its slice
-        root_tot_raw = jax.lax.psum(root_hist[0].sum(axis=0), "data")
-        n_in_g = jax.lax.psum(n_in, "data")
-        pool = jnp.zeros((L + 1, G, num_bins, CH), pool_dtype).at[0].set(
-            root_hist)
-        tpool = jnp.zeros((L + 1, CH), pool_dtype).at[0].set(root_tot_raw)
-        count_g = jnp.zeros(L + 1, jnp.int32).at[0].set(n_in_g)
-        root_rec, root_miss = vote_scan(
-            root_hist[None].astype(pool_dtype), root_tot_raw[None],
-            jnp.zeros(1, jnp.int32), jnp.int32(0))
-        root_rec = root_rec[0]
-    else:
-        root_tot = hist_totals(root_hist)
-        pool = jnp.zeros((L + 1, G, num_bins, CH), pool_dtype).at[0].set(
-            root_hist)
-        if feature_par:
-            root_rec = feature_scan(root_hist[None].astype(pool_dtype),
-                                    root_tot[None],
-                                    jnp.zeros(1, jnp.int32))[0]
+    with jax.named_scope(SCOPE_TREE_SETUP):
+        depth = jnp.zeros(L + 1, jnp.int32)
+        leaf_best = jnp.full((L + 1, REC), neg_inf, jnp.float32)
+    with jax.named_scope(SCOPE_SCAN):
+        if data_par:
+            with jax.named_scope(SCOPE_ALLREDUCE):
+                root_tot_raw = jax.lax.psum(root_hist[0].sum(axis=0), "data")
+                n_in_g = jax.lax.psum(n_in, "data")
+            root_blk = raw_blocks(root_hist[None])[0]
+            with jax.named_scope(SCOPE_TREE_SETUP):
+                pool = jnp.zeros((L + 1, f_local, Bmax, CH),
+                                 pool_dtype).at[0].set(root_blk)
+                tpool = jnp.zeros((L + 1, CH), pool_dtype).at[0].set(
+                    root_tot_raw)
+                count_g = jnp.zeros(L + 1, jnp.int32).at[0].set(n_in_g)
+            root_rec = scan_blocks(pool[0][None], root_tot_raw[None],
+                                   jnp.zeros(1, jnp.int32))[0]
+        elif voting:
+            # the pool keeps the LOCAL raw group layout — no feature-blocked
+            # histogram crosses the wire until the vote elects its slice
+            with jax.named_scope(SCOPE_ALLREDUCE):
+                root_tot_raw = jax.lax.psum(root_hist[0].sum(axis=0), "data")
+                n_in_g = jax.lax.psum(n_in, "data")
+            with jax.named_scope(SCOPE_TREE_SETUP):
+                pool = jnp.zeros((L + 1, G, num_bins, CH),
+                                 pool_dtype).at[0].set(root_hist)
+                tpool = jnp.zeros((L + 1, CH), pool_dtype).at[0].set(
+                    root_tot_raw)
+                count_g = jnp.zeros(L + 1, jnp.int32).at[0].set(n_in_g)
+            root_rec, root_miss = vote_scan(
+                root_hist[None].astype(pool_dtype), root_tot_raw[None],
+                jnp.zeros(1, jnp.int32), jnp.int32(0))
+            root_rec = root_rec[0]
         else:
-            root_rec = guard(find_best_split(scan_hist(root_hist), root_tot,
-                                             meta, params, feature_mask),
-                             root_tot[2], root_tot[1], jnp.int32(0))
-    leaf_best = leaf_best.at[0].set(root_rec)
-    # one extra dump row at the end for masked-out replay writes
-    rec_store = jnp.zeros((max(L - 1, 1) + 1, STORE), jnp.float32)
+            root_tot = hist_totals(root_hist)
+            with jax.named_scope(SCOPE_TREE_SETUP):
+                pool = jnp.zeros((L + 1, G, num_bins, CH),
+                                 pool_dtype).at[0].set(root_hist)
+            if feature_par:
+                root_rec = feature_scan(root_hist[None].astype(pool_dtype),
+                                        root_tot[None],
+                                        jnp.zeros(1, jnp.int32))[0]
+            else:
+                root_rec = guard(find_best_split(scan_hist(root_hist), root_tot,
+                                                 meta, params, feature_mask),
+                                 root_tot[2], root_tot[1], jnp.int32(0))
+    with jax.named_scope(SCOPE_TREE_SETUP):
+        leaf_best = leaf_best.at[0].set(root_rec)
+        # one extra dump row at the end for masked-out replay writes
+        rec_store = jnp.zeros((max(L - 1, 1) + 1, STORE), jnp.float32)
 
     l1, l2, max_delta = params[0], params[1], params[5]
 
@@ -583,247 +606,258 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             (bins_p, row_p, start, count, depth, leaf_best, rec_store, pool,
              n_cur, t, hist_rows, n_waves) = carry
         n_waves = n_waves + 1  # wave-efficiency telemetry (finalize())
-        gains = leaf_best[:L, 0]
-        sel_gain, sel = jax.lax.top_k(gains, K)  # [K] distinct leaves
-        sel = sel.astype(jnp.int32)
-        sel_ok = sel_gain > 0
+        with jax.named_scope(SCOPE_SELECT):
+            gains = leaf_best[:L, 0]
+            sel_gain, sel = jax.lax.top_k(gains, K)  # [K] distinct leaves
+            sel = sel.astype(jnp.int32)
+            sel_ok = sel_gain > 0
 
-        # --- per-selected-leaf split fields
-        recs_sel = leaf_best[sel]  # [K, REC]
-        f_k = jnp.maximum(recs_sel[:, 1].astype(jnp.int32), 0)
-        thresh_k = recs_sel[:, 2].astype(jnp.int32)
-        defl_k = recs_sel[:, 3] > 0.5
-        s_k = jnp.take(start, sel)
-        c_k = jnp.take(count, sel)
-        e_k = s_k + c_k
+            # --- per-selected-leaf split fields
+            recs_sel = leaf_best[sel]  # [K, REC]
+            f_k = jnp.maximum(recs_sel[:, 1].astype(jnp.int32), 0)
+            thresh_k = recs_sel[:, 2].astype(jnp.int32)
+            defl_k = recs_sel[:, 3] > 0.5
+            s_k = jnp.take(start, sel)
+            c_k = jnp.take(count, sel)
+            e_k = s_k + c_k
 
-        # --- per-row ownership by POSITION RANGE (leaf-contiguous layout).
-        # The [N, K] compare stays VECTORIZED on the VPU; a [L+1]-table
-        # gather formulation measured ~20% slower end to end (TPU gathers
-        # serialize, elementwise compares do not).
-        match = ((pos[:, None] >= s_k[None, :])
-                 & (pos[:, None] < e_k[None, :]) & sel_ok[None, :])  # [N, K]
-        kvalid = match.any(axis=1)
+        with jax.named_scope(SCOPE_ROUTE):
+            # --- per-row ownership by POSITION RANGE (leaf-contiguous layout).
+            # The [N, K] compare stays VECTORIZED on the VPU; a [L+1]-table
+            # gather formulation measured ~20% slower end to end (TPU gathers
+            # serialize, elementwise compares do not).
+            match = ((pos[:, None] >= s_k[None, :])
+                     & (pos[:, None] < e_k[None, :]) & sel_ok[None, :])  # [N, K]
+            kvalid = match.any(axis=1)
 
-        # per-row split fields as ONE masked [N,K]@[K,F] matmul over the
-        # match matrix — vectorized VPU/MXU work; jnp.take gathers here
-        # measured far slower (TPU gathers serialize), and separate
-        # per-field matvecs would re-read the [N, K] matrix from HBM many
-        # times. Field values are small ints, exact in f32. HIGHEST
-        # precision: default TPU matmul rounds operands to bf16 (8 mantissa
-        # bits), which would corrupt integer fields > 256 — group ids, new
-        # leaf ids, bin offsets, row positions.
-        matchf = match.astype(jnp.float32)
+            # per-row split fields as ONE masked [N,K]@[K,F] matmul over the
+            # match matrix — vectorized VPU/MXU work; jnp.take gathers here
+            # measured far slower (TPU gathers serialize), and separate
+            # per-field matvecs would re-read the [N, K] matrix from HBM many
+            # times. Field values are small ints, exact in f32. HIGHEST
+            # precision: default TPU matmul rounds operands to bf16 (8 mantissa
+            # bits), which would corrupt integer fields > 256 — group ids, new
+            # leaf ids, bin offsets, row positions.
+            matchf = match.astype(jnp.float32)
 
-        def rows_of(per_k_fields):  # [K, F] -> [N, F]
-            return jax.lax.dot(matchf, per_k_fields.astype(jnp.float32),
-                               precision=jax.lax.Precision.HIGHEST)
+            def rows_of(per_k_fields):  # [K, F] -> [N, F]
+                return jax.lax.dot(matchf, per_k_fields.astype(jnp.float32),
+                                   precision=jax.lax.Precision.HIGHEST)
 
-        fields = jnp.stack([
-            tables.group[f_k], thresh_k, defl_k.astype(jnp.int32),
-            tables.missing_type[f_k], tables.default_bin[f_k],
-            tables.nbins[f_k], tables.lo[f_k], tables.hi[f_k],
-            tables.is_efb[f_k].astype(jnp.int32),
-        ], axis=1)  # [K, 9]
-        rowsF = rows_of(fields)  # [N, 9]
-        ri = rowsF.astype(jnp.int32)
-        grp_row = ri[:, 0]
-        # bins[grp_row[n], n] without a gather: compare-select over the G
-        # group rows (G*N elementwise beats an N-sized row-varying gather)
-        gb_row = jnp.sum(
-            jnp.where(jnp.arange(Gp, dtype=jnp.int32)[:, None] == grp_row[None, :], bins_p,
-                      0), axis=0, dtype=jnp.int32)
-        go_left = _decide_go_left(
-            gb_row, ri[:, 1], rowsF[:, 2] > 0.5, ri[:, 3], ri[:, 4],
-            ri[:, 5], ri[:, 6], ri[:, 7], rowsF[:, 8] > 0.5)
+            fields = jnp.stack([
+                tables.group[f_k], thresh_k, defl_k.astype(jnp.int32),
+                tables.missing_type[f_k], tables.default_bin[f_k],
+                tables.nbins[f_k], tables.lo[f_k], tables.hi[f_k],
+                tables.is_efb[f_k].astype(jnp.int32),
+            ], axis=1)  # [K, 9]
+            rowsF = rows_of(fields)  # [N, 9]
+            ri = rowsF.astype(jnp.int32)
+            grp_row = ri[:, 0]
+            # bins[grp_row[n], n] without a gather: compare-select over the G
+            # group rows (G*N elementwise beats an N-sized row-varying gather)
+            grp_iota = jnp.arange(Gp, dtype=jnp.int32)[:, None]
+            gb_row = jnp.sum(
+                jnp.where(grp_iota == grp_row[None, :], bins_p, 0),
+                axis=0, dtype=jnp.int32)
+            go_left = _decide_go_left(
+                gb_row, ri[:, 1], rowsF[:, 2] > 0.5, ri[:, 3], ri[:, 4],
+                ri[:, 5], ri[:, 6], ri[:, 7], rowsF[:, 8] > 0.5)
 
-        # --- stable partition of EVERY selected range (speculative: an
-        # uncommitted leaf's range is merely reordered, still contiguous)
-        dst, nl_k = range_partition_dst(go_left, match, s_k, c_k, sel_ok)
-        cmasks = ([match[:, k] & go_left for k in range(K)]
-                  + [match[:, k] & ~go_left for k in range(K)])
-        bins_p, row_p = compact_rows(
-            bins_p, row_p, dst, cmasks, kvalid, tile=COMPACT_TILE,
-            use_pallas=use_kernels, interpret=interp)
+        with jax.named_scope(SCOPE_COMPACT):
+            # --- stable partition of EVERY selected range (speculative: an
+            # uncommitted leaf's range is merely reordered, still contiguous)
+            dst, nl_k = range_partition_dst(go_left, match, s_k, c_k, sel_ok)
+            cmasks = ([match[:, k] & go_left for k in range(K)]
+                      + [match[:, k] & ~go_left for k in range(K)])
+            bins_p, row_p = compact_rows(
+                bins_p, row_p, dst, cmasks, kvalid, tile=COMPACT_TILE,
+                use_pallas=use_kernels, interpret=interp)
 
-        # --- ragged histogram of ONLY the smaller children; tie -> left,
-        # matching the serial learner's _apply_split choice
-        nr_k = c_k - nl_k
-        if row_sharded:
-            # smaller/larger child by GLOBAL row counts (psum of the
-            # per-shard left counts — SyncUpGlobalBestSplit semantics):
-            # every device histograms its LOCAL rows of the globally
-            # smaller child, whatever their local count
-            nl_g = jax.lax.psum(nl_k, "data")
-            c_g = jnp.take(count_g, sel)
-            nr_g = c_g - nl_g
-            left_small = nl_g <= nr_g
-            sc_k = jnp.where(left_small, nl_k, nr_k)
-        else:
-            left_small = nl_k <= nr_k
-            sc_k = jnp.minimum(nl_k, nr_k)
-        ss_k = jnp.where(left_small, s_k, s_k + nl_k)
-        se_k = ss_k + sc_k
-        inS = ((pos[:, None] >= ss_k[None, :])
-               & (pos[:, None] < se_k[None, :]) & sel_ok[None, :])
-        slotS = jnp.where(inS.any(axis=1),
-                          jnp.argmax(inS, axis=1).astype(jnp.int32), K)
-        hist_rows = hist_rows + jnp.sum(jnp.where(sel_ok, sc_k, 0))
-        histS = ranged_hist(bins_p, row_p, slotS, K, ss_k, se_k,
-                            sel_ok & (sc_k > 0))
-        histS_k = jnp.moveaxis(
-            histS.reshape(G, num_bins, K, CH), 2, 0)  # [K, G, B, CH]
-        child_depth = depth[sel] + 1  # [K]
-        depth2 = jnp.repeat(child_depth, 2)  # [2K]
-        if data_par:
-            # global raw totals of the smaller children, then ONE
-            # psum_scatter merges the raw gathered feature hists into this
-            # device's reduced block; subtraction happens on reduced data
-            totS_raw = jax.lax.psum(histS_k[:, 0].sum(axis=1), "data")
-            blkS = raw_blocks(histS_k)  # [K, f_local, Bmax, CH]
-            pool_sel = jnp.take(pool, sel, axis=0)
-            tp_sel = jnp.take(tpool, sel, axis=0)  # [K, CH]
-            histL = jnp.where(left_small[:, None, None, None], blkS,
-                              pool_sel - blkS)
-            histR = pool_sel - histL  # subtract_histogram, on blocks
-            totL_raw = jnp.where(left_small[:, None], totS_raw,
-                                 tp_sel - totS_raw)
-            totR_raw = tp_sel - totL_raw
-            hists = jnp.stack([histL, histR], axis=1).reshape(
-                2 * K, f_local, Bmax, CH)
-            tot2_raw = jnp.stack([totL_raw, totR_raw], axis=1).reshape(
-                2 * K, CH)
-            totals = tot2_raw
-            if quantized:
-                totals = totals.astype(jnp.float32) * scale_vec[None, :]
-            recs2 = scan_blocks(hists, tot2_raw, depth2)
-        elif voting:
-            # double-buffered dispatch: elect + reduce the SMALLER children
-            # first, so their nomination gather and elected-slice psum are
-            # in flight while the larger-child subtraction runs on local
-            # data — the overlapped half of the wave's ICI traffic
-            # (device_ici_overlap_pct)
-            totS_raw = jax.lax.psum(histS_k[:, 0].sum(axis=1), "data")
-            histSblk = histS_k.astype(pool_dtype)
-            recsS, missS = vote_scan(histSblk, totS_raw, child_depth,
-                                     n_waves)
-            pool_sel = jnp.take(pool, sel, axis=0)  # [K, G, B, CH] local
-            tp_sel = jnp.take(tpool, sel, axis=0)  # [K, CH] global raw
-            histB = pool_sel - histSblk  # the bigger sibling, local raw
-            totB_raw = tp_sel - totS_raw
-            recsB, missB = vote_scan(histB, totB_raw, child_depth, n_waves)
-            miss = miss + missS + missB
-            histL = jnp.where(left_small[:, None, None, None], histSblk,
-                              histB)
-            histR = pool_sel - histL
-            totL_raw = jnp.where(left_small[:, None], totS_raw, totB_raw)
-            totR_raw = tp_sel - totL_raw
-            recsL = jnp.where(left_small[:, None], recsS, recsB)
-            recsR = jnp.where(left_small[:, None], recsB, recsS)
-            recs2 = jnp.stack([recsL, recsR], axis=1).reshape(2 * K, REC)
-            tot2_raw = jnp.stack([totL_raw, totR_raw], axis=1).reshape(
-                2 * K, CH)
-            totals = tot2_raw
-            if quantized:
-                totals = totals.astype(jnp.float32) * scale_vec[None, :]
-        else:
-            pool_sel = jnp.take(pool, sel, axis=0)  # [K, G, B, CH]
-            histL = jnp.where(left_small[:, None, None, None], histS_k,
-                              pool_sel - histS_k)
-            histR = pool_sel - histL  # subtract_histogram, vectorized
-            hists = jnp.stack([histL, histR], axis=1).reshape(
-                2 * K, G, num_bins, CH)
-            totals = hists[:, 0].sum(axis=1)  # bins-summed -> [2K, CH]
-            if quantized:
-                totals = totals.astype(jnp.float32) * scale_vec[None, :]
-            if feature_par:
-                recs2 = feature_scan(hists, totals, depth2)
+        with jax.named_scope(SCOPE_HIST):
+            # --- ragged histogram of ONLY the smaller children; tie -> left,
+            # matching the serial learner's _apply_split choice
+            nr_k = c_k - nl_k
+            if row_sharded:
+                # smaller/larger child by GLOBAL row counts (psum of the
+                # per-shard left counts — SyncUpGlobalBestSplit semantics):
+                # every device histograms its LOCAL rows of the globally
+                # smaller child, whatever their local count
+                with jax.named_scope(SCOPE_ALLREDUCE):
+                    nl_g = jax.lax.psum(nl_k, "data")
+                c_g = jnp.take(count_g, sel)
+                nr_g = c_g - nl_g
+                left_small = nl_g <= nr_g
+                sc_k = jnp.where(left_small, nl_k, nr_k)
             else:
-                recs2 = jax.vmap(
-                    lambda h, tot: find_best_split(scan_hist(h), tot, meta,
-                                                   params, feature_mask))(
-                    hists, totals)
-                recs2 = jax.vmap(guard)(recs2, totals[:, 2], totals[:, 1],
-                                        depth2)
+                left_small = nl_k <= nr_k
+                sc_k = jnp.minimum(nl_k, nr_k)
+            ss_k = jnp.where(left_small, s_k, s_k + nl_k)
+            se_k = ss_k + sc_k
+            inS = ((pos[:, None] >= ss_k[None, :])
+                   & (pos[:, None] < se_k[None, :]) & sel_ok[None, :])
+            slotS = jnp.where(inS.any(axis=1),
+                              jnp.argmax(inS, axis=1).astype(jnp.int32), K)
+            hist_rows = hist_rows + jnp.sum(jnp.where(sel_ok, sc_k, 0))
+            histS = ranged_hist(bins_p, row_p, slotS, K, ss_k, se_k,
+                                sel_ok & (sc_k > 0))
+            histS_k = jnp.moveaxis(
+                histS.reshape(G, num_bins, K, CH), 2, 0)  # [K, G, B, CH]
+        with jax.named_scope(SCOPE_SCAN):
+            child_depth = depth[sel] + 1  # [K]
+            depth2 = jnp.repeat(child_depth, 2)  # [2K]
+            if data_par:
+                # global raw totals of the smaller children, then ONE
+                # psum_scatter merges the raw gathered feature hists into this
+                # device's reduced block; subtraction happens on reduced data
+                with jax.named_scope(SCOPE_ALLREDUCE):
+                    totS_raw = jax.lax.psum(histS_k[:, 0].sum(axis=1), "data")
+                blkS = raw_blocks(histS_k)  # [K, f_local, Bmax, CH]
+                pool_sel = jnp.take(pool, sel, axis=0)
+                tp_sel = jnp.take(tpool, sel, axis=0)  # [K, CH]
+                histL = jnp.where(left_small[:, None, None, None], blkS,
+                                  pool_sel - blkS)
+                histR = pool_sel - histL  # subtract_histogram, on blocks
+                totL_raw = jnp.where(left_small[:, None], totS_raw,
+                                     tp_sel - totS_raw)
+                totR_raw = tp_sel - totL_raw
+                hists = jnp.stack([histL, histR], axis=1).reshape(
+                    2 * K, f_local, Bmax, CH)
+                tot2_raw = jnp.stack([totL_raw, totR_raw], axis=1).reshape(
+                    2 * K, CH)
+                totals = tot2_raw
+                if quantized:
+                    totals = totals.astype(jnp.float32) * scale_vec[None, :]
+                recs2 = scan_blocks(hists, tot2_raw, depth2)
+            elif voting:
+                # double-buffered dispatch: elect + reduce the SMALLER children
+                # first, so their nomination gather and elected-slice psum are
+                # in flight while the larger-child subtraction runs on local
+                # data — the overlapped half of the wave's ICI traffic
+                # (device_ici_overlap_pct)
+                with jax.named_scope(SCOPE_ALLREDUCE):
+                    totS_raw = jax.lax.psum(histS_k[:, 0].sum(axis=1), "data")
+                histSblk = histS_k.astype(pool_dtype)
+                recsS, missS = vote_scan(histSblk, totS_raw, child_depth,
+                                         n_waves)
+                pool_sel = jnp.take(pool, sel, axis=0)  # [K, G, B, CH] local
+                tp_sel = jnp.take(tpool, sel, axis=0)  # [K, CH] global raw
+                histB = pool_sel - histSblk  # the bigger sibling, local raw
+                totB_raw = tp_sel - totS_raw
+                recsB, missB = vote_scan(histB, totB_raw, child_depth, n_waves)
+                miss = miss + missS + missB
+                histL = jnp.where(left_small[:, None, None, None], histSblk,
+                                  histB)
+                histR = pool_sel - histL
+                totL_raw = jnp.where(left_small[:, None], totS_raw, totB_raw)
+                totR_raw = tp_sel - totL_raw
+                recsL = jnp.where(left_small[:, None], recsS, recsB)
+                recsR = jnp.where(left_small[:, None], recsB, recsS)
+                recs2 = jnp.stack([recsL, recsR], axis=1).reshape(2 * K, REC)
+                tot2_raw = jnp.stack([totL_raw, totR_raw], axis=1).reshape(
+                    2 * K, CH)
+                totals = tot2_raw
+                if quantized:
+                    totals = totals.astype(jnp.float32) * scale_vec[None, :]
+            else:
+                pool_sel = jnp.take(pool, sel, axis=0)  # [K, G, B, CH]
+                histL = jnp.where(left_small[:, None, None, None], histS_k,
+                                  pool_sel - histS_k)
+                histR = pool_sel - histL  # subtract_histogram, vectorized
+                hists = jnp.stack([histL, histR], axis=1).reshape(
+                    2 * K, G, num_bins, CH)
+                totals = hists[:, 0].sum(axis=1)  # bins-summed -> [2K, CH]
+                if quantized:
+                    totals = totals.astype(jnp.float32) * scale_vec[None, :]
+                if feature_par:
+                    recs2 = feature_scan(hists, totals, depth2)
+                else:
+                    recs2 = jax.vmap(
+                        lambda h, tot: find_best_split(scan_hist(h), tot, meta,
+                                                       params, feature_mask))(
+                        hists, totals)
+                    recs2 = jax.vmap(guard)(recs2, totals[:, 2], totals[:, 1],
+                                            depth2)
 
-        # --- exact best-first replay over the precomputed set
-        def replay_step(_, rp):
+        with jax.named_scope(SCOPE_REPLAY):
+            # --- exact best-first replay over the precomputed set
+            def replay_step(_, rp):
+                (leaf_best, depth, rec_store, n_cur, t, committed, newids,
+                 active) = rp
+                cur = leaf_best[:L, 0]
+                b = jnp.argmax(cur).astype(jnp.int32)
+                brec = leaf_best[b]
+                eq = (sel == b) & sel_ok
+                pos = jnp.argmax(eq).astype(jnp.int32)
+                # ~committed[pos]: a left child reuses its parent's leaf id; its
+                # slot holds the PARENT's children — never commit it twice.
+                # t < L-1: the leaf budget binds mid-wave too.
+                can = (active & (brec[0] > 0) & eq.any() & ~committed[pos]
+                       & (t < L - 1))
+
+                new_leaf = n_cur
+                lrec = recs2[2 * pos]
+                rrec = recs2[2 * pos + 1]
+                ltot = totals[2 * pos]
+                rtot = totals[2 * pos + 1]
+                ptot = ltot + rtot
+                pnum = -jnp.sign(ptot[0]) * jnp.maximum(jnp.abs(ptot[0]) - l1,
+                                                        0.0)
+                pout = pnum / jnp.maximum(ptot[1] + l2, 1e-15)
+                pout = jnp.where(max_delta > 0,
+                                 jnp.clip(pout, -max_delta, max_delta), pout)
+                nd = depth[b] + 1
+
+                wb = jnp.where(can, b, L)
+                wn = jnp.where(can, new_leaf, L)
+                depth = depth.at[wb].set(nd).at[wn].set(nd)
+                leaf_best = leaf_best.at[wb].set(lrec).at[wn].set(rrec)
+                leaf_best = leaf_best.at[L].set(jnp.full(REC, neg_inf,
+                                                         dtype=jnp.float32))
+                row = jnp.concatenate([
+                    jnp.stack([b.astype(jnp.float32), pout,
+                               nd.astype(jnp.float32),
+                               jnp.where(can, 1.0, 0.0)]), brec])
+                wt = jnp.where(can, t, rec_store.shape[0] - 1)
+                rec_store = rec_store.at[wt].set(row)
+                committed = committed.at[jnp.where(can, pos, K)].set(True)
+                newids = newids.at[jnp.where(can, pos, K)].set(new_leaf)
+                inc = jnp.where(can, 1, 0).astype(jnp.int32)
+                return (leaf_best, depth, rec_store, n_cur + inc, t + inc,
+                        committed, newids, active & can)
+
+            rp0 = (leaf_best, depth, rec_store, n_cur, t,
+                   jnp.zeros(K + 1, bool), jnp.zeros(K + 1, jnp.int32),
+                   jnp.bool_(True))
             (leaf_best, depth, rec_store, n_cur, t, committed, newids,
-             active) = rp
-            cur = leaf_best[:L, 0]
-            b = jnp.argmax(cur).astype(jnp.int32)
-            brec = leaf_best[b]
-            eq = (sel == b) & sel_ok
-            pos = jnp.argmax(eq).astype(jnp.int32)
-            # ~committed[pos]: a left child reuses its parent's leaf id; its
-            # slot holds the PARENT's children — never commit it twice.
-            # t < L-1: the leaf budget binds mid-wave too.
-            can = (active & (brec[0] > 0) & eq.any() & ~committed[pos]
-                   & (t < L - 1))
+             _) = jax.lax.fori_loop(0, K, replay_step, rp0)
 
-            new_leaf = n_cur
-            lrec = recs2[2 * pos]
-            rrec = recs2[2 * pos + 1]
-            ltot = totals[2 * pos]
-            rtot = totals[2 * pos + 1]
-            ptot = ltot + rtot
-            pnum = -jnp.sign(ptot[0]) * jnp.maximum(jnp.abs(ptot[0]) - l1,
-                                                    0.0)
-            pout = pnum / jnp.maximum(ptot[1] + l2, 1e-15)
-            pout = jnp.where(max_delta > 0,
-                             jnp.clip(pout, -max_delta, max_delta), pout)
-            nd = depth[b] + 1
+        with jax.named_scope(SCOPE_COMMIT):
+            # --- commit side effects, all OUTSIDE the replay fori_loop (the
+            # heavy [K, G, B, CH] pool writes and [N]-row updates run once per
+            # wave, vectorized over the committed mask, not once per replay
+            # step). Uncommitted leaves keep their old (start, count, pool)
+            # entries — their ranges were only reordered internally.
+            wbK = jnp.where(committed[:K], sel, L)       # parent keeps left
+            wnK = jnp.where(committed[:K], newids[:K], L)  # new leaf = right
+            pool = pool.at[wbK].set(histL).at[wnK].set(histR)
+            mid_k = s_k + nl_k
+            start = start.at[wnK].set(mid_k)
+            count = count.at[wnK].set(nr_k).at[wbK].set(nl_k)
+            if row_sharded:
+                # replicated raw totals + GLOBAL counts ride with the pool so
+                # later subtractions stay reduction-free
+                tpool = tpool.at[wbK].set(totL_raw).at[wnK].set(totR_raw)
+                count_g = count_g.at[wnK].set(nr_g).at[wbK].set(nl_g)
 
-            wb = jnp.where(can, b, L)
-            wn = jnp.where(can, new_leaf, L)
-            depth = depth.at[wb].set(nd).at[wn].set(nd)
-            leaf_best = leaf_best.at[wb].set(lrec).at[wn].set(rrec)
-            leaf_best = leaf_best.at[L].set(jnp.full(REC, neg_inf,
-                                                     dtype=jnp.float32))
-            row = jnp.concatenate([
-                jnp.stack([b.astype(jnp.float32), pout,
-                           nd.astype(jnp.float32),
-                           jnp.where(can, 1.0, 0.0)]), brec])
-            wt = jnp.where(can, t, rec_store.shape[0] - 1)
-            rec_store = rec_store.at[wt].set(row)
-            committed = committed.at[jnp.where(can, pos, K)].set(True)
-            newids = newids.at[jnp.where(can, pos, K)].set(new_leaf)
-            inc = jnp.where(can, 1, 0).astype(jnp.int32)
-            return (leaf_best, depth, rec_store, n_cur + inc, t + inc,
-                    committed, newids, active & can)
-
-        rp0 = (leaf_best, depth, rec_store, n_cur, t,
-               jnp.zeros(K + 1, bool), jnp.zeros(K + 1, jnp.int32),
-               jnp.bool_(True))
-        (leaf_best, depth, rec_store, n_cur, t, committed, newids,
-         _) = jax.lax.fori_loop(0, K, replay_step, rp0)
-
-        # --- commit side effects, all OUTSIDE the replay fori_loop (the
-        # heavy [K, G, B, CH] pool writes and [N]-row updates run once per
-        # wave, vectorized over the committed mask, not once per replay
-        # step). Uncommitted leaves keep their old (start, count, pool)
-        # entries — their ranges were only reordered internally.
-        wbK = jnp.where(committed[:K], sel, L)       # parent keeps left
-        wnK = jnp.where(committed[:K], newids[:K], L)  # new leaf = right
-        pool = pool.at[wbK].set(histL).at[wnK].set(histR)
-        mid_k = s_k + nl_k
-        start = start.at[wnK].set(mid_k)
-        count = count.at[wnK].set(nr_k).at[wbK].set(nl_k)
-        if row_sharded:
-            # replicated raw totals + GLOBAL counts ride with the pool so
-            # later subtractions stay reduction-free
-            tpool = tpool.at[wbK].set(totL_raw).at[wnK].set(totR_raw)
-            count_g = count_g.at[wnK].set(nr_g).at[wbK].set(nl_g)
-
-        # per-row leaf relabel via the same stacked masked matmul (position
-        # >= split midpoint <=> right child, thanks to the partition)
-        post = jnp.stack([committed[:K].astype(jnp.int32), newids[:K],
-                          mid_k], axis=1)  # [K, 3]
-        rowsP = rows_of(post)  # [N, 3]
-        com_row = kvalid & (rowsP[:, 0] > 0.5)
-        is_right = com_row & (pos >= rowsP[:, 2].astype(jnp.int32))
-        leafcol = jnp.where(is_right, rowsP[:, 1], row_p[:, LEAF_COL])
-        row_p = row_p.at[:, LEAF_COL].set(leafcol)
+            # per-row leaf relabel via the same stacked masked matmul (position
+            # >= split midpoint <=> right child, thanks to the partition)
+            post = jnp.stack([committed[:K].astype(jnp.int32), newids[:K],
+                              mid_k], axis=1)  # [K, 3]
+            rowsP = rows_of(post)  # [N, 3]
+            com_row = kvalid & (rowsP[:, 0] > 0.5)
+            is_right = com_row & (pos >= rowsP[:, 2].astype(jnp.int32))
+            leafcol = jnp.where(is_right, rowsP[:, 1], row_p[:, LEAF_COL])
+            row_p = row_p.at[:, LEAF_COL].set(leafcol)
         if voting:
             return (bins_p, row_p, start, count, depth, leaf_best,
                     rec_store, pool, n_cur, t, hist_rows, tpool, count_g,
@@ -836,8 +870,9 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
                 pool, n_cur, t, hist_rows, n_waves)
 
     def cond(carry):
-        leaf_best, t = carry[5], carry[9]
-        return (t < L - 1) & (jnp.max(leaf_best[:L, 0]) > 0)
+        with jax.named_scope(SCOPE_SELECT):
+            leaf_best, t = carry[5], carry[9]
+            return (t < L - 1) & (jnp.max(leaf_best[:L, 0]) > 0)
 
     carry = (bins_p, row_p, start, count, depth, leaf_best, rec_store, pool,
              jnp.int32(1), jnp.int32(0), hist_rows)
@@ -852,12 +887,14 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
         carry[10]
     n_waves = carry[-1]
     if row_sharded:
-        hist_rows = jax.lax.psum(hist_rows, "data")
-    # undo the permutation without a TPU scatter: sort leaf ids by the
-    # original-position column (both exact small ints in f32)
-    _, leaf_sorted = jax.lax.sort_key_val(
-        row_p[:, POS_COL].astype(jnp.int32),
-        row_p[:, LEAF_COL].astype(jnp.int32))
+        with jax.named_scope(SCOPE_ALLREDUCE):
+            hist_rows = jax.lax.psum(hist_rows, "data")
+    with jax.named_scope(SCOPE_FINISH):
+        # undo the permutation without a TPU scatter: sort leaf ids by the
+        # original-position column (both exact small ints in f32)
+        _, leaf_sorted = jax.lax.sort_key_val(
+            row_p[:, POS_COL].astype(jnp.int32),
+            row_p[:, LEAF_COL].astype(jnp.int32))
     if voting:
         return (rec_store[:-1], leaf_sorted[:N], n_cur, hist_rows, n_waves,
                 carry[13])
@@ -1304,10 +1341,11 @@ class DeviceTreeLearner(SerialTreeLearner):
         global_timer.add_count("wave_splits_committed", committed)
         global_timer.add_count("wave_splits_speculated", speculated)
         # flight-recorder mirror: plain already-computed ints, O(1), no
-        # sync — a postmortem sees the last trees' wave shape even with
-        # telemetry off
-        tracing.note("tree_wave", waves=n_waves, committed=committed,
-                     speculated=speculated)
+        # sync — the one in-memory record per tree (a postmortem sees the
+        # last trees' wave shape even with telemetry off)
+        tracing.note("tree_wave", waves=n_waves, wave_k=wave_k,
+                     committed=committed, speculated=speculated,
+                     hist_rows=self.last_hist_rows)
         if telemetry.enabled():
             telemetry.emit(
                 "tree_wave", waves=n_waves, wave_width=wave_k,

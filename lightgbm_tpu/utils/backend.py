@@ -43,4 +43,12 @@ def configure_compile_cache() -> str:
     if not (os.environ.get("JAX_COMPILATION_CACHE_DIR")
             or jax.config.jax_compilation_cache_dir):
         jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    # every operation's name stack and source line is part of the cache key.
+    # JAX leaves metadata out of it, so an executable compiled from ANOTHER
+    # version of this source with the same arithmetic would be loaded with
+    # that version's names, and a device trace would show `lgbm.` scopes
+    # (utils/timer.py) this source no longer has, or none. One key for every
+    # run: a profiled run reads the executable the timed runs ran, and the
+    # first run after an edit that moves a traced line compiles afresh.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return jax.config.jax_compilation_cache_dir

@@ -23,15 +23,14 @@ from typing import Optional
 from .log import Log
 
 ENV_VAR = "LGBM_TPU_PROFILE"
-ENV_VAR_LEGACY = "LGBM_TPU_PROFILE_DIR"  # same job, older spelling
 
 
 @contextlib.contextmanager
 def maybe_trace(trace_dir: Optional[str] = None):
-    """Trace into `trace_dir` (or $LGBM_TPU_PROFILE / $LGBM_TPU_PROFILE_DIR);
-    no-op when unset."""
-    target = (trace_dir or os.environ.get(ENV_VAR)
-              or os.environ.get(ENV_VAR_LEGACY))
+    """Trace into `trace_dir` (or $LGBM_TPU_PROFILE); no-op when unset.
+    With LGBM_TPU_TIMETAG=1 the program's host spans are in the trace too,
+    beside the device operations and their `lgbm.` scopes."""
+    target = trace_dir or os.environ.get(ENV_VAR)
     if not target:
         yield
         return

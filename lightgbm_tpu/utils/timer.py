@@ -22,6 +22,41 @@ import time
 from collections import defaultdict
 from typing import Callable, Dict, Iterator, List, Optional
 
+# Host span labels of the two roots and their children: one root per unit
+# of work (a boosting iteration, a predict call, a streamed chunk), opened
+# through `global_timer.scope` like every other host label.
+SPAN_ITERATION = "iteration"
+SPAN_PREDICT_CALL = "predict_call"
+SPAN_PREDICT_CHUNK = "predict_chunk"
+SPAN_PREDICT_UPLOAD = "predict_upload"
+SPAN_PREDICT_TRAVERSE = "predict_traverse"
+SPAN_PREDICT_FETCH = "predict_fetch"
+
+# Device scopes (`jax.named_scope`): every device operation of the training
+# and predict hot paths carries one of these in its name stack, under ONE
+# prefix so a trace reader can tell the program's scopes from JAX's own
+# name-stack components (`jit(...)`, `while`, `body`, `closed_call`). Where
+# scopes nest, the innermost is the operation's scope. Nothing else spells
+# these names (docs/OBSERVABILITY.md lists what each covers).
+SCOPE_PREFIX = "lgbm."
+SCOPE_TREE_SETUP = SCOPE_PREFIX + "tree_setup"
+SCOPE_SELECT = SCOPE_PREFIX + "select"
+SCOPE_ROUTE = SCOPE_PREFIX + "route"
+SCOPE_COMPACT = SCOPE_PREFIX + "compact"
+SCOPE_HIST = SCOPE_PREFIX + "hist"
+SCOPE_SCAN = SCOPE_PREFIX + "scan"
+SCOPE_ALLREDUCE = SCOPE_PREFIX + "allreduce"
+SCOPE_REPLAY = SCOPE_PREFIX + "replay"
+SCOPE_COMMIT = SCOPE_PREFIX + "commit"
+SCOPE_FINISH = SCOPE_PREFIX + "finish"
+SCOPE_GRADIENTS = SCOPE_PREFIX + "gradients"
+SCOPE_UPDATE_SCORE = SCOPE_PREFIX + "update_score"
+SCOPE_NODE_GATHER = SCOPE_PREFIX + "node_gather"
+SCOPE_FEATURE_GATHER = SCOPE_PREFIX + "feature_gather"
+SCOPE_DECIDE = SCOPE_PREFIX + "decide"
+SCOPE_LEAF_VALUES = SCOPE_PREFIX + "leaf_values"
+SCOPE_ACCUMULATE = SCOPE_PREFIX + "accumulate"
+
 
 class GlobalTimer:
     def __init__(self) -> None:

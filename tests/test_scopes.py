@@ -1,0 +1,433 @@
+"""The program's names for its own work: device scopes (`jax.named_scope`,
+one `lgbm.` prefix, constants in utils/timer.py) in the lowered programs of
+the training and predict hot paths, `name=` on every Pallas call, one root
+host span per boosting iteration and per predict call, one flight note per
+tree and per compile. docs/OBSERVABILITY.md lists what each name covers;
+the chip benchmark reads device time by them (benchmark/readers/).
+
+CPU, test size, Pallas kernels interpreted; nothing here reads a time.
+"""
+import ast
+import pathlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu import tracing
+from lightgbm_tpu.config import Config
+from lightgbm_tpu.io.dataset import Dataset as CoreDataset
+from lightgbm_tpu.models import gbdt as gbdt_mod
+from lightgbm_tpu.models.gbdt import GBDT
+from lightgbm_tpu.objectives import create_objective
+from lightgbm_tpu.ops import predict as predict_mod
+from lightgbm_tpu.parallel import learners as learners_mod
+from lightgbm_tpu.parallel.learners import DeviceDataParallelTreeLearner
+from lightgbm_tpu.treelearner import device as device_mod
+from lightgbm_tpu.treelearner.device import DeviceTreeLearner
+from lightgbm_tpu.utils import profile, timer
+from lightgbm_tpu.utils.timer import global_timer
+
+PACKAGE = pathlib.Path(lgb.__file__).parent
+SCOPES = {name: value for name, value in vars(timer).items()
+          if name.startswith("SCOPE_") and name != "SCOPE_PREFIX"}
+TREE_SCOPES = ["tree_setup", "select", "route", "compact", "hist", "scan",
+               "replay", "commit", "finish"]
+PREDICT_SCOPES = ["node_gather", "feature_gather", "decide", "leaf_values",
+                  "accumulate"]
+PARAMS = {"objective": "binary", "num_leaves": 15, "verbosity": -1}
+
+
+class _Lowered(Exception):
+    """Carries a program's lowered text out of the learner's dispatch."""
+
+
+def _lower_instead_of_running(fn, *_):
+    def dispatch(*args, **kwargs):
+        raise _Lowered(fn.lower(*args, **kwargs).as_text(debug_info=True))
+
+    return dispatch
+
+
+def _data(n=1500, seed=3):
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, 8)
+    return X, (X[:, 0] - 0.7 * X[:, 1] + 0.3 * rng.randn(n) > 0).astype(float)
+
+
+def _booster(learner_cls=DeviceTreeLearner, n=1500):
+    X, y = _data(n)
+    cfg = Config(PARAMS)
+    ds = CoreDataset.from_matrix(X, label=y, config=cfg)
+    bst = GBDT(cfg, ds, create_objective(cfg.objective, cfg))
+    bst.tree_learner = learner_cls(cfg, ds)
+    return bst, X
+
+
+def _dispatched_program(monkeypatch, module, learner_cls) -> str:
+    """The lowered text of the whole-tree program the learner dispatches
+    for its first tree, with the learner's own arguments."""
+    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(module.sanitize, "guard", _lower_instead_of_running)
+    bst, _ = _booster(learner_cls)
+    with pytest.raises(_Lowered) as caught:
+        bst.train_one_iter()
+    return str(caught.value)
+
+
+@pytest.fixture(scope="module")
+def tree_program():
+    mp = pytest.MonkeyPatch()
+    try:
+        return _dispatched_program(mp, device_mod, DeviceTreeLearner)
+    finally:
+        mp.undo()
+
+
+@pytest.fixture(scope="module")
+def predict_program():
+    X, y = _data(600)
+    bst = lgb.train(dict(PARAMS, num_leaves=7), lgb.Dataset(X, label=y),
+                    num_boost_round=3)
+    packed = bst._gbdt._packed()
+    return predict_mod._predict_raw_fused.lower(
+        packed, jnp.asarray(X, jnp.float32), 1).as_text(debug_info=True)
+
+
+# ------------------------------------------------------------ device scopes
+
+
+@pytest.mark.parametrize("scope", TREE_SCOPES)
+def test_whole_tree_program_carries_the_scope(tree_program, scope):
+    assert SCOPES["SCOPE_" + scope.upper()] in tree_program
+
+
+def test_sharded_program_puts_its_collectives_under_allreduce(monkeypatch):
+    text = _dispatched_program(monkeypatch, learners_mod,
+                               DeviceDataParallelTreeLearner)
+    assert timer.SCOPE_ALLREDUCE in text
+    assert timer.SCOPE_HIST in text
+
+
+def test_single_device_program_has_no_allreduce(tree_program):
+    """sharded=False prunes every collective from the trace, and the scope
+    with them."""
+    assert timer.SCOPE_ALLREDUCE not in tree_program
+
+
+def test_gradient_program_carries_its_scope():
+    bst, _ = _booster()
+    text = bst._grad_fn.lower(bst.score[0]).as_text(debug_info=True)
+    assert timer.SCOPE_GRADIENTS in text
+
+
+def test_score_update_program_carries_its_scope():
+    L, n = 15, 64
+    text = gbdt_mod._apply_split_log_to_score.lower(
+        jnp.zeros(n, jnp.float32),
+        jnp.zeros((L - 1, device_mod.STORE), jnp.float32),
+        jnp.zeros(n, jnp.int32), jnp.float32(0.1),
+        num_leaves=L).as_text(debug_info=True)
+    assert timer.SCOPE_UPDATE_SCORE in text
+
+
+@pytest.mark.parametrize("scope", PREDICT_SCOPES)
+def test_predict_program_carries_the_scope(predict_program, scope):
+    assert SCOPES["SCOPE_" + scope.upper()] in predict_program
+
+
+def test_the_table_of_scopes_is_the_constants():
+    """Every scope constant is one of the names checked above, under the
+    one prefix, and no two are alike."""
+    checked = set(TREE_SCOPES + PREDICT_SCOPES
+                  + ["allreduce", "gradients", "update_score"])
+    assert {v for v in SCOPES.values()} == {
+        timer.SCOPE_PREFIX + name for name in checked}
+    assert len(set(SCOPES.values())) == len(SCOPES)
+
+
+def test_scope_names_are_spelled_only_through_the_constants():
+    """No string of the package but utils/timer.py's holds the prefix, and
+    every named_scope takes one of the constants."""
+    offenders = []
+    for path in sorted(PACKAGE.rglob("*.py")):
+        tree = ast.parse(path.read_text())
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Constant) and isinstance(node.value, str)
+                    and re.search(r"\blgbm\.[a-z_]+\b", node.value)
+                    and path.name != "timer.py"
+                    and not _is_docstring(tree, node)):
+                offenders.append((path.name, node.lineno, node.value[:40]))
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "named_scope"):
+                arg = node.args[0]
+                if not (isinstance(arg, ast.Name) and arg.id in SCOPES):
+                    offenders.append((path.name, node.lineno, "named_scope"))
+    assert offenders == []
+
+
+def _is_docstring(tree, constant) -> bool:
+    for node in ast.walk(tree):
+        if isinstance(node, (ast.Module, ast.FunctionDef, ast.ClassDef,
+                             ast.AsyncFunctionDef)):
+            body = node.body
+            if (body and isinstance(body[0], ast.Expr)
+                    and body[0].value is constant):
+                return True
+    return False
+
+
+# ----------------------------------------------------------- Pallas call names
+
+PALLAS_CALLS = {"hist_pallas.py": ["pallas_histogram",
+                                   "pallas_histogram_slots",
+                                   "pallas_histogram_slots_ragged"],
+                "compact_pallas.py": ["_pallas_compact_call"],
+                "scan_pallas.py": ["fused_split_scan"],
+                "predict_pallas.py": ["pallas_predict_raw"]}
+
+
+def _pallas_call_names(path) -> list:
+    names = []
+    for node in ast.walk(ast.parse(path.read_text())):
+        if (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "pallas_call"):
+            kw = {k.arg: k.value for k in node.keywords}
+            assert "name" in kw, f"{path.name}:{node.lineno} has no name="
+            names.append(kw["name"].value)
+    return names
+
+
+@pytest.mark.parametrize("filename", sorted(PALLAS_CALLS))
+def test_every_pallas_call_is_named_after_its_wrapper(filename):
+    assert _pallas_call_names(PACKAGE / "ops" / filename) \
+        == PALLAS_CALLS[filename]
+
+
+def test_no_pallas_call_site_is_left_out():
+    found = {p.name: _pallas_call_names(p)
+             for p in sorted((PACKAGE / "ops").glob("*.py"))}
+    assert {k: v for k, v in found.items() if v} == PALLAS_CALLS
+
+
+# ----------------------------------------------------------------- host spans
+
+
+@pytest.fixture
+def spans(monkeypatch):
+    """Every closed host scope as (label, start, end), timing switched on
+    for the test as LGBM_TPU_TIMETAG=1 switches it on at import."""
+    seen = []
+    monkeypatch.setattr(global_timer, "enabled", True)
+    monkeypatch.setattr(global_timer, "span_hook",
+                        lambda label, t0, t1: seen.append((label, t0, t1)))
+    global_timer.new_epoch()
+    yield seen
+    global_timer.new_epoch()
+
+
+def test_without_timetag_a_scope_opens_no_annotation_and_keeps_no_total(
+        monkeypatch):
+    opened = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda *a, **k: opened.append(a) or (_ for _ in ()))
+    monkeypatch.setattr(global_timer, "enabled", False)
+    global_timer.new_epoch()
+    with global_timer.scope(timer.SPAN_ITERATION):
+        assert global_timer.label_stack[-1] == timer.SPAN_ITERATION
+    assert opened == []
+    assert timer.SPAN_ITERATION not in global_timer.totals
+    assert global_timer.label_stack == []
+
+
+def _inside(child, parent) -> bool:
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+@pytest.mark.parametrize("boosting", ["gbdt", "dart", "rf"])
+def test_three_trees_give_three_iteration_spans_that_hold_their_children(
+        spans, boosting):
+    """One root per boosting iteration whatever the boosting type: it is
+    opened where the iteration's `train_iteration` flight span is, in the
+    engine's loop, so RF (its own train_one_iter) has it too."""
+    X, y = _data(600)
+    params = dict(PARAMS, num_leaves=7, boosting=boosting,
+                  bagging_fraction=0.8, bagging_freq=1)
+    tracing.recorder().reset()
+    lgb.train(params, lgb.Dataset(X, label=y), num_boost_round=3)
+    roots = [s for s in spans if s[0] == timer.SPAN_ITERATION]
+    assert len(roots) == 3
+    assert global_timer.counts[timer.SPAN_ITERATION] == 3
+    inner = ("boosting", "bagging", "tree_train", "update_score")
+    for root in roots:
+        children = [s for s in spans if s[0] in inner and _inside(s, root)]
+        if boosting != "rf":  # RF's own train_one_iter opens none of them
+            assert set(inner) <= {c[0] for c in children}
+        assert sum(c[2] - c[1] for c in children) <= root[2] - root[1]
+    # nothing of the four is opened outside an iteration while training
+    assert [s for s in spans if s[0] in inner
+            and not any(_inside(s, r) for r in roots)] == []
+    # the same interval, once: the iteration's flight span
+    flight = [r for r in tracing.recorder().snapshot()
+              if r["kind"] == "span" and r["name"] == "train_iteration"]
+    assert len(flight) == len(roots)
+    for rec, root in zip(flight, roots):
+        assert rec["t0"] - 1e-5 <= root[1] and root[2] <= rec["t1"] + 1e-5
+
+
+def test_a_predict_call_is_one_root_with_upload_traverse_and_fetch_once(
+        spans):
+    X, y = _data(600)
+    bst = lgb.train(dict(PARAMS, num_leaves=7), lgb.Dataset(X, label=y),
+                    num_boost_round=3)
+    global_timer.new_epoch()
+    del spans[:]
+    bst.predict(X)
+    labels = [s[0] for s in spans]
+    for label in (timer.SPAN_PREDICT_CALL, timer.SPAN_PREDICT_UPLOAD,
+                  timer.SPAN_PREDICT_TRAVERSE, timer.SPAN_PREDICT_FETCH):
+        assert labels.count(label) == 1, (label, labels)
+        assert global_timer.counts[label] == 1
+    root = next(s for s in spans if s[0] == timer.SPAN_PREDICT_CALL)
+    children = [s for s in spans
+                if s[0] in (timer.SPAN_PREDICT_UPLOAD,
+                            timer.SPAN_PREDICT_TRAVERSE,
+                            timer.SPAN_PREDICT_FETCH)]
+    assert all(_inside(c, root) for c in children)
+    assert sum(c[2] - c[1] for c in children) <= root[2] - root[1]
+
+
+def test_a_streamed_predict_opens_one_chunk_span_per_chunk(spans):
+    X, y = _data(600)
+    bst = lgb.train(dict(PARAMS, num_leaves=7), lgb.Dataset(X, label=y),
+                    num_boost_round=3)
+    del spans[:]
+    out = bst.predict(X, pred_chunk_rows=256)
+    np.testing.assert_array_equal(out, bst.predict(X))
+    chunks = [s for s in spans[:spans.index(next(
+        s for s in spans if s[0] == timer.SPAN_PREDICT_CALL)) + 1]
+        if s[0] == timer.SPAN_PREDICT_CHUNK]
+    assert len(chunks) == 3  # 256 + 256 + 88 rows
+    for chunk in chunks:
+        inner = [s[0] for s in spans if s is not chunk and _inside(s, chunk)]
+        assert inner.count(timer.SPAN_PREDICT_UPLOAD) == 1
+        assert inner.count(timer.SPAN_PREDICT_TRAVERSE) == 1
+        assert inner.count(timer.SPAN_PREDICT_FETCH) == 1
+
+
+# --------------------------------------------------------------- flight notes
+
+
+def test_one_tree_wave_note_per_tree_carries_waves_rows_and_width(
+        monkeypatch):
+    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
+    tracing.recorder().reset()
+    rows_before = global_timer.counters["device_hist_rows"]
+    bst, _ = _booster()
+    for _ in range(3):
+        assert not bst.train_one_iter()
+    bst._flush_pending()
+    notes = [n for n in tracing.recorder().snapshot()
+             if n["kind"] == "tree_wave"]
+    assert len(notes) == 3
+    for note, tree in zip(notes, bst.models):
+        assert note["waves"] >= 1
+        assert note["wave_k"] == bst.tree_learner.wave_k
+        assert note["hist_rows"] >= bst.num_data  # the root pass at least
+        assert note["committed"] == tree.num_leaves - 1
+        assert note["speculated"] == note["waves"] * note["wave_k"]
+    assert sum(n["hist_rows"] for n in notes) \
+        == global_timer.counters["device_hist_rows"] - rows_before
+
+
+def _compile_notes():
+    return [n for n in tracing.recorder().snapshot()
+            if n["kind"] == "compile"]
+
+
+def test_a_first_call_leaves_one_compile_note_and_a_second_none():
+    x = jnp.arange(7, dtype=jnp.float32)
+    fresh = jax.jit(lambda v: (v * 3.0 + 1.0).sum())
+    before = len(_compile_notes())
+    fresh(x).block_until_ready()
+    first = _compile_notes()[before:]
+    assert len(first) == 1  # one program, one note
+    assert first[0]["seconds"] > 0.0 and first[0]["cache_hit"] is False
+    fresh(x).block_until_ready()
+    assert len(_compile_notes()) == before + 1
+
+
+def test_a_load_from_the_persistent_cache_is_one_note_marked_a_hit(tmp_path):
+    """jax 0.9.0 fires the retrieval event INSIDE the backend-compile event
+    on a hit: two notes would count the same seconds twice in
+    `*.setup_compile_s`, which sums `seconds` over the compile notes."""
+    from jax._src import compilation_cache
+
+    flags = {"jax_enable_compilation_cache": True,
+             "jax_compilation_cache_dir": str(tmp_path),
+             "jax_persistent_cache_min_compile_time_secs": 0.0,
+             "jax_persistent_cache_min_entry_size_bytes": -1}
+    was = {name: getattr(jax.config, name) for name in flags}
+    x = jnp.arange(11, dtype=jnp.float32)
+
+    try:
+        for name, value in flags.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+        before = len(_compile_notes())
+        # one call site for both: the lines of the call stack are part of
+        # the cache key. The first turn compiles and writes, the second loads
+        for _ in range(2):
+            jax.jit(lambda v: (v * 5.0 - 2.0).sum())(x).block_until_ready()
+            jax.clear_caches()
+    finally:
+        for name, value in was.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+    miss, hit = _compile_notes()[before:]
+    assert miss["cache_hit"] is False and hit["cache_hit"] is True
+    assert miss["seconds"] > 0.0 and hit["seconds"] > 0.0
+
+
+def test_the_compile_cache_is_keyed_by_names_too():
+    """JAX leaves metadata out of the persistent cache's key: an executable
+    compiled from another version of this source with the same arithmetic
+    would bring that version's `lgbm.` scopes into a profile. One key for
+    every run, so a traced run reads the executable the timed runs ran."""
+    from lightgbm_tpu.utils import backend
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, flag)
+    try:
+        jax.config.update(flag, False)
+        backend.configure_compile_cache()
+        assert getattr(jax.config, flag) is True
+    finally:
+        jax.config.update(flag, was)
+
+
+def test_the_legacy_profile_variable_is_gone(monkeypatch, tmp_path):
+    """LGBM_TPU_PROFILE_DIR was a second spelling nothing documented; only
+    LGBM_TPU_PROFILE starts a trace."""
+    started = []
+    monkeypatch.setattr(jax.profiler, "trace",
+                        lambda target: started.append(target) or _null())
+    monkeypatch.delenv("LGBM_TPU_PROFILE", raising=False)
+    monkeypatch.setenv("LGBM_TPU_PROFILE_DIR", str(tmp_path))
+    with profile.maybe_trace():
+        pass
+    assert started == []
+    monkeypatch.setenv("LGBM_TPU_PROFILE", str(tmp_path))
+    with profile.maybe_trace():
+        pass
+    assert started == [str(tmp_path)]
+
+
+def _null():
+    import contextlib
+
+    return contextlib.nullcontext()
