@@ -3,12 +3,30 @@
 TPU-native replacement for the reference's per-row recursive traversal
 (Tree::Predict / NumericalDecision, include/LightGBM/tree.h:338-420, and
 GBDT::PredictRaw, src/boosting/gbdt_prediction.cpp:15-56). All trees are
-packed into padded [T, nodes] SoA tensors and traversed with ONE
-level-synchronous gather loop over the whole forest: every (row, tree)
-pair advances one level per step, rows that reached a leaf (negative node
-id) freeze, and each level issues a single X gather for all T trees (the
-per-tree formulation would issue T). Scores accumulate in-register — the
-[T, N] per-tree score matrix is never materialized.
+packed into padded [T, nodes] SoA tensors (`pack_ensemble`), and the pack
+says which of two programs scores it (`PackedEnsemble.dense`, chosen from
+what the pack can see; `fused_program`):
+
+  * the DENSE evaluation (`_predict_raw_dense`), the TPU's program for
+    numerical forests: no gather. Every node of every tree decides for
+    every row (`numerical_go_left` on whole rows of X^T selected by the
+    nodes' feature ids, bit-exact), and a batched product with the
+    constant path matrix of `path_tables` on the MXU finds the one leaf
+    whose path agrees with all the decisions: exact, and independent of
+    depth. Rows and trees are blocked inside the one program.
+  * the gather TRAVERSAL (`_predict_raw_fused`), the plain version: ONE
+    level-synchronous gather loop over the whole forest, every (row, tree)
+    pair advancing one level per step, rows that reached a leaf (negative
+    node id) freezing, each level issuing a single X gather for all T
+    trees. It scores categorical forests (a bitset lookup is a gather by
+    nature), linear-leaf forests, float64 packs, forests whose path tables
+    would pass DENSE_PATH_BYTES_MAX, everything off the TPU (a gather is
+    cheap there, L * I multiply-adds a (row, tree) are not), and it is
+    what `predict_leaf_indices` and early stopping walk.
+
+Both state the numerical decision once (`numerical_go_left`). Scores
+accumulate in-register: the [T, N] per-tree score matrix is never
+materialized.
 
 Serving-path machinery on top of the traversal:
 
@@ -35,14 +53,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import perfmodel, telemetry
+from .. import perfmodel, telemetry, tracing
 from ..common import MISSING_NAN, MISSING_ZERO, K_ZERO_THRESHOLD
 from ..models.tree import Tree
-from ..utils.backend import pallas_interpret
+from ..utils.backend import on_tpu, pallas_interpret
 from ..utils.log import Log
 from ..utils.timer import (SCOPE_ACCUMULATE, SCOPE_DECIDE,
                            SCOPE_FEATURE_GATHER, SCOPE_LEAF_VALUES,
-                           SCOPE_NODE_GATHER, SPAN_PREDICT_CHUNK,
+                           SCOPE_NODE_GATHER, SCOPE_PATH_MATCH,
+                           SPAN_PREDICT_CHUNK,
                            SPAN_PREDICT_FETCH, SPAN_PREDICT_TRAVERSE,
                            SPAN_PREDICT_UPLOAD, global_timer)
 
@@ -74,6 +93,10 @@ class PackedEnsemble:
     lin_const: Optional[jax.Array] = None  # [T, L] (leaf_value for non-linear trees)
     lin_feat: Optional[jax.Array] = None  # [T, L, K] int32, -1 padding
     lin_coeff: Optional[jax.Array] = None  # [T, L, K]
+    # the dense evaluation's constants (path_tables); None on a gather pack
+    dense: bool = False  # static: which program predict_raw dispatches
+    path: Optional[jax.Array] = None  # [T, Lp, Ip] int8 in {+1, -1, 0}
+    path_depth: Optional[jax.Array] = None  # [T, Lp] float32, +inf padding
 
     def tree_slice(self, start: int, end: int) -> "PackedEnsemble":
         return PackedEnsemble(
@@ -93,6 +116,9 @@ class PackedEnsemble:
             lin_const=self.lin_const[start:end] if self.linear else None,
             lin_feat=self.lin_feat[start:end] if self.linear else None,
             lin_coeff=self.lin_coeff[start:end] if self.linear else None,
+            dense=self.dense,
+            path=self.path[start:end] if self.dense else None,
+            path_depth=self.path_depth[start:end] if self.dense else None,
         )
 
 
@@ -101,11 +127,71 @@ jax.tree_util.register_pytree_node(
     lambda p: ((p.split_feature, p.threshold, p.decision_type, p.left_child,
                 p.right_child, p.leaf_value, p.cat_words, p.cat_offset,
                 p.cat_n_words, p.num_leaves, p.lin_const, p.lin_feat,
-                p.lin_coeff), (p.max_depth, p.num_trees, p.linear)),
+                p.lin_coeff, p.path, p.path_depth),
+               (p.max_depth, p.num_trees, p.linear, p.dense)),
     lambda aux, ch: PackedEnsemble(
         *ch[:10], max_depth=aux[0], num_trees=aux[1], linear=aux[2],
-        lin_const=ch[10], lin_feat=ch[11], lin_coeff=ch[12]),
+        lin_const=ch[10], lin_feat=ch[11], lin_coeff=ch[12], dense=aux[3],
+        path=ch[13], path_depth=ch[14]),
 )
+
+
+# The dense evaluation (_predict_raw_dense) keeps T * Lp * Ip bytes of path
+# constants on the device where the traversal keeps T * I words, and pays
+# O(L * I) a (row, tree) where the traversal pays O(depth). The benchmark's
+# 500 trees of 255 leaves need 32.8 MB and score 50x and more faster dense
+# (PERF.md section 6, PR 27); trees of 4,095 leaves would need 16.8 MB EACH.
+# A forest over this many bytes keeps the gather traversal.
+DENSE_PATH_BYTES_MAX = 256 << 20
+_DENSE_PAD = 32  # Lp, Ip: multiples of the int8 sublane tile
+
+
+def path_tables(left_child: np.ndarray, right_child: np.ndarray,
+                num_leaves: np.ndarray, Lp: int, Ip: int):
+    """The constants of the dense evaluation, from the padded [T, I] child
+    tables (internal node j >= 0, leaf l as ~l):
+
+      path[t, l, i]  +1 where leaf l lies under the LEFT child of node i,
+                     -1 under the right, 0 where i is not on l's path
+      depth[t, l]    the number of nodes on l's path: with d[i] = +1 for
+                     "row goes left at i" and -1 for right, the row is in
+                     leaf l iff sum_i path[l, i] * d[i] == depth[l]. 0 for
+                     a stump's one leaf (the empty sum matches); +inf, which
+                     no sum reaches, for the leaves a tree does not have.
+
+    Every leaf of every tree climbs one level per pass, so the host work is
+    max_depth vectorised passes over [T, L]."""
+    T, I = left_child.shape
+    L = I + 1
+    node_up = np.full((T, I), -1, dtype=np.int64)  # parent of an internal node
+    node_side = np.zeros((T, I), dtype=np.int8)
+    leaf_up = np.full((T, L), -1, dtype=np.int64)
+    leaf_side = np.zeros((T, L), dtype=np.int8)
+    real = np.arange(I)[None, :] < (num_leaves[:, None] - 1)
+    for side, child in ((1, left_child), (-1, right_child)):
+        t, i = np.nonzero(real & (child < 0))
+        leaf_up[t, ~child[t, i]] = i
+        leaf_side[t, ~child[t, i]] = side
+        t, i = np.nonzero(real & (child >= 0))
+        node_up[t, child[t, i]] = i
+        node_side[t, child[t, i]] = side
+    path = np.zeros((T, Lp, Ip), dtype=np.int8)
+    depth = np.zeros((T, L), dtype=np.int64)
+    at, side = leaf_up, leaf_side
+    for _ in range(I):  # a path holds at most I nodes: a cyclic table ends
+        on = at >= 0
+        if not on.any():
+            break
+        t, l = np.nonzero(on)
+        path[t, l, at[t, l]] = side[t, l]
+        depth += on
+        up = np.where(on, at, 0)
+        side = np.take_along_axis(node_side, up, axis=1)
+        at = np.where(on, np.take_along_axis(node_up, up, axis=1), -1)
+    depth_p = np.full((T, Lp), np.inf, dtype=np.float32)
+    has = np.arange(L)[None, :] < num_leaves[:, None]
+    depth_p[:, :L][has] = depth[has]
+    return path, depth_p
 
 
 def pack_ensemble(trees: Sequence[Tree], dtype=jnp.float32,
@@ -181,6 +267,20 @@ def pack_ensemble(trees: Sequence[Tree], dtype=jnp.float32,
             over = th32.astype(np.float64) > th
             th32[over] = np.nextafter(th32[over], -np.inf)
             th = th32
+        # which program scores this pack, from what the pack can see: a
+        # categorical node is a bitset lookup (a gather by nature), linear
+        # leaves keep eager score math, a float64 pack keeps its width, and
+        # off the TPU a gather is cheap where L * I multiply-adds a (row,
+        # tree) are not
+        Lp, Ip = (-(-d // _DENSE_PAD) * _DENSE_PAD for d in (L, I))
+        dense = (len(trees) > 0 and not any_linear
+                 and np.dtype(dtype) == np.float32 and not (dt & 1).any()
+                 and T * Lp * Ip <= DENSE_PATH_BYTES_MAX and on_tpu())
+        path = path_depth = None
+        if dense:
+            path, path_depth = path_tables(lc, rc, nl, Lp, Ip)
+            path = jnp.asarray(path, dtype=jnp.int8)
+            path_depth = jnp.asarray(path_depth, dtype=jnp.float32)
         return PackedEnsemble(
             split_feature=jnp.asarray(sf, dtype=jnp.int32),
             threshold=jnp.asarray(th, dtype=jnp.float64 if f64_effective else jnp.float32),
@@ -199,6 +299,7 @@ def pack_ensemble(trees: Sequence[Tree], dtype=jnp.float32,
             lin_const=jnp.asarray(lin_const, dtype=dtype) if any_linear else None,
             lin_feat=jnp.asarray(lin_feat, dtype=jnp.int32) if any_linear else None,
             lin_coeff=jnp.asarray(lin_coeff, dtype=dtype) if any_linear else None,
+            dense=dense, path=path, path_depth=path_depth,
         )
 
 
@@ -213,6 +314,24 @@ def predict_dtype(X):
 
 
 # --------------------------------------------------------------- traversal
+
+
+def numerical_go_left(fval: jax.Array, thr: jax.Array,
+                      dt: jax.Array) -> jax.Array:
+    """NumericalDecision (tree.h:338-355), the one statement of the rule:
+    True where a row holding `fval` goes LEFT at a numerical node with
+    threshold `thr` and decision type `dt` (bit 1: default_left, bits 2-3:
+    missing_type). The gather traversal hands it gathered [N, T] node
+    fields, the dense evaluation [.., I, 1] node constants that broadcast
+    along the rows."""
+    default_left = (dt & 2) > 0
+    missing_type = (dt >> 2) & 3
+    is_nan = jnp.isnan(fval)
+    fval_num = jnp.where(is_nan & (missing_type != MISSING_NAN), 0.0, fval)
+    is_missing = ((missing_type == MISSING_ZERO)
+                  & (jnp.abs(fval_num) <= _EPS)) | (
+        (missing_type == MISSING_NAN) & jnp.isnan(fval_num))
+    return jnp.where(is_missing, default_left, fval_num <= thr)
 
 
 def forest_level_step(X: jax.Array, node: jax.Array, sf: jax.Array,
@@ -243,17 +362,9 @@ def forest_level_step(X: jax.Array, node: jax.Array, sf: jax.Array,
     with jax.named_scope(SCOPE_DECIDE):
         active = node >= 0
         is_cat = (d & 1) > 0
-        default_left = (d & 2) > 0
-        missing_type = (d >> 2) & 3
-        # --- numerical decision (tree.h:338-355)
-        is_nan = jnp.isnan(fval)
-        fval_num = jnp.where(is_nan & (missing_type != MISSING_NAN), 0.0, fval)
-        is_missing = ((missing_type == MISSING_ZERO)
-                      & (jnp.abs(fval_num) <= _EPS)) | (
-            (missing_type == MISSING_NAN) & jnp.isnan(fval_num))
-        go_left_num = jnp.where(is_missing, default_left, fval_num <= thr)
+        go_left_num = numerical_go_left(fval, thr, d)
         # --- categorical decision (tree.h:375-388)
-        int_fval = jnp.where(is_nan, -1, fval.astype(jnp.int32))
+        int_fval = jnp.where(jnp.isnan(fval), -1, fval.astype(jnp.int32))
         word_idx = jnp.clip(int_fval, 0, None) // 32
         bit_idx = jnp.clip(int_fval, 0, None) % 32
         in_range = (int_fval >= 0) & (word_idx < n_words)
@@ -328,6 +439,106 @@ def _predict_raw_fused(packed: PackedEnsemble, X: jax.Array,
                             num_tree_per_iteration).sum(axis=1)
 
 
+# ------------------------------------------------------------------- dense
+#
+# The TPU's program for numerical forests (PackedEnsemble.dense): no gather.
+# EVERY node of every tree decides for every row, rows on the lanes
+# ([nodes, rows], so node constants broadcast and no array has a tiny minor
+# dimension), and the row's leaf is the one whose path constants agree with
+# all its decisions: one batched [L, I] @ [I, rows] product a tree on the
+# MXU. Cost does not depend on depth. One (tree block, row chunk) step of
+# the loops below holds _DENSE_STEP_ELEMS node x row elements at a time, so
+# the [T*I, N] intermediates never exist whole.
+
+# Block sizes read on the chip at the benchmark's 500 x 255 forest, 131,072
+# rows (PERF.md section 6, PR 27): 2,048-row chunks of 2^24-element steps
+# score a call in 0.175 s; 8,192 rows or 2^25 elements take 0.21-0.28 s.
+_DENSE_ROW_CHUNK = 2048
+_DENSE_STEP_ELEMS = 1 << 24
+
+
+def _dense_leaf_match(xt: jax.Array, sf: jax.Array, thr: jax.Array,
+                      dt: jax.Array, path: jax.Array,
+                      depth: jax.Array) -> jax.Array:
+    """match[t, l, r]: row r of the chunk xt [F, rows] is in leaf l of tree
+    t, for a block of trees given by their [tb, Ip] node constants and
+    their path_tables. Exactly one real leaf of a tree matches a row."""
+    tb, Ip = sf.shape
+    with jax.named_scope(SCOPE_FEATURE_GATHER):
+        # whole rows of the chunk's X^T by the nodes' feature ids: each
+        # gathered element is `rows` contiguous floats, copied, so fval
+        # holds the input's bits (an id past the last feature reads NaN,
+        # as the traversal's gather does)
+        fval = jnp.take(xt, sf.reshape(-1), axis=0).reshape(
+            tb, Ip, xt.shape[1])
+    with jax.named_scope(SCOPE_DECIDE):
+        d = jnp.where(numerical_go_left(fval, thr[:, :, None],
+                                        dt[:, :, None]), 1, -1
+                      ).astype(jnp.bfloat16)
+    with jax.named_scope(SCOPE_PATH_MATCH):
+        # +-1/0 operands are exact in bfloat16 and the sums are integers of
+        # at most Ip terms accumulated in float32: the match is exact
+        s = jnp.einsum("tli,tir->tlr", path.astype(jnp.bfloat16), d,
+                       preferred_element_type=jnp.float32)
+    with jax.named_scope(SCOPE_LEAF_VALUES):
+        return s == depth[:, :, None]
+
+
+@partial(jax.jit, static_argnames=("num_tree_per_iteration",))
+def _predict_raw_dense(packed: PackedEnsemble, X: jax.Array,
+                       num_tree_per_iteration: int) -> jax.Array:
+    """[N, C] raw scores of a dense pack, gather-free (above); the pack's
+    trees must cover whole iterations."""
+    C = num_tree_per_iteration
+    n = X.shape[0]
+    T, Lp, Ip = packed.path.shape
+    rows = min(_DENSE_ROW_CHUNK, -(-max(n, 1) // 128) * 128)
+    n_chunks = -(-n // rows)
+    # whole iterations a block, so a block's trees fold onto the classes
+    tb = min(C * max(1, _DENSE_STEP_ELEMS // (Ip * rows * C)), T)
+    n_blocks = -(-T // tb)
+
+    def blocks(a, to, fill=0):
+        """[T, k, ..] -> [n_blocks, tb, to, ..]: padded nodes and leaves
+        are off every path, padded trees have no leaf to match."""
+        pad = [(0, n_blocks * tb - T), (0, to - a.shape[1])] \
+            + [(0, 0)] * (a.ndim - 2)
+        a = jnp.pad(a, pad, constant_values=fill)
+        return a.reshape((n_blocks, tb) + a.shape[1:])
+
+    with jax.named_scope(SCOPE_FEATURE_GATHER):
+        xt = jnp.pad(X.T, ((0, 0), (0, n_chunks * rows - n)))
+        xt = xt.reshape(X.shape[1], n_chunks, rows).transpose(1, 0, 2)
+    tables = (blocks(packed.split_feature, Ip), blocks(packed.threshold, Ip),
+              blocks(packed.decision_type, Ip),
+              blocks(packed.path, Lp),
+              blocks(packed.path_depth, Lp, jnp.inf),
+              blocks(packed.leaf_value, Lp))
+
+    def chunk(xt_c):
+        def block(acc, tab):
+            *node_tables, lv = tab
+            match = _dense_leaf_match(xt_c, *node_tables)
+            with jax.named_scope(SCOPE_LEAF_VALUES):
+                # one leaf of a tree matches: the sum has one term
+                vals = jnp.where(match, lv[:, :, None], 0).sum(axis=1)
+            with jax.named_scope(SCOPE_ACCUMULATE):
+                return acc + vals.reshape(tb // C, C, rows).sum(axis=0), None
+
+        with jax.named_scope(SCOPE_ACCUMULATE):
+            acc0 = jnp.zeros((C, rows), dtype=packed.leaf_value.dtype)
+        return jax.lax.scan(block, acc0, tables)[0]
+
+    out = jax.lax.map(chunk, xt)  # [n_chunks, C, rows]
+    with jax.named_scope(SCOPE_ACCUMULATE):
+        return out.transpose(0, 2, 1).reshape(n_chunks * rows, C)[:n]
+
+
+def fused_program(packed: PackedEnsemble):
+    """The jitted program that scores this pack: the pack says which."""
+    return _predict_raw_dense if packed.dense else _predict_raw_fused
+
+
 _leaf_indices_fused = jax.jit(_traverse_leaves)
 
 
@@ -361,13 +572,20 @@ def predict_raw(packed: PackedEnsemble, X: jax.Array,
                 num_tree_per_iteration: int = 1) -> jax.Array:
     """Raw scores [N, num_tree_per_iteration] summed over iterations. The
     one boundary of the `predict_traverse` span in this module: whichever
-    program traverses, its dispatch is inside it once."""
+    program scores, its dispatch is inside it once, and one
+    `predict_traverse` flight note and one counter say which it was."""
     T = packed.num_trees
     if T == 0:
         return jnp.zeros((X.shape[0], num_tree_per_iteration), dtype=X.dtype)
     validate_tree_count(packed, num_tree_per_iteration)
+    pallas = predict_pallas_enabled() and not packed.linear
+    dense = packed.dense and not pallas
+    tracing.note(SPAN_PREDICT_TRAVERSE, dense=int(dense),
+                 rows=int(X.shape[0]), trees=T)
+    global_timer.add_count(
+        "predict_dense_calls" if dense else "predict_gather_calls", 1)
     with global_timer.scope(SPAN_PREDICT_TRAVERSE):
-        if predict_pallas_enabled() and not packed.linear:
+        if pallas:
             from .predict_pallas import pallas_predict_raw
 
             return pallas_predict_raw(packed, X, num_tree_per_iteration,
@@ -381,11 +599,12 @@ def predict_raw(packed: PackedEnsemble, X: jax.Array,
             n, T = vals.shape
             return vals.reshape(n, T // num_tree_per_iteration,
                                 num_tree_per_iteration).sum(axis=1)
+        program = fused_program(packed)
         if telemetry.enabled():
             # one-time dispatch capture for perfmodel's AOT cost_analysis
-            perfmodel.note_dispatch("predict", _predict_raw_fused,
+            perfmodel.note_dispatch("predict", program,
                                     packed, X, num_tree_per_iteration)
-        return _predict_raw_fused(packed, X, num_tree_per_iteration)
+        return program(packed, X, num_tree_per_iteration)
 
 
 # --------------------------------------------------------------------- aot
@@ -445,7 +664,7 @@ def aot_compile(packed: PackedEnsemble, n_rows: int, n_cols: int,
     touching (or populating) the jit dispatch cache."""
     xs = jax.ShapeDtypeStruct((int(n_rows), int(n_cols)),
                               np.dtype(x_dtype))
-    return _predict_raw_fused.lower(
+    return fused_program(packed).lower(
         packed, xs, num_tree_per_iteration=num_tree_per_iteration).compile()
 
 

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
 
-from ..ops.predict import PackedEnsemble, _predict_raw_fused, validate_tree_count
+from ..ops.predict import PackedEnsemble, fused_program, validate_tree_count
 from ..utils.timer import global_timer
 from .dist import put_global, put_replicated
 from .mesh import data_mesh, padded_row_count
@@ -61,7 +61,7 @@ def _sharded_predict_fn(mesh: jax.sharding.Mesh, num_tree_per_iteration: int):
     P = jax.sharding.PartitionSpec
 
     def body(packed, x):
-        return _predict_raw_fused(packed, x, num_tree_per_iteration)
+        return fused_program(packed)(packed, x, num_tree_per_iteration)
 
     fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(), P("data")),
                            out_specs=P("data"), check_vma=False))

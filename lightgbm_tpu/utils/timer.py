@@ -54,6 +54,7 @@ SCOPE_UPDATE_SCORE = SCOPE_PREFIX + "update_score"
 SCOPE_NODE_GATHER = SCOPE_PREFIX + "node_gather"
 SCOPE_FEATURE_GATHER = SCOPE_PREFIX + "feature_gather"
 SCOPE_DECIDE = SCOPE_PREFIX + "decide"
+SCOPE_PATH_MATCH = SCOPE_PREFIX + "path_match"
 SCOPE_LEAF_VALUES = SCOPE_PREFIX + "leaf_values"
 SCOPE_ACCUMULATE = SCOPE_PREFIX + "accumulate"
 

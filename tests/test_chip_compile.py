@@ -13,6 +13,8 @@ on-chip-measurement guide), every compile happens in the test's own process,
 and the persistent compilation cache is off around them: an executable
 compiled for an absent chip cannot be read back.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,7 +27,8 @@ from lightgbm_tpu.ops.compact_pallas import (COMPACT_TILE, _pallas_compact_call,
                                              max_pairs_bound)
 from lightgbm_tpu.ops.hist_pallas import (DEFAULT_TILE_ROWS, pallas_histogram,
                                           pallas_histogram_slots_ragged)
-from lightgbm_tpu.ops.predict import PackedEnsemble, _predict_raw_fused
+from lightgbm_tpu.ops.predict import (PackedEnsemble, _predict_raw_dense,
+                                      _predict_raw_fused)
 from lightgbm_tpu.ops.predict_pallas import pallas_predict_raw
 from lightgbm_tpu.treelearner import device as device_mod
 
@@ -128,6 +131,25 @@ def test_predict_program_fits_at_the_streaming_chunk(on_chip):
         num_tree_per_iteration=1).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+
+
+def test_dense_predict_program_fits_with_room(on_chip):
+    """The gather-free program of the same forest at the streaming chunk:
+    rows and trees are blocked inside it, so its temp stays far under the
+    chip however many rows a call brings, and no node table is gathered
+    (the one gather left is the row gather of X^T by feature id)."""
+    packed = dataclasses.replace(
+        _packed_500x255(on_chip), dense=True,
+        path=on_chip((500, 256, 256), jnp.int8),
+        path_depth=on_chip((500, 256), jnp.float32))
+    compiled = _predict_raw_dense.lower(
+        packed, on_chip((1 << 18, FEATURES), jnp.float32),
+        num_tree_per_iteration=1).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 4 << 30
+    text = compiled.as_text()
+    assert text.count(" gather(") == 1
+    assert "convolution(" in text
 
 
 # The two opt-in kernels Mosaic refuses today. Neither is reachable with

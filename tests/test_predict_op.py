@@ -4,10 +4,13 @@ import jax.numpy as jnp
 import pytest
 
 import lightgbm_tpu as lgb
+from lightgbm_tpu import tracing
 from lightgbm_tpu.common import MISSING_ZERO, K_ZERO_THRESHOLD
 from lightgbm_tpu.models.tree import Tree, MISSING_NONE, MISSING_NAN
+from lightgbm_tpu.ops import predict as predict_mod
 from lightgbm_tpu.ops.predict import pack_ensemble, predict_raw, predict_leaf_indices
 from lightgbm_tpu.utils.log import LightGBMError
+from lightgbm_tpu.utils.timer import SPAN_PREDICT_TRAVERSE, global_timer
 from tests.test_tree import make_simple_tree
 
 
@@ -314,3 +317,269 @@ def test_threshold_downcast_preserves_f32_decisions():
     out = np.asarray(predict_raw(packed, X))[:, 0]
     assert out[0] == -1.0  # x <= t64 -> left, preserved after downcast
     assert out[1] == 1.0
+
+
+# ------------------------------------------- dense (gather-free) evaluation
+#
+# The TPU's program for numerical forests, reached here on the CPU by
+# answering on_tpu() for pack_ensemble (the pack decides which program
+# scores it) and calling the dense program directly. The gather traversal
+# is the reference: leaf membership must agree exactly.
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    monkeypatch.setattr(predict_mod, "on_tpu", lambda: True)
+
+
+def _random_tree(rng, num_leaves, n_features, missing_types=(MISSING_NONE,),
+                 max_leaves=None):
+    """A seeded numerical tree: the leaf to split, the feature, the
+    threshold, the missing type and the default side all drawn."""
+    t = Tree(max_leaves=max_leaves or max(num_leaves, 2))
+    if num_leaves == 1:
+        t.as_constant_tree(float(rng.randn()))
+        return t
+    for _ in range(num_leaves - 1):
+        t.split(leaf=int(rng.randint(t.num_leaves)), feature_inner=0,
+                real_feature=int(rng.randint(n_features)), threshold_bin=1,
+                threshold_double=float(rng.randn()),
+                default_left=bool(rng.randint(2)),
+                missing_type=int(missing_types[rng.randint(
+                    len(missing_types))]),
+                gain=1.0, left_value=float(rng.randn()),
+                right_value=float(rng.randn()), left_count=1, right_count=1,
+                left_weight=1.0, right_weight=1.0, parent_value=0.0)
+    return t
+
+
+def _dense_leaves(packed, X):
+    """[N, T] leaf per row per tree from the dense program's path match,
+    the whole forest as one block; asserts one real leaf matches."""
+    T, Lp, Ip = packed.path.shape
+
+    def padded(a):
+        return jnp.pad(a, ((0, 0), (0, Ip - a.shape[1])))
+
+    match = np.asarray(predict_mod._dense_leaf_match(
+        jnp.asarray(X).T, padded(packed.split_feature),
+        padded(packed.threshold), padded(packed.decision_type),
+        packed.path, packed.path_depth))  # [T, Lp, N]
+    assert (match.sum(axis=1) == 1).all()
+    real = np.arange(Lp)[None, :, None] < np.asarray(
+        packed.num_leaves)[:, None, None]
+    assert not (match & ~real).any()
+    return match.argmax(axis=1).T
+
+
+_FOREST_SHAPES = {
+    "ragged": dict(leaves=[2, 3, 7, 15, 31, 6]),
+    "stump_among": dict(leaves=[1, 9, 1, 4]),
+    "stump_alone": dict(leaves=[1]),
+    "padded_nodes": dict(leaves=[5, 33]),  # I = 32 -> Ip = 32, L = 33 -> Lp = 64
+    "fixed_leaves": dict(leaves=[7], fixed_leaves=31, fixed_depth=12),
+    "deep_chain": dict(leaves=[40]),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_FOREST_SHAPES))
+def test_dense_leaf_membership_equals_the_traversal(rng, as_on_tpu, shape):
+    spec = _FOREST_SHAPES[shape]
+    trees = [_random_tree(rng, n, 6, (MISSING_NONE, MISSING_ZERO, MISSING_NAN))
+             for n in spec["leaves"]]
+    packed = pack_ensemble(trees, fixed_leaves=spec.get("fixed_leaves", 0),
+                           fixed_depth=spec.get("fixed_depth", 0))
+    assert packed.dense
+    X = rng.randn(300, 6).astype(np.float32)
+    X[rng.rand(300, 6) < 0.1] = np.nan
+    X[rng.rand(300, 6) < 0.1] = 0.0
+    want = np.asarray(predict_mod._traverse_leaves(packed, jnp.asarray(X)))
+    np.testing.assert_array_equal(_dense_leaves(packed, X), want)
+
+
+def _ulp_neighbours(v):
+    v = np.float32(v)
+    return [np.nextafter(v, np.float32(-np.inf)), v,
+            np.nextafter(v, np.float32(np.inf))]
+
+
+@pytest.mark.parametrize("default_left", [False, True])
+@pytest.mark.parametrize("missing_type",
+                         [MISSING_NONE, MISSING_ZERO, MISSING_NAN])
+def test_dense_decisions_agree_on_special_values(as_on_tpu, missing_type,
+                                                 default_left):
+    """NaN, the infinities, both zeros, the zero band's edge and the values
+    one ulp either side of each threshold, through a three-node tree whose
+    thresholds include one float32 cannot hold."""
+    thresholds = [0.5, 1.0000001 + 1e-12, -1e-35]
+    tree = Tree(max_leaves=4)
+    leaf = 0
+    for k, thr in enumerate(thresholds):
+        leaf = tree.split(leaf, 0, 0, 1, thr, default_left, missing_type,
+                          1.0, float(k + 1), float(-k - 1), 1, 1, 1.0, 1.0,
+                          0.0)
+    packed = pack_ensemble([tree])
+    assert packed.dense
+    values = [np.nan, np.inf, -np.inf, 0.0, -0.0, 1e-35, -1e-35]
+    for thr in np.asarray(packed.threshold)[0]:
+        values += _ulp_neighbours(thr)
+    values += _ulp_neighbours(K_ZERO_THRESHOLD) + _ulp_neighbours(
+        -K_ZERO_THRESHOLD)
+    X = np.array(values, dtype=np.float32)[:, None]
+    want = np.asarray(predict_mod._traverse_leaves(packed, jnp.asarray(X)))
+    np.testing.assert_array_equal(_dense_leaves(packed, X), want)
+    # and the scores carry them: bit-equal where one tree is summed
+    np.testing.assert_array_equal(
+        np.asarray(predict_mod._predict_raw_dense(packed, jnp.asarray(X), 1)),
+        np.asarray(predict_mod._predict_raw_fused(packed, jnp.asarray(X), 1)))
+
+
+@pytest.mark.parametrize("case", ["one_tree_bit_equal", "forest",
+                                  "multiclass_3", "ragged_row_block",
+                                  "tree_blocks", "no_rows"])
+def test_dense_scores_equal_the_fused_traversal(rng, as_on_tpu, monkeypatch,
+                                                case):
+    n_trees = {"one_tree_bit_equal": 1, "multiclass_3": 12}.get(case, 10)
+    C = 3 if case == "multiclass_3" else 1
+    n = {"ragged_row_block": 333, "tree_blocks": 150,
+         "no_rows": 0}.get(case, 256)
+    if case == "ragged_row_block":  # three 128-row chunks, the last 77 rows
+        monkeypatch.setattr(predict_mod, "_DENSE_ROW_CHUNK", 128)
+    if case == "tree_blocks":  # three blocks of 4 trees, the last 2 padding
+        monkeypatch.setattr(predict_mod, "_DENSE_STEP_ELEMS", 4 * 32 * 256)
+    trees = [_random_tree(rng, int(rng.randint(2, 30)), 5,
+                          (MISSING_NONE, MISSING_NAN))
+             for _ in range(n_trees)]
+    packed = pack_ensemble(trees)
+    X = rng.randn(n, 5).astype(np.float32)
+    X[rng.rand(n, 5) < 0.05] = np.nan
+    # jit caches by the function: the patched block sizes need a fresh trace
+    dense = jax.jit(predict_mod._predict_raw_dense.__wrapped__,
+                    static_argnames=("num_tree_per_iteration",))
+    got = np.asarray(dense(packed, jnp.asarray(X), num_tree_per_iteration=C))
+    want = np.asarray(predict_mod._predict_raw_fused(packed, jnp.asarray(X), C))
+    assert got.shape == want.shape == (n, C)
+    if case == "one_tree_bit_equal":
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+
+
+def test_tree_slice_of_a_dense_pack(rng, as_on_tpu):
+    trees = [_random_tree(rng, int(rng.randint(2, 20)), 4) for _ in range(9)]
+    packed = pack_ensemble(trees)
+    sl = packed.tree_slice(3, 7)
+    assert sl.dense and sl.num_trees == 4
+    assert sl.path.shape == (4,) + packed.path.shape[1:]
+    assert sl.path_depth.shape == (4, packed.path_depth.shape[1])
+    X = jnp.asarray(rng.randn(64, 4).astype(np.float32))
+    np.testing.assert_allclose(
+        np.asarray(predict_mod._predict_raw_dense(sl, X, 1)),
+        np.asarray(predict_mod._predict_raw_fused(
+            pack_ensemble(trees[3:7]), X, 1)), rtol=0, atol=1e-6)
+    # the aux data follows the slice through a pytree round trip
+    leaves, treedef = jax.tree_util.tree_flatten(sl)
+    assert jax.tree_util.tree_unflatten(treedef, leaves).dense
+
+
+def test_path_tables_of_the_simple_tree():
+    """f0 <= 0.5 -> leaf0; else f1 <= 2.5 -> leaf1 else leaf2."""
+    t = make_simple_tree()
+    path, depth = predict_mod.path_tables(
+        t.left_child[None, :2], t.right_child[None, :2],
+        np.array([3], dtype=np.int32), Lp=4, Ip=4)
+    assert path[0].tolist() == [[1, 0, 0, 0], [-1, 1, 0, 0], [-1, -1, 0, 0],
+                                [0, 0, 0, 0]]
+    assert depth[0].tolist() == [1.0, 2.0, 2.0, np.inf]
+
+
+# ----------------------------------------------- which program scores a pack
+
+def _categorical_pack():
+    return pack_ensemble([_nan_cat_tree(), make_simple_tree()])
+
+
+def _linear_pack():
+    t = make_simple_tree()
+    t.is_linear = True
+    t.leaf_const = np.array(t.leaf_value[: t.max_leaves], dtype=np.float64)
+    t.leaf_coeff = [[0.5], [], []] + [[]] * (t.max_leaves - 3)
+    t.leaf_features = [[0], [], []] + [[]] * (t.max_leaves - 3)
+    return pack_ensemble([t])
+
+
+def _over_the_byte_bound_pack(monkeypatch):
+    monkeypatch.setattr(predict_mod, "DENSE_PATH_BYTES_MAX", 3 * 32 * 32 - 1)
+    return pack_ensemble([make_simple_tree() for _ in range(3)])
+
+
+def _cpu_pack(monkeypatch):
+    monkeypatch.undo()  # on_tpu() answers for this process again: the CPU
+    return pack_ensemble([make_simple_tree()])
+
+
+_GATHER_PACKS = {
+    "one_categorical_node": lambda mp: _categorical_pack(),
+    "linear_leaves": lambda mp: _linear_pack(),
+    "over_the_byte_bound": _over_the_byte_bound_pack,
+    "cpu_backend": _cpu_pack,
+}
+
+
+def _run_and_read_the_choice(packed, X):
+    tracing.recorder().reset()
+    before = {k: global_timer.counters[k]
+              for k in ("predict_dense_calls", "predict_gather_calls")}
+    out = predict_raw(packed, X)
+    notes = [r for r in tracing.recorder().snapshot()
+             if r["kind"] == SPAN_PREDICT_TRAVERSE]
+    assert len(notes) == 1
+    assert notes[0]["rows"] == X.shape[0]
+    assert notes[0]["trees"] == packed.num_trees
+    moved = {k: global_timer.counters[k] - v for k, v in before.items()}
+    return out, notes[0]["dense"], moved
+
+
+@pytest.mark.parametrize("why", sorted(_GATHER_PACKS))
+def test_a_pack_the_dense_program_cannot_serve_takes_the_gather_path(
+        as_on_tpu, monkeypatch, why):
+    packed = _GATHER_PACKS[why](monkeypatch)
+    assert not packed.dense and packed.path is None
+    X = jnp.asarray(np.array([[0.0, 0.0], [1.0, 2.0], [1.0, 3.0]],
+                             dtype=np.float32))
+    _, dense, moved = _run_and_read_the_choice(packed, X)
+    assert dense == 0
+    assert moved == {"predict_dense_calls": 0, "predict_gather_calls": 1}
+
+
+def test_a_numerical_pack_on_the_tpu_takes_the_dense_path(rng, as_on_tpu):
+    trees = [make_simple_tree() for _ in range(3)]
+    packed = pack_ensemble(trees)
+    assert packed.dense
+    assert predict_mod.fused_program(packed) is predict_mod._predict_raw_dense
+    X = rng.uniform(-1, 5, size=(64, 2)).astype(np.float32)
+    out, dense, moved = _run_and_read_the_choice(packed, jnp.asarray(X))
+    assert dense == 1
+    assert moved == {"predict_dense_calls": 1, "predict_gather_calls": 0}
+    expected = np.array([[sum(t.predict(row) for t in trees)] for row in X])
+    np.testing.assert_allclose(np.asarray(out), expected, rtol=1e-6)
+
+
+@pytest.mark.parametrize("entry", ["aot", "sharded"])
+def test_the_other_entries_run_the_packs_own_program(rng, as_on_tpu, entry):
+    """The AOT bundle's executable and the row-sharded call lower
+    `fused_program(packed)`: a dense pack's answers come from the dense
+    program there too, and equal the traversal's."""
+    trees = [_random_tree(rng, int(rng.randint(2, 20)), 4) for _ in range(6)]
+    packed = pack_ensemble(trees)
+    assert packed.dense
+    X = rng.randn(64, 4).astype(np.float32)
+    if entry == "aot":
+        compiled = predict_mod.aot_compile(packed, 64, 4, 1)
+        assert "lgbm.path_match" in compiled.as_text()
+        got = np.asarray(compiled(packed, jnp.asarray(X)))
+    else:
+        from lightgbm_tpu.parallel.predict import predict_raw_sharded
+
+        got = predict_raw_sharded(packed, X, 1)
+    want = np.asarray(predict_mod._predict_raw_fused(packed, jnp.asarray(X), 1))
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)

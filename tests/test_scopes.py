@@ -38,6 +38,8 @@ TREE_SCOPES = ["tree_setup", "select", "route", "compact", "hist", "scan",
                "replay", "commit", "finish"]
 PREDICT_SCOPES = ["node_gather", "feature_gather", "decide", "leaf_values",
                   "accumulate"]
+DENSE_PREDICT_SCOPES = ["feature_gather", "decide", "path_match",
+                        "leaf_values", "accumulate"]
 PARAMS = {"objective": "binary", "num_leaves": 15, "verbosity": -1}
 
 
@@ -97,6 +99,24 @@ def predict_program():
         packed, jnp.asarray(X, jnp.float32), 1).as_text(debug_info=True)
 
 
+@pytest.fixture(scope="module")
+def dense_predict_program():
+    """The TPU's gather-free program for the same forest: pack_ensemble
+    asks on_tpu(), the CPU in this process, so the test answers for it."""
+    X, y = _data(600)
+    bst = lgb.train(dict(PARAMS, num_leaves=7), lgb.Dataset(X, label=y),
+                    num_boost_round=3)
+    mp = pytest.MonkeyPatch()
+    try:
+        mp.setattr(predict_mod, "on_tpu", lambda: True)
+        packed = predict_mod.pack_ensemble(bst._gbdt.models)
+    finally:
+        mp.undo()
+    assert packed.dense
+    return predict_mod.fused_program(packed).lower(
+        packed, jnp.asarray(X, jnp.float32), 1).as_text(debug_info=True)
+
+
 # ------------------------------------------------------------ device scopes
 
 
@@ -139,10 +159,20 @@ def test_predict_program_carries_the_scope(predict_program, scope):
     assert SCOPES["SCOPE_" + scope.upper()] in predict_program
 
 
+@pytest.mark.parametrize("scope", DENSE_PREDICT_SCOPES)
+def test_dense_predict_program_carries_the_scope(dense_predict_program,
+                                                 scope):
+    assert SCOPES["SCOPE_" + scope.upper()] in dense_predict_program
+
+
+def test_dense_predict_program_has_no_node_gather(dense_predict_program):
+    assert timer.SCOPE_NODE_GATHER not in dense_predict_program
+
+
 def test_the_table_of_scopes_is_the_constants():
     """Every scope constant is one of the names checked above, under the
     one prefix, and no two are alike."""
-    checked = set(TREE_SCOPES + PREDICT_SCOPES
+    checked = set(TREE_SCOPES + PREDICT_SCOPES + DENSE_PREDICT_SCOPES
                   + ["allreduce", "gradients", "update_score"])
     assert {v for v in SCOPES.values()} == {
         timer.SCOPE_PREFIX + name for name in checked}
