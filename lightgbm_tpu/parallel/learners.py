@@ -53,7 +53,8 @@ from ..treelearner.serial import (SerialTreeLearner, _LeafState,
                                   device_growth_applies)
 from ..utils import sanitize
 from ..utils.log import Log
-from ..utils.timer import global_timer
+from ..utils.timer import (SPAN_GATHER_LEAF_IDS, SPAN_SHARD_INPUTS,
+                           global_timer)
 from .dist import (host_value, init_distributed, put_global, put_global_tree,
                    put_replicated)
 from .mesh import data_mesh, padded_row_count
@@ -601,20 +602,29 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
         records. O(K*F*Bmax*CH): independent of the row count
         (docs/PERF_NOTES.md comm-volume model); tests assert the
         N-independence."""
-        K = max(1, min(self.wave, self.config.num_leaves))
+        K = self._dispatched_wave_k()
         pool_bytes = 2 if narrow else 4
-        global_timer.set_count(
-            "device_ici_bytes_per_wave",
+        self._set_ici_bytes_per_wave(
             K * self.f_pad * self.meta.max_bins * 3 * pool_bytes
             + 2 * K * self.f_pad * REC * 4)
 
-    def train_async(self, gh_ext: jax.Array,
-                    bag_indices: Optional[np.ndarray] = None) -> _PendingTree:
-        cfg = self.config
+    def _dispatched_wave_k(self) -> int:
+        """The wave width of every sharded dispatch: `_grow_fn` compiles
+        the ceiling `wave`, never the adaptive controller's `wave_k`."""
+        return max(1, min(self.wave, self.config.num_leaves))
+
+    def _set_ici_bytes_per_wave(self, bytes_w: int) -> None:
+        """The gauge, and the learner's own copy of it for the tree's
+        `tree_wave` note (a gauge is the process's, not the learner's)."""
+        self._ici_bytes_per_wave = int(bytes_w)
+        global_timer.set_count("device_ici_bytes_per_wave", bytes_w)
+
+    def _shard_inputs(self, gh_ext: jax.Array,
+                      bag_indices: Optional[np.ndarray]) -> tuple:
+        """The tree's inputs across the mesh: gradients (computed on one
+        chip) and initial leaf ids padded to the sharded row count and
+        split on `data`, the feature mask, the quantization scales."""
         n, npad = self.num_data, self.n_pad
-        bag_indices = host_bag_indices(bag_indices)
-        if self.quantized:
-            gh_ext = self._prepare_gh(gh_ext)  # int8 rows + scales
         gh = gh_ext[:-1]
         if bag_indices is not None:
             in_bag = np.zeros(n, dtype=bool)
@@ -643,6 +653,17 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
                  else jnp.ones(3, jnp.float32))
         scale_rep = put_global(scale, self.mesh, P())
 
+        return gh_sh, leaf_sh, fmask_sh, scale_rep, n_bag
+
+    def train_async(self, gh_ext: jax.Array,
+                    bag_indices: Optional[np.ndarray] = None) -> _PendingTree:
+        cfg = self.config
+        bag_indices = host_bag_indices(bag_indices)
+        if self.quantized:
+            gh_ext = self._prepare_gh(gh_ext)  # int8 rows + scales
+        with global_timer.scope(SPAN_SHARD_INPUTS):
+            gh_sh, leaf_sh, fmask_sh, scale_rep, n_bag = self._shard_inputs(
+                gh_ext, bag_indices)
         narrow = self._narrow(leaf_sh)
         self._record_carry_bytes()
         self._record_ici_bytes(narrow)
@@ -657,13 +678,27 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
                 *self._extra_grow_args())
         rec_store, leaf_id, _, hist_rows, n_waves = out[:5]
         self._note_grow_extras(out[5:])
-        leaf_id = leaf_id[:n]
+        with global_timer.scope(SPAN_GATHER_LEAF_IDS):
+            leaf_id = self._gather_leaf_ids(leaf_id)
         for arr in (rec_store, leaf_id, hist_rows, n_waves):
             start = getattr(arr, "copy_to_host_async", None)
             if start is not None:
                 start()
         return _PendingTree(Tree(cfg.num_leaves), rec_store, leaf_id,
-                            hist_rows, n_waves, n_bag)
+                            hist_rows, n_waves, n_bag,
+                            wave_k=self._dispatched_wave_k())
+
+    def _gather_leaf_ids(self, leaf_id: jax.Array) -> jax.Array:
+        """The tree's per-row leaf ids without the row padding, on the
+        mesh's first chip, where the scores and gradients of a one-process
+        run live (the score update reads them there). Both steps are
+        enqueued behind the tree's program and block nothing. A multi-
+        process mesh keeps the sharded array: no one process can address
+        it whole, and models/gbdt.py `_colocate` allgathers it."""
+        leaf_id = leaf_id[:self.num_data]
+        if leaf_id.is_fully_addressable:
+            leaf_id = jax.device_put(leaf_id, self.mesh.devices.flat[0])
+        return leaf_id
 
     def _renew_quantized_leaves_device(self, tree: Tree,
                                        leaf_id: jax.Array) -> None:
@@ -726,12 +761,12 @@ class VotingDataParallelTreeLearner(DeviceDataParallelTreeLearner):
         widths). The smaller-child half of each wave is dispatched before
         the larger-child subtraction it overlaps, so half the wave's ICI
         bytes hide behind local compute by construction."""
-        K = max(1, min(self.wave, self.config.num_leaves))
+        K = self._dispatched_wave_k()
         pool_bytes = 2 if narrow else 4
         bytes_w = voting_ici_bytes_per_wave(
             K, self._k_local, self._k_global, self.meta.max_bins, self.D,
             pool_bytes=pool_bytes)
-        global_timer.set_count("device_ici_bytes_per_wave", bytes_w)
+        self._set_ici_bytes_per_wave(bytes_w)
         global_timer.set_count("voting_ici_bytes_per_wave", bytes_w)
         global_timer.set_count(
             "device_ici_overlap_pct",
@@ -780,9 +815,9 @@ class DeviceFeatureParallelTreeLearner(DeviceDataParallelTreeLearner):
         """Gauge: the best-record all_gather is the ONLY collective —
         O(2K*D*REC), independent of N and F (tests assert the
         N-independence)."""
-        K = max(1, min(self.wave, self.config.num_leaves))
-        bytes_w = feature_ici_bytes_per_wave(K, self.D)
-        global_timer.set_count("device_ici_bytes_per_wave", bytes_w)
+        bytes_w = feature_ici_bytes_per_wave(self._dispatched_wave_k(),
+                                             self.D)
+        self._set_ici_bytes_per_wave(bytes_w)
         global_timer.set_count("feature_ici_bytes_per_wave", bytes_w)
 
 

@@ -1125,6 +1125,12 @@ class DeviceTreeLearner(SerialTreeLearner):
     t-1 by holding the _PendingTree across iterations; the plain train()
     path chains the two immediately and is bit-identical."""
 
+    # one chip: no mesh, and nothing of a wave crosses ICI (the sharded
+    # learners of parallel/learners.py set both); the `tree_wave` note
+    # carries them
+    D = 1
+    _ici_bytes_per_wave = 0
+
     def __init__(self, config, dataset) -> None:
         super().__init__(config, dataset)
         self.tables = _feature_tables(dataset, dataset.used_features)
@@ -1345,7 +1351,9 @@ class DeviceTreeLearner(SerialTreeLearner):
         # last trees' wave shape even with telemetry off)
         tracing.note("tree_wave", waves=n_waves, wave_k=wave_k,
                      committed=committed, speculated=speculated,
-                     hist_rows=self.last_hist_rows)
+                     hist_rows=self.last_hist_rows,
+                     ici_bytes=n_waves * self._ici_bytes_per_wave,
+                     mesh_devices=self.D)
         if telemetry.enabled():
             telemetry.emit(
                 "tree_wave", waves=n_waves, wave_width=wave_k,

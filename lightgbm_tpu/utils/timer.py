@@ -31,6 +31,12 @@ SPAN_PREDICT_CHUNK = "predict_chunk"
 SPAN_PREDICT_UPLOAD = "predict_upload"
 SPAN_PREDICT_TRAVERSE = "predict_traverse"
 SPAN_PREDICT_FETCH = "predict_fetch"
+# The row-sharded device learners' two per-tree host phases around the
+# whole-tree dispatch (parallel/learners.py train_async): the gradients,
+# leaf ids and feature mask padded and placed across the mesh, and the
+# tree's per-row leaf ids brought back to the score's chip.
+SPAN_SHARD_INPUTS = "shard_inputs"
+SPAN_GATHER_LEAF_IDS = "gather_leaf_ids"
 
 # Device scopes (`jax.named_scope`): every device operation of the training
 # and predict hot paths carries one of these in its name stack, under ONE

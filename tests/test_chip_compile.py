@@ -207,3 +207,67 @@ def test_whole_tree_program_compiles_and_fits(on_chip, monkeypatch, quantized):
     assert _mosaic_calls(compiled) == 3
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+
+
+HIGGS_ROWS = 10_500_000
+
+
+@pytest.mark.slow
+def test_sharded_whole_tree_program_fits_four_chips_at_higgs_full(
+        topo, monkeypatch):
+    """`tree_learner=data, num_machines=4` at Higgs's published 10,500,000
+    rows (the benchmark's `higgs_full.train_4chip`): the sharded whole-tree
+    program, 2,625,536 rows a shard, compiled for the four described chips
+    (~55 s). One chip's program is refused at this row count (35.43 GB);
+    a shard's has to fit with room for what chip 0 holds besides (scores,
+    labels, gradients: ~50 bytes a row of the whole table). Read by PR 28:
+    11.03 GB temp + 0.14 GB arguments a chip."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from lightgbm_tpu.parallel.learners import DeviceDataParallelTreeLearner
+    from lightgbm_tpu.parallel.mesh import padded_row_count
+
+    monkeypatch.setattr(histogram, "on_tpu", lambda: True)
+    monkeypatch.delenv("LGBM_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setenv("LGBM_TPU_HIST_F32", "1")
+    rng = np.random.default_rng(0)
+    X = rng.standard_normal((8192, FEATURES), dtype=np.float32)
+    cfg = Config({"objective": "binary", "num_leaves": 255, "max_bin": BINS,
+                  "min_sum_hessian_in_leaf": 100, "tree_learner": "data",
+                  "num_machines": 4, "verbosity": -1})
+    ds = CoreDataset.from_matrix(X, label=(X[:, 0] > 0).astype(np.float64),
+                                 config=cfg)
+    learner = DeviceDataParallelTreeLearner(cfg, ds)  # on four CPU devices
+    assert learner.D == 4
+    n_pad = padded_row_count(HIGGS_ROWS, 4, learner._row_unit)
+    assert n_pad == 10_502_144
+    mesh = Mesh(np.array(topo.devices), ("data",))
+
+    def on(spec, shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def like(tree, spec):
+        return jax.tree_util.tree_map(
+            lambda a: on(spec, a.shape, a.dtype), tree)
+
+    grow = device_mod.make_sharded_grow_fn(
+        mesh, num_leaves=255, num_bins=learner.group_bin_padded,
+        max_depth=cfg.max_depth, quantized=False, batch=learner.wave,
+        bagged=False, narrow=False)
+    compiled = grow.lower(
+        on(P(None, "data"), (FEATURES, n_pad), jnp.uint8),
+        on(P("data"), (n_pad, 3), jnp.float32),
+        on(P("data"), (n_pad,), jnp.int32),
+        like(learner._gidx_arg, P()), like(learner._vslot_arg, P()),
+        like(learner._scan_meta_arg, P("data")),
+        like(learner._tables_rep, P()), like(learner._params_rep, P()),
+        on(P("data"), (learner.f_pad,), jnp.bool_),
+        on(P(), (3,), jnp.float32)).compile()
+    assert _mosaic_calls(compiled) == 3
+    text = compiled.as_text()
+    assert " all-reduce(" in text and " all-gather(" in text
+    mem = compiled.memory_analysis()  # bytes on each device
+    whole_table_on_chip_0 = 50 * HIGGS_ROWS
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + whole_table_on_chip_0) < HBM_BYTES

@@ -27,6 +27,7 @@ from lightgbm_tpu.ops import predict as predict_mod
 from lightgbm_tpu.parallel import learners as learners_mod
 from lightgbm_tpu.parallel.learners import DeviceDataParallelTreeLearner
 from lightgbm_tpu.treelearner import device as device_mod
+from lightgbm_tpu.treelearner import serial as serial_mod
 from lightgbm_tpu.treelearner.device import DeviceTreeLearner
 from lightgbm_tpu.utils import profile, timer
 from lightgbm_tpu.utils.timer import global_timer
@@ -130,6 +131,51 @@ def test_sharded_program_puts_its_collectives_under_allreduce(monkeypatch):
                                DeviceDataParallelTreeLearner)
     assert timer.SCOPE_ALLREDUCE in text
     assert timer.SCOPE_HIST in text
+
+
+COLLECTIVE = re.compile(
+    r'"?stablehlo\.(all_reduce|reduce_scatter|all_gather|all_to_all|'
+    r'collective_permute)"?\(')
+LOC_REF = re.compile(r"loc\((#loc\d+)\)\s*$")
+
+
+def _collective_name_stacks(text: str) -> list:
+    """[(collective, name stack)] of a lowered program printed with
+    debug_info: an operation's location closes its line, or the line that
+    closes its reduction's region."""
+    table = dict(re.findall(r'^(#loc\d+) = loc\((.*)\)$', text, re.M))
+    lines = text.splitlines()
+    out = []
+    for at, line in enumerate(lines):
+        hit = COLLECTIVE.search(line)
+        if not hit:
+            continue
+        if line.rstrip().endswith("({"):  # the region's close carries it
+            indent = len(line) - len(line.lstrip())
+            line = next(ln for ln in lines[at + 1:]
+                        if ln.startswith(" " * indent + "})"))
+        ref = LOC_REF.search(line)
+        assert ref, line
+        out.append((hit.group(1), table[ref.group(1)]))
+    return out
+
+
+@pytest.mark.parametrize("learner_cls,collectives", [
+    (learners_mod.DeviceDataParallelTreeLearner,
+     {"all_reduce", "reduce_scatter", "all_gather"}),
+    (learners_mod.VotingDataParallelTreeLearner,
+     {"all_reduce", "all_gather"}),
+    (learners_mod.DeviceFeatureParallelTreeLearner, {"all_gather"}),
+], ids=["data", "voting", "feature"])
+def test_every_collective_of_a_sharded_program_carries_allreduce(
+        monkeypatch, learner_cls, collectives):
+    """Not one of them outside the scope: the chip benchmark's collective
+    time (`train_4chip.allreduce_ms_per_tree`) is the scope's self time."""
+    stacks = _collective_name_stacks(
+        _dispatched_program(monkeypatch, learners_mod, learner_cls))
+    assert {kind for kind, _ in stacks} == collectives
+    for kind, stack in stacks:
+        assert timer.SCOPE_ALLREDUCE + "/" in stack, (kind, stack)
 
 
 def test_single_device_program_has_no_allreduce(tree_program):
@@ -309,6 +355,53 @@ def test_three_trees_give_three_iteration_spans_that_hold_their_children(
         assert rec["t0"] - 1e-5 <= root[1] and root[2] <= rec["t1"] + 1e-5
 
 
+def test_a_sharded_tree_opens_its_two_host_spans_once_each(spans,
+                                                           monkeypatch):
+    """`shard_inputs`, `tree_device`, `gather_leaf_ids`, in that order and
+    apart, inside the iteration's `tree_train`, once a tree; the tree's
+    flight note says how wide the mesh was and what crossed it."""
+    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(serial_mod, "on_tpu", lambda: True)
+    X, y = _data(1500)
+    tracing.recorder().reset()
+    del spans[:]
+    bst = lgb.train(dict(PARAMS, tree_learner="data", num_machines=4),
+                    lgb.Dataset(X, label=y), num_boost_round=3)
+    learner = bst._gbdt.tree_learner
+    assert type(learner) is DeviceDataParallelTreeLearner
+    by_label = {label: [s for s in spans if s[0] == label]
+                for label in (timer.SPAN_SHARD_INPUTS, "tree_device",
+                              timer.SPAN_GATHER_LEAF_IDS, "tree_train")}
+    for label in (timer.SPAN_SHARD_INPUTS, timer.SPAN_GATHER_LEAF_IDS):
+        assert len(by_label[label]) == 3, label
+        assert global_timer.counts[label] == 3
+    for shard, device, gather in zip(by_label[timer.SPAN_SHARD_INPUTS],
+                                     by_label["tree_device"],
+                                     by_label[timer.SPAN_GATHER_LEAF_IDS]):
+        assert shard[2] <= device[1] and device[2] <= gather[1]
+        assert any(_inside(shard, t) and _inside(gather, t)
+                   for t in by_label["tree_train"])
+    notes = [n for n in tracing.recorder().snapshot()
+             if n["kind"] == "tree_wave"]
+    assert len(notes) == 3
+    per_wave = global_timer.counters["device_ici_bytes_per_wave"]
+    assert per_wave > 0
+    for note in notes:
+        assert note["mesh_devices"] == 4
+        assert note["ici_bytes"] == note["waves"] * per_wave
+        assert note["wave_k"] == min(learner.wave, PARAMS["num_leaves"])
+
+
+def test_a_one_chip_tree_opens_neither_sharded_span(spans, monkeypatch):
+    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
+    bst, _ = _booster()
+    assert not bst.train_one_iter()
+    labels = {s[0] for s in spans}
+    assert "tree_device" in labels
+    assert timer.SPAN_SHARD_INPUTS not in labels
+    assert timer.SPAN_GATHER_LEAF_IDS not in labels
+
+
 def test_a_predict_call_is_one_root_with_upload_traverse_and_fetch_once(
         spans):
     X, y = _data(600)
@@ -370,6 +463,7 @@ def test_one_tree_wave_note_per_tree_carries_waves_rows_and_width(
         assert note["hist_rows"] >= bst.num_data  # the root pass at least
         assert note["committed"] == tree.num_leaves - 1
         assert note["speculated"] == note["waves"] * note["wave_k"]
+        assert note["mesh_devices"] == 1 and note["ici_bytes"] == 0
     assert sum(n["hist_rows"] for n in notes) \
         == global_timer.counters["device_hist_rows"] - rows_before
 

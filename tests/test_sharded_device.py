@@ -139,9 +139,11 @@ def test_sharded_quantized_driver_bit_identical(rng):
 
 
 def test_sharded_learner_is_actually_sharded(rng):
-    """The carry really spans the mesh: the bin plane and the returned
+    """The carry really spans the mesh: the bin plane and the program's
     leaf ids are laid out over all 8 fake devices, the split log is
-    replicated, and growth commits the same tree everywhere."""
+    replicated, and growth commits the same tree everywhere. What the
+    learner hands on (`gather_leaf_ids`) is the ids without the padding on
+    the mesh's first device, where the score update reads them."""
     n = 900
     X = rng.randn(n, 6)
     y = (X[:, 0] > 0).astype(float)
@@ -150,8 +152,15 @@ def test_sharded_learner_is_actually_sharded(rng):
                         "verbosity": -1})
     assert learner.D == 8
     assert len(learner.bins_dev.sharding.device_set) == 8
+    program_ids, gather = [], learner._gather_leaf_ids
+    learner._gather_leaf_ids = lambda ids: program_ids.append(ids) or gather(
+        ids)
     pending = learner.train_async(_snapped_gh(rng, n))
-    assert len(pending.leaf_id.sharding.device_set) == 8
+    assert len(program_ids[0].sharding.device_set) == 8
+    assert program_ids[0].shape == (learner.n_pad,)
+    assert pending.leaf_id.sharding.device_set == {
+        learner.mesh.devices.flat[0]}
+    assert pending.leaf_id.shape == (n,)
     tree = learner.finalize(pending)
     assert tree.num_leaves > 1
     assert learner.partition.ids_host.shape == (n,)
