@@ -13,28 +13,40 @@ there, a bitvector + block prefix-scan + global scatter. TPUs have no fast
 global scatter, so the same data movement is phrased as dense tile algebra:
 
   1. XLA side (range_partition_dst): per-range stable left/right ranks via
-     two global exclusive scans + a [N, K] range-membership matmul for the
-     per-row destination base -> forward map dst[j] (a permutation of
-     [0, N); rows outside every range keep their position).
+     two global exclusive scans + a [2, K] @ [K, N] range-membership matmul
+     for the per-row destination base -> forward map dst[j] (a permutation
+     of [0, N); rows outside every range keep their position).
   2. XLA side (build_pair_tables): each INPUT tile's rows land in at most a
      handful of OUTPUT tiles — per (range, side) the destinations are
      contiguous, so a tile's class rows span <= 2 output tiles. The pair
      list (in_tile -> out_tile), sorted by out_tile, is the kernel's grid.
-  3. Pallas kernel (pallas_compact): sequential grid over pairs; per pair
-     build the in-tile one-hot P[i, o] = (dst[i] - out*T == o) and
-     accumulate out_block += P^T @ rows (and bins @ P). Consecutive pairs
-     share the output block (sorted order), so accumulation stays in VMEM;
-     a scalar-prefetched copy flag routes untouched tiles through a plain
-     VPU copy with no matmul.
+  3. Pallas kernel (_pallas_compact_call): sequential grid over pairs.
+     Every per-row operand has the ROWS ON THE LANES: the bin plane
+     [Gp, N] (block (Gp, T)), the f32 payload [rc, N] (block (rc, T), rc a
+     multiple of 8) and dst [1, N] (block (1, T)). A custom call's operand
+     layout is fixed, and XLA carries it back into the glue that makes the
+     operand: an [N, 1] or [N, rc] operand pads its minor dimension to 128
+     lanes (2.15 GB for 4M int32, not 16 MB) and the whole wave's routing
+     arithmetic then runs at 8 useful values a vector register. Per pair
+     the kernel builds the one-hot PT[o, i] = (o == dst[i] - out*T) from
+     the lane-major dst (a sublane broadcast, no relayout), stacks the
+     payload's four limbs and the plane's limb(s) into ONE operand
+     X [4*rc + Gp*(1|2), T] and accumulates
+     out[c, o] += sum_i X[c, i] * PT[o, i] — one matmul contracting the
+     last dimension of both (the q @ k^T form), so payload and plane move
+     the same way. Consecutive pairs share the output block (sorted
+     order), so accumulation stays in VMEM; a scalar-prefetched copy flag
+     routes untouched tiles through a plain VPU copy with no matmul.
 
 Exactness: values transit the MXU as 8-bit limbs of their raw bits (bf16
 operands — 0/1 one-hot and limbs <= 255 are exact in bf16, and each output
-row receives exactly ONE source row), and the limbs recombine and accumulate
-as integers, so payloads are moved bit-exactly (-0.0 and denormals included)
-at full bf16 MXU rate: f32 rows as four limbs, the bin plane as two limbs for
-int32 (values < 2**16) or ONE limb when the plane is already 8-bit (uint8
-bins, values <= 255) — a 2x cut in the plane's transport matmuls on top of
-the 4x HBM cut of the narrow plane itself. The only lax.sort is the single
+row receives exactly ONE source row: dst is injective, so every row of PT
+holds at most one 1), and the limbs recombine and accumulate as integers, so
+payloads are moved bit-exactly (-0.0 and denormals included) at full bf16
+MXU rate: f32 rows as four limbs, the bin plane as two limbs for int32
+(values < 2**16) or ONE limb when the plane is already 8-bit (uint8 bins,
+values <= 255) — a 2x cut in the plane's transport rows on top of the 4x HBM
+cut of the narrow plane itself. The only lax.sort is the single
 composite-key sort ordering the pair list; no row-wise sort anywhere — at
 10.5M rows a global row sort costs more than the histograms it would save
 (docs/PERF_NOTES.md).
@@ -76,19 +88,20 @@ def range_partition_dst(go_left: jax.Array, match: jax.Array,
     """Forward destination map of a stable 2-way partition of K disjoint
     position ranges.
 
-    go_left [N] bool, match [N, K] bool (row-in-range membership, already
-    masked by `valid`), starts/counts [K] int32, valid [K] bool.
+    go_left [N] bool, match [K, N] bool (row-in-range membership, already
+    masked by `valid`; rows on the minor axis like every per-row array of
+    the wave), starts/counts [K] int32, valid [K] bool.
     Returns (dst [N] int32, n_left [K] int32). Rows outside every valid
     range keep their position; rows of range k land stably in
     [starts[k], starts[k]+n_left[k]) or [starts[k]+n_left[k], ends[k]).
 
-    All vectorized: two global scans, K-sized gathers, one [N, K] matmul
-    for the per-row base (gathers at N scale serialize on TPU; the matmul
+    All vectorized: two global scans, K-sized gathers, one [2, K] @ [K, N]
+    matmul for the per-row base (gathers at N scale serialize on TPU; the matmul
     does not). Positions must be < 2**24 (exact in f32).
     """
-    N, K = match.shape
+    K, N = match.shape
     pos = jnp.arange(N, dtype=jnp.int32)
-    in_any = match.any(axis=1)
+    in_any = match.any(axis=0)
     lmask = in_any & go_left
     rmask = in_any & ~go_left
     lcum = exclusive_cumsum(lmask)
@@ -102,13 +115,12 @@ def range_partition_dst(go_left: jax.Array, match: jax.Array,
     n_left = jnp.take(lext, ends) - jnp.take(lext, starts)
     base_l = starts - jnp.take(lext, starts)
     base_r = starts + n_left - jnp.take(rext, starts)
-    bases = jax.lax.dot(match.astype(jnp.float32),
-                        jnp.stack([base_l, base_r], axis=1)
-                        .astype(jnp.float32),
-                        precision=jax.lax.Precision.HIGHEST)  # [N, 2]
+    bases = jax.lax.dot(jnp.stack([base_l, base_r]).astype(jnp.float32),
+                        match.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)  # [2, N]
     dst = jnp.where(
-        lmask, bases[:, 0].astype(jnp.int32) + lcum,
-        jnp.where(rmask, bases[:, 1].astype(jnp.int32) + rcum, pos))
+        lmask, bases[0].astype(jnp.int32) + lcum,
+        jnp.where(rmask, bases[1].astype(jnp.int32) + rcum, pos))
     return dst, jnp.where(valid, n_left, 0)
 
 
@@ -217,19 +229,20 @@ def build_pair_tables(dst: jax.Array, class_masks: Sequence[jax.Array],
     return pair_in, pair_out, pcopy, n_pairs[None]
 
 
-def _limbs(x_int: jax.Array, n: int, axis: int) -> jax.Array:
-    """Split int32 values into n 8-bit limbs concatenated along `axis`
-    (each limb <= 255: exact as a bf16 matmul operand)."""
+def _limbs(x_int: jax.Array, n: int) -> jax.Array:
+    """Split int32 values [c, T] into n 8-bit limbs stacked along the rows,
+    [n*c, T] (each limb <= 255: exact as a bf16 matmul operand)."""
     parts = [jnp.bitwise_and(jax.lax.shift_right_logical(x_int, 8 * i), 255)
              for i in range(n)]
-    return jnp.concatenate(parts, axis=axis)
+    return jnp.concatenate(parts, axis=0)
 
 
 def _make_compact_kernel(tile: int, gp: int, rc: int, plane8: bool):
     """plane8: the bin plane is an 8-bit dtype (uint8). Its values fit one
-    bf16 limb, so the plane transports through ONE matmul instead of two,
-    and the accumulate widens to i32 in-register (Mosaic has no elementwise
-    8-bit vectors) before narrowing back to the 8-bit output block."""
+    bf16 limb, so the plane rides the transport matmul as Gp rows instead
+    of 2*Gp, and the accumulate widens to i32 in-register (Mosaic has no
+    elementwise 8-bit vectors) before narrowing back to the 8-bit output
+    block."""
 
     def kernel(pin_ref, pout_ref, pcopy_ref, npair_ref,
                bins_ref, row_ref, dst_ref, bins_out, row_out):
@@ -254,18 +267,22 @@ def _make_compact_kernel(tile: int, gp: int, rc: int, plane8: bool):
                 bins_out[...] = jnp.zeros_like(bins_out)
                 row_out[...] = jnp.zeros_like(row_out)
 
-            rel = dst_ref[...][:, 0] - out_t * tile  # [tile] int32
-            iota = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
-            # P[i, o] = 1 iff in-row i lands at out-row o of this block.
-            # dst is injective => every column has at most one 1, so each
+            rel = dst_ref[...] - out_t * tile  # [1, tile]: in-rows on lanes
+            iota = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+            # PT[o, i] = 1 iff in-row i lands at out-row o of this block.
+            # dst is injective => every row has at most one 1, so each
             # output row below receives exactly one source row: the limb
-            # matmuls are exact bit transport, not sums.
-            P = (rel[:, None] == iota).astype(jnp.bfloat16)
+            # matmul is exact bit transport, not a sum.
+            PT = (iota == rel).astype(jnp.bfloat16)
             rbits = jax.lax.bitcast_convert_type(row_ref[...], jnp.int32)
-            rl = _limbs(rbits, 4, axis=1).astype(jnp.bfloat16)  # [tile, 4*rc]
-            orl = jax.lax.dot_general(
-                P, rl, dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            # single limb for an 8-bit plane: values <= 255 are exact bf16
+            plane = (bins_ref[...].astype(jnp.int32) if plane8
+                     else _limbs(bins_ref[...], 2))
+            X = jnp.concatenate([_limbs(rbits, 4), plane],
+                                axis=0).astype(jnp.bfloat16)
+            out = jax.lax.dot_general(
+                X, PT, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [4*rc + planes, tile]
             # The low three limbs recombine in f32 (a sum below 2**24 is
             # exact), the top one by the only integer shift. NOT
             # `limb2 << 16`: on a v5e (my chip run, PR 22) Mosaic's i32
@@ -273,28 +290,20 @@ def _make_compact_kernel(tile: int, gp: int, rc: int, plane8: bool):
             # bf16 -> f32 widening does to a bf16 denormal — so payloads
             # lost bits 16..22 wherever bit 23 was clear, and interpret
             # mode never showed it.
-            low = (orl[:, :rc] + 256.0 * orl[:, rc:2 * rc]
-                   + 65536.0 * orl[:, 2 * rc:3 * rc]).astype(jnp.int32)
-            obits = low | (orl[:, 3 * rc:].astype(jnp.int32) << 24)
+            low = (out[:rc] + 256.0 * out[rc:2 * rc]
+                   + 65536.0 * out[2 * rc:3 * rc]).astype(jnp.int32)
+            obits = low | (out[3 * rc:4 * rc].astype(jnp.int32) << 24)
             # rows not sourced by this pair recombine to bits 0, and the
             # accumulate ORs bits, so no float operation ever touches a
             # payload: -0.0 and denormals ride along exactly
             row_out[...] = jax.lax.bitcast_convert_type(
                 jax.lax.bitcast_convert_type(row_out[...], jnp.int32) | obits,
                 jnp.float32)
+            obl = out[4 * rc:].astype(jnp.int32)
             if plane8:
-                # single limb: values <= 255 are exact bf16 operands
-                bl = bins_ref[...].astype(jnp.int32).astype(jnp.bfloat16)
-                obl = jax.lax.dot_general(
-                    bl, P, dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32).astype(jnp.int32)
                 bins_out[...] = (bins_out[...].astype(jnp.int32)
                                  + obl).astype(bins_out.dtype)
             else:
-                bl = _limbs(bins_ref[...], 2, axis=0).astype(jnp.bfloat16)
-                obl = jax.lax.dot_general(
-                    bl, P, dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32).astype(jnp.int32)
                 bins_out[...] += obl[:gp] | (obl[gp:] << 8)
 
     return kernel
@@ -305,7 +314,7 @@ def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
                          n_pairs, tile: int, interpret: bool,
                          alias: bool = False):
     Gp, N = bins_p.shape
-    rc = row_p.shape[1]
+    rc = row_p.shape[0]
     mp = pair_in.shape[0]
     plane8 = bins_p.dtype.itemsize == 1
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -313,12 +322,12 @@ def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
         grid=(mp,),
         in_specs=[
             pl.BlockSpec((Gp, tile), lambda p, pi, po, pc, npr: (0, pi[p])),
-            pl.BlockSpec((tile, rc), lambda p, pi, po, pc, npr: (pi[p], 0)),
-            pl.BlockSpec((tile, 1), lambda p, pi, po, pc, npr: (pi[p], 0)),
+            pl.BlockSpec((rc, tile), lambda p, pi, po, pc, npr: (0, pi[p])),
+            pl.BlockSpec((1, tile), lambda p, pi, po, pc, npr: (0, pi[p])),
         ],
         out_specs=[
             pl.BlockSpec((Gp, tile), lambda p, pi, po, pc, npr: (0, po[p])),
-            pl.BlockSpec((tile, rc), lambda p, pi, po, pc, npr: (po[p], 0)),
+            pl.BlockSpec((rc, tile), lambda p, pi, po, pc, npr: (0, po[p])),
         ],
     )
     kwargs = {}
@@ -336,7 +345,7 @@ def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((Gp, N), bins_p.dtype),
-            jax.ShapeDtypeStruct((N, rc), jnp.float32),
+            jax.ShapeDtypeStruct((rc, N), jnp.float32),
         ],
         interpret=interpret,
         # the jitted wrapper's own name, not compact_rows': the trace names
@@ -345,19 +354,20 @@ def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
         name="_pallas_compact_call",
         **kwargs,
     )(pair_in, pair_out, is_copy, n_pairs, bins_p, row_p,
-      dst.reshape(N, 1))
+      dst.reshape(1, N))
 
 
 def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,
                  class_masks: Sequence[jax.Array], moved: jax.Array,
                  *, tile: int = COMPACT_TILE, use_pallas: bool = True,
                  interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """Apply the forward permutation dst to bins_p [Gp, N] (uint8, or int32
-    with values < 2**16) and row_p [N, rc] (f32 payload, moved bit-exactly).
-    The output bin plane keeps bins_p's dtype.
+    """Apply the forward permutation dst [N] to bins_p [Gp, N] (uint8, or
+    int32 with values < 2**16) and row_p [rc, N] (f32 payload, one row per
+    channel, moved bit-exactly). The output bin plane keeps bins_p's dtype.
 
-    Pallas path requirements: N % tile == 0, Gp % 8 == 0 for int32 planes
-    and Gp % 32 == 0 for 8-bit planes (Mosaic (32, 128) tiling),
+    Pallas path requirements: N % tile == 0, rc % 8 == 0 (the payload's
+    limbs stack on whole sublane tiles), Gp % 8 == 0 for int32 planes and
+    Gp % 32 == 0 for 8-bit planes (Mosaic (32, 128) tiling),
     class_masks disjoint with per-tile-contiguous destinations
     (range_partition_dst output qualifies), moved == union(class_masks).
     The XLA path is a plain permutation scatter — exact on CPU, used when
@@ -368,8 +378,12 @@ def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,
     if not use_pallas:
         bins_o = jnp.zeros_like(bins_p).at[:, dst].set(
             bins_p, unique_indices=True)
-        row_o = jnp.zeros_like(row_p).at[dst].set(row_p, unique_indices=True)
+        row_o = jnp.zeros_like(row_p).at[:, dst].set(
+            row_p, unique_indices=True)
         return bins_o, row_o
+    if row_p.shape[0] % 8:
+        raise ValueError("compaction kernel needs the payload's channel "
+                         f"count padded to 8, got {row_p.shape[0]}")
     pair_in, pair_out, is_copy, n_pairs = build_pair_tables(
         dst, class_masks, moved, tile)
     alias = os.environ.get("LGBM_TPU_COMPACT_ALIAS", "") == "1"

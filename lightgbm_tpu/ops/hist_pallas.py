@@ -319,14 +319,19 @@ def _make_slots_ragged_kernel(num_bins: int, tile_rows: int, n_slots: int,
 
         @pl.when(t < nact_ref[0])
         def _acc():
-            s = slot_ref[...]  # [TN, 1] int32
-            ghc = gh_ref[...]  # [TN, ch] f32 (quantized: exact small ints)
-            col = jax.lax.broadcasted_iota(jnp.int32, (1, SC), 1)
-            colslot, colch = col // ch, col % ch
-            gsum = jnp.zeros((tile_rows, SC), jnp.float32)
+            s = slot_ref[...]  # [1, TN] int32: rows on the lanes
+            ghc = gh_ref[...]  # [ch, TN] f32 (quantized: exact small ints)
+            # slot-expanded gradient tile, row j = slot*ch + channel: the
+            # same per-channel masked adds and slot mask as the dense slots
+            # kernel above, built [SC, TN] straight from the lane-major
+            # payload rows (a sublane broadcast each), so the contraction
+            # below is the plain [SC, TN] @ [TN, B] form
+            row = jax.lax.broadcasted_iota(jnp.int32, (SC, 1), 0)
+            rowslot, rowch = row // ch, row % ch  # [SC, 1]: 8 registers
+            gsum = jnp.zeros((SC, tile_rows), jnp.float32)
             for c in range(ch):
-                gsum += ghc[:, c:c + 1] * (colch == c).astype(jnp.float32)
-            ghK = (gsum * (colslot == s).astype(jnp.float32)
+                gsum += ghc[c:c + 1, :] * (rowch == c).astype(jnp.float32)
+            ghK = (gsum * (rowslot == s).astype(jnp.float32)
                    ).astype(compute_dtype)
             iota = jax.lax.broadcasted_iota(jnp.int32,
                                             (tile_rows, num_bins), 1)
@@ -335,7 +340,7 @@ def _make_slots_ragged_kernel(num_bins: int, tile_rows: int, n_slots: int,
                 onehot = (b[:, None] == iota).astype(compute_dtype)
                 acc = jax.lax.dot_general(
                     ghK, onehot,
-                    dimension_numbers=(((0,), (0,)), ((), ())),
+                    dimension_numbers=(((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                     precision=(jax.lax.Precision.HIGHEST
                                if compute_dtype == jnp.float32 else
@@ -368,13 +373,17 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
     int32 — inactive tail entries of `tiles` repeat the last active tile
     and are skipped.
 
-    gh is ALWAYS [N, CH] f32 here (the leaf-contiguous row payload).
+    gh is ALWAYS [CH, N] f32 here: the gh rows of the leaf-contiguous
+    payload, rows on the lanes like bins (block (CH, tile_rows)), and slot
+    [N] rides as [1, N] (block (1, tile_rows)). An [N, CH] / [N, 1]
+    operand would pad its minor dimension to 128 lanes in HBM and drag
+    that layout into the caller's glue (ops/compact_pallas.py, step 3).
     quantized=True means gh holds small exact ints; the build stays f32,
     operands go bf16 (exact <= 255), per-tile partials are exact in f32
     and accumulate int32 — bit-identical to the int8 dense path.
     """
     G, N = bins.shape
-    CH = gh.shape[1]
+    CH = gh.shape[0]
     SC = n_slots * CH
     if N % tile_rows:
         raise ValueError("ragged histogram requires N padded to tile_rows")
@@ -386,7 +395,7 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
         compute_dtype, acc_dtype = jnp.bfloat16, jnp.float32
     T = tiles.shape[0]
     bins, GB = _prep_bins(bins, SC, num_bins)
-    slot = slot.reshape(N, 1).astype(jnp.int32)
+    slot = slot.reshape(1, N).astype(jnp.int32)
     g_blocks = max(-(-G // GB), 1)
     g_pad = g_blocks * GB - G
     if g_pad:
@@ -396,8 +405,8 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
         grid=(g_blocks, T),
         in_specs=[
             pl.BlockSpec((GB, tile_rows), lambda g, t, tr, na: (g, tr[t])),
-            pl.BlockSpec((tile_rows, CH), lambda g, t, tr, na: (tr[t], 0)),
-            pl.BlockSpec((tile_rows, 1), lambda g, t, tr, na: (tr[t], 0)),
+            pl.BlockSpec((CH, tile_rows), lambda g, t, tr, na: (0, tr[t])),
+            pl.BlockSpec((1, tile_rows), lambda g, t, tr, na: (0, tr[t])),
         ],
         out_specs=pl.BlockSpec((GB, SC, num_bins),
                                lambda g, t, tr, na: (g, 0, 0)),

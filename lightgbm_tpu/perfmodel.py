@@ -42,7 +42,8 @@ from typing import Any, Dict, List, Mapping, NamedTuple, Optional, Tuple
 # Analytic formulas — the executable docs/PERF_NOTES.md model
 # ---------------------------------------------------------------------------
 
-# per-wave loop-carry payload: gh channels + position + leaf id, 4 B each
+# per-wave loop-carry payload: gh channels + position + leaf id, one f32
+# row of the [8, Np] carry each (the rows pad to the 8-sublane tile)
 PAYLOAD_COLS = 5
 # packed best-split record length ([2K, F_pad, REC] all_gather, f32)
 REC_FIELDS = 14
@@ -63,10 +64,12 @@ def plane_groups_padded(n_groups: int, plane_bytes: int) -> int:
 def carry_bytes_per_wave(n_rows: int, n_groups: int, plane_bytes: int,
                          unit: int, payload_cols: int = PAYLOAD_COLS) -> int:
     """HBM bytes of the wave loop carry (PERF_NOTES round-5):
-    ``Gp * Np * plane_bytes + Np * payload_cols * 4``."""
+    ``Gp * Np * plane_bytes + Np * payload_rows * 4``, the payload's
+    `payload_cols` channels carried as rows padded to a multiple of 8."""
     np_rows = padded_rows(n_rows, unit)
     gp = plane_groups_padded(n_groups, plane_bytes)
-    return gp * np_rows * int(plane_bytes) + np_rows * int(payload_cols) * 4
+    payload_rows = -(-int(payload_cols) // 8) * 8
+    return gp * np_rows * int(plane_bytes) + np_rows * payload_rows * 4
 
 
 def hist_bytes_per_row(n_groups: int, plane_bytes: int, ch: int = 3) -> int:

@@ -369,7 +369,8 @@ class StreamedTreeLearner(SerialTreeLearner):
             slot = jnp.ones((padded,), jnp.int32).at[loc].set(0)
             gh_rows = jnp.take(self._gh, jnp.asarray(sel),
                                axis=0).astype(jnp.float32)
-            gh = jnp.zeros((padded, CH), jnp.float32).at[loc].set(gh_rows)
+            gh = jnp.zeros((CH, padded), jnp.float32).at[:, loc].set(
+                gh_rows.T)  # the kernel takes its per-row operands [k, N]
             tiles, n_act = active_tile_table(
                 jnp.asarray([sel[0] - lo], jnp.int32),
                 jnp.asarray([sel[-1] - lo + 1], jnp.int32),

@@ -7,7 +7,8 @@ function: a `lax.while_loop` over speculative WAVES carrying the data in a
 LEAF-CONTIGUOUS permutation:
 
     bins_p     [Gp,Np]      bin columns, rows permuted leaf-contiguously
-    row_p      [Np,CH+2]    f32 payload: gh channels + perm + leaf id
+    row_p      [8,Np]       f32 payload rows: gh channels, perm, leaf id
+                            (+ zero rows up to the 8-sublane tile)
     start/cnt  [L+1]        per-leaf (start, count) row ranges
     pool       [L+1,G,B,CH] per-leaf histograms (subtraction trick)
     leaf_best  [L+1,R]      per-leaf packed best-split records
@@ -30,8 +31,13 @@ Design notes:
     replay later declines — an internally reordered range is still one
     contiguous range), then histograms only the smaller-child subranges.
   * Row routing (which leaf owns a row, split decision fields, commit
-    application) is position-range compares and masked [N,K]@[K,F]
+    application) is position-range compares and masked [F,K]@[K,N]
     matmuls — TPU gathers serialize, compares and matmuls vectorize.
+  * Every per-row array has the ROWS ON THE MINOR AXIS ([k, Np], never
+    [Np, k]): the two Pallas kernels take their per-row operands that way,
+    and a custom call's operand layout reaches back into the glue that
+    makes it — an [Np, k<128] array pads k to 128 lanes, 2 GB a pass at 4M
+    rows and 8 useful values a vector register (ops/compact_pallas.py).
   * The wave replay keeps the reference's leaf-wise semantics bit-exact
     (tree.h best-first; growth stops when the best gain <= 0; masked no-op
     steps write to dump rows so the loop body stays branch-free).
@@ -265,41 +271,46 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
         pool_dtype = jnp.int32 if quantized else jnp.float32
         pos = jnp.arange(Np, dtype=jnp.int32)
 
-        # leaf-contiguous payload: gh channels + original position + leaf id,
-        # all exact in f32 (positions < 2**24, ids < 2**8; quantized int8 gh
-        # values are exact too) and moved bit-exactly by the compaction kernel.
+        # leaf-contiguous payload, one ROW a channel: gh channels, original
+        # position, leaf id, all exact in f32 (positions < 2**24, ids < 2**8;
+        # quantized int8 gh values are exact too) and moved bit-exactly by the
+        # compaction kernel.
         # LGBM_TPU_GH_BF16=1 (opt-in, float path only): gh rides as bf16 PAIRS
-        # bitcast into f32 payload columns — half the gh carry bytes. The
-        # packed bits survive compaction unchanged (the kernel moves f32 limbs
-        # exactly) and are unpacked per histogram pass; bit-identity with the
-        # f32 path is NOT guaranteed (the learner warns once).
+        # packed into the bits of f32 payload rows. The packed bits survive
+        # compaction unchanged (the kernel moves f32 limbs exactly) and are
+        # unpacked per histogram pass; bit-identity with the f32 path is NOT
+        # guaranteed (the learner warns once). With the payload carried as
+        # rows it saves no bytes: 4 rows pad to the 8-sublane tile as 5 do.
         pack_bf16 = (not quantized) and os.environ.get(
             "LGBM_TPU_GH_BF16", "").lower() in ("1", "true", "on")
+        gh_rows = gh.astype(jnp.float32).T  # [CH, Np]: the tree's one relayout
         if pack_bf16:
-            CHp = CH + (CH % 2)
-            ghb = gh.astype(jnp.float32).astype(jnp.bfloat16)
-            if CHp != CH:
-                ghb = jnp.pad(ghb, ((0, 0), (0, CHp - CH)))
-            gh_cols = jax.lax.bitcast_convert_type(
-                ghb.reshape(Np, CHp // 2, 2), jnp.float32)  # [Np, CHp//2]
-            n_gh = CHp // 2
-        else:
-            gh_cols = gh.astype(jnp.float32)
-            n_gh = CH
+            if CH % 2:
+                gh_rows = jnp.pad(gh_rows, ((0, 1), (0, 0)))
+            u = jax.lax.bitcast_convert_type(
+                gh_rows.astype(jnp.bfloat16), jnp.uint16).astype(jnp.uint32)
+            gh_rows = jax.lax.bitcast_convert_type(
+                u[0::2] | (u[1::2] << 16), jnp.float32)  # [ceil(CH/2), Np]
+        n_gh = gh_rows.shape[0]
+        POS_ROW = n_gh
+        LEAF_ROW = n_gh + 1
+        # the compaction kernel stacks the payload's limbs on whole 8-sublane
+        # tiles: zero rows fill the last one
         row_p = jnp.concatenate([
-            gh_cols, pos.astype(jnp.float32)[:, None],
-            leaf_id0.astype(jnp.float32)[:, None]], axis=1)  # [Np, n_gh+2]
-        POS_COL = n_gh
-        LEAF_COL = n_gh + 1
+            gh_rows, pos.astype(jnp.float32)[None],
+            leaf_id0.astype(jnp.float32)[None],
+            jnp.zeros((-(n_gh + 2) % 8, Np), jnp.float32)])  # [8, Np]
 
     def payload_gh(row_c):
-        """gh channels of a payload slice as f32 [rows, CH] (unpacks the
-        bf16 pairs when the narrow carry is on)."""
+        """gh channels of the payload as f32 [CH, rows] (unpacks the bf16
+        pairs when the narrow carry is on)."""
         if not pack_bf16:
-            return row_c[:, :CH]
-        pairs = jax.lax.bitcast_convert_type(row_c[:, :n_gh], jnp.bfloat16)
-        return pairs.reshape(row_c.shape[0], 2 * n_gh)[:, :CH].astype(
-            jnp.float32)
+            return row_c[:CH]
+        u = jax.lax.bitcast_convert_type(row_c[:n_gh], jnp.uint32)
+        halves = jnp.stack([u & 0xFFFF, u >> 16], axis=1).astype(jnp.uint16)
+        return jax.lax.bitcast_convert_type(
+            halves.reshape(2 * n_gh, -1)[:CH], jnp.bfloat16).astype(
+                jnp.float32)
 
     def scan_hist(hist):
         if quantized:
@@ -336,7 +347,7 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             # XLA fallback: flat slot-expanded build over the full row set
             col_slot = jnp.arange(n_slots * CH, dtype=jnp.int32) // CH
             ghK = jnp.where(slot[:, None] == col_slot[None, :],
-                            jnp.tile(ghc, (1, n_slots)), 0.0)
+                            jnp.tile(ghc.T, (1, n_slots)), 0.0)
             h = build_histogram(bins_c[:G], ghK, num_bins)
             return h.astype(pool_dtype)  # quantized: exact ints below 2**24
 
@@ -518,7 +529,7 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             in_bag = leaf_id0 == 0
             n_in = in_bag.sum().astype(jnp.int32)
             dst0, _ = range_partition_dst(
-                in_bag, jnp.ones((Np, 1), bool), jnp.zeros(1, jnp.int32),
+                in_bag, jnp.ones((1, Np), bool), jnp.zeros(1, jnp.int32),
                 jnp.full(1, Np, jnp.int32), jnp.ones(1, bool))
             bins_p, row_p = compact_rows(
                 bins_p, row_p, dst0, [in_bag, ~in_bag],
@@ -623,25 +634,25 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
 
         with jax.named_scope(SCOPE_ROUTE):
             # --- per-row ownership by POSITION RANGE (leaf-contiguous layout).
-            # The [N, K] compare stays VECTORIZED on the VPU; a [L+1]-table
+            # The [K, N] compare stays VECTORIZED on the VPU; a [L+1]-table
             # gather formulation measured ~20% slower end to end (TPU gathers
             # serialize, elementwise compares do not).
-            match = ((pos[:, None] >= s_k[None, :])
-                     & (pos[:, None] < e_k[None, :]) & sel_ok[None, :])  # [N, K]
-            kvalid = match.any(axis=1)
+            match = ((pos[None, :] >= s_k[:, None])
+                     & (pos[None, :] < e_k[:, None]) & sel_ok[:, None])  # [K, N]
+            kvalid = match.any(axis=0)
 
-            # per-row split fields as ONE masked [N,K]@[K,F] matmul over the
+            # per-row split fields as ONE masked [F,K]@[K,N] matmul over the
             # match matrix — vectorized VPU/MXU work; jnp.take gathers here
             # measured far slower (TPU gathers serialize), and separate
-            # per-field matvecs would re-read the [N, K] matrix from HBM many
+            # per-field matvecs would re-read the [K, N] matrix from HBM many
             # times. Field values are small ints, exact in f32. HIGHEST
             # precision: default TPU matmul rounds operands to bf16 (8 mantissa
             # bits), which would corrupt integer fields > 256 — group ids, new
             # leaf ids, bin offsets, row positions.
             matchf = match.astype(jnp.float32)
 
-            def rows_of(per_k_fields):  # [K, F] -> [N, F]
-                return jax.lax.dot(matchf, per_k_fields.astype(jnp.float32),
+            def rows_of(per_k_fields):  # [F, K] -> [F, N]
+                return jax.lax.dot(per_k_fields.astype(jnp.float32), matchf,
                                    precision=jax.lax.Precision.HIGHEST)
 
             fields = jnp.stack([
@@ -649,10 +660,10 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
                 tables.missing_type[f_k], tables.default_bin[f_k],
                 tables.nbins[f_k], tables.lo[f_k], tables.hi[f_k],
                 tables.is_efb[f_k].astype(jnp.int32),
-            ], axis=1)  # [K, 9]
-            rowsF = rows_of(fields)  # [N, 9]
+            ])  # [9, K]
+            rowsF = rows_of(fields)  # [9, N]
             ri = rowsF.astype(jnp.int32)
-            grp_row = ri[:, 0]
+            grp_row = ri[0]
             # bins[grp_row[n], n] without a gather: compare-select over the G
             # group rows (G*N elementwise beats an N-sized row-varying gather)
             grp_iota = jnp.arange(Gp, dtype=jnp.int32)[:, None]
@@ -660,15 +671,15 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
                 jnp.where(grp_iota == grp_row[None, :], bins_p, 0),
                 axis=0, dtype=jnp.int32)
             go_left = _decide_go_left(
-                gb_row, ri[:, 1], rowsF[:, 2] > 0.5, ri[:, 3], ri[:, 4],
-                ri[:, 5], ri[:, 6], ri[:, 7], rowsF[:, 8] > 0.5)
+                gb_row, ri[1], rowsF[2] > 0.5, ri[3], ri[4],
+                ri[5], ri[6], ri[7], rowsF[8] > 0.5)
 
         with jax.named_scope(SCOPE_COMPACT):
             # --- stable partition of EVERY selected range (speculative: an
             # uncommitted leaf's range is merely reordered, still contiguous)
             dst, nl_k = range_partition_dst(go_left, match, s_k, c_k, sel_ok)
-            cmasks = ([match[:, k] & go_left for k in range(K)]
-                      + [match[:, k] & ~go_left for k in range(K)])
+            cmasks = ([match[k] & go_left for k in range(K)]
+                      + [match[k] & ~go_left for k in range(K)])
             bins_p, row_p = compact_rows(
                 bins_p, row_p, dst, cmasks, kvalid, tile=COMPACT_TILE,
                 use_pallas=use_kernels, interpret=interp)
@@ -693,10 +704,10 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
                 sc_k = jnp.minimum(nl_k, nr_k)
             ss_k = jnp.where(left_small, s_k, s_k + nl_k)
             se_k = ss_k + sc_k
-            inS = ((pos[:, None] >= ss_k[None, :])
-                   & (pos[:, None] < se_k[None, :]) & sel_ok[None, :])
-            slotS = jnp.where(inS.any(axis=1),
-                              jnp.argmax(inS, axis=1).astype(jnp.int32), K)
+            inS = ((pos[None, :] >= ss_k[:, None])
+                   & (pos[None, :] < se_k[:, None]) & sel_ok[:, None])  # [K, N]
+            slotS = jnp.where(inS.any(axis=0),
+                              jnp.argmax(inS, axis=0).astype(jnp.int32), K)
             hist_rows = hist_rows + jnp.sum(jnp.where(sel_ok, sc_k, 0))
             histS = ranged_hist(bins_p, row_p, slotS, K, ss_k, se_k,
                                 sel_ok & (sc_k > 0))
@@ -852,12 +863,12 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             # per-row leaf relabel via the same stacked masked matmul (position
             # >= split midpoint <=> right child, thanks to the partition)
             post = jnp.stack([committed[:K].astype(jnp.int32), newids[:K],
-                              mid_k], axis=1)  # [K, 3]
-            rowsP = rows_of(post)  # [N, 3]
-            com_row = kvalid & (rowsP[:, 0] > 0.5)
-            is_right = com_row & (pos >= rowsP[:, 2].astype(jnp.int32))
-            leafcol = jnp.where(is_right, rowsP[:, 1], row_p[:, LEAF_COL])
-            row_p = row_p.at[:, LEAF_COL].set(leafcol)
+                              mid_k])  # [3, K]
+            rowsP = rows_of(post)  # [3, N]
+            com_row = kvalid & (rowsP[0] > 0.5)
+            is_right = com_row & (pos >= rowsP[2].astype(jnp.int32))
+            row_p = row_p.at[LEAF_ROW].set(
+                jnp.where(is_right, rowsP[1], row_p[LEAF_ROW]))
         if voting:
             return (bins_p, row_p, start, count, depth, leaf_best,
                     rec_store, pool, n_cur, t, hist_rows, tpool, count_g,
@@ -891,10 +902,10 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             hist_rows = jax.lax.psum(hist_rows, "data")
     with jax.named_scope(SCOPE_FINISH):
         # undo the permutation without a TPU scatter: sort leaf ids by the
-        # original-position column (both exact small ints in f32)
+        # original-position row (both exact small ints in f32)
         _, leaf_sorted = jax.lax.sort_key_val(
-            row_p[:, POS_COL].astype(jnp.int32),
-            row_p[:, LEAF_COL].astype(jnp.int32))
+            row_p[POS_ROW].astype(jnp.int32),
+            row_p[LEAF_ROW].astype(jnp.int32))
     if voting:
         return (rec_store[:-1], leaf_sorted[:N], n_cur, hist_rows, n_waves,
                 carry[13])
@@ -1139,17 +1150,20 @@ class DeviceTreeLearner(SerialTreeLearner):
         # 21 -> 126 channels (one 128-lane M-tile on the MXU); raise for
         # deeper amortization, lower if speculation hit-rate drops.
         self.wave = int(os.environ.get("LGBM_TPU_WAVE", "21"))
-        # gain-adaptive wave width: `wave` is the ceiling, `wave_k` the
-        # width actually dispatched; _record_wave_efficiency moves it one
-        # power-of-two rung per tree from the observed commit rate
-        # (LGBM_TPU_ADAPTIVE_WAVE=0 pins K to the ceiling). Rungs reuse
+        # gain-adaptive wave width (LGBM_TPU_ADAPTIVE_WAVE=1, opt-in):
+        # `wave` is the ceiling, `wave_k` the width actually dispatched;
+        # _record_wave_efficiency moves it one power-of-two rung per tree
+        # from the observed commit rate. Rungs reuse
         # ops.partition.bucket_size so `batch` — a static jit arg of
         # grow_tree_on_device — takes at most ~log2(wave) distinct values
-        # per run instead of recompiling on every width change.
+        # per run. Off by default, like the sharded learners, which always
+        # dispatch the ceiling: every rung is another whole-tree program,
+        # 36-40 s to compile on a v5e in the middle of training, against
+        # ~1.3 s a tree at 4.19 M rows (PERF.md, PR 29: the HIGGS cell's
+        # first rung move comes at tree 22).
         self._wave_cap = max(1, min(self.wave, int(config.num_leaves)))
         self._adaptive_wave = os.environ.get(
-            "LGBM_TPU_ADAPTIVE_WAVE", "1").lower() not in (
-                "0", "false", "off")
+            "LGBM_TPU_ADAPTIVE_WAVE", "0").lower() in ("1", "true", "on")
         self.wave_k = self._wave_cap
         self._gh_bf16 = (not self.quantized) and os.environ.get(
             "LGBM_TPU_GH_BF16", "").lower() in ("1", "true", "on")
@@ -1180,8 +1194,9 @@ class DeviceTreeLearner(SerialTreeLearner):
         super().restore_snapshot_state(st)
 
     def _payload_cols(self) -> int:
-        """Payload columns of the wave carry: gh channels (bf16-packed in
-        pairs when opted in) + position + leaf id."""
+        """Payload channels of the wave carry: gh channels (bf16-packed in
+        pairs when opted in) + position + leaf id. Each is one row of the
+        [8, Np] carry, which pads to the sublane tile either way."""
         n_gh = 2 if self._gh_bf16 else 3
         return n_gh + 2
 

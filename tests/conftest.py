@@ -13,11 +13,11 @@ flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
 
-# The gain-adaptive wave controller (default on) walks wave_k down a
-# bucket_size rung per tree, and every rung is a fresh static shape for
-# grow_tree_on_device — a few extra XLA compiles that amortize over real
-# training runs but triple the wall time of every 3-iteration device test
-# here. Pin it off for the suite; the controller's own tests opt back in
+# The gain-adaptive wave controller (opt-in since PR 29) walks wave_k down
+# a bucket_size rung per tree, and every rung is a fresh static shape for
+# grow_tree_on_device — extra XLA compiles that triple the wall time of
+# every 3-iteration device test here. Keep it off for the suite whatever
+# the caller's environment says; the controller's own tests opt back in
 # with monkeypatch.setenv("LGBM_TPU_ADAPTIVE_WAVE", "1").
 os.environ.setdefault("LGBM_TPU_ADAPTIVE_WAVE", "0")
 

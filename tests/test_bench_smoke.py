@@ -42,10 +42,11 @@ def test_bench_smoke_cpu(tmp_path):
     assert record["device_hist_rows"] >= 0
     assert record["est_carried_bytes_per_wave"] > 0
     # 28 features -> Gp=32 groups; rows pad to the 1024-row wave unit.
-    # uint8 plane: carry = np_rows * (32*1 + 20); the int32 figure would be
-    # np_rows * (32*4 + 20) — assert we sit in the narrow-plane regime.
+    # uint8 plane: carry = np_rows * (32*1 + 32), the payload's 5 channels
+    # carried as 8 f32 rows; the int32 figure would be np_rows * (32*4 + 32)
+    # — assert we sit in the narrow-plane regime.
     n_pad = -(-20000 // 1024) * 1024
-    assert record["est_carried_bytes_per_wave"] == n_pad * (32 + 20)
+    assert record["est_carried_bytes_per_wave"] == n_pad * (32 + 32)
     # round-8 kernel instrumentation: both microlatency fields are real
     # timed dispatches (the fused-scan/XLA routing and the device GOSS
     # select both run on any backend); the wave-controller fields are 0 on

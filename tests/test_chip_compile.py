@@ -14,6 +14,7 @@ and the persistent compilation cache is off around them: an executable
 compiled for an absent chip cannot be read back.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +36,11 @@ from lightgbm_tpu.treelearner import device as device_mod
 N = 1 << 20
 FEATURES, GROUPS_PADDED, BINS, WAVE_K = 28, 32, 255, 21
 HBM_BYTES = int(15.75 * 2 ** 30)  # what the v5e compiler admits a program
+# The whole-tree program's temp at N = 2^20 read 307,058,688 B (float32) and
+# 307,123,200 B (quantized) once every per-row carry was [k, N] (AOT, PR 29);
+# with rows on the sublanes one [N, k] float32 carry alone was 0.54 GB and
+# the program 4.47 GB (PR 22). 15 % above the reading.
+TREE_TEMP_CEILING = 353_000_000
 
 
 @pytest.fixture(scope="module")
@@ -69,6 +75,17 @@ def _mosaic_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _rows_on_sublanes(compiled, n_rows: int) -> list:
+    """Arrays of the compiled program laid out [n_rows, k<100] with the rows
+    on the sublanes: k pads to 128 lanes, so each is n_rows * 512 bytes and
+    every operation on it runs at 8 useful values a vector register. One
+    such operand of a kernel drags the layout through the wave's glue
+    (PERF.md, PR 29: 2.28 s of a 4.24 s tree at N = 2^22)."""
+    return sorted(set(re.findall(
+        r"\w+\[%d,\d{1,2}\]\{1,0:T\(8,128\)[^}]*\}" % n_rows,
+        compiled.as_text())))
+
+
 @pytest.mark.parametrize("quantized", [False, True])
 def test_dense_histogram_kernel_compiles(on_chip, quantized):
     gh = jnp.int8 if quantized else jnp.float32
@@ -86,7 +103,7 @@ def test_ragged_histogram_kernel_compiles(on_chip, n_slots, quantized):
     the uint8 plane at 255 bins."""
     tiles = N // DEFAULT_TILE_ROWS
     compiled = pallas_histogram_slots_ragged.lower(
-        on_chip((GROUPS_PADDED, N), jnp.uint8), on_chip((N, 3), jnp.float32),
+        on_chip((GROUPS_PADDED, N), jnp.uint8), on_chip((3, N), jnp.float32),
         on_chip((N,), jnp.int32), on_chip((tiles,), jnp.int32),
         on_chip((1,), jnp.int32), num_bins=BINS, n_slots=n_slots,
         quantized=quantized, interpret=False).compile()
@@ -99,7 +116,7 @@ def test_compaction_kernel_compiles(on_chip, plane):
     EFB bundles pass 256 bins a group gets with the same default settings."""
     pairs = max_pairs_bound(N // COMPACT_TILE, 2 * WAVE_K)
     compiled = _pallas_compact_call.lower(
-        on_chip((GROUPS_PADDED, N), plane), on_chip((N, 5), jnp.float32),
+        on_chip((GROUPS_PADDED, N), plane), on_chip((8, N), jnp.float32),
         on_chip((N,), jnp.int32), on_chip((pairs,), jnp.int32),
         on_chip((pairs,), jnp.int32), on_chip((pairs,), jnp.int32),
         on_chip((1,), jnp.int32), tile=COMPACT_TILE,
@@ -205,8 +222,10 @@ def test_whole_tree_program_compiles_and_fits(on_chip, monkeypatch, quantized):
         batch=WAVE_K, bagged=False).compile()
     # root histogram, wave histogram, wave compaction
     assert _mosaic_calls(compiled) == 3
+    assert _rows_on_sublanes(compiled, N) == []
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+    assert mem.temp_size_in_bytes < TREE_TEMP_CEILING
 
 
 HIGGS_ROWS = 10_500_000
@@ -218,10 +237,11 @@ def test_sharded_whole_tree_program_fits_four_chips_at_higgs_full(
     """`tree_learner=data, num_machines=4` at Higgs's published 10,500,000
     rows (the benchmark's `higgs_full.train_4chip`): the sharded whole-tree
     program, 2,625,536 rows a shard, compiled for the four described chips
-    (~55 s). One chip's program is refused at this row count (35.43 GB);
-    a shard's has to fit with room for what chip 0 holds besides (scores,
-    labels, gradients: ~50 bytes a row of the whole table). Read by PR 28:
-    11.03 GB temp + 0.14 GB arguments a chip."""
+    (~55 s). A shard's has to fit with room for what chip 0 holds besides
+    (scores, labels, gradients: ~50 bytes a row of the whole table). Read
+    by PR 29: 0.83 GB temp + 0.14 GB arguments a chip (11.03 GB temp at PR
+    28, with the per-row carries [N, k]; one chip's program, refused then
+    at 35.43 GB, now asks 3.00 GB for the same 10,500,000 rows)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from lightgbm_tpu.parallel.learners import DeviceDataParallelTreeLearner
@@ -265,6 +285,7 @@ def test_sharded_whole_tree_program_fits_four_chips_at_higgs_full(
         on(P("data"), (learner.f_pad,), jnp.bool_),
         on(P(), (3,), jnp.float32)).compile()
     assert _mosaic_calls(compiled) == 3
+    assert _rows_on_sublanes(compiled, n_pad // 4) == []
     text = compiled.as_text()
     assert " all-reduce(" in text and " all-gather(" in text
     mem = compiled.memory_analysis()  # bytes on each device

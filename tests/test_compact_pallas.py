@@ -22,11 +22,11 @@ def _np_dst(go_left, ranges, n):
 
 
 def _masks(go_left, ranges, n):
-    match = np.zeros((n, len(ranges)), dtype=bool)
+    match = np.zeros((len(ranges), n), dtype=bool)  # [K, N]: rows minor
     for k, (s, c) in enumerate(ranges):
-        match[s:s + c, k] = True
-    cm = [match[:, k] & go_left for k in range(len(ranges))]
-    cm += [match[:, k] & ~go_left for k in range(len(ranges))]
+        match[k, s:s + c] = True
+    cm = [match[k] & go_left for k in range(len(ranges))]
+    cm += [match[k] & ~go_left for k in range(len(ranges))]
     return match, cm
 
 
@@ -61,15 +61,15 @@ def test_range_partition_dst_matches_oracle(rng, name, ranges):
 @pytest.mark.parametrize("name,ranges", CASES)
 @pytest.mark.parametrize("tile", [256, 512])
 def test_compact_pallas_bit_exact(rng, name, ranges, tile):
-    n, gp, rc = 2048, 8, 5
+    n, gp, rc = 2048, 8, 8  # payload [rc, n]: one row a channel
     go_left = rng.rand(n) < 0.5
     dst, _, cm, match = _dst(go_left, ranges, n)
     bins = rng.randint(0, 60000, size=(gp, n)).astype(np.int32)
-    row = rng.randn(n, rc).astype(np.float32)
-    row[:, 3] = np.arange(n)  # a perm-style integer column rides along
+    row = rng.randn(rc, n).astype(np.float32)
+    row[3] = np.arange(n)  # a perm-style integer row rides along
     # bit patterns a float accumulate would not carry: the kernel ORs bits
-    row[::5, 0], row[1::5, 0] = -0.0, 1e-39
-    moved = match.any(axis=1)
+    row[0, ::5], row[0, 1::5] = -0.0, 1e-39
+    moved = match.any(axis=0)
     ours_b, ours_r = compact_rows(
         jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst),
         [jnp.asarray(m) for m in cm], jnp.asarray(moved),
@@ -77,7 +77,7 @@ def test_compact_pallas_bit_exact(rng, name, ranges, tile):
     ref_b = np.zeros_like(bins)
     ref_b[:, dst] = bins
     ref_r = np.zeros_like(row)
-    ref_r[dst] = row
+    ref_r[:, dst] = row
     np.testing.assert_array_equal(np.asarray(ours_b), ref_b)
     # bit-exact: limb transport must preserve f32 payloads exactly
     np.testing.assert_array_equal(
@@ -90,23 +90,23 @@ def test_pair_list_holds_a_range_spanning_many_tiles(rng):
     3 per tile truncated there, dropping the last output tiles' rows — on
     every tree over ~90k rows, and in no test, since none spanned more
     than 8 tiles."""
-    n, gp, rc, tile = 16384, 32, 5, 256
+    n, gp, rc, tile = 16384, 32, 8, 256
     go_left = rng.rand(n) < 0.5
     dst, _, cm, match = _dst(go_left, [(0, n)], n)
     masks = [jnp.asarray(m) for m in cm]
-    moved = jnp.asarray(match.any(axis=1))
+    moved = jnp.asarray(match.any(axis=0))
     *_, n_pairs = build_pair_tables(jnp.asarray(dst), masks, moved, tile)
     assert 3 * (n // tile) < int(n_pairs[0]) <= max_pairs_bound(n // tile, 2)
     bins = rng.randint(0, 256, size=(gp, n)).astype(np.uint8)
-    row = rng.randn(n, rc).astype(np.float32)
-    row[:, 3] = np.arange(n)
+    row = rng.randn(rc, n).astype(np.float32)
+    row[3] = np.arange(n)
     ours_b, ours_r = compact_rows(
         jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst), masks, moved,
         tile=tile, use_pallas=True, interpret=True)
     ref_b = np.zeros_like(bins)
     ref_b[:, dst] = bins
     ref_r = np.zeros_like(row)
-    ref_r[dst] = row
+    ref_r[:, dst] = row
     np.testing.assert_array_equal(np.asarray(ours_b), ref_b)
     np.testing.assert_array_equal(
         np.asarray(ours_r).view(np.uint32), ref_r.view(np.uint32))
@@ -116,12 +116,12 @@ def test_pair_list_holds_a_range_spanning_many_tiles(rng):
 def test_compact_pallas_uint8_plane(rng, name, ranges):
     """8-bit bin plane rides the single-limb path, output stays uint8 and
     matches both the permutation oracle and the int32 2-limb result."""
-    n, gp, rc, tile = 2048, 32, 5, 256  # gp % 32 == 0 for the 8-bit tile
+    n, gp, rc, tile = 2048, 32, 8, 256  # gp % 32 == 0 for the 8-bit tile
     go_left = rng.rand(n) < 0.5
     dst, _, cm, match = _dst(go_left, ranges, n)
     bins8 = rng.randint(0, 256, size=(gp, n)).astype(np.uint8)
-    row = rng.randn(n, rc).astype(np.float32)
-    moved = match.any(axis=1)
+    row = rng.randn(rc, n).astype(np.float32)
+    moved = match.any(axis=0)
     args = ([jnp.asarray(m) for m in cm], jnp.asarray(moved))
     b8, r8 = compact_rows(
         jnp.asarray(bins8), jnp.asarray(row), jnp.asarray(dst), *args,
@@ -145,10 +145,10 @@ def test_compact_xla_fallback_uint8(rng):
     go_left = rng.rand(n) < 0.3
     dst, _, cm, match = _dst(go_left, ranges, n)
     bins = rng.randint(0, 256, size=(gp, n)).astype(np.uint8)
-    row = rng.randn(n, 3).astype(np.float32)
+    row = rng.randn(3, n).astype(np.float32)
     ours_b, _ = compact_rows(
         jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst),
-        [jnp.asarray(m) for m in cm], jnp.asarray(match.any(axis=1)),
+        [jnp.asarray(m) for m in cm], jnp.asarray(match.any(axis=0)),
         use_pallas=False)
     assert np.asarray(ours_b).dtype == np.uint8
     ref_b = np.zeros_like(bins)
@@ -162,15 +162,15 @@ def test_compact_xla_fallback_exact(rng):
     go_left = rng.rand(n) < 0.3
     dst, _, cm, match = _dst(go_left, ranges, n)
     bins = rng.randint(0, 256, size=(gp, n)).astype(np.int32)
-    row = rng.randn(n, rc).astype(np.float32)
+    row = rng.randn(rc, n).astype(np.float32)
     ours_b, ours_r = compact_rows(
         jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst),
-        [jnp.asarray(m) for m in cm], jnp.asarray(match.any(axis=1)),
+        [jnp.asarray(m) for m in cm], jnp.asarray(match.any(axis=0)),
         use_pallas=False)
     ref_b = np.zeros_like(bins)
     ref_b[:, dst] = bins
     ref_r = np.zeros_like(row)
-    ref_r[dst] = row
+    ref_r[:, dst] = row
     np.testing.assert_array_equal(np.asarray(ours_b), ref_b)
     np.testing.assert_array_equal(np.asarray(ours_r), ref_r)
 
@@ -186,10 +186,10 @@ def test_compact_one_sided(rng):
         assert n_left[0] == (600 if flag else 0)
         bins = np.arange(2 * n, dtype=np.int32).reshape(2, n) % 256
         bins = np.vstack([bins] * 4)  # gp=8
-        row = np.arange(n * 5, dtype=np.float32).reshape(n, 5)
+        row = np.arange(n * 8, dtype=np.float32).reshape(8, n)
         ob, orr = compact_rows(
             jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst),
-            [jnp.asarray(m) for m in cm], jnp.asarray(match.any(axis=1)),
+            [jnp.asarray(m) for m in cm], jnp.asarray(match.any(axis=0)),
             tile=tile, use_pallas=True, interpret=True)
         np.testing.assert_array_equal(np.asarray(ob), bins)
         np.testing.assert_array_equal(np.asarray(orr), row)
@@ -203,7 +203,7 @@ def test_pair_table_bound_and_coverage(rng):
     dst, _, cm, match = _dst(go_left, ranges, n)
     pi, po, copy, npairs = build_pair_tables(
         jnp.asarray(dst), [jnp.asarray(m) for m in cm],
-        jnp.asarray(match.any(axis=1)), tile)
+        jnp.asarray(match.any(axis=0)), tile)
     t = n // tile
     mp = max_pairs_bound(t, len(cm))
     assert pi.shape == (mp,)
@@ -215,7 +215,7 @@ def test_pair_table_bound_and_coverage(rng):
     # pcopy semantics: 1 = raw copy of an untouched identity tile,
     # 2 = duplicate pair demoted to a skip (must repeat its predecessor's
     # blocks and never open an output block), 0 = one-hot permute.
-    touched = match.any(axis=1).reshape(t, tile).any(axis=1)
+    touched = match.any(axis=0).reshape(t, tile).any(axis=1)
     live_in = np.asarray(pi)[:int(npairs[0])]
     live_copy = np.asarray(copy)[:int(npairs[0])]
     for p in range(int(npairs[0])):

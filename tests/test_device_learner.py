@@ -485,3 +485,88 @@ def test_goss_device_selection_is_sync_free(rng, monkeypatch):
     finally:
         sanitize.clear_override()
         sanitize.reset()
+
+
+# ---------------------------------------------------------------- rows on the lanes
+# A custom call's operand layout is fixed, and XLA carries it back into the
+# glue that makes the operand: one [Np, k<128] operand of a kernel pads k to
+# 128 lanes in HBM (2.15 GB for 4M int32) and drags the wave's routing
+# arithmetic onto 8 useful values a vector register (PERF.md, PR 29). What
+# guards the orientation is a shape property of the traced program.
+
+class _Traced(Exception):
+    """Carries a program's jaxpr out of the learner's dispatch."""
+
+
+def _trace_instead_of_running(fn, *_):
+    def dispatch(*args, **kwargs):
+        raise _Traced(fn.trace(*args, **kwargs).jaxpr)
+
+    return dispatch
+
+
+def _pallas_calls(jaxpr):
+    """Every pallas_call equation of a jaxpr, nested programs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+            continue
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else (value,)):
+                inner = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
+                if hasattr(inner, "eqns"):
+                    yield from _pallas_calls(inner)
+
+
+@pytest.mark.parametrize("learner,params,env,kernels", [
+    ("DeviceTreeLearner", {}, {}, 3),
+    # the bagged tree compacts the in-bag rows to the front first
+    ("DeviceTreeLearner", {"bagging_fraction": 0.5, "bagging_freq": 1}, {},
+     4),
+    ("DeviceTreeLearner", {"use_quantized_grad": True}, {}, 3),
+    ("DeviceTreeLearner", {}, {"LGBM_TPU_GH_BF16": "1"}, 3),
+    ("DeviceTreeLearner", {}, {"LGBM_TPU_BINS_I32": "1"}, 3),
+    ("DeviceDataParallelTreeLearner", {}, {}, 3),
+    ("VotingDataParallelTreeLearner", {}, {}, 3),
+    ("DeviceFeatureParallelTreeLearner", {}, {}, 3),
+], ids=["plain", "bagged", "quantized", "gh_bf16", "int32_plane",
+        "data_parallel", "voting", "feature"])
+def test_no_kernel_operand_has_rows_on_the_sublanes(
+        rng, monkeypatch, learner, params, env, kernels):
+    """Every per-row operand and result of the whole-tree program's
+    pallas_calls is [k, Np], rows on the minor axis, never [Np, k<128]."""
+    from lightgbm_tpu.parallel import learners
+    from lightgbm_tpu.treelearner import device as device_mod
+
+    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
+    for name, value in env.items():
+        monkeypatch.setenv(name, value)
+    monkeypatch.setattr(device_mod.sanitize, "guard",
+                        _trace_instead_of_running)
+    X = rng.randn(1500, 8)
+    y = (X[:, 0] - 0.7 * X[:, 1] + 0.3 * rng.randn(1500) > 0).astype(float)
+    cfg = Config({"objective": "binary", "num_leaves": 15, "verbosity": -1,
+                  **params})
+    ds = CoreDataset.from_matrix(X, label=y, config=cfg)
+    bst = GBDT(cfg, ds, create_objective(cfg.objective, cfg))
+    # the switches are read while the program is traced: no trace of another
+    # case may answer for this one
+    device_mod.grow_tree_on_device.clear_cache()
+    try:
+        bst.tree_learner = getattr(learners, learner)(cfg, ds)
+        with pytest.raises(_Traced) as caught:
+            bst.train_one_iter()
+    finally:
+        device_mod.grow_tree_on_device.clear_cache()
+    calls = list(_pallas_calls(caught.value.args[0].jaxpr))
+    assert len(calls) == kernels
+    for eqn in calls:
+        shapes = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
+        n_rows = max(s[-1] for s in shapes if len(s) == 2)  # the bin plane's
+        assert n_rows % 1024 == 0 and n_rows >= 1024
+        per_row = [s for s in shapes if n_rows in s]
+        assert len(per_row) >= 3  # plane, payload, slot or dst (+ results)
+        for shape in per_row:
+            assert len(shape) == 2 and shape[-1] == n_rows, (
+                eqn.params.get("name"), shapes)

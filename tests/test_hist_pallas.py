@@ -137,7 +137,7 @@ def test_pallas_histogram_slots_ragged(rng, ranges):
     G, B, bins, gh, slot, tiles, n_act = _ragged_setup(rng, n, tile, ranges,
                                                        S)
     ours = np.asarray(pallas_histogram_slots_ragged(
-        jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(slot), tiles, n_act,
+        jnp.asarray(bins), jnp.asarray(gh.T), jnp.asarray(slot), tiles, n_act,
         B, S, tile_rows=tile, f32=True, interpret=True))
     assert ours.shape == (G, B, S * 3)
     covered = int(np.asarray(n_act)[0]) * tile
@@ -159,7 +159,7 @@ def test_pallas_histogram_slots_ragged_quantized_exact(rng):
     G, B, bins, gh, slot, tiles, n_act = _ragged_setup(
         rng, n, tile, ranges, S, quantized=True)
     ours = np.asarray(pallas_histogram_slots_ragged(
-        jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(slot), tiles, n_act,
+        jnp.asarray(bins), jnp.asarray(gh.T), jnp.asarray(slot), tiles, n_act,
         B, S, tile_rows=tile, quantized=True, interpret=True))
     assert ours.dtype == np.int32
     dense = np.asarray(pallas_histogram_slots(
@@ -215,10 +215,10 @@ def test_pallas_histogram_slots_ragged_uint8_bit_identical(rng):
             rng, n, tile, ranges, S, quantized=quant)
         bins8 = bins.astype(np.uint8)
         a = np.asarray(pallas_histogram_slots_ragged(
-            jnp.asarray(bins8), jnp.asarray(gh), jnp.asarray(slot), tiles,
+            jnp.asarray(bins8), jnp.asarray(gh.T), jnp.asarray(slot), tiles,
             n_act, B, S, tile_rows=tile, quantized=quant, interpret=True))
         b = np.asarray(pallas_histogram_slots_ragged(
-            jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(slot), tiles,
+            jnp.asarray(bins), jnp.asarray(gh.T), jnp.asarray(slot), tiles,
             n_act, B, S, tile_rows=tile, quantized=quant, interpret=True))
         np.testing.assert_array_equal(a.view(np.uint32), b.view(np.uint32))
 

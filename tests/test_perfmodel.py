@@ -43,13 +43,14 @@ def _clean_capture_state():
 
 def test_carry_formula_matches_bench_expectation():
     # the bench smoke's locked figure: 28 features -> Gp=32 uint8 groups,
-    # 20000 rows pad to the 1024-row wave unit, payload 5 cols x 4 B
+    # 20000 rows pad to the 1024-row wave unit, payload 5 channels carried
+    # as 8 rows x 4 B (the [8, Np] carry pads to the sublane tile)
     n_pad = -(-20000 // 1024) * 1024
     assert perfmodel.carry_bytes_per_wave(20000, 28, 1, 1024) \
-        == n_pad * (32 * 1 + 5 * 4)
+        == n_pad * (32 * 1 + 8 * 4)
     # int32 planes pad the group dim to 8: ceil(28/8)*8 = 32 groups still
     assert perfmodel.carry_bytes_per_wave(20000, 28, 4, 1024) \
-        == n_pad * (32 * 4 + 5 * 4)
+        == n_pad * (32 * 4 + 8 * 4)
     assert perfmodel.plane_groups_padded(17, 4) == 24
 
 
