@@ -110,12 +110,10 @@ def _wave_traffic_fields(ds) -> dict:
 
 def _kernel_micro_fields(ds, n_rows: int) -> dict:
     """Per-dispatch microlatency of the round-8 kernels, measured with the
-    session's real dataset shapes on this backend so kernel-on/off ledger
-    rows attribute the fused-scan and device-GOSS wins directly:
+    session's real dataset shapes on this backend:
 
     * scan_kernel_ms: one `find_best_split` dispatch (the same call the
-      serial learner's per-leaf scan makes; routed through the fused
-      Pallas kernel or the XLA path by LGBM_TPU_SCAN_PALLAS);
+      serial learner's per-leaf scan makes: the XLA scan of ops/split.py);
     * goss_device_gather_ms: one jitted GOSS select (score + stable
       argsort + top-rate mask + small-gradient rescale) at the training
       row count — the work the device bag keeps off the host.
@@ -352,11 +350,11 @@ def run_bench(n_rows: int) -> dict:
         from lightgbm_tpu import perfmodel
         from lightgbm_tpu.utils.timer import global_timer
 
-        # round-8 wave controller + kernel instrumentation: the observed
-        # commit rate and the K the adaptive controller settled on (both 0
-        # when the run never dispatched the device learner), plus the
-        # per-dispatch microlatency of the fused scan and the device GOSS
-        # select at this session's shapes
+        # wave accounting: the observed commit rate and the wave width
+        # (the record keeps its old field name; both 0 when the run never
+        # dispatched the device learner), plus the per-dispatch
+        # microlatency of the split scan and the device GOSS select at
+        # this session's shapes
         spec = int(global_timer.counters.get("wave_splits_speculated", 0))
         out["wave_commit_rate"] = round(
             int(global_timer.counters.get("wave_splits_committed", 0))

@@ -43,8 +43,8 @@ def _git_sha(repo_dir: Optional[str] = None) -> str:
 
 def _flag_env() -> Dict[str, str]:
     """Every set LGBM_TPU_* flag plus the jax/bench knobs that change what
-    a capture measures — the flags ARE the experiment axes (GH_BF16,
-    COMPACT_ALIAS, ...), so they belong in the fingerprint."""
+    a capture measures — a path choice (HIST_F32, BINS_I32, ...) is an
+    experiment axis, so it belongs in the fingerprint."""
     keep_exact = ("JAX_PLATFORMS",)
     out = {k: v for k, v in os.environ.items()
            if k.startswith("LGBM_TPU_") or k in keep_exact}

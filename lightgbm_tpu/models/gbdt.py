@@ -659,11 +659,8 @@ class GBDT:
                 # replica's first bucket-shaped request skips the XLA compile
                 fn = None
                 if packed.num_trees > 0 and not packed.linear:
-                    from ..ops.predict import predict_pallas_enabled
-
-                    if not predict_pallas_enabled():
-                        fn = self._predictor.aot_get(
-                            packed, n, X.shape[1], C, np.dtype(dtype))
+                    fn = self._predictor.aot_get(
+                        packed, n, X.shape[1], C, np.dtype(dtype))
                 xd = upload()
                 if fn is not None:
                     with global_timer.scope(SPAN_PREDICT_TRAVERSE):

@@ -53,7 +53,6 @@ composite-key sort ordering the pair list; no row-wise sort anywhere — at
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import List, Sequence, Tuple
 
@@ -309,10 +308,9 @@ def _make_compact_kernel(tile: int, gp: int, rc: int, plane8: bool):
     return kernel
 
 
-@partial(jax.jit, static_argnames=("tile", "interpret", "alias"))
+@partial(jax.jit, static_argnames=("tile", "interpret"))
 def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
-                         n_pairs, tile: int, interpret: bool,
-                         alias: bool = False):
+                         n_pairs, tile: int, interpret: bool):
     Gp, N = bins_p.shape
     rc = row_p.shape[0]
     mp = pair_in.shape[0]
@@ -330,16 +328,6 @@ def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
             pl.BlockSpec((rc, tile), lambda p, pi, po, pc, npr: (0, po[p])),
         ],
     )
-    kwargs = {}
-    if alias:
-        # LGBM_TPU_COMPACT_ALIAS=1: reuse the bins/row input buffers as the
-        # outputs (no double buffering of the two largest carries). Indices
-        # count the 4 scalar-prefetch operands first. UNSAFE in general: a
-        # pair whose in_tile < out_tile reads its input tile after the
-        # aliased output tile has already been flushed over it. Safe only
-        # when the runtime keeps a private copy or the permutation never
-        # moves rows to a later tile than any unread source — hence opt-in.
-        kwargs["input_output_aliases"] = {4: 0, 5: 1}
     return pl.pallas_call(
         _make_compact_kernel(tile, Gp, rc, plane8),
         grid_spec=grid_spec,
@@ -352,7 +340,6 @@ def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
         # the custom call after it, and the benchmark's
         # train.compact_kernel_ms_per_tree finds it by ^_pallas_compact_call
         name="_pallas_compact_call",
-        **kwargs,
     )(pair_in, pair_out, is_copy, n_pairs, bins_p, row_p,
       dst.reshape(1, N))
 
@@ -371,9 +358,7 @@ def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,
     class_masks disjoint with per-tile-contiguous destinations
     (range_partition_dst output qualifies), moved == union(class_masks).
     The XLA path is a plain permutation scatter — exact on CPU, used when
-    no TPU backend is live. LGBM_TPU_COMPACT_ALIAS=1 opts in to
-    input/output buffer aliasing on the pallas_call (see
-    _pallas_compact_call for the hazard).
+    no TPU backend is live.
     """
     if not use_pallas:
         bins_o = jnp.zeros_like(bins_p).at[:, dst].set(
@@ -386,7 +371,6 @@ def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,
                          f"count padded to 8, got {row_p.shape[0]}")
     pair_in, pair_out, is_copy, n_pairs = build_pair_tables(
         dst, class_masks, moved, tile)
-    alias = os.environ.get("LGBM_TPU_COMPACT_ALIAS", "") == "1"
     row_f32 = row_p.astype(jnp.float32)
     dst_i32 = dst.astype(jnp.int32)
     if telemetry.enabled():
@@ -394,6 +378,6 @@ def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,
         # shape/dtype perfmodel's AOT cost_analysis re-lower needs)
         perfmodel.note_dispatch("compact", _pallas_compact_call,
                                 bins_p, row_f32, dst_i32, pair_in, pair_out,
-                                is_copy, n_pairs, tile, interpret, alias)
+                                is_copy, n_pairs, tile, interpret)
     return _pallas_compact_call(bins_p, row_f32, dst_i32, pair_in, pair_out,
-                                is_copy, n_pairs, tile, interpret, alias)
+                                is_copy, n_pairs, tile, interpret)

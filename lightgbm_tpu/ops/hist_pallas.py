@@ -28,10 +28,9 @@ Counterpart of the CUDA shared-memory scatter kernels
 "accumulate in fast memory, flush once" structure, with the TPU twist that
 the accumulation is an MXU contraction instead of atomic scatters.
 
-Used automatically on TPU backends (ops/histogram.py routes here); the XLA
-path remains for CPU and as the LGBM_TPU_HIST=xla escape hatch. Correctness
-is pinned by tests running this kernel in interpret mode against the XLA
-path and the numpy reference.
+Used on TPU backends (ops/histogram.py routes here from on_tpu()); the XLA
+path is the CPU's. Correctness is pinned by tests running the kernels in
+interpret mode against the XLA path and a numpy reference.
 """
 from __future__ import annotations
 
@@ -47,8 +46,7 @@ from .. import telemetry
 
 # classify these entries' jit cache misses as kernel compiles (telemetry's
 # recompile watcher keeps them in a counter separate from XLA churn)
-for _fn in ("pallas_histogram", "pallas_histogram_slots",
-            "pallas_histogram_slots_ragged"):
+for _fn in ("pallas_histogram", "pallas_histogram_slots_ragged"):
     telemetry.register_kernel_fn(_fn)
 
 DEFAULT_TILE_ROWS = 1024  # best of {512, 1024, 2048, 4096} on v5e
@@ -71,7 +69,7 @@ def _group_block(n_groups: int, n_channels: int, num_bins: int,
 
 
 def _prep_bins(bins: jax.Array, n_channels: int, num_bins: int):
-    """Bin-plane dtype + group-block policy shared by the three wrappers.
+    """Bin-plane dtype + group-block policy shared by the two wrappers.
 
     8-bit planes (uint8 bins) pass through UNWIDENED — the dominant [G, N]
     array moves 4x fewer HBM bytes — and the kernels widen each group row
@@ -179,109 +177,6 @@ def pallas_histogram(bins: jax.Array, gh: jax.Array, num_bins: int,
     return out[:G].transpose(0, 2, 1)  # [G, B, CH]; 172KB, free vs the dot
 
 
-def _make_slots_kernel(num_bins: int, tile_rows: int, n_slots: int,
-                       ch: int, compute_dtype, acc_dtype, group_block: int):
-    SC = n_slots * ch
-
-    def kernel(bins_ref, gh_ref, slot_ref, out_ref):
-        @pl.when(pl.program_id(1) == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        s = slot_ref[...]  # [TN, 1] int32
-        ghc = gh_ref[...]  # [TN, ch]
-        # flat 2D build of the slot-expanded gradient tile — column
-        # j = slot*ch + channel. Strictly 2D broadcasts: per-channel masked
-        # adds instead of a concat/tile (an n_slots-way concat lowers to a
-        # serial copy chain in Mosaic; measured ~2x slower end to end), and
-        # the whole [TN, SC] tile lives only in VMEM (the XLA-side
-        # materialization of this matrix cost ~18 ms/wave of HBM traffic).
-        # Mosaic has no elementwise int8 vectors ("only vector<i16/i32>"),
-        # so the quantized build runs in int32 and casts to int8 only at
-        # the matmul operand.
-        build_dtype = (jnp.int32 if jnp.issubdtype(jnp.dtype(compute_dtype),
-                                                   jnp.integer)
-                       else ghc.dtype)
-        ghb = ghc.astype(build_dtype)
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, SC), 1)
-        colslot, colch = col // ch, col % ch
-        gsum = jnp.zeros((tile_rows, SC), build_dtype)
-        for c in range(ch):
-            gsum += ghb[:, c:c + 1] * (colch == c).astype(build_dtype)
-        ghK = (gsum * (colslot == s).astype(build_dtype)).astype(compute_dtype)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (tile_rows, num_bins), 1)
-        for gi in range(group_block):
-            b = bins_ref[gi, :].astype(jnp.int32)
-            onehot = (b[:, None] == iota).astype(compute_dtype)
-            acc = jax.lax.dot_general(
-                ghK, onehot,
-                dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=acc_dtype,
-                precision=(jax.lax.Precision.HIGHEST
-                           if compute_dtype == jnp.float32 else
-                           jax.lax.Precision.DEFAULT))  # [SC, B]
-            out_ref[gi] += acc
-
-    return kernel
-
-
-@partial(jax.jit, static_argnames=("num_bins", "n_slots", "tile_rows",
-                                   "quantized", "f32", "interpret"))
-def pallas_histogram_slots(bins: jax.Array, gh: jax.Array, slot: jax.Array,
-                           num_bins: int, n_slots: int,
-                           tile_rows: int = DEFAULT_TILE_ROWS,
-                           quantized: bool = False,
-                           f32: bool = False,
-                           interpret: bool = False) -> jax.Array:
-    """Slot-expanded histogram: [G, N] bins + [N, CH] gh + [N] slot ids ->
-    [G, num_bins, n_slots*CH], where row n contributes its gh to channel
-    block slot[n] (rows with slot outside [0, n_slots) contribute nowhere).
-
-    This is the wave histogram of the batched device learner: building the
-    [N, n_slots*CH] slot-expanded gradient matrix in XLA costs a full HBM
-    round trip of n_slots*CH f32 per row (~10 ms/wave at 1M rows); here the
-    expansion happens per-tile in VMEM for free. Dtype policy matches
-    pallas_histogram."""
-    G, N = bins.shape
-    CH = gh.shape[1]
-    SC = n_slots * CH
-    if quantized:
-        compute_dtype, acc_dtype = jnp.int8, jnp.int32
-    elif f32:
-        compute_dtype, acc_dtype = jnp.float32, jnp.float32
-    else:
-        compute_dtype, acc_dtype = jnp.bfloat16, jnp.float32
-    n_tiles = max(-(-N // tile_rows), 1)
-    pad = n_tiles * tile_rows - N
-    bins, GB = _prep_bins(bins, SC, num_bins)
-    slot = slot.reshape(N, 1).astype(jnp.int32)
-    if pad:
-        bins = jnp.pad(bins, ((0, 0), (0, pad)), constant_values=0)
-        gh = jnp.pad(gh, ((0, pad), (0, 0)))  # zero gh => no contribution
-        slot = jnp.pad(slot, ((0, pad), (0, 0)), constant_values=n_slots)
-    g_blocks = max(-(-G // GB), 1)
-    g_pad = g_blocks * GB - G
-    if g_pad:
-        bins = jnp.pad(bins, ((0, g_pad), (0, 0)), constant_values=0)
-    out = pl.pallas_call(
-        _make_slots_kernel(num_bins, tile_rows, n_slots, CH, compute_dtype,
-                           acc_dtype, GB),
-        grid=(g_blocks, n_tiles),
-        in_specs=[
-            pl.BlockSpec((GB, tile_rows), lambda g, t: (g, t)),
-            pl.BlockSpec((tile_rows, CH), lambda g, t: (t, 0)),
-            pl.BlockSpec((tile_rows, 1), lambda g, t: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((GB, SC, num_bins),
-                               lambda g, t: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((g_blocks * GB, SC, num_bins),
-                                       acc_dtype),
-        interpret=interpret,
-        name="pallas_histogram_slots",
-    )(bins, gh, slot)
-    return out[:G].transpose(0, 2, 1)  # [G, B, SC]
-
-
 def active_tile_table(starts: jax.Array, ends: jax.Array, valid: jax.Array,
                       n_tiles: int, tile_rows: int):
     """Row-tile indirection table for the ragged wave histogram.
@@ -321,11 +216,13 @@ def _make_slots_ragged_kernel(num_bins: int, tile_rows: int, n_slots: int,
         def _acc():
             s = slot_ref[...]  # [1, TN] int32: rows on the lanes
             ghc = gh_ref[...]  # [ch, TN] f32 (quantized: exact small ints)
-            # slot-expanded gradient tile, row j = slot*ch + channel: the
-            # same per-channel masked adds and slot mask as the dense slots
-            # kernel above, built [SC, TN] straight from the lane-major
-            # payload rows (a sublane broadcast each), so the contraction
-            # below is the plain [SC, TN] @ [TN, B] form
+            # slot-expanded gradient tile, row j = slot*ch + channel, built
+            # [SC, TN] in VMEM straight from the lane-major payload rows (a
+            # sublane broadcast each), so the contraction below is the plain
+            # [SC, TN] @ [TN, B] form. Strictly 2D broadcasts: per-channel
+            # masked adds, not a concat/tile (an n_slots-way concat lowers
+            # to a serial copy chain in Mosaic, ~2x slower end to end; the
+            # XLA-side materialization of this matrix cost ~18 ms a wave).
             row = jax.lax.broadcasted_iota(jnp.int32, (SC, 1), 0)
             rowslot, rowch = row // ch, row % ch  # [SC, 1]: 8 registers
             gsum = jnp.zeros((SC, tile_rows), jnp.float32)
@@ -362,7 +259,10 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
                                   quantized: bool = False,
                                   f32: bool = False,
                                   interpret: bool = False) -> jax.Array:
-    """pallas_histogram_slots restricted to an indirected set of row tiles.
+    """Slot-expanded histogram over an indirected set of row tiles:
+    [G, N] bins + [CH, N] gh + [N] slot ids -> [G, num_bins, n_slots*CH],
+    where row n adds its gh to channel block slot[n] and a row whose slot
+    is outside [0, n_slots) adds nowhere. Dtype policy as pallas_histogram.
 
     The rows-in-leaf wave histogram: `tiles` (from active_tile_table) names
     the row tiles overlapping the wave's selected leaf ranges; the grid

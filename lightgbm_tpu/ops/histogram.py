@@ -23,7 +23,6 @@ The channel layout is [grad, hess, count].
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Optional
 
@@ -34,21 +33,6 @@ from .. import perfmodel, telemetry
 from ..utils.backend import on_tpu
 
 DEFAULT_ROW_CHUNK = 16384
-
-
-def _use_pallas() -> bool:
-    """Pallas kernel on TPU ONLY (the XLA one-hot contraction risks
-    materializing the [G, chunk, B] one-hot in HBM); the kernel's
-    revisited-output accumulation relies on TPU's sequential grid, so other
-    backends (cpu, gpu) always take the XLA path. LGBM_TPU_HIST=xla|pallas
-    overrides, resolved at CALL time (the public entry points are unjitted
-    wrappers so the env var participates in dispatch, not a baked trace)."""
-    mode = os.environ.get("LGBM_TPU_HIST", "auto")
-    if mode == "xla":
-        return False
-    if mode == "pallas":
-        return True
-    return on_tpu()
 
 
 def _acc_dtype(compute_dtype):
@@ -81,13 +65,14 @@ def build_histogram(bins: jax.Array, gh: jax.Array, num_bins: int,
     gh:   [N, 3] float (grad, hess, 1.0)
     Returns [G, num_bins, 3] float32.
 
-    Unjitted dispatch wrapper: the backend choice (Pallas on TPU, XLA
-    elsewhere / LGBM_TPU_HIST override) resolves per call, then routes to a
+    Unjitted dispatch wrapper: the backend choice (the Pallas kernel on a
+    TPU, whose sequential grid its revisited-output accumulation relies on;
+    the XLA contraction elsewhere) resolves per call, then routes to a
     jitted implementation. Inside an outer jit the choice is baked at that
     trace's creation, as any Python-level branch must be.
     """
     if use_pallas is None:
-        use_pallas = _use_pallas()
+        use_pallas = on_tpu()
     if use_pallas:
         from .hist_pallas import hist_force_f32, pallas_histogram
 
@@ -141,7 +126,7 @@ def build_histogram_rows(bins: jax.Array, gh_ext: jax.Array, row_idx: jax.Array,
     gather is clamped (any bin works since the weight is zero).
     """
     if use_pallas is None:
-        use_pallas = _use_pallas()
+        use_pallas = on_tpu()
     if use_pallas:
         from .hist_pallas import hist_force_f32, pallas_histogram
 

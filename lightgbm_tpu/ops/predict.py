@@ -37,8 +37,6 @@ Serving-path machinery on top of the traversal:
     copy_to_host_async double buffering for large N.
   * `predict_raw_early_stop` — device-resident: scores and the active-row
     mask stay on device; the only per-block host sync is one scalar.
-  * optional Pallas row-tile traversal behind LGBM_TPU_PREDICT_PALLAS=1
-    (ops/predict_pallas.py: interpret-tested; Mosaic refuses it today).
 """
 from __future__ import annotations
 
@@ -56,7 +54,7 @@ import numpy as np
 from .. import perfmodel, telemetry, tracing
 from ..common import MISSING_NAN, MISSING_ZERO, K_ZERO_THRESHOLD
 from ..models.tree import Tree
-from ..utils.backend import on_tpu, pallas_interpret
+from ..utils.backend import on_tpu
 from ..utils.log import Log
 from ..utils.timer import (SCOPE_ACCUMULATE, SCOPE_DECIDE,
                            SCOPE_FEATURE_GATHER, SCOPE_LEAF_VALUES,
@@ -343,8 +341,7 @@ def forest_level_step(X: jax.Array, node: jax.Array, sf: jax.Array,
     Node attributes for ALL T trees' current nodes gather from the
     flattened [T*I] tables in one shot, and the feature values for the
     whole forest come from ONE take_along_axis over X — the per-tree
-    formulation issued T X-gathers per level. Shared verbatim by the XLA
-    path and the Pallas row-tile kernel (ops/predict_pallas.py)."""
+    formulation issued T X-gathers per level."""
     I = sf.shape[1]
     T = sf.shape[0]
     with jax.named_scope(SCOPE_NODE_GATHER):
@@ -563,11 +560,6 @@ def validate_tree_count(packed: PackedEnsemble,
             packed.num_trees, num_tree_per_iteration)
 
 
-def predict_pallas_enabled() -> bool:
-    return os.environ.get("LGBM_TPU_PREDICT_PALLAS", "").lower() in (
-        "1", "true", "on")
-
-
 def predict_raw(packed: PackedEnsemble, X: jax.Array,
                 num_tree_per_iteration: int = 1) -> jax.Array:
     """Raw scores [N, num_tree_per_iteration] summed over iterations. The
@@ -578,18 +570,11 @@ def predict_raw(packed: PackedEnsemble, X: jax.Array,
     if T == 0:
         return jnp.zeros((X.shape[0], num_tree_per_iteration), dtype=X.dtype)
     validate_tree_count(packed, num_tree_per_iteration)
-    pallas = predict_pallas_enabled() and not packed.linear
-    dense = packed.dense and not pallas
-    tracing.note(SPAN_PREDICT_TRAVERSE, dense=int(dense),
+    tracing.note(SPAN_PREDICT_TRAVERSE, dense=int(packed.dense),
                  rows=int(X.shape[0]), trees=T)
     global_timer.add_count(
-        "predict_dense_calls" if dense else "predict_gather_calls", 1)
+        "predict_dense_calls" if packed.dense else "predict_gather_calls", 1)
     with global_timer.scope(SPAN_PREDICT_TRAVERSE):
-        if pallas:
-            from .predict_pallas import pallas_predict_raw
-
-            return pallas_predict_raw(packed, X, num_tree_per_iteration,
-                                      interpret=pallas_interpret())
         if packed.linear:
             # under jit XLA contracts the linear mul+sum into fmas, a 1-ulp
             # drift vs the eager reference arithmetic; keep the score math

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common import MISSING_NAN, MISSING_NONE, MISSING_ZERO
-from ..utils.backend import pallas_interpret
 
 K_EPSILON = 1e-15
 K_MIN_GAIN = -np.inf
@@ -330,19 +329,7 @@ def per_feature_best(fh: jax.Array, totals: jax.Array, meta: FeatureMeta,
     core scan shared by the serial learner and the data/feature/voting
     parallel learners (the reference runs FindBestThresholdSequentially per
     rank feature block, data_parallel_tree_learner.cpp:305+).
-
-    LGBM_TPU_SCAN_PALLAS=1 routes the numeric lanes to the fused Pallas
-    kernel instead (ops/scan_pallas.py: bit-identical interpreted, refused
-    by Mosaic compiled — an opt-in that raises, not a default).
-    Monotone-constrained scans — the clamped-output gain variant below —
-    always take the XLA body.
     """
-    from . import scan_pallas  # local import: scan_pallas has no split dep
-    if (constraint is None and fh.dtype == jnp.float32
-            and scan_pallas.use_scan_pallas()):
-        return scan_pallas.per_feature_best_fused(
-            fh, totals, meta, params, feature_mask, penalty,
-            interpret=pallas_interpret())
     l1, l2, min_data, min_hess, min_gain, max_delta = (
         params[0], params[1], params[2], params[3], params[4], params[5])
     F, Bmax, _ = fh.shape

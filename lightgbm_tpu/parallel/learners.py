@@ -602,16 +602,11 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
         records. O(K*F*Bmax*CH): independent of the row count
         (docs/PERF_NOTES.md comm-volume model); tests assert the
         N-independence."""
-        K = self._dispatched_wave_k()
+        K = self.wave_k
         pool_bytes = 2 if narrow else 4
         self._set_ici_bytes_per_wave(
             K * self.f_pad * self.meta.max_bins * 3 * pool_bytes
             + 2 * K * self.f_pad * REC * 4)
-
-    def _dispatched_wave_k(self) -> int:
-        """The wave width of every sharded dispatch: `_grow_fn` compiles
-        the ceiling `wave`, never the adaptive controller's `wave_k`."""
-        return max(1, min(self.wave, self.config.num_leaves))
 
     def _set_ici_bytes_per_wave(self, bytes_w: int) -> None:
         """The gauge, and the learner's own copy of it for the tree's
@@ -686,7 +681,7 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
                 start()
         return _PendingTree(Tree(cfg.num_leaves), rec_store, leaf_id,
                             hist_rows, n_waves, n_bag,
-                            wave_k=self._dispatched_wave_k())
+                            wave_k=self.wave_k)
 
     def _gather_leaf_ids(self, leaf_id: jax.Array) -> jax.Array:
         """The tree's per-row leaf ids without the row padding, on the
@@ -761,7 +756,7 @@ class VotingDataParallelTreeLearner(DeviceDataParallelTreeLearner):
         widths). The smaller-child half of each wave is dispatched before
         the larger-child subtraction it overlaps, so half the wave's ICI
         bytes hide behind local compute by construction."""
-        K = self._dispatched_wave_k()
+        K = self.wave_k
         pool_bytes = 2 if narrow else 4
         bytes_w = voting_ici_bytes_per_wave(
             K, self._k_local, self._k_global, self.meta.max_bins, self.D,
@@ -815,7 +810,7 @@ class DeviceFeatureParallelTreeLearner(DeviceDataParallelTreeLearner):
         """Gauge: the best-record all_gather is the ONLY collective —
         O(2K*D*REC), independent of N and F (tests assert the
         N-independence)."""
-        bytes_w = feature_ici_bytes_per_wave(self._dispatched_wave_k(),
+        bytes_w = feature_ici_bytes_per_wave(self.wave_k,
                                              self.D)
         self._set_ici_bytes_per_wave(bytes_w)
         global_timer.set_count("feature_ici_bytes_per_wave", bytes_w)

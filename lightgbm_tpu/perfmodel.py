@@ -62,13 +62,13 @@ def plane_groups_padded(n_groups: int, plane_bytes: int) -> int:
 
 
 def carry_bytes_per_wave(n_rows: int, n_groups: int, plane_bytes: int,
-                         unit: int, payload_cols: int = PAYLOAD_COLS) -> int:
+                         unit: int) -> int:
     """HBM bytes of the wave loop carry (PERF_NOTES round-5):
     ``Gp * Np * plane_bytes + Np * payload_rows * 4``, the payload's
-    `payload_cols` channels carried as rows padded to a multiple of 8."""
+    PAYLOAD_COLS channels carried as rows padded to a multiple of 8."""
     np_rows = padded_rows(n_rows, unit)
     gp = plane_groups_padded(n_groups, plane_bytes)
-    payload_rows = -(-int(payload_cols) // 8) * 8
+    payload_rows = -(-PAYLOAD_COLS // 8) * 8
     return gp * np_rows * int(plane_bytes) + np_rows * payload_rows * 4
 
 
@@ -89,20 +89,16 @@ def stream_block_bytes(block_rows: int, n_groups: int, plane_bytes: int) -> int:
 
 
 def scan_bytes_per_wave(wave_width: int, f_pad: int, max_bins: int,
-                        ch: int = 3, pool_bytes: int = 4,
-                        fused: bool = False) -> int:
+                        ch: int = 3, pool_bytes: int = 4) -> int:
     """Gain-scan traffic per wave (PERF_NOTES round-4 step 5, round-8):
-    both regimes read the [K, F_pad, Bmax, CH] histogram pool block and
-    write the [2K, F_pad, REC] best-record store; the unfused XLA path
-    additionally materializes the two per-lane gain tensors ([K, F_pad,
-    2*Bmax] f32, written then re-read by the argmax) through HBM, which
-    the fused Pallas kernel (ops/scan_pallas.py) keeps in VMEM."""
+    the XLA scan reads the [K, F_pad, Bmax, CH] histogram pool block,
+    writes the [2K, F_pad, REC] best-record store, and materializes the
+    two per-lane gain tensors ([K, F_pad, 2*Bmax] f32, written then
+    re-read by the argmax) through HBM."""
     k = int(wave_width)
-    base = (k * int(f_pad) * int(max_bins) * int(ch) * int(pool_bytes)
-            + 2 * k * int(f_pad) * REC_FIELDS * 4)
-    if not fused:
-        base += 2 * k * int(f_pad) * 2 * int(max_bins) * 4
-    return base
+    return (k * int(f_pad) * int(max_bins) * int(ch) * int(pool_bytes)
+            + 2 * k * int(f_pad) * REC_FIELDS * 4
+            + 2 * k * int(f_pad) * 2 * int(max_bins) * 4)
 
 
 def ici_bytes_per_wave(wave_width: int, f_pad: int, max_bins: int,

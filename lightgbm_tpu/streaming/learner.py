@@ -55,11 +55,11 @@ from ..io.dataset import Dataset
 from ..ops.hist_pallas import (DEFAULT_TILE_ROWS, active_tile_table,
                                hist_force_f32,
                                pallas_histogram_slots_ragged)
-from ..ops.histogram import (DEFAULT_ROW_CHUNK, _acc_dtype, _hist_chunk,
-                             _use_pallas)
+from ..ops.histogram import DEFAULT_ROW_CHUNK, _acc_dtype, _hist_chunk
 from ..ops.partition import pad_indices
 from ..ops.score import binned_leaf_index, binned_tree_arrays
 from ..treelearner.serial import SerialTreeLearner
+from ..utils.backend import on_tpu
 from ..utils.timer import global_timer
 
 BUDGET_ENV = "LGBM_TPU_HBM_BUDGET"
@@ -266,8 +266,8 @@ class StreamedTreeLearner(SerialTreeLearner):
     # ------------------------------------------------------- histograms
 
     def _ragged_mode(self) -> Optional[str]:
-        """Resolve RAGGED_ENV at call time (mirrors _use_pallas's unjitted
-        dispatch contract): None = XLA scatter, else 'compiled'|'interpret'."""
+        """Resolve RAGGED_ENV at call time (the unjitted dispatch contract
+        of ops.histogram): None = XLA scatter, else 'compiled'|'interpret'."""
         mode = os.environ.get(RAGGED_ENV, "")
         if mode == "0":
             return None
@@ -275,7 +275,7 @@ class StreamedTreeLearner(SerialTreeLearner):
             return "interpret"
         if mode == "1":
             return "compiled"
-        return "compiled" if _use_pallas() else None
+        return "compiled" if on_tpu() else None
 
     def _leaf_hist(self, leaf: int) -> jax.Array:
         mode = self._ragged_mode()

@@ -67,9 +67,8 @@ _session: Optional["TelemetrySession"] = None
 # --- kernel-compile classification -----------------------------------------
 # Pallas/Mosaic kernel wrappers register their jitted entry names here at
 # import; the recompile watcher splits their cache misses into the separate
-# `kernel_compiles` counter so kernel-flag experiments (LGBM_TPU_GH_BF16,
-# LGBM_TPU_COMPACT_ALIAS change kernel signatures, hence kernel compiles)
-# show their compile cost apart from ordinary XLA jit churn. The substring
+# `kernel_compiles` counter so a change of a kernel's signature shows its
+# compile cost apart from ordinary XLA jit churn. The substring
 # markers back up the registry for names we never saw registered.
 _KERNEL_FN_MARKERS = ("pallas", "mosaic")
 _kernel_fns: set = set()

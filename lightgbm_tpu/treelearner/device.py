@@ -51,7 +51,6 @@ cuda_single_gpu_tree_learner.cpp:169-360, cuda_data_partition.cu).
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Dict, NamedTuple, Optional, Tuple
 
@@ -63,13 +62,12 @@ from jax import shard_map
 from ..models.sample_strategy import DeviceBag
 from ..models.tree import Tree
 from ..ops.histogram import build_histogram
-from ..ops.partition import bucket_size
 from ..ops.split import (SPLIT_FIELDS, ScanMeta, SplitInfo, find_best_split,
                          fix_feature_hist, gather_feature_hist_raw,
                          per_feature_best, reduce_best_record)
 from .. import perfmodel, telemetry
 from ..utils import sanitize
-from ..utils.backend import pallas_interpret
+from ..utils.backend import on_tpu, pallas_interpret
 from ..utils.log import Log
 from ..utils.timer import (SCOPE_ALLREDUCE, SCOPE_COMMIT, SCOPE_COMPACT,
                            SCOPE_FINISH, SCOPE_HIST, SCOPE_REPLAY,
@@ -81,11 +79,14 @@ REC = len(SPLIT_FIELDS)
 # rec_store row: [leaf, parent_output, depth, valid] + SPLIT_FIELDS
 STORE = REC + 4
 
-# gain-adaptive wave-width thresholds: commit rate (committed splits /
-# speculated splits) below which K steps one rung down, above which it
-# steps back up toward the LGBM_TPU_WAVE ceiling
-_WAVE_SHRINK_RATE = 0.5
-_WAVE_GROW_RATE = 0.9
+# Speculative-wave width: a wave partitions and histograms K candidate
+# splits, 2*K*3 histogram channels a pass; 2*21*3 = 126 fills one 128-lane
+# M tile of the MXU. One width a run: `batch` is a static argument of the
+# whole-tree program, so every other width is another program (36-40 s to
+# compile on a v5e). A controller that stepped K down with the commit rate
+# lost on the chip (PERF.md, PR 29: trees at K = 16 cost 1.18-1.47 s and at
+# K = 8 1.34-1.42 s against 1.12-1.37 s at 21, 4.19 M rows) and is gone.
+WAVE_K = 21
 
 
 class FeatureTables(NamedTuple):
@@ -236,7 +237,6 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
     from ..ops.hist_pallas import (DEFAULT_TILE_ROWS, active_tile_table,
                                    hist_force_f32,
                                    pallas_histogram_slots_ragged)
-    from ..ops.histogram import _use_pallas
 
     # pad rows ONCE to a common multiple of the histogram and compaction
     # tiles; padded rows carry leaf_id -1 and zero gh and (like bagged-out
@@ -266,51 +266,22 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
         # LGBM_TPU_PALLAS_INTERPRET=1 runs the TPU kernel path in interpret
         # mode — CPU-runnable end-to-end coverage of the ragged machinery.
         interp = pallas_interpret()
-        use_kernels = (_use_pallas() or interp) and os.environ.get(
-            "LGBM_TPU_HIST_SLOTS", "1").lower() not in ("0", "false", "off")
+        use_kernels = on_tpu() or interp
         pool_dtype = jnp.int32 if quantized else jnp.float32
         pos = jnp.arange(Np, dtype=jnp.int32)
 
         # leaf-contiguous payload, one ROW a channel: gh channels, original
         # position, leaf id, all exact in f32 (positions < 2**24, ids < 2**8;
         # quantized int8 gh values are exact too) and moved bit-exactly by the
-        # compaction kernel.
-        # LGBM_TPU_GH_BF16=1 (opt-in, float path only): gh rides as bf16 PAIRS
-        # packed into the bits of f32 payload rows. The packed bits survive
-        # compaction unchanged (the kernel moves f32 limbs exactly) and are
-        # unpacked per histogram pass; bit-identity with the f32 path is NOT
-        # guaranteed (the learner warns once). With the payload carried as
-        # rows it saves no bytes: 4 rows pad to the 8-sublane tile as 5 do.
-        pack_bf16 = (not quantized) and os.environ.get(
-            "LGBM_TPU_GH_BF16", "").lower() in ("1", "true", "on")
+        # compaction kernel, which stacks the payload's limbs on whole
+        # 8-sublane tiles: zero rows fill the last one
         gh_rows = gh.astype(jnp.float32).T  # [CH, Np]: the tree's one relayout
-        if pack_bf16:
-            if CH % 2:
-                gh_rows = jnp.pad(gh_rows, ((0, 1), (0, 0)))
-            u = jax.lax.bitcast_convert_type(
-                gh_rows.astype(jnp.bfloat16), jnp.uint16).astype(jnp.uint32)
-            gh_rows = jax.lax.bitcast_convert_type(
-                u[0::2] | (u[1::2] << 16), jnp.float32)  # [ceil(CH/2), Np]
-        n_gh = gh_rows.shape[0]
-        POS_ROW = n_gh
-        LEAF_ROW = n_gh + 1
-        # the compaction kernel stacks the payload's limbs on whole 8-sublane
-        # tiles: zero rows fill the last one
+        POS_ROW = CH
+        LEAF_ROW = CH + 1
         row_p = jnp.concatenate([
             gh_rows, pos.astype(jnp.float32)[None],
             leaf_id0.astype(jnp.float32)[None],
-            jnp.zeros((-(n_gh + 2) % 8, Np), jnp.float32)])  # [8, Np]
-
-    def payload_gh(row_c):
-        """gh channels of the payload as f32 [CH, rows] (unpacks the bf16
-        pairs when the narrow carry is on)."""
-        if not pack_bf16:
-            return row_c[:CH]
-        u = jax.lax.bitcast_convert_type(row_c[:n_gh], jnp.uint32)
-        halves = jnp.stack([u & 0xFFFF, u >> 16], axis=1).astype(jnp.uint16)
-        return jax.lax.bitcast_convert_type(
-            halves.reshape(2 * n_gh, -1)[:CH], jnp.bfloat16).astype(
-                jnp.float32)
+            jnp.zeros((-(CH + 2) % 8, Np), jnp.float32)])  # [8, Np]
 
     def scan_hist(hist):
         if quantized:
@@ -335,7 +306,7 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
         bins_c/row_c passed explicitly: inside the wave loop they are the
         CARRY arrays, not the pre-loop closure values."""
         with jax.named_scope(SCOPE_HIST):
-            ghc = payload_gh(row_c)
+            ghc = row_c[:CH]  # the payload's gh rows, f32 [CH, rows]
             if use_kernels:
                 tiles, nact = active_tile_table(starts, ends, valid, T_hist,
                                                 DEFAULT_TILE_ROWS)
@@ -1123,7 +1094,7 @@ class _PendingTree(NamedTuple):
     hist_rows: jax.Array
     n_waves: jax.Array
     n_bag: int
-    wave_k: int = 0  # wave width this tree was dispatched with
+    wave_k: int  # wave width this tree was dispatched with
 
 
 class DeviceTreeLearner(SerialTreeLearner):
@@ -1146,38 +1117,11 @@ class DeviceTreeLearner(SerialTreeLearner):
         super().__init__(config, dataset)
         self.tables = _feature_tables(dataset, dataset.used_features)
         self._row_arange = np.arange(self.num_data, dtype=np.int32)
-        # speculative-wave width: 2*K*3 histogram channels per pass.
-        # 21 -> 126 channels (one 128-lane M-tile on the MXU); raise for
-        # deeper amortization, lower if speculation hit-rate drops.
-        self.wave = int(os.environ.get("LGBM_TPU_WAVE", "21"))
-        # gain-adaptive wave width (LGBM_TPU_ADAPTIVE_WAVE=1, opt-in):
-        # `wave` is the ceiling, `wave_k` the width actually dispatched;
-        # _record_wave_efficiency moves it one power-of-two rung per tree
-        # from the observed commit rate. Rungs reuse
-        # ops.partition.bucket_size so `batch` — a static jit arg of
-        # grow_tree_on_device — takes at most ~log2(wave) distinct values
-        # per run. Off by default, like the sharded learners, which always
-        # dispatch the ceiling: every rung is another whole-tree program,
-        # 36-40 s to compile on a v5e in the middle of training, against
-        # ~1.3 s a tree at 4.19 M rows (PERF.md, PR 29: the HIGGS cell's
-        # first rung move comes at tree 22).
-        self._wave_cap = max(1, min(self.wave, int(config.num_leaves)))
-        self._adaptive_wave = os.environ.get(
-            "LGBM_TPU_ADAPTIVE_WAVE", "0").lower() in ("1", "true", "on")
-        self.wave_k = self._wave_cap
-        self._gh_bf16 = (not self.quantized) and os.environ.get(
-            "LGBM_TPU_GH_BF16", "").lower() in ("1", "true", "on")
-        if os.environ.get("LGBM_TPU_GH_BF16", "").lower() in (
-                "1", "true", "on"):
-            if self.quantized:
-                Log.warning("LGBM_TPU_GH_BF16=1 is ignored with "
-                            "use_quantized_grad (the int8 payload is "
-                            "already narrow)")
-            else:
-                Log.warning(
-                    "LGBM_TPU_GH_BF16=1: gh wave-carry payload packed as "
-                    "bf16 — bit-identity with the f32 path is NOT "
-                    "guaranteed (bf16 keeps 8 mantissa bits)")
+        # `wave` is the width asked for, `wave_k` the width a wave can use
+        # (a tree of L leaves never holds more than L candidates) and the
+        # one-chip program's static `batch`
+        self.wave = WAVE_K
+        self.wave_k = max(1, min(self.wave, int(config.num_leaves)))
 
     def snapshot_state(self) -> dict:
         st = super().snapshot_state()
@@ -1192,13 +1136,6 @@ class DeviceTreeLearner(SerialTreeLearner):
                       "histogram accumulation order would differ, breaking "
                       "bit-identical resume", want, self.bins_dev.dtype)
         super().restore_snapshot_state(st)
-
-    def _payload_cols(self) -> int:
-        """Payload channels of the wave carry: gh channels (bf16-packed in
-        pairs when opted in) + position + leaf id. Each is one row of the
-        [8, Np] carry, which pads to the sublane tile either way."""
-        n_gh = 2 if self._gh_bf16 else 3
-        return n_gh + 2
 
     def _record_carry_bytes(self) -> None:
         """Gauges for the analytic bandwidth model (docs/PERF_NOTES.md,
@@ -1215,21 +1152,17 @@ class DeviceTreeLearner(SerialTreeLearner):
         plane_b = plane_b if plane_b == 1 else 4
         global_timer.set_count(
             "device_carry_bytes_per_wave",
-            perfmodel.carry_bytes_per_wave(
-                self.num_data, G, plane_b, unit,
-                payload_cols=self._payload_cols()))
+            perfmodel.carry_bytes_per_wave(self.num_data, G, plane_b, unit))
         global_timer.set_count(
             "device_hist_bytes_per_row",
             perfmodel.hist_bytes_per_row(G, plane_b))
         # the replay scan sweeps the [K, G, Bpad, CH] pool block and writes
         # the [2K, G, REC] best-record store; the pool is 4-byte in both the
         # float and quantized (int32) regimes
-        from ..ops import scan_pallas
         global_timer.set_count(
             "device_scan_bytes_per_wave",
             perfmodel.scan_bytes_per_wave(self.wave_k, G,
-                                          self.group_bin_padded,
-                                          fused=scan_pallas.use_scan_pallas()))
+                                          self.group_bin_padded))
 
     def train(self, gh_ext: jax.Array,
               bag_indices: Optional[np.ndarray] = None) -> Tree:
@@ -1342,19 +1275,17 @@ class DeviceTreeLearner(SerialTreeLearner):
 
     def _record_wave_efficiency(self, pending: _PendingTree,
                                 tree: Tree) -> None:
-        """Committed-vs-speculated wave accounting + the gain-adaptive
-        wave-width controller: each wave partitions + histograms K candidate
-        splits but the replay commits only as many as stay globally
-        best-first — the measured ratio drives the next tree's K
-        (ROADMAP item 1; split decisions are K-invariant given the same
-        histogram sums, so only the amount of speculative work changes,
-        never the model: exact with use_quantized_grad, and in float up to
-        the summation order of a backend whose histogram contraction
-        depends on the 3*K output width — XLA:CPU's does,
-        tests/test_device_learner.py)."""
+        """Committed-vs-speculated wave accounting: each wave partitions +
+        histograms K candidate splits but the replay commits only as many
+        as stay globally best-first. Split decisions are K-invariant given
+        the same histogram sums, so K changes only the amount of
+        speculative work, never the model: exact with use_quantized_grad
+        (tests/test_device_learner.py), and in float up to the summation
+        order of a backend whose histogram contraction depends on the 3*K
+        output width (XLA:CPU's does)."""
         from .. import telemetry, tracing
         n_waves = int(pending.n_waves)
-        wave_k = pending.wave_k or self.wave_k
+        wave_k = pending.wave_k
         committed = tree.num_leaves - 1
         speculated = n_waves * wave_k
         commit_rate = committed / speculated if speculated else 1.0
@@ -1379,29 +1310,7 @@ class DeviceTreeLearner(SerialTreeLearner):
                     "device_ici_bytes_per_wave", 0)),
                 carry_bytes_per_wave=int(global_timer.counters.get(
                     "device_carry_bytes_per_wave", 0)))
-        new_k = self._next_wave_k(commit_rate)
-        if telemetry.enabled() and new_k != self.wave_k:
-            telemetry.emit("wave_ctl", wave_k=new_k, prev_k=self.wave_k,
-                           wave_commit_rate=round(commit_rate, 4))
-        self.wave_k = new_k
         global_timer.set_count("wave_k", self.wave_k)
-
-    def _next_wave_k(self, commit_rate: float) -> int:
-        """One power-of-two rung per tree: commit rate under 50% means the
-        replay declined half the partition+histogram work a wave paid for —
-        halve K; above 90% speculation is nearly free — grow back toward the
-        ceiling. Rungs come from ops.partition.bucket_size, so the static
-        `batch` jit arg takes at most ~log2(wave) distinct values per run
-        (pinned by the recompile-watcher test in test_device_learner.py)."""
-        if not self._adaptive_wave:
-            return self.wave_k
-        k = self.wave_k
-        if commit_rate < _WAVE_SHRINK_RATE and k > 1:
-            return min(bucket_size(max(1, k // 2), minimum=1),
-                       self._wave_cap)
-        if commit_rate > _WAVE_GROW_RATE and k < self._wave_cap:
-            return min(bucket_size(k + 1, minimum=1), self._wave_cap)
-        return k
 
     def _renew_quantized_leaves_device(self, tree: Tree,
                                        leaf_id: jax.Array) -> None:

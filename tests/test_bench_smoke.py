@@ -47,10 +47,10 @@ def test_bench_smoke_cpu(tmp_path):
     # — assert we sit in the narrow-plane regime.
     n_pad = -(-20000 // 1024) * 1024
     assert record["est_carried_bytes_per_wave"] == n_pad * (32 + 32)
-    # round-8 kernel instrumentation: both microlatency fields are real
-    # timed dispatches (the fused-scan/XLA routing and the device GOSS
-    # select both run on any backend); the wave-controller fields are 0 on
-    # CPU benches (serial learner — no waves dispatched) but must exist
+    # kernel instrumentation: both microlatency fields are real timed
+    # dispatches (the XLA split scan and the device GOSS select both run
+    # on any backend); the wave fields are 0 on CPU benches (serial
+    # learner — no waves dispatched) but must exist
     assert "scan_kernel_error" not in record, record
     assert "goss_kernel_error" not in record, record
     assert record["scan_kernel_ms"] > 0

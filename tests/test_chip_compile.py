@@ -23,14 +23,12 @@ import pytest
 
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.dataset import Dataset as CoreDataset
-from lightgbm_tpu.ops import histogram, scan_pallas
 from lightgbm_tpu.ops.compact_pallas import (COMPACT_TILE, _pallas_compact_call,
                                              max_pairs_bound)
 from lightgbm_tpu.ops.hist_pallas import (DEFAULT_TILE_ROWS, pallas_histogram,
                                           pallas_histogram_slots_ragged)
 from lightgbm_tpu.ops.predict import (PackedEnsemble, _predict_raw_dense,
                                       _predict_raw_fused)
-from lightgbm_tpu.ops.predict_pallas import pallas_predict_raw
 from lightgbm_tpu.treelearner import device as device_mod
 
 N = 1 << 20
@@ -169,34 +167,12 @@ def test_dense_predict_program_fits_with_room(on_chip):
     assert "convolution(" in text
 
 
-# The two opt-in kernels Mosaic refuses today. Neither is reachable with
-# default settings; each raises, compiled, instead of falling back. When a
-# JAX release lowers one of them this test fails: turn it into a compile
-# test like the ones above and revisit the kernel's default.
-
-def test_opt_in_scan_kernel_is_refused_not_hidden(on_chip):
-    with pytest.raises(NotImplementedError, match="cumsum"):
-        scan_pallas.fused_split_scan.lower(
-            on_chip((3, GROUPS_PADDED, 256), jnp.float32),
-            on_chip((GROUPS_PADDED, scan_pallas.REC_PAD), jnp.float32),
-            on_chip((GROUPS_PADDED, 256), jnp.float32),
-            interpret=False).compile()
-
-
-def test_opt_in_predict_kernel_is_refused_not_hidden(on_chip):
-    with pytest.raises(NotImplementedError, match="gather"):
-        pallas_predict_raw.lower(
-            _packed_500x255(on_chip), on_chip((1 << 16, FEATURES),
-                                              jnp.float32),
-            num_tree_per_iteration=1, interpret=False).compile()
-
-
 @pytest.mark.slow
 @pytest.mark.parametrize("quantized", [False, True])
 def test_whole_tree_program_compiles_and_fits(on_chip, monkeypatch, quantized):
     """grow_tree_on_device whole, ~45 s a compile. The learner asks
     on_tpu() — the CPU, in this process — so the test answers for it."""
-    monkeypatch.setattr(histogram, "on_tpu", lambda: True)
+    monkeypatch.setattr(device_mod, "on_tpu", lambda: True)
     monkeypatch.delenv("LGBM_TPU_PALLAS_INTERPRET", raising=False)
     rng = np.random.default_rng(0)
     X = rng.standard_normal((4096, FEATURES), dtype=np.float32)
@@ -247,7 +223,7 @@ def test_sharded_whole_tree_program_fits_four_chips_at_higgs_full(
     from lightgbm_tpu.parallel.learners import DeviceDataParallelTreeLearner
     from lightgbm_tpu.parallel.mesh import padded_row_count
 
-    monkeypatch.setattr(histogram, "on_tpu", lambda: True)
+    monkeypatch.setattr(device_mod, "on_tpu", lambda: True)
     monkeypatch.delenv("LGBM_TPU_PALLAS_INTERPRET", raising=False)
     monkeypatch.setenv("LGBM_TPU_HIST_F32", "1")
     rng = np.random.default_rng(0)

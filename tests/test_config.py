@@ -71,12 +71,17 @@ def test_kv2map_and_config_file(tmp_path):
     assert c.num_iterations == 5
 
 
-def test_reference_train_conf_parses():
-    kvs = load_config_file("/root/reference/examples/binary_classification/train.conf")
+def test_reference_train_conf_parses(examples):
+    """A train.conf in the reference's own style: comment lines, blank
+    lines, `key = value` with spaces, commented-out keys, a list value."""
+    kvs = load_config_file(str(examples / "train.conf"))
+    assert "num_threads" not in kvs  # commented out in the file
     c = Config(kvs)
     assert c.objective == "binary"
-    assert c.num_trees == 100 if hasattr(c, "num_trees") else True
+    assert c.num_iterations == 100  # the file says num_trees: an alias
     assert c.metric == ["binary_logloss", "auc"]
+    assert (c.num_leaves, c.learning_rate, c.min_sum_hessian_in_leaf) == (
+        63, 0.1, 5.0)
 
 
 def test_to_string_roundtrip_keys():

@@ -106,7 +106,7 @@ def test_device_hist_rows_counter(rng):
     obj = create_objective("regression", cfg)
     bst = GBDT(cfg, ds, obj)
     learner = DeviceTreeLearner(cfg, ds)
-    learner.wave = 4  # many waves: the O(N * waves) failure mode is loud
+    learner.wave_k = 4  # many waves: the O(N * waves) failure mode is loud
     bst.tree_learner = learner
     global_timer.counters.pop("device_hist_rows", None)
     bst.train_one_iter()
@@ -298,106 +298,87 @@ def test_device_learner_quantized_matches_serial_quantized(rng):
     assert acc > 0.9, acc
 
 
-# -- gain-adaptive wave width (round 8) -----------------------------------
+# -- the wave width changes the work, never the trees ----------------------
 
-def _adaptive_run(X, y, params, n_iters, adaptive, monkeypatch):
+def test_quantized_trees_identical_at_any_wave_width(rng):
+    """A wave partitions and histograms K candidate splits; the replay then
+    commits them in exact best-first order from the same records. So K
+    decides how much speculative work a tree costs and nothing else: with
+    use_quantized_grad (integer histogram sums, exact in any order) the
+    trees grown at K = 21 and at K = 8 are the same byte for byte. (In
+    float the XLA body's one-hot contraction is 3*K columns wide and
+    XLA:CPU sums the rows in another order at another width, so there they
+    agree to rounding only.)"""
     from lightgbm_tpu.utils.timer import global_timer
 
-    monkeypatch.setenv("LGBM_TPU_ADAPTIVE_WAVE", "1" if adaptive else "0")
-    global_timer.counters.pop("device_hist_rows", None)
-    cfg = Config(params)
-    ds = CoreDataset.from_matrix(X, label=y, config=cfg)
-    bst = GBDT(cfg, ds, create_objective(cfg.objective, cfg))
-    learner = DeviceTreeLearner(cfg, ds)
-    bst.tree_learner = learner
-    ks = []
-    for _ in range(n_iters):
-        if bst.train_one_iter():
-            break
-        ks.append(learner.wave_k)
-    bst.to_model()
-    rows = int(global_timer.counters["device_hist_rows"])
-    return bst, learner, ks, rows
-
-
-@pytest.mark.slow  # tier-1 budget triage: heavy full-training driver, runs in the slow tier
-@pytest.mark.parametrize("plane", ["float", "quantized"])
-def test_adaptive_wave_width_byte_identical_and_cheaper(rng, monkeypatch,
-                                                        plane):
-    """The wave-width controller only changes how much speculative work a
-    wave dispatches, never which splits win: split decisions are replayed
-    exact best-first from the same records, so the adaptive run must
-    produce byte-identical trees while histogramming measurably fewer
-    rows on a low-commit-rate workload (ISSUE round-8 acceptance).
-
-    Byte-identical wherever the histogram sums do not depend on K: always
-    with use_quantized_grad (integer sums are exact). In float the XLA
-    body is one one-hot contraction whose output is 3*K columns wide, and
-    XLA:CPU (jax 0.9) sums the rows in another order at width 63 (K = 21)
-    than at 12/24/48 (K = 4/8/16) — 678 of 6120 sums differ, up to 4e-5
-    relative, for the same rows in slot 0. So there the trees agree to
-    that rounding only: ULPs on gains and outputs, a near-tie threshold
-    between empty bins may flip, predictions within the device-vs-serial
-    tolerance. (The Pallas kernel on a TPU accumulates per slot; whether
-    it is K-invariant in float there: PERF.md, open questions.)"""
     n = 1200
     X = rng.randn(n, 8)
     y = 2 * X[:, 0] - X[:, 1] + np.sin(3 * X[:, 2]) + 0.1 * rng.randn(n)
     params = {"objective": "regression", "num_leaves": 31,
               "min_data_in_leaf": 5, "verbosity": -1,
-              "use_quantized_grad": plane == "quantized"}
-    b_on, l_on, ks_on, rows_on = _adaptive_run(
-        X, y, params, 6, True, monkeypatch)
-    b_off, l_off, ks_off, rows_off = _adaptive_run(
-        X, y, params, 6, False, monkeypatch)
-    # the fixed run pins K at the cap; the adaptive run must have shrunk
-    assert all(k == l_off._wave_cap for k in ks_off), ks_off
-    assert ks_on[-1] < l_on._wave_cap, ks_on
-    # every adaptive width is a bucket_size rung (bounds the jit cache)
-    from lightgbm_tpu.ops.partition import bucket_size
-    assert all(k == l_on._wave_cap or k == bucket_size(k, minimum=1)
-               for k in ks_on), ks_on
-    # fewer speculative leaves per wave -> fewer rows histogrammed
-    assert rows_on < rows_off, (rows_on, rows_off)
-    p_on = np.asarray(b_on.predict(X, raw_score=True))
-    p_off = np.asarray(b_off.predict(X, raw_score=True))
-    if plane == "quantized":
-        _assert_same_models(b_on, b_off)
-        np.testing.assert_array_equal(p_on, p_off)
-    else:
-        assert ([t.num_leaves for t in b_on.models]
-                == [t.num_leaves for t in b_off.models])
-        np.testing.assert_allclose(p_on, p_off, rtol=1e-4, atol=1e-5)
-    # the controller publishes its state as a gauge
-    from lightgbm_tpu.utils.timer import global_timer
-    assert global_timer.counters.get("wave_k") == l_off.wave_k
+              "use_quantized_grad": True}
+
+    def grow(wave_k):
+        global_timer.counters.pop("device_hist_rows", None)
+        cfg = Config(params)
+        ds = CoreDataset.from_matrix(X, label=y, config=cfg)
+        bst = GBDT(cfg, ds, create_objective(cfg.objective, cfg))
+        bst.tree_learner = DeviceTreeLearner(cfg, ds)
+        assert bst.tree_learner.wave_k == 21
+        bst.tree_learner.wave_k = wave_k
+        for _ in range(3):
+            assert not bst.train_one_iter()
+        bst.to_model()  # flushes any in-flight async tree
+        assert global_timer.counters["wave_k"] == wave_k
+        return bst, int(global_timer.counters["device_hist_rows"])
+
+    wide, rows_wide = grow(21)
+    narrow, rows_narrow = grow(8)
+    _assert_same_models(wide, narrow)
+    np.testing.assert_array_equal(
+        np.asarray(wide.predict(X, raw_score=True)),
+        np.asarray(narrow.predict(X, raw_score=True)))
+    # and the width did change the work: fewer speculative leaves a wave
+    assert rows_narrow < rows_wide, (rows_narrow, rows_wide)
 
 
-@pytest.mark.slow  # tier-1 budget triage: heavy full-training driver, runs in the slow tier
-def test_adaptive_wave_width_bounded_recompiles(rng, monkeypatch):
-    """Satellite 2: K moves only along bucket_size power-of-two rungs, so
-    the static `batch` arg of grow_tree_on_device takes at most
-    log2(K_max)+2 distinct values — the controller must never trigger a
-    per-tree recompile cascade."""
-    from lightgbm_tpu import telemetry
-    from lightgbm_tpu.treelearner import device as device_mod
+@pytest.mark.parametrize("num_leaves, want", [(255, 21), (31, 21), (7, 7),
+                                              (2, 2)])
+def test_wave_width_is_the_constant_capped_by_the_leaves(rng, num_leaves,
+                                                         want):
+    """`wave` is WAVE_K for every learner; `wave_k`, the width a wave can
+    use and every gauge and note reports, is capped by num_leaves, on one
+    chip and sharded alike (the sharded learners had a method of their own
+    for it while a controller could move the one-chip learner's)."""
+    from lightgbm_tpu.parallel.learners import DeviceDataParallelTreeLearner
+    from lightgbm_tpu.treelearner.device import WAVE_K
 
-    # start cold: an earlier test may have compiled the same K rungs
-    device_mod.grow_tree_on_device.clear_cache()
-    monkeypatch.setenv("LGBM_TPU_ADAPTIVE_WAVE", "1")
-    n = 1200
-    X = rng.randn(n, 8)
-    y = 2 * X[:, 0] - X[:, 1] + np.sin(3 * X[:, 2]) + 0.1 * rng.randn(n)
-    params = {"objective": "regression", "num_leaves": 31,
-              "min_data_in_leaf": 5, "verbosity": -1}
-    with telemetry.capture(None, label="adaptive-k") as s:
-        _, learner, ks, _ = _adaptive_run(X, y, params, 8, True, monkeypatch)
-        grow_compiles = sum(
-            c for fn, c in s.recompiles.per_fn.items() if "grow_tree" in fn)
-    assert len(set(ks)) >= 3, ks  # the controller actually moved
-    cap = learner._wave_cap
-    bound = int(np.log2(max(cap, 2))) + 2
-    assert 0 < grow_compiles <= bound, (grow_compiles, bound, ks)
+    X = rng.randn(300, 4)
+    cfg = Config({"objective": "binary", "num_leaves": num_leaves,
+                  "verbosity": -1})
+    ds = CoreDataset.from_matrix(X, label=(X[:, 0] > 0).astype(float),
+                                 config=cfg)
+    for cls in (DeviceTreeLearner, DeviceDataParallelTreeLearner):
+        learner = cls(cfg, ds)
+        assert (learner.wave, learner.wave_k) == (WAVE_K, want) == (21, want)
+
+
+def test_learner_state_with_a_wave_width_still_restores(rng):
+    """A learner state written while the width was a controller's variable
+    may hold a `wave_k`: restoring ignores it and keeps the constant."""
+    X = rng.randn(300, 4)
+    cfg = Config({"objective": "binary", "num_leaves": 15, "verbosity": -1,
+                  "feature_fraction": 0.5})
+    ds = CoreDataset.from_matrix(X, label=(X[:, 0] > 0).astype(float),
+                                 config=cfg)
+    old = DeviceTreeLearner(cfg, ds)
+    old.col_sampler.reset_by_tree()  # move the sampler's stream
+    state = dict(old.snapshot_state(), wave_k=8)
+    new = DeviceTreeLearner(cfg, ds)
+    new.restore_snapshot_state(state)
+    assert new.wave_k == 15
+    np.testing.assert_array_equal(new.col_sampler.reset_by_tree(),
+                                  old.col_sampler.reset_by_tree())
 
 
 # -- device-resident GOSS (round 8) ---------------------------------------
@@ -525,13 +506,12 @@ def _pallas_calls(jaxpr):
     ("DeviceTreeLearner", {"bagging_fraction": 0.5, "bagging_freq": 1}, {},
      4),
     ("DeviceTreeLearner", {"use_quantized_grad": True}, {}, 3),
-    ("DeviceTreeLearner", {}, {"LGBM_TPU_GH_BF16": "1"}, 3),
     ("DeviceTreeLearner", {}, {"LGBM_TPU_BINS_I32": "1"}, 3),
     ("DeviceDataParallelTreeLearner", {}, {}, 3),
     ("VotingDataParallelTreeLearner", {}, {}, 3),
     ("DeviceFeatureParallelTreeLearner", {}, {}, 3),
-], ids=["plain", "bagged", "quantized", "gh_bf16", "int32_plane",
-        "data_parallel", "voting", "feature"])
+], ids=["plain", "bagged", "quantized", "int32_plane", "data_parallel",
+        "voting", "feature"])
 def test_no_kernel_operand_has_rows_on_the_sublanes(
         rng, monkeypatch, learner, params, env, kernels):
     """Every per-row operand and result of the whole-tree program's

@@ -6,6 +6,8 @@ CPU path", a Pallas kernel is interpreted only when asked by name, and the
 compile cache sits where the operator — or else the checkout — says.
 """
 import os
+import pathlib
+import re
 
 import jax
 import numpy as np
@@ -82,8 +84,8 @@ def _no_backend():
 @pytest.mark.parametrize("ask", [
     lambda mp: (mp.setattr(serial, "on_tpu", _no_backend), _learner({})),
     lambda mp: (mp.setattr(histogram, "on_tpu", _no_backend),
-                mp.delenv("LGBM_TPU_HIST", raising=False),
-                histogram._use_pallas()),
+                histogram.build_histogram(np.zeros((1, 8), np.uint8),
+                                          np.zeros((8, 3), np.float32), 4)),
     lambda mp: (mp.setattr(backend, "on_tpu", _no_backend),
                 mp.delenv("LGBM_TPU_GOSS_DEVICE", raising=False),
                 sample_strategy.use_device_goss()),
@@ -152,3 +154,53 @@ def test_compile_cache_dir_defaults_into_the_checkout(cache_dir_restored,
     assert backend.configure_compile_cache() == os.path.join(REPO,
                                                              ".jax_cache")
     assert backend.COMPILE_CACHE_DIR == os.path.join(REPO, ".jax_cache")
+
+
+# ------------------------------------------------- the LGBM_TPU_* switches
+
+SWITCH = re.compile(r"LGBM_TPU_[A-Z0-9_]+")
+# Chosen by the chip and deleted with their losing paths (CHANGES.md, PR
+# 30). A name comes back only with a cell that measures it.
+DELETED_SWITCHES = {"LGBM_TPU_" + tail for tail in (
+    "SCAN_PALLAS", "PREDICT_PALLAS", "HIST_SLOTS", "HIST", "GH_BF16",
+    "ADAPTIVE_WAVE", "WAVE", "COMPACT_ALIAS")}
+
+
+def _switches_under(*dirs) -> dict:
+    """name -> the files under `dirs` that spell it (text files whole:
+    a comment that names a switch keeps it alive in a reader's mind)."""
+    found = {}
+    for top in dirs:
+        for path in sorted(pathlib.Path(REPO, top).rglob("*")):
+            if (not path.is_file() or "__pycache__" in path.parts
+                    or path.suffix in (".pyc", ".so")):
+                continue
+            for name in SWITCH.findall(path.read_text(errors="ignore")):
+                found.setdefault(name, set()).add(
+                    str(path.relative_to(REPO)))
+    return found
+
+
+def test_every_switch_the_package_reads_is_in_the_one_table():
+    """docs/SWITCHES.md has one row per LGBM_TPU_* name the package reads,
+    and no row for a name it does not: a switch cannot arrive, or outlive
+    its code, without the table saying what it selects and why it stays."""
+    rows = re.findall(r"^\| `(LGBM_TPU_[A-Z0-9_]+)` \| ([a-z ]+) \|",
+                      pathlib.Path(REPO, "docs", "SWITCHES.md").read_text(),
+                      flags=re.M)
+    documented = [name for name, _ in rows]
+    assert len(documented) == len(set(documented))
+    assert {kind for _, kind in rows} == {"deployment", "instrument",
+                                          "path choice"}
+    read = _switches_under("lightgbm_tpu")
+    assert set(read) - set(documented) == set(), "read, not documented"
+    assert set(documented) - set(read) == set(), "documented, never read"
+
+
+def test_no_deleted_switch_is_spelled_anywhere():
+    spelled = _switches_under("lightgbm_tpu", "tools", "tests")
+    here = str(pathlib.Path(__file__).relative_to(REPO))
+    left = {name: sorted(files - {here})
+            for name, files in spelled.items()
+            if name in DELETED_SWITCHES and files - {here}}
+    assert left == {}

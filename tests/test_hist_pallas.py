@@ -45,61 +45,73 @@ def test_pallas_histogram_bf16_default(rng):
     np.testing.assert_allclose(ours, ref, rtol=2e-2, atol=2e-1)
 
 
+def _slots_every_tile(bins, gh, slot, num_bins, n_slots, tile=512, **kw):
+    """The wave kernel with EVERY row tile active: the slot-expanded
+    histogram of the whole row set (rows padded to the tile with the dump
+    slot, as the learner pads them)."""
+    from lightgbm_tpu.ops.hist_pallas import (active_tile_table,
+                                              pallas_histogram_slots_ragged)
+
+    n = bins.shape[1]
+    n_pad = -(-n // tile) * tile
+    bins = np.pad(bins, ((0, 0), (0, n_pad - n)))
+    gh = np.pad(gh.astype(np.float32), ((0, n_pad - n), (0, 0)))
+    slot = np.pad(slot, (0, n_pad - n), constant_values=n_slots)
+    tiles, n_act = active_tile_table(
+        jnp.zeros(1, jnp.int32), jnp.full(1, n_pad, jnp.int32),
+        jnp.ones(1, bool), n_pad // tile, tile)
+    assert int(n_act[0]) == n_pad // tile
+    return np.asarray(pallas_histogram_slots_ragged(
+        jnp.asarray(bins), jnp.asarray(gh.T), jnp.asarray(slot), tiles,
+        n_act, num_bins, n_slots, tile_rows=tile, interpret=True, **kw))
+
+
+def _ref_slots(bins, gh, slot, num_bins, s):
+    return _ref_hist(bins, np.where((slot == s)[:, None], gh, 0), num_bins)
+
+
 def test_pallas_histogram_slots(rng):
     """Slot-expanded wave histogram == per-slot masked histograms."""
-    from lightgbm_tpu.ops.hist_pallas import pallas_histogram_slots
-
     G, B, n, S = 3, 16, 3000, 4
     bins = rng.randint(0, B, size=(G, n)).astype(np.int32)
     gh = rng.randn(n, 3).astype(np.float32)
     slot = rng.randint(0, S + 2, size=n).astype(np.int32)  # S+ = dump
-    ours = np.asarray(pallas_histogram_slots(
-        jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(slot), B, S,
-        f32=True, interpret=True))
+    ours = _slots_every_tile(bins, gh, slot, B, S, f32=True)
     assert ours.shape == (G, B, S * 3)
     for s in range(S):
-        ref = _ref_hist(bins, np.where((slot == s)[:, None], gh, 0.0), B)
-        np.testing.assert_allclose(ours[..., s * 3:(s + 1) * 3], ref,
+        np.testing.assert_allclose(ours[..., s * 3:(s + 1) * 3],
+                                   _ref_slots(bins, gh, slot, B, s),
                                    rtol=1e-5, atol=1e-4)
 
 
 def test_pallas_histogram_slots_bf16_default(rng):
     """The default TPU wave path: bf16 operands, f32 accumulation."""
-    from lightgbm_tpu.ops.hist_pallas import pallas_histogram_slots
-
     G, B, n, S = 3, 16, 4000, 4
     bins = rng.randint(0, B, size=(G, n)).astype(np.int32)
     gh = rng.randn(n, 3).astype(np.float32)
     slot = rng.randint(0, S + 2, size=n).astype(np.int32)
-    ours = np.asarray(pallas_histogram_slots(
-        jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(slot), B, S,
-        interpret=True))
+    ours = _slots_every_tile(bins, gh, slot, B, S)
     assert ours.dtype == np.float32
     for s in range(S):
-        ref = _ref_hist(bins, np.where((slot == s)[:, None], gh, 0.0), B)
-        np.testing.assert_allclose(ours[..., s * 3:(s + 1) * 3], ref,
+        np.testing.assert_allclose(ours[..., s * 3:(s + 1) * 3],
+                                   _ref_slots(bins, gh, slot, B, s),
                                    rtol=2e-2, atol=2e-1)
 
 
 def test_pallas_histogram_slots_quantized_exact(rng):
-    """Quantized wave path: int32 in-kernel build, int8 matmul operands,
-    exact int32 accumulation."""
-    from lightgbm_tpu.ops.hist_pallas import pallas_histogram_slots
-
+    """Quantized wave path: f32 gh rows holding small ints, bf16 matmul
+    operands (exact up to 255), exact int32 accumulation."""
     G, B, n, S = 3, 16, 4000, 4
     bins = rng.randint(0, B, size=(G, n)).astype(np.int32)
     gh = np.stack([rng.randint(-4, 5, n), rng.randint(0, 6, n),
-                   np.ones(n)], axis=1).astype(np.int8)
+                   np.ones(n)], axis=1).astype(np.int64)
     slot = rng.randint(0, S + 2, size=n).astype(np.int32)
-    ours = np.asarray(pallas_histogram_slots(
-        jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(slot), B, S,
-        quantized=True, interpret=True))
+    ours = _slots_every_tile(bins, gh, slot, B, S, quantized=True)
     assert ours.dtype == np.int32
     for s in range(S):
-        ref = _ref_hist(bins, np.where((slot == s)[:, None],
-                                       gh.astype(np.int64), 0), B)
-        np.testing.assert_array_equal(ours[..., s * 3:(s + 1) * 3],
-                                      ref.astype(np.int64))
+        np.testing.assert_array_equal(
+            ours[..., s * 3:(s + 1) * 3],
+            _ref_slots(bins, gh, slot, B, s).astype(np.int64))
 
 
 def _ragged_setup(rng, n, tile, ranges, S, quantized=False):
@@ -150,9 +162,8 @@ def test_pallas_histogram_slots_ragged(rng, ranges):
 
 def test_pallas_histogram_slots_ragged_quantized_exact(rng):
     """Quantized ragged path: f32 gh holding small ints, bf16 operands,
-    int32 accumulation — must match the dense int8 path bit-for-bit."""
-    from lightgbm_tpu.ops.hist_pallas import (pallas_histogram_slots,
-                                              pallas_histogram_slots_ragged)
+    int32 accumulation — the exact integer histogram of each range."""
+    from lightgbm_tpu.ops.hist_pallas import pallas_histogram_slots_ragged
 
     n, tile, S = 4096, 512, 3
     ranges = [(0, 900), (1500, 2600), (3000, 4000)]
@@ -162,10 +173,10 @@ def test_pallas_histogram_slots_ragged_quantized_exact(rng):
         jnp.asarray(bins), jnp.asarray(gh.T), jnp.asarray(slot), tiles, n_act,
         B, S, tile_rows=tile, quantized=True, interpret=True))
     assert ours.dtype == np.int32
-    dense = np.asarray(pallas_histogram_slots(
-        jnp.asarray(bins), jnp.asarray(gh.astype(np.int8)),
-        jnp.asarray(slot), B, S, quantized=True, interpret=True))
-    np.testing.assert_array_equal(ours, dense)
+    for s in range(S):
+        np.testing.assert_array_equal(
+            ours[..., s * 3:(s + 1) * 3],
+            _ref_slots(bins, gh.astype(np.int64), slot, B, s).astype(np.int64))
 
 
 def test_active_tile_table():
@@ -224,18 +235,13 @@ def test_pallas_histogram_slots_ragged_uint8_bit_identical(rng):
 
 
 def test_pallas_histogram_slots_uint8_bit_identical(rng):
-    from lightgbm_tpu.ops.hist_pallas import pallas_histogram_slots
-
+    """Every tile active, the bf16 default: uint8 bins == int32 bins."""
     G, B, n, S = 3, 16, 3000, 4
     bins8 = rng.randint(0, B, size=(G, n)).astype(np.uint8)
     gh = rng.randn(n, 3).astype(np.float32)
     slot = rng.randint(0, S + 2, size=n).astype(np.int32)
-    a = np.asarray(pallas_histogram_slots(
-        jnp.asarray(bins8), jnp.asarray(gh), jnp.asarray(slot), B, S,
-        interpret=True))
-    b = np.asarray(pallas_histogram_slots(
-        jnp.asarray(bins8.astype(np.int32)), jnp.asarray(gh),
-        jnp.asarray(slot), B, S, interpret=True))
+    a = _slots_every_tile(bins8, gh, slot, B, S)
+    b = _slots_every_tile(bins8.astype(np.int32), gh, slot, B, S)
     np.testing.assert_array_equal(a.view(np.uint32), b.view(np.uint32))
 
 

@@ -152,16 +152,25 @@ def test_r5_scope_covers_serving_hot_path(fixture_result):
     assert len(bad) == 1 and "'big_untimed_pack'" in bad[0].message
 
 
-def test_r5_scope_covers_fused_scan(fixture_result):
-    # ops/scan_pallas.py joined the R5 scope (scope_exact, round 8): the
-    # untimed staging helper fires at its def line; the jitted dispatch
-    # stays exempt (the call site owns the scope, device.py's
+def test_r5_scope_covers_fused_scan(fixture_result, monkeypatch):
+    # a file outside the rule's prefixes is in R5's scope exactly when
+    # scope_exact names it. The fixture's ops/scan_pallas.py (the package's
+    # own went with its kernel, PR 30) is silent until it is named; then
+    # the untimed staging helper fires at its def line and the jitted
+    # dispatch stays exempt (the call site owns the scope, device.py's
     # "tree_device")
-    bad = _hits(fixture_result, "untimed-hot-func", "ops/scan_pallas.py")
+    from tools.graftlint.rules.timer_discipline import TimerDisciplineRule
+
+    assert _hits(fixture_result, "untimed-hot-func",
+                 "ops/scan_pallas.py") == []
+    monkeypatch.setattr(
+        TimerDisciplineRule, "scope_exact",
+        TimerDisciplineRule.scope_exact + ("ops/scan_pallas.py",))
+    result = run_lint(FIXTURES)
+    bad = _hits(result, "untimed-hot-func", "ops/scan_pallas.py")
     assert len(bad) == 1 and "'big_untimed_stage'" in bad[0].message
     assert bad[0].line == 7
-    msgs = [v.message for v in
-            fixture_result.violations + fixture_result.suppressed]
+    msgs = [v.message for v in result.violations + result.suppressed]
     assert not any("'big_jitted_scan'" in m for m in msgs)
 
 

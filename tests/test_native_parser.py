@@ -54,13 +54,20 @@ def test_csv_with_header_parity(rng, tmp_path):
     _parity(p, header=True, label_column="name:target")
 
 
-def test_libsvm_parity():
-    path = "/root/reference/examples/lambdarank/rank.train"
+def test_libsvm_parity(examples):
+    """The native parser equals the Python one on a LibSVM file in the
+    reference's rank.train style (1-based ids, zeros left out)."""
+    path = str(examples / "rank.train")
+    X, y, _ = parse_file(path)
+    assert X.shape[1] == 21 and (X[:, 0] == 0).all()  # ids start at 1
+    assert set(np.unique(y)) == {0, 1, 2, 3, 4}
     _parity(path)
 
 
-def test_reference_example_parses_identically():
-    path = "/root/reference/examples/binary_classification/binary.train"
+def test_reference_example_parses_identically(examples):
+    path = str(examples / "binary.train")
+    X, y, _ = parse_file(path)
+    assert X.shape == (7000, 28) and set(np.unique(y)) == {0, 1}
     _parity(path)
 
 

@@ -226,35 +226,6 @@ def test_fused_leaf_indices_bit_identical(rng):
         np.testing.assert_array_equal(got, ref, err_msg=name)
 
 
-def test_pallas_interpret_bit_identical(rng):
-    from lightgbm_tpu.ops.predict_pallas import pallas_predict_raw
-
-    for name, packed, X, C in _trained_ensembles(rng):
-        if packed.linear:
-            continue  # linear ensembles keep the XLA path
-        got = np.asarray(pallas_predict_raw(packed, jnp.asarray(X), C,
-                                            tile_rows=128, interpret=True))
-        ref = np.asarray(predict_raw(packed, jnp.asarray(X), C))
-        np.testing.assert_array_equal(got, ref, err_msg=name)
-
-
-def test_pallas_env_flag_interprets_only_when_asked(rng, monkeypatch):
-    # LGBM_TPU_PREDICT_PALLAS=1 routes predict_raw to the kernel; it runs
-    # interpreted only under LGBM_TPU_PALLAS_INTERPRET=1. Off a TPU without
-    # it the compiled kernel fails to lower, loudly — never a quiet
-    # interpreted run under the kernel's name.
-    monkeypatch.delenv("LGBM_TPU_PREDICT_PALLAS", raising=False)
-    monkeypatch.delenv("LGBM_TPU_PALLAS_INTERPRET", raising=False)
-    name, packed, X, C = _trained_ensembles(rng)[0]
-    ref = np.asarray(predict_raw(packed, jnp.asarray(X), C))
-    monkeypatch.setenv("LGBM_TPU_PREDICT_PALLAS", "1")
-    with pytest.raises(ValueError, match="interpret mode"):
-        predict_raw(packed, jnp.asarray(X), C)
-    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
-    got = np.asarray(predict_raw(packed, jnp.asarray(X), C))
-    np.testing.assert_array_equal(got, ref, err_msg=name)
-
-
 def test_ragged_tree_count_is_fatal():
     trees = [make_simple_tree() for _ in range(5)]
     packed = pack_ensemble(trees)

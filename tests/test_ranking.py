@@ -1,14 +1,24 @@
-"""Ranking tests on the reference's examples/lambdarank data."""
+"""Ranking tests on files in the format of the reference's
+examples/lambdarank: LibSVM rows graded 0-4 with a .query side file
+(tests/conftest.py `examples`: 200 train queries of 5-40 documents, 4,323
+rows, 50 test queries; grades from a noisy linear score).
+
+What the thresholds stand against, on the seeded files: scores drawn at
+random read NDCG@5 0.29-0.37 on rank.test (five draws), one tree 0.73-0.75.
+"""
 import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
 
-RANK_TRAIN = "/root/reference/examples/lambdarank/rank.train"
-RANK_TEST = "/root/reference/examples/lambdarank/rank.test"
+
+@pytest.fixture
+def rank_files(examples):
+    return str(examples / "rank.train"), str(examples / "rank.test")
 
 
-def test_lambdarank_reference_example():
+def test_lambdarank_reference_example(rank_files):
+    RANK_TRAIN, RANK_TEST = rank_files
     ds = lgb.Dataset(RANK_TRAIN)
     dv = lgb.Dataset(RANK_TEST, reference=ds)
     rec = {}
@@ -18,11 +28,14 @@ def test_lambdarank_reference_example():
                     ds, num_boost_round=30, valid_sets=[dv],
                     callbacks=[lgb.record_evaluation(rec)])
     ndcg5 = rec["valid_0"]["ndcg@5"]
-    assert ndcg5[-1] > 0.55, f"ndcg@5 too low: {ndcg5[-1]}"
-    assert ndcg5[-1] > ndcg5[0] - 0.02  # learning, not diverging
+    # read 0.734 after one tree and 0.947 after 30: 0.85 is far above one
+    # tree's worth and 0.097 under the reading
+    assert ndcg5[-1] > 0.85, f"ndcg@5 too low: {ndcg5[-1]}"
+    assert ndcg5[-1] > ndcg5[0] + 0.1  # rising: learning, not diverging
 
 
-def test_rank_xendcg():
+def test_rank_xendcg(rank_files):
+    RANK_TRAIN, RANK_TEST = rank_files
     ds = lgb.Dataset(RANK_TRAIN)
     rec = {}
     dv = lgb.Dataset(RANK_TEST, reference=ds)
@@ -31,7 +44,10 @@ def test_rank_xendcg():
                      "min_sum_hessian_in_leaf": 1e-3},
                     ds, num_boost_round=20, valid_sets=[dv],
                     callbacks=[lgb.record_evaluation(rec)])
-    assert rec["valid_0"]["ndcg@5"][-1] > 0.5
+    ndcg5 = rec["valid_0"]["ndcg@5"]
+    # read 0.749 after one tree and 0.930 after 20
+    assert ndcg5[-1] > 0.85, f"ndcg@5 too low: {ndcg5[-1]}"
+    assert ndcg5[-1] > ndcg5[0] + 0.1
 
 
 def test_ndcg_metric_values():
@@ -77,16 +93,12 @@ def test_map_metric():
     assert val[0] == pytest.approx((1.0 + 2.0 / 3.0) / 2.0, abs=1e-6)
 
 
-def test_lambdarank_position_debias():
+def test_lambdarank_position_debias(rank_files):
     """Position-debiased lambdarank (rank_objective.hpp:43-90,296-340):
     positions accepted via Dataset, bias factors iteratively estimated,
     NDCG no worse on unbiased data."""
+    RANK_TRAIN, RANK_TEST = rank_files
     rng = np.random.RandomState(5)
-
-    def load(path):
-        ds = lgb.Dataset(path)
-        ds.construct()
-        return ds
 
     def ndcg(params, position=None):
         ds = lgb.Dataset(RANK_TRAIN, position=position)
@@ -107,14 +119,17 @@ def test_lambdarank_position_debias():
     num_rows = n._handle.num_data
     positions = rng.randint(0, 10, size=num_rows)
     debiased = ndcg(params, position=positions)
+    # read 0.9284 without positions and 0.9279 with random ones
+    assert base > 0.85, base
     assert debiased > base - 0.02, (debiased, base)
 
 
-def test_position_bias_factors_move():
+def test_position_bias_factors_move(rank_files):
     """The per-position bias factors are actually updated during training."""
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.objectives import create_objective
 
+    RANK_TRAIN, _ = rank_files
     rng = np.random.RandomState(3)
     ds = lgb.Dataset(RANK_TRAIN)
     ds.construct()

@@ -260,11 +260,8 @@ def _is_docstring(tree, constant) -> bool:
 # ----------------------------------------------------------- Pallas call names
 
 PALLAS_CALLS = {"hist_pallas.py": ["pallas_histogram",
-                                   "pallas_histogram_slots",
                                    "pallas_histogram_slots_ragged"],
-                "compact_pallas.py": ["_pallas_compact_call"],
-                "scan_pallas.py": ["fused_split_scan"],
-                "predict_pallas.py": ["pallas_predict_raw"]}
+                "compact_pallas.py": ["_pallas_compact_call"]}
 
 
 def _pallas_call_names(path) -> list:

@@ -184,36 +184,6 @@ def test_ici_bytes_gauge_independent_of_rows(rng):
     assert gauges[0] > 0
 
 
-def test_gh_bf16_payload_opt_in(rng, monkeypatch):
-    """LGBM_TPU_GH_BF16=1 narrows the wave-carry payload (2 packed gh
-    columns instead of 3) and still grows a sane tree; default stays f32
-    with full payload width."""
-    from lightgbm_tpu.treelearner import device as device_mod
-
-    n = 700
-    X = rng.randn(n, 6)
-    y = (X[:, 0] - 0.5 * X[:, 1] > 0).astype(float)
-    params = {"objective": "binary", "num_leaves": 7, "verbosity": -1}
-
-    monkeypatch.delenv("LGBM_TPU_GH_BF16", raising=False)
-    base = _learner(DeviceTreeLearner, X, y, params)
-    assert base._payload_cols() == 5
-    tree_f32 = base.train(_snapped_gh(rng, n))
-
-    monkeypatch.setenv("LGBM_TPU_GH_BF16", "1")
-    device_mod.grow_tree_on_device.clear_cache()
-    try:
-        narrow = _learner(DeviceTreeLearner, X, y, params)
-        assert narrow._payload_cols() == 4
-        tree_bf16 = narrow.train(_snapped_gh(rng, n))
-        # bit-identity is NOT guaranteed (bf16 keeps 8 mantissa bits, the
-        # snapped grid needs 10) — it must simply grow a real tree
-        assert tree_bf16.num_leaves > 1
-        assert tree_f32.num_leaves > 1
-    finally:
-        device_mod.grow_tree_on_device.clear_cache()
-
-
 def test_factory_routes_data_to_host_learner_on_cpu(rng):
     """On the CPU backend device growth never applies, so tree_learner=data
     keeps selecting the host-driven data-parallel learner (the fallback

@@ -277,9 +277,14 @@ def test_sharded_stream_worker_lost_is_typed(monkeypatch):
     monkeypatch.setenv(MESH_ENV, "8")
     X, y = _data(n=600)
     params = {**BASE, "tree_learner": "data", "use_quantized_grad": True}
-    # warm the jit caches: the watchdog deadline must measure the planted
-    # hang, not the first iteration's compile stall
-    train(dict(params), lgb.Dataset(X, label=y), num_boost_round=1)
+    # warm the jit caches before the watchdog is armed: the deadline must
+    # measure the planted hang, not a compile. Every iteration the armed run
+    # reaches (0, 1 and 2, where the hang is planted) compiles programs of
+    # its own (a leaf's padded row bucket is a shape), so the warm-up runs
+    # the same three on the same data; one round left iterations 1 and 2 to
+    # compile against the 2 s deadline, and under six loaded workers lost
+    warm = 3
+    train(dict(params), lgb.Dataset(X, label=y), num_boost_round=warm)
     elastic.install(timeout_s=2.0)
     faults.install("worker_hang@0:2")
     with pytest.raises(WorkerLostError) as ei:

@@ -8,9 +8,6 @@ import pytest
 
 import lightgbm_tpu as lgb
 
-BINARY_TRAIN = "/root/reference/examples/binary_classification/binary.train"
-BINARY_TEST = "/root/reference/examples/binary_classification/binary.test"
-
 
 def make_synthetic(n=2000, f=10, seed=7):
     rng = np.random.RandomState(seed)
@@ -77,20 +74,25 @@ def test_model_save_load_predict_consistency(tmp_path):
         assert abs(p_host - p1[i]) < 1e-4
 
 
-def test_reference_example_binary_auc():
-    """Train on the reference's example data; AUC threshold mirrors the
-    distributed-test accuracy gates."""
-    ds = lgb.Dataset(BINARY_TRAIN, params={"header": False})
-    dv = lgb.Dataset(BINARY_TEST, reference=ds)
+def test_reference_example_binary_auc(examples):
+    """Train from files in the reference example's format (label-first
+    TSV, 7,000 x 28 train, 500 test; tests/conftest.py `examples`); the AUC
+    threshold mirrors the distributed-test accuracy gates."""
+    ds = lgb.Dataset(str(examples / "binary.train"),
+                     params={"header": False})
+    dv = lgb.Dataset(str(examples / "binary.test"), reference=ds)
     rec = {}
     bst = lgb.train({"objective": "binary", "metric": "auc", "num_leaves": 31,
                      "learning_rate": 0.1, "verbosity": -1},
                     ds, num_boost_round=50, valid_sets=[dv],
                     callbacks=[lgb.record_evaluation(rec)])
     auc = rec["valid_0"]["auc"][-1]
-    # binary.train is a 7k-row HIGGS subset; HIGGS AUC tops out ~0.845
-    # (docs/Experiments.rst:134). 0.80 at 50 rounds gates real learning.
+    # On the seeded files an untrained model reads 0.5, the first tree
+    # alone 0.784 and 50 rounds 0.8527: 0.80 asks for more than one tree's
+    # worth of learning with a margin of 0.05. (The reference's own
+    # binary.train, a 7k-row HIGGS subset, was held to the same 0.80.)
     assert auc > 0.80, f"reference-example AUC too low: {auc}"
+    assert auc > rec["valid_0"]["auc"][0] + 0.03
 
 
 def test_regression_l2():

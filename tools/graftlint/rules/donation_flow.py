@@ -4,8 +4,8 @@
 the input buffer to XLA for reuse: after the dispatch returns, the
 caller's reference points at memory the output may already occupy. On CPU
 donation is silently ignored, so the bug ships green and detonates on the
-TPU — the exact trap the `donate_argnums=(0,1,2)` device learner and the
-`LGBM_TPU_COMPACT_ALIAS=1` pallas path can grow.
+TPU — the exact trap the `donate_argnums=(0,1,2)` device learner can
+grow, and a pallas_call that aliases an input to an output with it.
 
 The pass finds donating call sites through the package call graph, so
 every dispatch shape the codebase actually uses is covered:

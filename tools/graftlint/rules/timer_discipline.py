@@ -5,10 +5,9 @@ Perf accounting is only trustworthy when it is complete: the
 O(selected rows) lives next to a `global_timer.scope("tree_device")`
 wall-clock scope, and a 100-line helper that bypasses both is invisible
 in every perf report. Any function of more than 50 source lines in
-treelearner/, parallel/, the serving hot path ops/predict.py, or the
-fused split-scan ops/scan_pallas.py must reference
-`utils.timer.global_timer` (a scope, an add_count, anything) or wear the
-`@timed(...)` decorator.
+treelearner/, parallel/ or the serving hot path ops/predict.py must
+reference `utils.timer.global_timer` (a scope, an add_count, anything) or
+wear the `@timed(...)` decorator.
 
 Exemptions, because they are structurally untimeable from the inside:
   * jit-decorated functions — host timers inside a traced body measure
@@ -50,7 +49,7 @@ class TimerDisciplineRule(Rule):
                    "ops/predict.py without a global_timer scope/counter "
                    "(perf accounting gap)")
     scope_prefixes = ("treelearner/", "parallel/")
-    scope_exact = ("ops/predict.py", "ops/scan_pallas.py")
+    scope_exact = ("ops/predict.py",)
 
     def check(self, pkg: Package) -> Iterable[Violation]:
         out: List[Violation] = []

@@ -152,8 +152,6 @@ def _pod_phase() -> None:
     base_env = dict(os.environ)
     base_env["PYTHONPATH"] = _REPO
     base_env["JAX_PLATFORMS"] = "cpu"
-    # bit-identity across world sizes needs a fixed wave schedule
-    base_env["LGBM_TPU_ADAPTIVE_WAVE"] = "0"
     base_env.pop("XLA_FLAGS", None)
     for k in ("JAX_COORDINATOR_ADDRESS", "JAX_NUM_PROCESSES",
               "JAX_PROCESS_ID", "LGBM_TPU_HBM_BUDGET",
