@@ -1,16 +1,30 @@
-"""Pallas TPU histogram kernel: one-hot stays in VMEM.
+"""Pallas TPU histogram kernels: the one-hot stays in VMEM.
 
 The XLA formulation in ops/histogram.py materializes the [G, chunk, B]
 one-hot operand of the contraction unless XLA fuses it into the dot; at
 HIGGS scale (N=10.5M, B=256) a materialized one-hot costs G*N*B*4 bytes of
-HBM traffic per histogram — catastrophically bandwidth-bound. This kernel
-generates each [TN, B] one-hot tile INSIDE the kernel (VMEM-resident, never
-touches HBM) and feeds the MXU directly, so HBM traffic drops to the
-irreducible G*N*(bins + gh) bytes:
+HBM traffic per histogram — catastrophically bandwidth-bound. These kernels
+generate each one-hot tile INSIDE the kernel (VMEM-resident, never touches
+HBM) and feed the MXU directly, so HBM traffic drops to the irreducible
+G*N*(bins + gh) bytes. The wave kernel (pallas_histogram_slots_ragged: the
+device learner's and the streamed learner's), per grid step (group block,
+row tile) and per REAL group of the block:
 
-    grid (G/GB, N/TN); per step, for each of the GB groups in the block:
-        onehot[TN, B] = (bins_tile[g][:, None] == iota)   # VPU, VMEM only
-        out[g] += gh_tile^T @ onehot                      # MXU, [CH, B]
+    onehot[Bp, TN] = (iota_sublane == bins_tile[g][None, :])  # VPU, bf16
+    acc[L*SCp, Bp] = X[L*SCp, TN] . onehot^T   # MXU: bf16 x bf16 -> f32
+    out[g]        += acc[0:SC] + acc[SCp:SCp+SC] + ...        # [SC, Bp]
+
+The bin row is lane-major as it arrives, so the one-hot is a sublane
+broadcast and a compare (no lanes-to-sublanes relayout), and the
+contraction runs over the last dimension of both operands (the q @ k^T
+form, as ops/compact_pallas.py). X is the slot-expanded gradient tile as L
+bfloat16 limbs stacked on the sublanes (bf16_limbs): L = 3 holds a float32
+exactly, so f32=True is the float32 histogram of the unrounded gradients in
+ONE MXU pass where float32 operands at Precision.HIGHEST cost six, three of
+them against the one-hot's all-zero low parts; L = 1 is the bfloat16
+default and the quantized path. Bp is num_bins rounded up to whole 128-lane
+tiles. The dense kernel of the host learners (pallas_histogram) still
+builds onehot[TN, B] and contracts gh_tile^T @ onehot, [CH, B].
 
 GB is chosen per call by _prep_bins/_group_block: as large as the output
 block fits comfortably in VMEM (32 -> 16 -> 8; bigger blocks amortize
@@ -116,13 +130,25 @@ def _make_kernel(num_bins: int, tile_rows: int, compute_dtype, acc_dtype,
 
 
 def hist_force_f32() -> bool:
-    """LGBM_TPU_HIST_F32=1 forces f32 operands. Resolved by the unjitted
-    dispatch wrappers in ops.histogram so it enters the jit cache key as the
-    `f32` static arg — but outer jitted callers (grow_tree_on_device) bake
-    the value into their own trace, so set it BEFORE the first training
-    call, not mid-run."""
+    """LGBM_TPU_HIST_F32=1 asks for the float32 histogram: the wave kernel
+    then carries the gradients as three exact bfloat16 limbs (hist_operand
+    "bf16x3"), the dense kernel takes float32 operands. Resolved by the
+    unjitted dispatch wrappers in ops.histogram so it enters the jit cache
+    key as the `f32` static arg — but outer jitted callers
+    (grow_tree_on_device) bake the value into their own trace, so set it
+    BEFORE the first training call, not mid-run."""
     return os.environ.get("LGBM_TPU_HIST_F32", "").lower() not in (
         "", "0", "false", "off")
+
+
+def hist_operand(quantized: bool, f32: bool) -> str:
+    """What pallas_histogram_slots_ragged feeds the MXU as its gradient
+    operand under a dtype policy: "int" (quantized: small exact ints in one
+    bfloat16 limb, int32 accumulation), "bf16x3" (f32: three exact bfloat16
+    limbs) or "bf16" (the default: one limb, the bfloat16 rounding)."""
+    if quantized:
+        return "int"
+    return "bf16x3" if f32 else "bf16"
 
 
 @partial(jax.jit, static_argnames=("num_bins", "tile_rows", "quantized",
@@ -199,14 +225,40 @@ def active_tile_table(starts: jax.Array, ends: jax.Array, valid: jax.Array,
     return tiles, n_act[None]
 
 
-def _make_slots_ragged_kernel(num_bins: int, tile_rows: int, n_slots: int,
-                              ch: int, compute_dtype, acc_dtype,
-                              group_block: int):
+def bf16_limbs(x: jax.Array, n: int) -> jax.Array:
+    """f32 [r, T] -> bf16 [n * r, T]: x as n bfloat16 limbs stacked along
+    the rows, limb i the bfloat16 rounding of what limbs < i left over.
+    Three limbs hold a float32 exactly (24 significand bits = 3 x 8, the
+    same exponent range; a limb under bfloat16's smallest normal, that of
+    an |x| below ~2e-31, is the one exception a TPU flushes): hi + mid + lo
+    == x bit for bit (-0.0 comes back +0.0), every subtraction below exact.
+    One limb is the plain bfloat16 rounding."""
+    parts = []
+    for i in range(n):
+        limb = x.astype(jnp.bfloat16)
+        parts.append(limb)
+        if i + 1 < n:
+            x = x - limb.astype(jnp.float32)
+    return parts[0] if n == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _make_slots_ragged_kernel(bins_p: int, tile_rows: int, n_slots: int,
+                              ch: int, limbs: int, acc_dtype,
+                              group_block: int, n_groups: int):
+    """bins_p: the bin axis padded to whole 128-lane tiles. limbs: how many
+    bfloat16 limbs carry the gradient operand (3 = exact float32, 1 = the
+    bfloat16 rounding or the quantized path's small ints).
+    n_groups: the plane's real groups; the groups behind them in the last
+    group block are padding and get no one-hot and no contraction."""
     SC = n_slots * ch
+    g_blocks = -(-n_groups // group_block)
+    last_block_groups = n_groups - (g_blocks - 1) * group_block
+    SCp = -(-SC // 16) * 16  # a limb block starts on a packed bf16 tile
     quantized = jnp.issubdtype(jnp.dtype(acc_dtype), jnp.integer)
 
     def kernel(tiles_ref, nact_ref, bins_ref, gh_ref, slot_ref, out_ref):
         t = pl.program_id(1)
+        full_block = pl.program_id(0) < g_blocks - 1
 
         @pl.when(t == 0)
         def _init():
@@ -217,40 +269,56 @@ def _make_slots_ragged_kernel(num_bins: int, tile_rows: int, n_slots: int,
             s = slot_ref[...]  # [1, TN] int32: rows on the lanes
             ghc = gh_ref[...]  # [ch, TN] f32 (quantized: exact small ints)
             # slot-expanded gradient tile, row j = slot*ch + channel, built
-            # [SC, TN] in VMEM straight from the lane-major payload rows (a
-            # sublane broadcast each), so the contraction below is the plain
-            # [SC, TN] @ [TN, B] form. Strictly 2D broadcasts: per-channel
-            # masked adds, not a concat/tile (an n_slots-way concat lowers
-            # to a serial copy chain in Mosaic, ~2x slower end to end; the
-            # XLA-side materialization of this matrix cost ~18 ms a wave).
-            row = jax.lax.broadcasted_iota(jnp.int32, (SC, 1), 0)
-            rowslot, rowch = row // ch, row % ch  # [SC, 1]: 8 registers
-            gsum = jnp.zeros((SC, tile_rows), jnp.float32)
+            # [SCp, TN] f32 in VMEM straight from the lane-major payload
+            # rows (a sublane broadcast each; rows past SC stay zero).
+            # Strictly 2D broadcasts: per-channel masked adds, not a
+            # concat/tile (an n_slots-way concat lowers to a serial copy
+            # chain in Mosaic, ~2x slower end to end; the XLA-side
+            # materialization of this matrix cost ~18 ms a wave).
+            row = jax.lax.broadcasted_iota(jnp.int32, (SCp, 1), 0)
+            rowslot = row // ch  # [SCp, 1]: 8 registers
+            rowch = jnp.where(row < SC, row % ch, -1)
+            gsum = jnp.zeros((SCp, tile_rows), jnp.float32)
             for c in range(ch):
                 gsum += ghc[c:c + 1, :] * (rowch == c).astype(jnp.float32)
-            ghK = (gsum * (rowslot == s).astype(jnp.float32)
-                   ).astype(compute_dtype)
-            iota = jax.lax.broadcasted_iota(jnp.int32,
-                                            (tile_rows, num_bins), 1)
-            for gi in range(group_block):
-                b = bins_ref[gi, :].astype(jnp.int32)
-                onehot = (b[:, None] == iota).astype(compute_dtype)
+            # the MXU multiplies bfloat16: the gradients go in as exact
+            # bfloat16 limbs stacked on the sublanes, [limbs * SCp, TN]
+            X = bf16_limbs(gsum * (rowslot == s).astype(jnp.float32), limbs)
+            iota = jax.lax.broadcasted_iota(jnp.int32, (bins_p, tile_rows), 0)
+
+            def group(gi):
+                # onehot[b, n] from the lane-major bin row: a sublane
+                # broadcast, no relayout; 0 and 1 are exact in bfloat16
+                b = bins_ref[gi:gi + 1, :].astype(jnp.int32)  # [1, TN]
+                onehot = (iota == b).astype(jnp.bfloat16)
+                # one MXU pass contracting the last dimension of both (the
+                # q @ k^T form): a limb times 0 or 1 is exact and the
+                # accumulation is float32, so the limb blocks of acc sum to
+                # the float32 histogram at the gradients' full 24 bits
                 acc = jax.lax.dot_general(
-                    ghK, onehot,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                    precision=(jax.lax.Precision.HIGHEST
-                               if compute_dtype == jnp.float32 else
-                               jax.lax.Precision.DEFAULT))  # [SC, B]
+                    X, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [limbs*SCp, Bp]
+                h = acc[:SC]
+                for i in range(1, limbs):
+                    h = h + acc[i * SCp:i * SCp + SC]
                 # quantized: per-tile partial sums are exact ints in f32
                 # (<= tile_rows * 127 * 255 < 2**24); accumulate int32
-                out_ref[gi] += acc.astype(acc_dtype) if quantized else acc
+                out_ref[gi] += h.astype(acc_dtype) if quantized else h
+
+            for gi in range(last_block_groups):
+                group(gi)
+            if g_blocks > 1 and last_block_groups < group_block:
+                @pl.when(full_block)
+                def _rest():
+                    for gi in range(last_block_groups, group_block):
+                        group(gi)
 
     return kernel
 
 
 @partial(jax.jit, static_argnames=("num_bins", "n_slots", "tile_rows",
-                                   "quantized", "f32", "interpret"))
+                                   "quantized", "f32", "n_groups",
+                                   "interpret"))
 def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
                                   slot: jax.Array, tiles: jax.Array,
                                   n_active: jax.Array,
@@ -258,11 +326,12 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
                                   tile_rows: int = DEFAULT_TILE_ROWS,
                                   quantized: bool = False,
                                   f32: bool = False,
+                                  n_groups: int | None = None,
                                   interpret: bool = False) -> jax.Array:
     """Slot-expanded histogram over an indirected set of row tiles:
     [G, N] bins + [CH, N] gh + [N] slot ids -> [G, num_bins, n_slots*CH],
     where row n adds its gh to channel block slot[n] and a row whose slot
-    is outside [0, n_slots) adds nowhere. Dtype policy as pallas_histogram.
+    is outside [0, n_slots) adds nowhere.
 
     The rows-in-leaf wave histogram: `tiles` (from active_tile_table) names
     the row tiles overlapping the wave's selected leaf ranges; the grid
@@ -278,27 +347,37 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
     [N] rides as [1, N] (block (1, tile_rows)). An [N, CH] / [N, 1]
     operand would pad its minor dimension to 128 lanes in HBM and drag
     that layout into the caller's glue (ops/compact_pallas.py, step 3).
-    quantized=True means gh holds small exact ints; the build stays f32,
-    operands go bf16 (exact <= 255), per-tile partials are exact in f32
-    and accumulate int32 — bit-identical to the int8 dense path.
+
+    Both matmul operands are bfloat16 on every path, one MXU pass a group
+    with float32 accumulation; the dtype policy says how many bfloat16
+    limbs carry the gradients (bf16_limbs). f32=True: three, which hold a
+    float32 exactly, so the result is the float32 histogram of the
+    unrounded gradients. Default: one, the gradients rounded to bfloat16.
+    quantized=True: gh holds small exact ints (<= 255, exact in one limb),
+    per-tile partials are exact in f32 and accumulate int32 — bit-identical
+    to the int8 dense path.
+
+    n_groups: the plane's real group count where the caller padded the
+    plane (Mosaic tiles 8-bit as (32, 128)): the groups behind it cost
+    nothing and the result is [n_groups, num_bins, n_slots*CH].
     """
     G, N = bins.shape
+    n_groups = G if n_groups is None else n_groups
     CH = gh.shape[0]
     SC = n_slots * CH
     if N % tile_rows:
         raise ValueError("ragged histogram requires N padded to tile_rows")
-    if quantized:
-        compute_dtype, acc_dtype = jnp.bfloat16, jnp.int32
-    elif f32:
-        compute_dtype, acc_dtype = jnp.float32, jnp.float32
-    else:
-        compute_dtype, acc_dtype = jnp.bfloat16, jnp.float32
+    if not 0 < n_groups <= G:
+        raise ValueError(f"n_groups {n_groups} outside the plane's {G} rows")
+    limbs = 3 if hist_operand(quantized, f32) == "bf16x3" else 1
+    acc_dtype = jnp.int32 if quantized else jnp.float32
+    bins_p = -(-num_bins // 128) * 128  # whole lane tiles; no bin >= num_bins
     T = tiles.shape[0]
-    bins, GB = _prep_bins(bins, SC, num_bins)
+    bins, GB = _prep_bins(bins, SC, bins_p)
     slot = slot.reshape(1, N).astype(jnp.int32)
-    g_blocks = max(-(-G // GB), 1)
-    g_pad = g_blocks * GB - G
-    if g_pad:
+    g_blocks = -(-n_groups // GB)
+    g_pad = g_blocks * GB - G  # negative where the caller padded past it
+    if g_pad > 0:
         bins = jnp.pad(bins, ((0, g_pad), (0, 0)), constant_values=0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -308,17 +387,17 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
             pl.BlockSpec((CH, tile_rows), lambda g, t, tr, na: (0, tr[t])),
             pl.BlockSpec((1, tile_rows), lambda g, t, tr, na: (0, tr[t])),
         ],
-        out_specs=pl.BlockSpec((GB, SC, num_bins),
+        out_specs=pl.BlockSpec((GB, SC, bins_p),
                                lambda g, t, tr, na: (g, 0, 0)),
     )
     out = pl.pallas_call(
-        _make_slots_ragged_kernel(num_bins, tile_rows, n_slots, CH,
-                                  compute_dtype, acc_dtype, GB),
+        _make_slots_ragged_kernel(bins_p, tile_rows, n_slots, CH, limbs,
+                                  acc_dtype, GB, n_groups),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((g_blocks * GB, SC, num_bins),
+        out_shape=jax.ShapeDtypeStruct((g_blocks * GB, SC, bins_p),
                                        acc_dtype),
         interpret=interpret,
         name="pallas_histogram_slots_ragged",
     )(tiles.astype(jnp.int32), n_active.astype(jnp.int32),
       bins, gh.astype(jnp.float32), slot)
-    return out[:G].transpose(0, 2, 1)  # [G, B, SC]
+    return out[:n_groups, :, :num_bins].transpose(0, 2, 1)  # [G, B, SC]

@@ -310,11 +310,10 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             if use_kernels:
                 tiles, nact = active_tile_table(starts, ends, valid, T_hist,
                                                 DEFAULT_TILE_ROWS)
-                h = pallas_histogram_slots_ragged(
+                return pallas_histogram_slots_ragged(
                     bins_c, ghc, slot, tiles, nact, num_bins,
                     n_slots, quantized=quantized, f32=hist_force_f32(),
-                    interpret=interp)
-                return h[:G]
+                    n_groups=G, interpret=interp)
             # XLA fallback: flat slot-expanded build over the full row set
             col_slot = jnp.arange(n_slots * CH, dtype=jnp.int32) // CH
             ghK = jnp.where(slot[:, None] == col_slot[None, :],
@@ -1122,6 +1121,14 @@ class DeviceTreeLearner(SerialTreeLearner):
         # one-chip program's static `batch`
         self.wave = WAVE_K
         self.wave_k = max(1, min(self.wave, int(config.num_leaves)))
+        # which contraction this learner's histograms take (the `tree_wave`
+        # note says it): the ragged kernel's gradient operand, or the XLA
+        # body off the TPU. Read once, as the whole-tree program's trace
+        # bakes LGBM_TPU_HIST_F32 in at its first call
+        from ..ops.hist_pallas import hist_force_f32, hist_operand
+        self.hist_operand = (
+            hist_operand(self.quantized, hist_force_f32())
+            if on_tpu() or pallas_interpret() else "xla")
 
     def snapshot_state(self) -> dict:
         st = super().snapshot_state()
@@ -1298,6 +1305,7 @@ class DeviceTreeLearner(SerialTreeLearner):
         tracing.note("tree_wave", waves=n_waves, wave_k=wave_k,
                      committed=committed, speculated=speculated,
                      hist_rows=self.last_hist_rows,
+                     hist_operand=self.hist_operand,
                      ici_bytes=n_waves * self._ici_bytes_per_wave,
                      mesh_devices=self.D)
         if telemetry.enabled():

@@ -93,19 +93,54 @@ def test_dense_histogram_kernel_compiles(on_chip, quantized):
     assert _mosaic_calls(compiled) == 1
 
 
-@pytest.mark.parametrize("quantized", [False, True])
+def _mosaic_matmuls(compiled) -> list:
+    """(lhs, rhs, result) vector types of every matmul in the compiled
+    program's Mosaic kernel bodies, decoded from the custom calls' MLIR
+    bytecode."""
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    found = []
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    for body in re.findall(r'"custom_call_config":\{"body":"([^"]+)"',
+                           compiled.as_text()):
+        with ctx:
+            text = str(ir.Module.parse(base64.b64decode(body)))
+        found += re.findall(
+            r'tpu\.matmul"?\(.*:\s*\(vector<([^>]+)>, vector<([^>]+)>, '
+            r'vector<([^>]+)>\)', text)
+    return found
+
+
+@pytest.mark.parametrize("policy", ["f32", "bf16", "int"])
 @pytest.mark.parametrize("n_slots", [1, WAVE_K, 2 * WAVE_K])
-def test_ragged_histogram_kernel_compiles(on_chip, n_slots, quantized):
+def test_ragged_histogram_kernel_compiles(on_chip, n_slots, policy):
     """1 slot is the root pass, WAVE_K the wave's smaller children, and
     2 * WAVE_K the widest slot block the 4 MiB output budget still keeps on
-    the uint8 plane at 255 bins."""
+    the uint8 plane at 255 bins (256 in the kernel). f32 is the path every
+    benchmark cell runs: three bfloat16 limbs of the gradients against a
+    bfloat16 one-hot, so NO policy leaves a float32 x float32 matmul (six
+    MXU passes at Precision.HIGHEST) in the body, and the four groups that
+    pad HIGGS's 28 to 32 get no contraction."""
     tiles = N // DEFAULT_TILE_ROWS
     compiled = pallas_histogram_slots_ragged.lower(
         on_chip((GROUPS_PADDED, N), jnp.uint8), on_chip((3, N), jnp.float32),
         on_chip((N,), jnp.int32), on_chip((tiles,), jnp.int32),
         on_chip((1,), jnp.int32), num_bins=BINS, n_slots=n_slots,
-        quantized=quantized, interpret=False).compile()
+        quantized=policy == "int", f32=policy == "f32", n_groups=FEATURES,
+        interpret=False).compile()
     assert _mosaic_calls(compiled) == 1
+    matmuls = _mosaic_matmuls(compiled)
+    assert len(matmuls) == FEATURES
+    sc_padded = -(-3 * n_slots // 16) * 16
+    rows = (3 if policy == "f32" else 1) * sc_padded
+    for lhs, rhs, res in matmuls:
+        assert lhs == f"{rows}x{DEFAULT_TILE_ROWS}xbf16"
+        assert rhs == f"256x{DEFAULT_TILE_ROWS}xbf16"  # one-hot [Bp, TN]
+        assert res == f"{rows}x256xf32"
 
 
 @pytest.mark.parametrize("plane", [jnp.uint8, jnp.int32])

@@ -442,15 +442,25 @@ def test_a_streamed_predict_opens_one_chunk_span_per_chunk(spans):
 # --------------------------------------------------------------- flight notes
 
 
+@pytest.mark.parametrize("hist_f32, operand", [("0", "bf16"),
+                                               ("1", "bf16x3")])
 def test_one_tree_wave_note_per_tree_carries_waves_rows_and_width(
-        monkeypatch):
+        monkeypatch, hist_f32, operand):
     monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setenv("LGBM_TPU_HIST_F32", hist_f32)
     tracing.recorder().reset()
     rows_before = global_timer.counters["device_hist_rows"]
-    bst, _ = _booster()
-    for _ in range(3):
-        assert not bst.train_one_iter()
-    bst._flush_pending()
+    # the whole-tree program bakes the operand in as it is traced: no
+    # program of another test may stand in for this one, nor this one's
+    # for a later test's
+    device_mod.grow_tree_on_device.clear_cache()
+    try:
+        bst, _ = _booster()
+        for _ in range(3):
+            assert not bst.train_one_iter()
+        bst._flush_pending()
+    finally:
+        device_mod.grow_tree_on_device.clear_cache()
     notes = [n for n in tracing.recorder().snapshot()
              if n["kind"] == "tree_wave"]
     assert len(notes) == 3
@@ -461,6 +471,7 @@ def test_one_tree_wave_note_per_tree_carries_waves_rows_and_width(
         assert note["committed"] == tree.num_leaves - 1
         assert note["speculated"] == note["waves"] * note["wave_k"]
         assert note["mesh_devices"] == 1 and note["ici_bytes"] == 0
+        assert note["hist_operand"] == operand
     assert sum(n["hist_rows"] for n in notes) \
         == global_timer.counters["device_hist_rows"] - rows_before
 
