@@ -9,9 +9,19 @@ linearly quantized to small signed integers,
     g_int = trunc(g / grad_scale +- r)   (r ~ U[0,1) stochastic rounding,
                                           0.5 for nearest rounding)
 
-and histograms accumulate the integers exactly in int32 via the one-hot MXU
-contraction (ops/histogram.py with an int8 compute dtype — int8 x int8 ->
-int32 is MXU-native). The split scan rescales integer sums back to float.
+and histograms accumulate the integers exactly in int32. On the device
+learner the ragged wave kernel (ops/hist_pallas.py, `hist_operand` "int")
+carries them as ONE bfloat16 limb (integers up to 255 are exact in
+bfloat16) against the bfloat16 one-hot of the bin row, one MXU pass a group
+with float32 partial sums per tile of 1,024 rows (exact: below 2**24) and
+int32 accumulation across tiles; the host-driven learners' dense kernel and
+the XLA body contract int8 against an int8 one-hot into int32. The split
+scan rescales integer sums back to float (`hist.astype(float32) * scales`).
+
+`quantize_pack` is the per-tree step as ONE jitted program under the device
+scope `lgbm.quantize`: the key's split, the discretizer, the scales and the
+[N+1, 3] int8 pack (g_int, h_int, 1; a zero sentinel row) that every
+learner's histograms take.
 
 TPU-first notes vs the reference: the int8/int16/int32 per-leaf histogram
 bit-width machinery (gradient_discretizer.hpp:60-90, bin.h:63-81) exists to
@@ -20,7 +30,9 @@ overflow for any leaf below 2^23 rows per bin at 4-bit quantization) and
 instead narrows the DISTRIBUTED reduction to int16 when the per-device shard
 provably fits (parallel/learners.py), halving psum_scatter bytes — the
 analog of the reference's int16 histogram reduction
-(data_parallel_tree_learner.cpp:285-297).
+(data_parallel_tree_learner.cpp:285-297). Row counts come from the pack's
+third channel, exactly, where the reference estimates them from the hessian
+sum.
 """
 from __future__ import annotations
 
@@ -29,6 +41,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ..utils.timer import SCOPE_QUANTIZE
 
 
 def int16_reduction_safe(row_count: int, num_grad_quant_bins: int) -> bool:
@@ -73,3 +87,21 @@ def discretize_gradients(grad: jax.Array, hess: jax.Array, key: jax.Array,
     h_int = jnp.where(const_hess, jnp.int8(1),
                       jnp.trunc(hess * inv_h + rh).astype(jnp.int8))
     return g_int, h_int, g_scale, h_scale
+
+
+@partial(jax.jit, static_argnames=("num_bins", "stochastic"))
+def quantize_pack(gh_ext: jax.Array, key: jax.Array, num_bins: int = 4,
+                  stochastic: bool = True
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One tree's quantization in one dispatch: [N+1, 3] float gradient pack
+    (zero sentinel row last) + the learner's PRNG key -> (the next key, the
+    [N+1, 3] int8 pack (g_int, h_int, 1) with its zero sentinel, the
+    float32 scales [grad_scale, hess_scale, 1])."""
+    with jax.named_scope(SCOPE_QUANTIZE):
+        next_key, sub = jax.random.split(key)
+        g_int, h_int, gs, hs = discretize_gradients(
+            gh_ext[:-1, 0], gh_ext[:-1, 1], sub, num_bins, stochastic)
+        scale_vec = jnp.stack([gs, hs, jnp.float32(1.0)])
+        ghq = jnp.stack([g_int, h_int, jnp.ones_like(g_int)], axis=1)
+        ghq_ext = jnp.concatenate([ghq, jnp.zeros((1, 3), jnp.int8)], axis=0)
+    return next_key, ghq_ext, scale_vec

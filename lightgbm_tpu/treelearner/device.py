@@ -70,9 +70,9 @@ from ..utils import sanitize
 from ..utils.backend import on_tpu, pallas_interpret
 from ..utils.log import Log
 from ..utils.timer import (SCOPE_ALLREDUCE, SCOPE_COMMIT, SCOPE_COMPACT,
-                           SCOPE_FINISH, SCOPE_HIST, SCOPE_REPLAY,
-                           SCOPE_ROUTE, SCOPE_SCAN, SCOPE_SELECT,
-                           SCOPE_TREE_SETUP, global_timer)
+                           SCOPE_FINISH, SCOPE_HIST, SCOPE_RENEW_LEAVES,
+                           SCOPE_REPLAY, SCOPE_ROUTE, SCOPE_SCAN,
+                           SCOPE_SELECT, SCOPE_TREE_SETUP, global_timer)
 from .serial import SerialTreeLearner, _leaf_output_host
 
 REC = len(SPLIT_FIELDS)
@@ -1306,6 +1306,7 @@ class DeviceTreeLearner(SerialTreeLearner):
                      committed=committed, speculated=speculated,
                      hist_rows=self.last_hist_rows,
                      hist_operand=self.hist_operand,
+                     hist_int=int(self.hist_operand == "int"),
                      ici_bytes=n_waves * self._ici_bytes_per_wave,
                      mesh_devices=self.D)
         if telemetry.enabled():
@@ -1326,14 +1327,24 @@ class DeviceTreeLearner(SerialTreeLearner):
         on-device leaf-id vector (no per-leaf host scans; no frontier bounds
         here — the factory routes monotone configs to the host learner)."""
         cfg = self.config
-        L = tree.num_leaves
-        ghf = self._gh_float[:-1, :2]
-        ids = jnp.where(leaf_id >= 0, leaf_id, L)  # bagged-out -> dump row
-        sums = np.asarray(
-            jnp.zeros((L + 1, 2), jnp.float32).at[ids].add(ghf))
-        for leaf in range(L):
+        sums = np.asarray(_leaf_gradient_sums(self._gh_float, leaf_id,
+                                              cfg.num_leaves))
+        for leaf in range(tree.num_leaves):
             out = _leaf_output_host(float(sums[leaf, 0]),
                                     float(sums[leaf, 1]),
                                     cfg.lambda_l1, cfg.lambda_l2,
                                     cfg.max_delta_step)
             tree.set_leaf_output(leaf, out)
+
+
+# graftlint: disable=R6 -- both inputs must survive: the float pack is the learner's `_gh_float` and the leaf ids are the tree's partition, read again by the score update; no input matches the [L + 1, 2] output
+@partial(jax.jit, static_argnames=("num_leaves",))
+def _leaf_gradient_sums(gh_float: jax.Array, leaf_id: jax.Array,
+                        num_leaves: int) -> jax.Array:
+    """[num_leaves + 1, 2] float32 sums of the true (gradient, hessian)
+    over each leaf's rows; bagged-out rows (leaf id -1) go to the last,
+    dump row. One program whatever the tree's final leaf count."""
+    with jax.named_scope(SCOPE_RENEW_LEAVES):
+        ids = jnp.where(leaf_id >= 0, leaf_id, num_leaves)
+        return jnp.zeros((num_leaves + 1, 2), jnp.float32).at[ids].add(
+            gh_float[:-1, :2])

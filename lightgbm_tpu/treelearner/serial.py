@@ -33,7 +33,7 @@ from ..models.sample_strategy import host_bag_indices
 from ..models.tree import Tree
 from ..ops.histogram import build_histogram_rows, subtract_histogram
 from ..ops.partition import RowPartition
-from ..ops.quantize import discretize_gradients
+from ..ops.quantize import quantize_pack
 from ..ops.split import (FeatureMeta, SplitInfo, bins_to_bitset,
                          derive_cat_left_bins, find_best_split,
                          make_feature_meta)
@@ -42,7 +42,7 @@ from .col_sampler import ColSampler
 from .. import perfmodel, telemetry
 from ..utils.backend import on_tpu
 from ..utils.log import Log
-from ..utils.timer import global_timer
+from ..utils.timer import SPAN_QUANTIZE, global_timer
 
 
 @dataclass
@@ -98,6 +98,7 @@ class SerialTreeLearner:
         # quantized-gradient training (GradientDiscretizer analog)
         self.quantized = bool(config.use_quantized_grad)
         self._scale_vec: Optional[jax.Array] = None
+        self._gh_int: Optional[jax.Array] = None
         if self.quantized:
             self._quant_key = jax.random.PRNGKey(
                 int(getattr(config, "data_random_seed", 1)))
@@ -239,18 +240,30 @@ class SerialTreeLearner:
 
     def _prepare_gh(self, gh_ext: jax.Array) -> jax.Array:
         """Quantize the gradient pack when use_quantized_grad is on: int8
-        (g, h, 1) rows + a zero sentinel; scales kept for the scan."""
+        (g, h, 1) rows + a zero sentinel; scales kept for the scan. One
+        jitted step a tree (ops/quantize.py `quantize_pack`); the tree's
+        pack and scales stay on the learner (`quant_pack`)."""
         if not self.quantized:
             return gh_ext
         self._gh_float = gh_ext  # kept for leaf-output renewal
-        self._quant_key, sub = jax.random.split(self._quant_key)
-        g_int, h_int, gs, hs = discretize_gradients(
-            gh_ext[:-1, 0], gh_ext[:-1, 1], sub,
-            self.config.num_grad_quant_bins,
-            self.config.stochastic_rounding)
-        self._scale_vec = jnp.stack([gs, hs, jnp.float32(1.0)])
-        ghq = jnp.stack([g_int, h_int, jnp.ones_like(g_int)], axis=1)
-        return jnp.concatenate([ghq, jnp.zeros((1, 3), jnp.int8)], axis=0)
+        with global_timer.scope(SPAN_QUANTIZE):
+            self._quant_key, ghq_ext, self._scale_vec = quantize_pack(
+                gh_ext, self._quant_key, self.config.num_grad_quant_bins,
+                self.config.stochastic_rounding)
+        self._gh_int = ghq_ext
+        global_timer.add_count("quantized_trees", 1)
+        global_timer.add_count("quantized_rows", gh_ext.shape[0] - 1)
+        return ghq_ext
+
+    def quant_pack(self) -> Optional[Tuple[jax.Array, jax.Array]]:
+        """The current tree's integer pack [N+1, 3] int8 (g_int, h_int, 1;
+        zero sentinel row) and its float32 scales [grad_scale, hess_scale,
+        1], as the histograms took them: references to the device arrays,
+        no copy and no pull. None before a quantized learner's first tree,
+        and for a float learner."""
+        if not self.quantized or self._gh_int is None:
+            return None
+        return self._gh_int, self._scale_vec
 
     def _hist_for_scan(self, hist: jax.Array) -> jax.Array:
         """Integer histograms re-enter float space via the quantization

@@ -37,6 +37,10 @@ SPAN_PREDICT_FETCH = "predict_fetch"
 # tree's per-row leaf ids brought back to the score's chip.
 SPAN_SHARD_INPUTS = "shard_inputs"
 SPAN_GATHER_LEAF_IDS = "gather_leaf_ids"
+# A quantized-gradient learner's per-tree step (treelearner/serial.py
+# `_prepare_gh`): the host's dispatch of the one jitted program that turns
+# the float gradient pack into the tree's int8 pack and its two scales.
+SPAN_QUANTIZE = "quantize"
 
 # Device scopes (`jax.named_scope`): every device operation of the training
 # and predict hot paths carries one of these in its name stack, under ONE
@@ -57,6 +61,8 @@ SCOPE_COMMIT = SCOPE_PREFIX + "commit"
 SCOPE_FINISH = SCOPE_PREFIX + "finish"
 SCOPE_GRADIENTS = SCOPE_PREFIX + "gradients"
 SCOPE_UPDATE_SCORE = SCOPE_PREFIX + "update_score"
+SCOPE_QUANTIZE = SCOPE_PREFIX + "quantize"
+SCOPE_RENEW_LEAVES = SCOPE_PREFIX + "renew_leaves"
 SCOPE_NODE_GATHER = SCOPE_PREFIX + "node_gather"
 SCOPE_FEATURE_GATHER = SCOPE_PREFIX + "feature_gather"
 SCOPE_DECIDE = SCOPE_PREFIX + "decide"

@@ -303,3 +303,73 @@ def test_sharded_whole_tree_program_fits_four_chips_at_higgs_full(
     whole_table_on_chip_0 = 50 * HIGGS_ROWS
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + whole_table_on_chip_0) < HBM_BYTES
+
+
+@pytest.mark.slow
+def test_quantized_whole_tree_program_fits_one_chip_at_higgs_full(
+        on_chip, monkeypatch, capsys):
+    """`use_quantized_grad` at Higgs's published 10,500,000 rows on ONE
+    described v5e (the benchmark's `higgs_binary_quant.train`): the
+    quantized whole-tree program holds both Mosaic kernels, their histogram
+    output in int32, no per-row operand on the sublanes, and fits; so does
+    the per-tree quantization step beside it. Read by PR 32 (PERF.md): the
+    tree program 2,995,152,896 B temp + 420,052,992 B arguments (the float
+    one's temp to within a tile), the quantization step 42,339,328 B temp +
+    168,002,048 B arguments + 42,001,920 B output."""
+    from lightgbm_tpu.ops.quantize import quantize_pack
+
+    monkeypatch.setattr(device_mod, "on_tpu", lambda: True)
+    monkeypatch.delenv("LGBM_TPU_PALLAS_INTERPRET", raising=False)
+    rng = np.random.default_rng(0)
+    X = rng.standard_normal((4096, FEATURES), dtype=np.float32)
+    cfg = Config({"objective": "binary", "num_leaves": 255, "max_bin": BINS,
+                  "min_sum_hessian_in_leaf": 100, "use_quantized_grad": True,
+                  "verbosity": -1})
+    ds = CoreDataset.from_matrix(X, label=(X[:, 0] > 0).astype(np.float64),
+                                 config=cfg)
+    learner = device_mod.DeviceTreeLearner(cfg, ds)
+    assert learner.quantized and learner.hist_operand == "int"
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: on_chip(a.shape, a.dtype),
+                                      tree)
+
+    n = HIGGS_ROWS
+    compiled = device_mod.grow_tree_on_device.lower(
+        on_chip((FEATURES, n), jnp.uint8), on_chip((n, 3), jnp.int8),
+        on_chip((n,), jnp.int32), abstract(learner.meta),
+        abstract(learner.tables), abstract(learner.params_dev),
+        on_chip((FEATURES,), jnp.bool_), num_leaves=255,
+        num_bins=learner.group_bin_padded, max_depth=cfg.max_depth,
+        quantized=True, scale_vec=on_chip((3,), jnp.float32),
+        batch=WAVE_K, bagged=False).compile()
+    assert _mosaic_calls(compiled) == 3
+    text = compiled.as_text()
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    hist = [ln for ln in kernels if "pallas_histogram_slots_ragged" in ln]
+    assert len(hist) == 2 and len(kernels) - len(hist) == 1
+    for ln in hist:  # the kernel's result, left of the `=`
+        assert re.search(r"= s32\[32,\d+,256\]", ln), ln[:200]
+    n_pad = -(-n // 1024) * 1024
+    assert _rows_on_sublanes(compiled, n) == []
+    assert _rows_on_sublanes(compiled, n_pad) == []
+    mem = compiled.memory_analysis()
+    tree_bytes = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    assert tree_bytes < HBM_BYTES
+
+    step = quantize_pack.lower(
+        on_chip((n + 1, 3), jnp.float32), on_chip((2,), jnp.uint32),
+        num_bins=4, stochastic=True).compile()
+    smem = step.memory_analysis()
+    step_bytes = (smem.temp_size_in_bytes + smem.argument_size_in_bytes
+                  + smem.output_size_in_bytes)
+    assert step_bytes < HBM_BYTES
+    with capsys.disabled():
+        print(f"\nAOT quantized tree at {n} rows: temp "
+              f"{mem.temp_size_in_bytes} arguments "
+              f"{mem.argument_size_in_bytes} output "
+              f"{mem.output_size_in_bytes}; quantize step: temp "
+              f"{smem.temp_size_in_bytes} arguments "
+              f"{smem.argument_size_in_bytes} output "
+              f"{smem.output_size_in_bytes}")

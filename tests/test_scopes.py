@@ -200,6 +200,30 @@ def test_score_update_program_carries_its_scope():
     assert timer.SCOPE_UPDATE_SCORE in text
 
 
+@pytest.mark.parametrize("stochastic", [True, False])
+def test_quantize_step_is_one_program_under_its_scope(stochastic):
+    """The per-tree quantization of a `use_quantized_grad` learner: the
+    key's split, the discretizer, the scales and the int8 pack in ONE
+    jitted program under `lgbm.quantize`."""
+    from lightgbm_tpu.ops.quantize import quantize_pack
+
+    text = quantize_pack.lower(
+        jnp.zeros((65, 3), jnp.float32), jax.random.PRNGKey(1),
+        num_bins=4, stochastic=stochastic).as_text(debug_info=True)
+    scoped = [ln for ln in text.splitlines()
+              if ln.startswith("#loc") and timer.SCOPE_QUANTIZE in ln]
+    assert scoped
+    for op in ("threefry_split", "discretize_gradients", "concatenate"):
+        assert any(op in ln for ln in scoped), op
+
+
+def test_leaf_renewal_program_carries_its_scope():
+    text = device_mod._leaf_gradient_sums.lower(
+        jnp.zeros((65, 3), jnp.float32), jnp.zeros(64, jnp.int32),
+        num_leaves=15).as_text(debug_info=True)
+    assert timer.SCOPE_RENEW_LEAVES in text
+
+
 @pytest.mark.parametrize("scope", PREDICT_SCOPES)
 def test_predict_program_carries_the_scope(predict_program, scope):
     assert SCOPES["SCOPE_" + scope.upper()] in predict_program
@@ -219,7 +243,8 @@ def test_the_table_of_scopes_is_the_constants():
     """Every scope constant is one of the names checked above, under the
     one prefix, and no two are alike."""
     checked = set(TREE_SCOPES + PREDICT_SCOPES + DENSE_PREDICT_SCOPES
-                  + ["allreduce", "gradients", "update_score"])
+                  + ["allreduce", "gradients", "update_score", "quantize",
+                     "renew_leaves"])
     assert {v for v in SCOPES.values()} == {
         timer.SCOPE_PREFIX + name for name in checked}
     assert len(set(SCOPES.values())) == len(SCOPES)
@@ -397,6 +422,62 @@ def test_a_one_chip_tree_opens_neither_sharded_span(spans, monkeypatch):
     assert "tree_device" in labels
     assert timer.SPAN_SHARD_INPUTS not in labels
     assert timer.SPAN_GATHER_LEAF_IDS not in labels
+
+
+@pytest.mark.parametrize("renew", [False, True])
+def test_a_quantized_tree_opens_one_quantize_span_and_says_so_in_its_note(
+        spans, monkeypatch, renew):
+    """`quantize` once a tree, before `tree_device`, inside `tree_train`;
+    the tree's note says `hist_int` 1 beside `hist_operand` "int"; the two
+    counters count trees and rows; the learner hands over the tree's int8
+    pack and scales without a copy."""
+    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(serial_mod, "on_tpu", lambda: True)
+    X, y = _data(1500)
+    tracing.recorder().reset()
+    del spans[:]
+    before = {k: global_timer.counters[k]
+              for k in ("quantized_trees", "quantized_rows")}
+    bst = lgb.train(dict(PARAMS, use_quantized_grad=True,
+                         quant_train_renew_leaf=renew),
+                    lgb.Dataset(X, label=y), num_boost_round=3)
+    learner = bst._gbdt.tree_learner
+    assert type(learner) is DeviceTreeLearner and learner.quantized
+    quant = [s for s in spans if s[0] == timer.SPAN_QUANTIZE]
+    device = [s for s in spans if s[0] == "tree_device"]
+    trees = [s for s in spans if s[0] == "tree_train"]
+    assert len(quant) == len(device) == 3
+    for q, d in zip(quant, device):
+        assert q[2] <= d[1]
+        assert any(_inside(q, t) and _inside(d, t) for t in trees)
+    notes = [n for n in tracing.recorder().snapshot()
+             if n["kind"] == "tree_wave"]
+    assert len(notes) == 3
+    assert all(n["hist_int"] == 1 and n["hist_operand"] == "int"
+               for n in notes)
+    assert global_timer.counters["quantized_trees"] \
+        - before["quantized_trees"] == 3
+    assert global_timer.counters["quantized_rows"] \
+        - before["quantized_rows"] == 3 * 1500
+    pack, scales = learner.quant_pack()
+    assert pack is learner._gh_int and pack.dtype == jnp.int8
+    assert pack.shape == (1501, 3) and scales.shape == (3,)
+    assert int(pack[-1].sum()) == 0 and int(pack[:-1, 2].min()) == 1
+
+
+def test_a_float_learner_has_no_pack_and_its_note_says_hist_int_0(
+        monkeypatch):
+    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
+    tracing.recorder().reset()
+    before = global_timer.counters["quantized_trees"]
+    bst, _ = _booster()
+    assert not bst.train_one_iter()
+    bst._flush_pending()
+    assert bst.tree_learner.quant_pack() is None
+    note, = [n for n in tracing.recorder().snapshot()
+             if n["kind"] == "tree_wave"]
+    assert note["hist_int"] == 0
+    assert global_timer.counters["quantized_trees"] == before
 
 
 def test_a_predict_call_is_one_root_with_upload_traverse_and_fetch_once(
