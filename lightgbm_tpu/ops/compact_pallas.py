@@ -12,14 +12,21 @@ Counterpart of CUDADataPartition::SplitInner (cuda_data_partition.cu):
 there, a bitvector + block prefix-scan + global scatter. TPUs have no fast
 global scatter, so the same data movement is phrased as dense tile algebra:
 
-  1. XLA side (range_partition_dst): per-range stable left/right ranks via
-     two global exclusive scans + a [2, K] @ [K, N] range-membership matmul
-     for the per-row destination base -> forward map dst[j] (a permutation
-     of [0, N); rows outside every range keep their position).
+  1. XLA side (range_partition_dst): ONE global exclusive scan, of the left
+     mask. A wave's ranges are disjoint position ranges and every row of a
+     range is left or right, so with lext the length-(N+1) prefix count of
+     left rows a left row j lands at starts[k] - lext[starts[k]] + lext[j]
+     and a right row at j + lext[ends[k]] - lext[j]: the per-row bases come
+     from one [2, K] @ [K, N] range-membership matmul -> forward map dst[j]
+     (a permutation of [0, N); rows outside every range keep their
+     position). The scan is sampled at the tile boundaries and the range
+     ends (LeftCounts) for step 2.
   2. XLA side (build_pair_tables): each INPUT tile's rows land in at most a
      handful of OUTPUT tiles — per (range, side) the destinations are
-     contiguous, so a tile's class rows span <= 2 output tiles. The pair
-     list (in_tile -> out_tile), sorted by out_tile, is the kernel's grid.
+     contiguous, so a tile's class rows span <= 2 output tiles. Both ends of
+     that run follow from the samples of lext alone, so the tables are
+     [K, T] integer arithmetic and read no per-row array. The pair list
+     (in_tile -> out_tile), sorted by out_tile, is the kernel's grid.
   3. Pallas kernel (_pallas_compact_call): sequential grid over pairs.
      Every per-row operand has the ROWS ON THE LANES: the bin plane
      [Gp, N] (block (Gp, T)), the f32 payload [rc, N] (block (rc, T), rc a
@@ -54,7 +61,7 @@ composite-key sort ordering the pair list; no row-wise sort anywhere — at
 from __future__ import annotations
 
 from functools import partial
-from typing import List, Sequence, Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -81,51 +88,69 @@ def exclusive_cumsum(x: jax.Array) -> jax.Array:
     return jnp.cumsum(x) - x
 
 
+class LeftCounts(NamedTuple):
+    """The samples of lext, the length-(N+1) exclusive prefix count of left
+    rows, that build_pair_tables needs: every end of a (range, tile) overlap
+    is a tile boundary or a range end."""
+    tiles: jax.Array   # [T + 1] int32: lext[t * tile]
+    starts: jax.Array  # [K] int32: lext[starts[k]]
+    ends: jax.Array    # [K] int32: lext[starts[k] + counts[k]]
+
+
 def range_partition_dst(go_left: jax.Array, match: jax.Array,
-                        starts: jax.Array, counts: jax.Array,
-                        valid: jax.Array) -> Tuple[jax.Array, jax.Array]:
+                        in_any: jax.Array, starts: jax.Array,
+                        counts: jax.Array, valid: jax.Array, tile: int
+                        ) -> Tuple[jax.Array, jax.Array, LeftCounts]:
     """Forward destination map of a stable 2-way partition of K disjoint
     position ranges.
 
     go_left [N] bool, match [K, N] bool (row-in-range membership, already
     masked by `valid`; rows on the minor axis like every per-row array of
-    the wave), starts/counts [K] int32, valid [K] bool.
-    Returns (dst [N] int32, n_left [K] int32). Rows outside every valid
-    range keep their position; rows of range k land stably in
+    the wave), in_any [N] bool (= match.any(axis=0), which the caller has),
+    starts/counts [K] int32, valid [K] bool, tile the compaction tile
+    (N % tile == 0).
+    Returns (dst [N] int32, n_left [K] int32, LeftCounts). Rows outside
+    every valid range keep their position; rows of range k land stably in
     [starts[k], starts[k]+n_left[k]) or [starts[k]+n_left[k], ends[k]).
 
-    All vectorized: two global scans, K-sized gathers, one [2, K] @ [K, N]
-    matmul for the per-row base (gathers at N scale serialize on TPU; the matmul
-    does not). Positions must be < 2**24 (exact in f32).
+    All vectorized: ONE global scan (of the left mask; a right row's rank is
+    its position less the left rows before it), K-sized gathers, one
+    [2, K] @ [K, N] matmul for the per-row base (gathers at N scale
+    serialize on TPU; the matmul does not). Positions must be < 2**24
+    (exact in f32).
     """
     K, N = match.shape
     pos = jnp.arange(N, dtype=jnp.int32)
-    in_any = match.any(axis=0)
     lmask = in_any & go_left
-    rmask = in_any & ~go_left
     lcum = exclusive_cumsum(lmask)
-    rcum = exclusive_cumsum(rmask)
-    # length-(N+1) inclusive tails so ends[k] == N indexes safely
+    # length-(N+1) inclusive tail so ends[k] == N indexes safely
     lext = jnp.concatenate(
         [lcum, (lcum[-1] + lmask[-1].astype(jnp.int32))[None]])
-    rext = jnp.concatenate(
-        [rcum, (rcum[-1] + rmask[-1].astype(jnp.int32))[None]])
     ends = starts + counts
-    n_left = jnp.take(lext, ends) - jnp.take(lext, starts)
-    base_l = starts - jnp.take(lext, starts)
-    base_r = starts + n_left - jnp.take(rext, starts)
-    bases = jax.lax.dot(jnp.stack([base_l, base_r]).astype(jnp.float32),
+    lefts = LeftCounts(lext[::tile], jnp.take(lext, starts),
+                       jnp.take(lext, ends))
+    n_left = lefts.ends - lefts.starts
+    # left row j of range k -> base_l[k] + lext[j]; right row j ->
+    # j + lext[ends[k]] - lext[j]
+    base_l = starts - lefts.starts
+    bases = jax.lax.dot(jnp.stack([base_l, lefts.ends]).astype(jnp.float32),
                         match.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)  # [2, N]
     dst = jnp.where(
         lmask, bases[0].astype(jnp.int32) + lcum,
-        jnp.where(rmask, bases[1].astype(jnp.int32) + rcum, pos))
-    return dst, jnp.where(valid, n_left, 0)
+        jnp.where(in_any, pos + bases[1].astype(jnp.int32) - lcum, pos))
+    # dst before the pair tables: they no longer read it, and a scheduler
+    # left free makes it last, straight into the kernel from HBM. Made
+    # first, the compiler has the tables' sort to prefetch it into fast
+    # memory (its layout gains S(1)), where the kernel's per-pair (1, tile)
+    # block DMAs cost 11 % less of the whole kernel (PERF.md, PR 33).
+    dst, lefts = jax.lax.optimization_barrier((dst, lefts))
+    return dst, jnp.where(valid, n_left, 0), lefts
 
 
 def max_pairs_bound(n_tiles: int, n_classes: int) -> int:
     """Static upper bound on the pair-list length (skip pairs included) for
-    the left|right class masks of disjoint position ranges.
+    the left|right classes of disjoint position ranges (n_classes = 2 * K).
 
     identity pairs: n_tiles. A class whose destinations are one contiguous
     run lists, per input tile it touches, one pair plus one more where the
@@ -148,52 +173,67 @@ def _check_pairs_fit(mp: int, n_pairs) -> None:
         raise ValueError(
             f"compaction pair list needs {needed} pairs, "
             f"max_pairs_bound allows {mp}: the truncated list drops rows "
-            "(class masks must be disjoint, per-tile-contiguous ranges)")
+            "(the ranges must be disjoint position ranges)")
 
 
-def build_pair_tables(dst: jax.Array, class_masks: Sequence[jax.Array],
-                      moved: jax.Array, tile: int):
-    """Pair list (in_tile -> out_tile) covering every row movement.
+def build_pair_tables(lefts: LeftCounts, starts: jax.Array,
+                      counts: jax.Array, valid: jax.Array, tile: int):
+    """Pair list (in_tile -> out_tile) covering every row movement of
+    range_partition_dst's permutation, from its LeftCounts and the K ranges
+    alone: no per-row operand.
 
-    dst [N] int32 forward permutation; class_masks: disjoint row sets whose
-    destinations are contiguous PER TILE (e.g. left rows of one range);
-    moved [N] bool = union of class masks (rows whose dst may differ from
-    their position). Returns (pair_in, pair_out, pcopy, n_pairs[1]) with
-    static length max_pairs_bound(T, len(class_masks)); entries past
-    n_pairs repeat the last real pair (same blocks -> the kernel skips DMA
-    and compute for them). pcopy per pair: 0 = one-hot permute, 1 = raw
-    block copy (untouched identity tile), 2 = SKIP (duplicate of the
-    previous pair — processing it would double-count rows). Sorted by
-    out_tile so the kernel revisits each output block in one consecutive
-    run.
+    The rows of range k in tile t are the positions [a, b) =
+    [max(t*tile, starts[k]), min((t+1)*tile, ends[k])); lext[b] - lext[a] of
+    them go left, to the contiguous run that starts at
+    starts[k] - lext[starts[k]] + lext[a], and the rest right, to the run
+    from a + lext[ends[k]] - lext[a]. Each run lists its first output tile
+    and, where it crosses a boundary, its last. a and b are tile boundaries
+    or range ends, so every lext[.] is one of lefts' samples.
+
+    Returns (pair_in, pair_out, pcopy, n_pairs[1]) with static length
+    max_pairs_bound(T, 2 * K); entries past n_pairs repeat the last real
+    pair (same blocks -> the kernel skips DMA and compute for them). pcopy
+    per pair: 0 = one-hot permute, 1 = raw block copy (untouched identity
+    tile), 2 = SKIP (duplicate of the previous pair — processing it would
+    double-count rows). Sorted by out_tile so the kernel revisits each
+    output block in one consecutive run.
 
     One fused lax.sort: candidate pairs (with duplicates still in) are
     sorted by the composite key out_tile*T + in_tile, so duplicates —
     which always share an input tile AND an output tile — land adjacent
-    and are demoted to skip pairs by one post-sort compare. The previous
-    formulation pre-deduplicated with a second per-tile jnp.sort of the
-    candidate matrix; the fused key sort removes that whole pass.
+    and are demoted to skip pairs by one post-sort compare.
     """
-    N = dst.shape[0]
-    T = N // tile
+    T = lefts.tiles.shape[0] - 1
+    K = starts.shape[0]
     if T * T + T >= 2 ** 30:
         raise ValueError("pair sort key would overflow int32; use a larger "
                          "compaction tile for this row count")
-    dstT = dst.reshape(T, tile)
     big = jnp.int32(2 ** 30)
     ids = jnp.arange(T, dtype=jnp.int32)
-    cands = [ids[:, None]]  # identity pair for every tile: full coverage
-    for m in class_masks:
-        mT = m.reshape(T, tile)
-        any_m = mT.any(axis=1)
-        dmin = jnp.min(jnp.where(mT, dstT, big), axis=1) // tile
-        dmax = jnp.max(jnp.where(mT, dstT, -1), axis=1) // tile
-        c0 = jnp.where(any_m, dmin, T)
-        c1 = jnp.where(any_m & (dmax > dmin), dmax, T)
-        cands.append(jnp.stack([c0, c1], axis=1))
-    cand = jnp.concatenate(cands, axis=1)  # [T, 1 + 2*len(masks)]
+    lo = (ids * tile)[None, :]  # [1, T]; everything below is [K, T]
+    s_k = starts[:, None]
+    e_k = (starts + counts)[:, None]
+    a = jnp.maximum(lo, s_k)
+    b = jnp.minimum(lo + tile, e_k)
+    overlap = valid[:, None] & (b > a)
+    lext_a = jnp.where(lo >= s_k, lefts.tiles[None, :-1],
+                       lefts.starts[:, None])
+    lext_b = jnp.where(lo + tile <= e_k, lefts.tiles[None, 1:],
+                       lefts.ends[:, None])
+    n_l = lext_b - lext_a
+    n_r = (b - a) - n_l
+    first_l = s_k - lefts.starts[:, None] + lext_a
+    first_r = a + lefts.ends[:, None] - lext_a
+    cands = [ids[None, :]]  # identity pair for every tile: full coverage
+    for first, n in ((first_l, n_l), (first_r, n_r)):
+        any_m = overlap & (n > 0)
+        dmin = first // tile
+        dmax = (first + n - 1) // tile
+        cands.append(jnp.where(any_m, dmin, T))
+        cands.append(jnp.where(any_m & (dmax > dmin), dmax, T))
+    cand = jnp.concatenate(cands, axis=0)  # [1 + 4 * K, T]
     out_flat = cand.reshape(-1)
-    in_flat = jnp.repeat(ids, cand.shape[1])
+    in_flat = jnp.tile(ids, cand.shape[0])
     ok = out_flat < T
     key = jnp.where(ok, out_flat * T + in_flat, big)
     key = jax.lax.sort(key)
@@ -203,10 +243,11 @@ def build_pair_tables(dst: jax.Array, class_masks: Sequence[jax.Array],
     # but the kernel must not process them (double-counted rows). They
     # share both blocks with their predecessor, so they cost no extra DMA.
     dup = jnp.concatenate([jnp.zeros(1, bool), key[1:] == key[:-1]])
-    mp = max_pairs_bound(T, len(class_masks))
+    mp = max_pairs_bound(T, 2 * K)
     if sanitize.enabled():
-        # the bound is derived for range_partition_dst's masks; any other
-        # caller that outgrows it would lose rows below without a sign
+        # the bound is derived for disjoint ranges; ranges that overlap
+        # list a tile's rows more than once, outgrow it, and the truncated
+        # list would lose rows below without a sign
         jax.debug.callback(partial(_check_pairs_fit, mp), n_pairs)
     if key.shape[0] < mp:
         pad_n = mp - key.shape[0]
@@ -222,7 +263,7 @@ def build_pair_tables(dst: jax.Array, class_masks: Sequence[jax.Array],
     # untouched tiles: identity pair does a raw block copy, no matmul.
     # (A tile receiving rows from elsewhere necessarily lost rows too —
     # dst is a permutation — so untouched tiles exchange nothing.)
-    touched = moved.reshape(T, tile).any(axis=1)
+    touched = overlap.any(axis=0)
     is_copy = (pair_in == pair_out) & ~jnp.take(touched, pair_in)
     pcopy = jnp.where(dup & live, 2, is_copy.astype(jnp.int32))
     return pair_in, pair_out, pcopy, n_pairs[None]
@@ -345,18 +386,20 @@ def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
 
 
 def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,
-                 class_masks: Sequence[jax.Array], moved: jax.Array,
-                 *, tile: int = COMPACT_TILE, use_pallas: bool = True,
-                 interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
+                 lefts: LeftCounts, starts: jax.Array, counts: jax.Array,
+                 valid: jax.Array, *, tile: int = COMPACT_TILE,
+                 use_pallas: bool = True, interpret: bool = False
+                 ) -> Tuple[jax.Array, jax.Array]:
     """Apply the forward permutation dst [N] to bins_p [Gp, N] (uint8, or
     int32 with values < 2**16) and row_p [rc, N] (f32 payload, one row per
     channel, moved bit-exactly). The output bin plane keeps bins_p's dtype.
 
+    dst and lefts are range_partition_dst's, for the same K ranges
+    (starts, counts, valid) and the same tile.
     Pallas path requirements: N % tile == 0, rc % 8 == 0 (the payload's
     limbs stack on whole sublane tiles), Gp % 8 == 0 for int32 planes and
-    Gp % 32 == 0 for 8-bit planes (Mosaic (32, 128) tiling),
-    class_masks disjoint with per-tile-contiguous destinations
-    (range_partition_dst output qualifies), moved == union(class_masks).
+    Gp % 32 == 0 for 8-bit planes (Mosaic (32, 128) tiling), the ranges
+    disjoint.
     The XLA path is a plain permutation scatter — exact on CPU, used when
     no TPU backend is live.
     """
@@ -369,8 +412,12 @@ def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,
     if row_p.shape[0] % 8:
         raise ValueError("compaction kernel needs the payload's channel "
                          f"count padded to 8, got {row_p.shape[0]}")
+    if (lefts.tiles.shape[0] - 1) * tile != dst.shape[0]:
+        raise ValueError(
+            f"LeftCounts holds {lefts.tiles.shape[0] - 1} tiles, "
+            f"{dst.shape[0]} rows at tile {tile} need {dst.shape[0] // tile}")
     pair_in, pair_out, is_copy, n_pairs = build_pair_tables(
-        dst, class_masks, moved, tile)
+        lefts, starts, counts, valid, tile)
     row_f32 = row_p.astype(jnp.float32)
     dst_i32 = dst.astype(jnp.int32)
     if telemetry.enabled():

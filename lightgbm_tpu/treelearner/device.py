@@ -498,12 +498,15 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
         if bagged:
             in_bag = leaf_id0 == 0
             n_in = in_bag.sum().astype(jnp.int32)
-            dst0, _ = range_partition_dst(
-                in_bag, jnp.ones((1, Np), bool), jnp.zeros(1, jnp.int32),
-                jnp.full(1, Np, jnp.int32), jnp.ones(1, bool))
+            # the one-range case of the wave's partition: all rows, in-bag
+            # rows left
+            whole = (jnp.zeros(1, jnp.int32), jnp.full(1, Np, jnp.int32),
+                     jnp.ones(1, bool))
+            dst0, _, lefts0 = range_partition_dst(
+                in_bag, jnp.ones((1, Np), bool), jnp.ones(Np, bool), *whole,
+                COMPACT_TILE)
             bins_p, row_p = compact_rows(
-                bins_p, row_p, dst0, [in_bag, ~in_bag],
-                jnp.ones(Np, bool), tile=COMPACT_TILE,
+                bins_p, row_p, dst0, lefts0, *whole, tile=COMPACT_TILE,
                 use_pallas=use_kernels, interpret=interp)
         elif row_sharded:
             # the learner's global row padding trails the real rows, so every
@@ -647,12 +650,11 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
         with jax.named_scope(SCOPE_COMPACT):
             # --- stable partition of EVERY selected range (speculative: an
             # uncommitted leaf's range is merely reordered, still contiguous)
-            dst, nl_k = range_partition_dst(go_left, match, s_k, c_k, sel_ok)
-            cmasks = ([match[k] & go_left for k in range(K)]
-                      + [match[k] & ~go_left for k in range(K)])
+            dst, nl_k, lefts = range_partition_dst(
+                go_left, match, kvalid, s_k, c_k, sel_ok, COMPACT_TILE)
             bins_p, row_p = compact_rows(
-                bins_p, row_p, dst, cmasks, kvalid, tile=COMPACT_TILE,
-                use_pallas=use_kernels, interpret=interp)
+                bins_p, row_p, dst, lefts, s_k, c_k, sel_ok,
+                tile=COMPACT_TILE, use_pallas=use_kernels, interpret=interp)
 
         with jax.named_scope(SCOPE_HIST):
             # --- ragged histogram of ONLY the smaller children; tie -> left,

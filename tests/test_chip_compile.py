@@ -73,6 +73,17 @@ def _mosaic_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _compact_dst_operand(compiled) -> str:
+    """The HLO instruction that makes the compaction kernel's last operand,
+    dst [1, N]."""
+    text = compiled.as_text()
+    call = next(ln for ln in text.splitlines()
+                if "_pallas_compact_call" in ln and " custom-call(" in ln)
+    name = call.split(" custom-call(")[1].split(")")[0].split(", ")[-1]
+    return next(ln for ln in text.splitlines()
+                if ln.lstrip().startswith(name + " = "))
+
+
 def _rows_on_sublanes(compiled, n_rows: int) -> list:
     """Arrays of the compiled program laid out [n_rows, k<100] with the rows
     on the sublanes: k pads to 128 lanes, so each is n_rows * 512 bytes and
@@ -354,6 +365,11 @@ def test_quantized_whole_tree_program_fits_one_chip_at_higgs_full(
     n_pad = -(-n // 1024) * 1024
     assert _rows_on_sublanes(compiled, n) == []
     assert _rows_on_sublanes(compiled, n_pad) == []
+    # the destinations are made before the pair tables, so the compiler
+    # prefetches them into fast memory under the tables' sort; made last
+    # they stay in HBM and the kernel reads 11 % slower (PERF.md, PR 33)
+    assert re.search(r"s32\[1,\d+\]\{1,0:T\(1,128\)S\(1\)\}",
+                     _compact_dst_operand(compiled))
     mem = compiled.memory_analysis()
     tree_bytes = mem.temp_size_in_bytes + mem.argument_size_in_bytes
     assert tree_bytes < HBM_BYTES

@@ -486,18 +486,45 @@ def _trace_instead_of_running(fn, *_):
     return dispatch
 
 
-def _pallas_calls(jaxpr):
-    """Every pallas_call equation of a jaxpr, nested programs included."""
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, nested programs included."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn
-            continue
+        yield eqn
         for value in eqn.params.values():
             for sub in (value if isinstance(value, (tuple, list))
                         else (value,)):
                 inner = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
                 if hasattr(inner, "eqns"):
-                    yield from _pallas_calls(inner)
+                    yield from _eqns(inner)
+
+
+def _whole_tree_program(rng, monkeypatch, learner, params=None, env=None):
+    """The jaxpr of the whole-tree program `learner` would dispatch for one
+    iteration (kernels on the interpreted Pallas path), caught untraced."""
+    from lightgbm_tpu.parallel import learners
+    from lightgbm_tpu.treelearner import device as device_mod
+
+    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
+    for name, value in (env or {}).items():
+        monkeypatch.setenv(name, value)
+    monkeypatch.setattr(device_mod.sanitize, "guard",
+                        _trace_instead_of_running)
+    X = rng.randn(1500, 8)
+    y = (X[:, 0] - 0.7 * X[:, 1] + 0.3 * rng.randn(1500) > 0).astype(float)
+    cfg = Config({"objective": "binary", "num_leaves": 15, "verbosity": -1,
+                  **(params or {})})
+    ds = CoreDataset.from_matrix(X, label=y, config=cfg)
+    bst = GBDT(cfg, ds, create_objective(cfg.objective, cfg))
+    # the switches are read while the program is traced: no trace of another
+    # case may answer for this one
+    device_mod.grow_tree_on_device.clear_cache()
+    try:
+        bst.tree_learner = getattr(learners, learner)(cfg, ds)
+        with pytest.raises(_Traced) as caught:
+            bst.train_one_iter()
+    finally:
+        device_mod.grow_tree_on_device.clear_cache()
+    return caught.value.args[0].jaxpr
 
 
 @pytest.mark.parametrize("learner,params,env,kernels", [
@@ -516,30 +543,8 @@ def test_no_kernel_operand_has_rows_on_the_sublanes(
         rng, monkeypatch, learner, params, env, kernels):
     """Every per-row operand and result of the whole-tree program's
     pallas_calls is [k, Np], rows on the minor axis, never [Np, k<128]."""
-    from lightgbm_tpu.parallel import learners
-    from lightgbm_tpu.treelearner import device as device_mod
-
-    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
-    for name, value in env.items():
-        monkeypatch.setenv(name, value)
-    monkeypatch.setattr(device_mod.sanitize, "guard",
-                        _trace_instead_of_running)
-    X = rng.randn(1500, 8)
-    y = (X[:, 0] - 0.7 * X[:, 1] + 0.3 * rng.randn(1500) > 0).astype(float)
-    cfg = Config({"objective": "binary", "num_leaves": 15, "verbosity": -1,
-                  **params})
-    ds = CoreDataset.from_matrix(X, label=y, config=cfg)
-    bst = GBDT(cfg, ds, create_objective(cfg.objective, cfg))
-    # the switches are read while the program is traced: no trace of another
-    # case may answer for this one
-    device_mod.grow_tree_on_device.clear_cache()
-    try:
-        bst.tree_learner = getattr(learners, learner)(cfg, ds)
-        with pytest.raises(_Traced) as caught:
-            bst.train_one_iter()
-    finally:
-        device_mod.grow_tree_on_device.clear_cache()
-    calls = list(_pallas_calls(caught.value.args[0].jaxpr))
+    program = _whole_tree_program(rng, monkeypatch, learner, params, env)
+    calls = [e for e in _eqns(program) if e.primitive.name == "pallas_call"]
     assert len(calls) == kernels
     for eqn in calls:
         shapes = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
@@ -550,3 +555,37 @@ def test_no_kernel_operand_has_rows_on_the_sublanes(
         for shape in per_row:
             assert len(shape) == 2 and shape[-1] == n_rows, (
                 eqn.params.get("name"), shapes)
+
+
+# ---------------------------------------------------------------- one scan a wave
+# Compaction's glue reads the rows once (PR 33): a wave takes ONE cumulative
+# sum over the rows (the left mask's) and derives the pair tables from its
+# samples, [K, T] arithmetic. A second scan, or a per-tile min/max of the
+# destinations under a class mask ([T, COMPACT_TILE] operands, 2K of them a
+# wave), is the glue this replaced: 300 of 1,894 device-ms a tree at
+# 10.5 M rows (PERF.md, PR 33).
+
+@pytest.mark.parametrize("learner", ["DeviceTreeLearner",
+                                     "DeviceDataParallelTreeLearner"])
+def test_a_wave_scans_the_rows_once(rng, monkeypatch, learner):
+    from lightgbm_tpu.ops.compact_pallas import COMPACT_TILE
+
+    program = list(_eqns(_whole_tree_program(rng, monkeypatch, learner)))
+    # the wave is the body of the program's one while loop over the rows
+    waves = [e for e in program if e.primitive.name == "while"
+             and any(q.primitive.name == "pallas_call"
+                     for q in _eqns(e.params["body_jaxpr"].jaxpr))]
+    assert len(waves) == 1
+    wave = list(_eqns(waves[0].params["body_jaxpr"].jaxpr))
+    compact = [e for e in wave if e.primitive.name == "pallas_call"
+               and "compact" in str(e.params.get("name"))]
+    assert len(compact) == 1
+    n_rows = compact[0].invars[-1].aval.shape[-1]  # dst [1, Np]
+    assert n_rows % COMPACT_TILE == 0
+    scans = [e for e in wave if e.primitive.name.startswith("cum")
+             and n_rows in e.invars[0].aval.shape]
+    assert [e.primitive.name for e in scans] == ["cumsum"]
+    per_tile = (n_rows // COMPACT_TILE, COMPACT_TILE)
+    assert not [e for e in wave
+                if e.primitive.name in ("reduce_min", "reduce_max")
+                and e.invars[0].aval.shape == per_tile]

@@ -254,6 +254,10 @@ def test_watchdog_converts_hang_to_worker_lost(tmp_path, monkeypatch):
     postmortem."""
     monkeypatch.setenv("LGBM_TPU_FLIGHT_DIR", str(tmp_path))
     X, y = _data(n=300)
+    # compile every program the run dispatches before the 2 s watchdog is
+    # armed: on a loaded machine a first iteration's compiles outlast it,
+    # and it fires at iteration 0 instead of at the planted hang
+    train(dict(BASE), lgb.Dataset(X, label=y), num_boost_round=3)
     elastic.install(timeout_s=2.0)
     faults.install("worker_hang@0:2")
     t0 = time.perf_counter()
