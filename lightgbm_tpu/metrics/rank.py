@@ -6,8 +6,9 @@ support; queries with no relevant docs count as 1.0) and src/metric/
 map_metric.hpp (MapMetric).
 
 Device design: queries use the same padded [Q, L] bucket layout as the
-ranking objectives; a bucket's NDCG@k for all its queries is one jitted
-sort + gather + masked dot.
+ranking objectives; NDCG at every `eval_at` over all of a set's buckets is
+one jitted program (a slice, a stable sort and a masked dot a bucket) and
+one host fetch of its [Q, n_ks] values, summed in float64 on the host.
 """
 from __future__ import annotations
 
@@ -16,7 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from .registry import Metric, register_metric
-from ..objectives.rank import QueryLayout, default_label_gain, max_dcg_at_k
+from ..objectives.rank import (QueryLayout, default_label_gain, discounts,
+                               max_dcg_at_k, slice_queries)
+from ..utils.timer import SCOPE_EVAL_NDCG
 
 
 class _RankMetricBase(Metric):
@@ -44,7 +47,6 @@ class NDCGMetric(_RankMetricBase):
         gains = (np.array(self.config.label_gain, dtype=np.float64)
                  if self.config.label_gain else default_label_gain())
         self.gains = gains
-        self._gain_dev = jnp.asarray(gains, dtype=jnp.float32)
         qb = metadata.query_boundaries
         label = metadata.label
         # per (query, k): 1/maxDCG@k ; 0 marks "no relevant docs" -> ndcg 1
@@ -54,52 +56,57 @@ class NDCGMetric(_RankMetricBase):
             for j, k in enumerate(self.eval_at):
                 mx = max_dcg_at_k(srt, k, gains)
                 inv[q, j] = 1.0 / mx if mx > 0 else 0.0
-        for b in self.layout.buckets:
-            b["ndcg_inv"] = jnp.asarray(inv[b["qids"]], dtype=jnp.float32)
-        self._fns = {}
+        qw = (np.asarray(self.query_weights, dtype=np.float64)
+              if self.query_weights is not None
+              else np.ones(self.layout.num_queries))
+        self._sum_weights = float(qw.sum())
+        self._per_bucket = [
+            (b["starts"], b["lengths"], gain,
+             jnp.asarray(inv[b["qids"]], dtype=jnp.float32),
+             jnp.asarray(qw[b["qids"]], dtype=jnp.float32))
+            for b, gain in zip(self.layout.buckets, self.layout.spread(
+                gains[label.astype(np.int64)]))]
+        self._program = self._build_program()
 
-    def _bucket_fn(self, L: int, ks: tuple):
-        key = (L, ks)
-        if key in self._fns:
-            return self._fns[key]
-        gains = self._gain_dev
+    def _build_program(self):
+        """NDCG at every `eval_at` of one set as ONE jitted program an
+        evaluation, under `lgbm.eval_ndcg`: (score [N], per-bucket arrays)
+        -> every query's weighted NDCG values [Q, n_ks], bucket after
+        bucket. The caller fetches that one array (one host transfer a set)
+        and sums it in float64, as the per-bucket fetches were summed."""
+        ks = tuple(self.eval_at)
+        widths = [b["L"] for b in self.layout.buckets]
+        spare = self.layout.max_len
 
-        def bucket(score_ext, doc_idx, lab, valid, inv):
-            s = jnp.where(valid, score_ext[doc_idx], -jnp.inf)
-            order = jnp.argsort(-s, axis=1, stable=True)
-            ls = jnp.take_along_axis(lab, order, axis=1)
-            vs = jnp.take_along_axis(valid, order, axis=1)
-            g = jnp.where(vs, gains[ls.astype(jnp.int32)], 0.0)
-            disc = 1.0 / jnp.log2(jnp.arange(L) + 2.0)
-            out = []
-            for j, k in enumerate(ks):
-                mask = jnp.arange(L) < k
-                dcg = jnp.sum(g * disc * mask, axis=1)
-                ndcg = jnp.where(inv[:, j] > 0, dcg * inv[:, j], 1.0)
-                out.append(ndcg)
-            return jnp.stack(out, axis=1)  # [Qb, n_ks]
+        def program(score, per_bucket):
+            with jax.named_scope(SCOPE_EVAL_NDCG):
+                padded = jnp.concatenate([score, jnp.zeros(spare, score.dtype)])
+                values = []
+                for L, (starts, lengths, gain, inv, w) in zip(widths,
+                                                              per_bucket):
+                    within = jax.lax.broadcasted_iota(
+                        jnp.int32, (starts.shape[0], L), 1)
+                    valid = within < lengths[:, None]
+                    # descending by score, stably; no document sorts last
+                    key = jnp.where(valid, -slice_queries(padded, starts, L),
+                                    jnp.inf)
+                    _, g = jax.lax.sort((key, jnp.where(valid, gain, 0.0)),
+                                        dimension=1, num_keys=1,
+                                        is_stable=True)
+                    top = min(max(ks), L)
+                    gd = g[:, :top] * discounts(top)
+                    dcg = jnp.stack([jnp.sum(gd[:, :min(k, L)], axis=1)
+                                     for k in ks], axis=1)
+                    ndcg = jnp.where(inv > 0, dcg * inv, 1.0)
+                    values.append(ndcg * w[:, None])
+                return jnp.concatenate(values, axis=0)
 
-        fn = jax.jit(bucket)
-        self._fns[key] = fn
-        return fn
+        return jax.jit(program)
 
     def eval(self, score, objective):
-        ks = tuple(self.eval_at)
-        totals = np.zeros(len(ks))
-        sumw = 0.0
-        for b in self.layout.buckets:
-            fn = self._bucket_fn(b["L"], ks)
-            score_ext = jnp.concatenate([score, jnp.zeros(1, score.dtype)])
-            ndcgs = np.asarray(fn(score_ext, b["doc_idx"], b["labels"],
-                                  b["valid"], b["ndcg_inv"]))
-            if self.query_weights is not None:
-                w = self.query_weights[b["qids"]]
-                totals += (ndcgs * w[:, None]).sum(axis=0)
-                sumw += w.sum()
-            else:
-                totals += ndcgs.sum(axis=0)
-                sumw += len(b["qids"])
-        return [float(t / max(sumw, 1e-20)) for t in totals]
+        values = np.asarray(self._program(score, self._per_bucket))
+        totals = values.sum(axis=0, dtype=np.float64)
+        return [float(t / max(self._sum_weights, 1e-20)) for t in totals]
 
 
 @register_metric("map", "mean_average_precision")

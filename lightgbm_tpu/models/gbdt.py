@@ -33,6 +33,7 @@ from ..treelearner import create_tree_learner
 from ..utils import faults, sanitize
 from ..utils.log import Log
 from ..utils.timer import (SCOPE_GRADIENTS, SCOPE_UPDATE_SCORE,
+                           SCOPE_VALID_SCORE, SPAN_EVAL_VALID, SPAN_GRADIENTS,
                            SPAN_PREDICT_CALL, SPAN_PREDICT_FETCH,
                            SPAN_PREDICT_TRAVERSE, SPAN_PREDICT_UPLOAD,
                            global_timer)
@@ -83,6 +84,17 @@ def _apply_split_log_to_score(score: jax.Array, rec_store: jax.Array,
         lv = lv[:L] * rate
         return score + jnp.where(
             leaf_ids >= 0, lv[jnp.clip(leaf_ids, 0, L - 1)], 0.0)
+
+
+@partial(jax.jit, static_argnums=(2,), donate_argnums=(0,))
+def _add_valid_delta(score: jax.Array, out: jax.Array,
+                     class_id: int) -> jax.Array:
+    """A validation set's scores [C, Nv] plus one tree's outputs [Nv, 1]
+    (what `predict_raw` of the one-tree pack returned): one program, so the
+    column slice and the row update carry a scope of the program's. The
+    old scores are donated: the caller keeps only the sum."""
+    with jax.named_scope(SCOPE_VALID_SCORE):
+        return score.at[class_id].add(out[:, 0])
 
 
 def _colocate(arr: jax.Array, ref: jax.Array) -> jax.Array:
@@ -334,8 +346,9 @@ class GBDT:
                 if C == 1:
                     grads, hesses = grads[0], hesses[0]
             else:
-                grads, hesses = self._grad_fn(
-                    self.score if C > 1 else self.score[0])
+                with global_timer.scope(SPAN_GRADIENTS):
+                    grads, hesses = self._grad_fn(
+                        self.score if C > 1 else self.score[0])
         grads, hesses = faults.maybe_poison_gh(grads, hesses, self.iter_)
         if self._health is not None:
             grads, hesses = self._health.admit(self, grads, hesses)
@@ -568,8 +581,8 @@ class GBDT:
         packed = pack_ensemble([tree], fixed_leaves=self.config.num_leaves,
                                fixed_depth=depth_bound)
         for vd in self.valid_sets:
-            delta = predict_raw(packed, vd.raw)[:, 0]
-            vd.score = vd.score.at[class_id].add(delta)
+            vd.score = _add_valid_delta(vd.score, predict_raw(packed, vd.raw),
+                                        class_id)
 
     # ------------------------------------------------------------------- eval
 
@@ -585,11 +598,13 @@ class GBDT:
     def eval_valid(self) -> List[Tuple[str, str, float, bool]]:
         self._flush_pending()
         out = []
-        for vname, vd in zip(self.valid_names, self.valid_sets):
-            for m in vd.metrics:
-                score = vd.score[0] if self.num_tree_per_iteration == 1 else vd.score
-                for name, val in zip(m.name, m.eval(score, self.objective)):
-                    out.append((vname, name, val, m.greater_is_better))
+        with global_timer.scope(SPAN_EVAL_VALID):
+            for vname, vd in zip(self.valid_names, self.valid_sets):
+                for m in vd.metrics:
+                    score = (vd.score[0] if self.num_tree_per_iteration == 1
+                             else vd.score)
+                    for name, val in zip(m.name, m.eval(score, self.objective)):
+                        out.append((vname, name, val, m.greater_is_better))
         return out
 
     # ---------------------------------------------------------------- predict

@@ -41,6 +41,12 @@ SPAN_GATHER_LEAF_IDS = "gather_leaf_ids"
 # `_prepare_gh`): the host's dispatch of the one jitted program that turns
 # the float gradient pack into the tree's int8 pack and its two scales.
 SPAN_QUANTIZE = "quantize"
+# The objective's gradient pass (models/gbdt.py train_one_iter: the host's
+# dispatch of the gradient program, one an iteration for every objective but
+# rank_xendcg) and an iteration's evaluation of the validation sets
+# (GBDT.eval_valid: the metrics' programs and the fetch of their values).
+SPAN_GRADIENTS = "gradients"
+SPAN_EVAL_VALID = "eval_valid"
 
 # Device scopes (`jax.named_scope`): every device operation of the training
 # and predict hot paths carries one of these in its name stack, under ONE
@@ -62,6 +68,14 @@ SCOPE_FINISH = SCOPE_PREFIX + "finish"
 SCOPE_GRADIENTS = SCOPE_PREFIX + "gradients"
 SCOPE_UPDATE_SCORE = SCOPE_PREFIX + "update_score"
 SCOPE_QUANTIZE = SCOPE_PREFIX + "quantize"
+# lambdarank's gradient program, inside lgbm.gradients (objectives/rank.py)
+SCOPE_RANK_SORT = SCOPE_PREFIX + "rank_sort"
+SCOPE_RANK_PAIRS = SCOPE_PREFIX + "rank_pairs"
+SCOPE_RANK_SCATTER = SCOPE_PREFIX + "rank_scatter"
+# the per-tree update of the validation scores and the ranking metrics'
+# programs (models/gbdt.py _update_valid_scores, metrics/rank.py)
+SCOPE_VALID_SCORE = SCOPE_PREFIX + "valid_score"
+SCOPE_EVAL_NDCG = SCOPE_PREFIX + "eval_ndcg"
 SCOPE_RENEW_LEAVES = SCOPE_PREFIX + "renew_leaves"
 SCOPE_NODE_GATHER = SCOPE_PREFIX + "node_gather"
 SCOPE_FEATURE_GATHER = SCOPE_PREFIX + "feature_gather"

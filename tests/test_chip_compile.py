@@ -389,3 +389,97 @@ def test_quantized_whole_tree_program_fits_one_chip_at_higgs_full(
               f"{smem.temp_size_in_bytes} arguments "
               f"{smem.argument_size_in_bytes} output "
               f"{smem.output_size_in_bytes}")
+
+
+MSLR_ROWS, MSLR_QUERIES, MSLR_FEATURES = 2_270_296, 18_919, 136
+# The whole-tree program at uint8[136, 2,270,296] read 1,035,323,392 B of
+# temp + 354,362,880 B of arguments (AOT, ISSUE 34 and PR 34): 15 % above.
+MSLR_TREE_TEMP_CEILING = 1_190_000_000
+
+
+@pytest.mark.slow
+def test_whole_tree_and_gradient_programs_fit_one_chip_at_mslr(
+        on_chip, monkeypatch, capsys):
+    """The benchmark's `mslr_lambdarank.train` on ONE described v5e: the
+    whole-tree program at MSLR-WEB30K Fold 1's 2,270,296 rows x 136 features
+    (a plane of 136 groups: five 32-group blocks in the histogram kernel's
+    grid, the last with 8 real groups; float32 histogram operands as the
+    configuration's `env` states) holds three Mosaic calls, no per-row
+    operand on the sublanes, and its temp stays under the ceiling; the ONE
+    lambdarank gradient program at the cell's query layout (18,919 queries of
+    1..1,251 documents, nine length buckets) fits beside it."""
+    import importlib.util
+    import pathlib
+
+    from lightgbm_tpu.io.metadata import Metadata
+    from lightgbm_tpu.objectives import create_objective
+
+    monkeypatch.setattr(device_mod, "on_tpu", lambda: True)
+    monkeypatch.delenv("LGBM_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setenv("LGBM_TPU_HIST_F32", "1")
+    rng = np.random.default_rng(0)
+    X = rng.standard_normal((4096, MSLR_FEATURES), dtype=np.float32)
+    cfg = Config({"objective": "lambdarank", "num_leaves": 255,
+                  "max_bin": BINS, "min_data_in_leaf": 0,
+                  "min_sum_hessian_in_leaf": 100, "verbosity": -1})
+    ds = CoreDataset.from_matrix(X, label=(X[:, 0] > 0).astype(np.float64),
+                                 config=cfg)
+    learner = device_mod.DeviceTreeLearner(cfg, ds)
+    assert learner.bins_dev.shape[0] == MSLR_FEATURES
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: on_chip(a.shape, a.dtype),
+                                      tree)
+
+    n = MSLR_ROWS
+    compiled = device_mod.grow_tree_on_device.lower(
+        on_chip((MSLR_FEATURES, n), jnp.uint8), on_chip((n, 3), jnp.float32),
+        on_chip((n,), jnp.int32), abstract(learner.meta),
+        abstract(learner.tables), abstract(learner.params_dev),
+        on_chip((MSLR_FEATURES,), jnp.bool_), num_leaves=255,
+        num_bins=learner.group_bin_padded, max_depth=cfg.max_depth,
+        quantized=False, scale_vec=learner._scale_vec, batch=WAVE_K,
+        bagged=False).compile()
+    assert _mosaic_calls(compiled) == 3
+    kernels = [ln for ln in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    hist = [ln for ln in kernels if "pallas_histogram_slots_ragged" in ln]
+    assert len(hist) == 2 and len(kernels) - len(hist) == 1
+    for ln in hist:  # five blocks of 32 groups: the result, left of the `=`
+        assert re.search(r"= f32\[160,\d+,256\]", ln), ln[:200]
+    n_pad = -(-n // 1024) * 1024
+    assert _rows_on_sublanes(compiled, n) == []
+    assert _rows_on_sublanes(compiled, n_pad) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < MSLR_TREE_TEMP_CEILING
+    tree_bytes = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+
+    # the gradient program at the cell's layout (the benchmark's own sizes)
+    spec = importlib.util.spec_from_file_location(
+        "bench_data_rank", pathlib.Path(__file__).resolve().parent.parent
+        / "benchmark" / "data_rank.py")
+    data_rank = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(data_rank)
+    sizes = data_rank.query_sizes(n, MSLR_QUERIES, rng)
+    md = Metadata(n)
+    md.set_label(rng.integers(0, 5, n).astype(np.float64))
+    md.set_query(sizes)
+    obj = create_objective("lambdarank", cfg)
+    obj.init(md, n)
+    assert len(obj.layout.buckets) == 9
+    grad = obj._program.lower(
+        on_chip((n,), jnp.float32), None, abstract(obj._per_bucket),
+        abstract(obj.layout.flat_pos), None, None, None).compile()
+    assert _mosaic_calls(grad) == 0
+    gmem = grad.memory_analysis()
+    grad_bytes = (gmem.temp_size_in_bytes + gmem.argument_size_in_bytes
+                  + gmem.output_size_in_bytes)
+    assert tree_bytes + grad_bytes < HBM_BYTES
+    with capsys.disabled():
+        print(f"\nAOT MSLR tree at {n} x {MSLR_FEATURES}: temp "
+              f"{mem.temp_size_in_bytes} arguments "
+              f"{mem.argument_size_in_bytes} output "
+              f"{mem.output_size_in_bytes}; gradient program: temp "
+              f"{gmem.temp_size_in_bytes} arguments "
+              f"{gmem.argument_size_in_bytes} output "
+              f"{gmem.output_size_in_bytes}; pair slots {obj.pair_slots}")

@@ -224,6 +224,57 @@ def test_leaf_renewal_program_carries_its_scope():
     assert timer.SCOPE_RENEW_LEAVES in text
 
 
+RANK_SCOPES = ["rank_sort", "rank_pairs", "rank_scatter"]
+RANK_PARAMS = {"objective": "lambdarank", "metric": "ndcg",
+               "eval_at": [1, 3], "num_leaves": 7, "min_data_in_leaf": 1,
+               "min_sum_hessian_in_leaf": 1e-3, "verbosity": -1}
+
+
+def _rank_data(queries=30, seed=5):
+    """(X, grades 0..4, sizes): ragged queries of 1 to 60 documents."""
+    rng = np.random.RandomState(seed)
+    sizes = rng.randint(1, 61, size=queries)
+    X = rng.randn(int(sizes.sum()), 8)
+    grades = np.clip(np.round(X[:, 0] + 0.5 * rng.randn(len(X)) + 1), 0, 4)
+    return X, grades, sizes
+
+
+@pytest.fixture(scope="module")
+def rank_gradient_program():
+    """The one gradient program of a lambdarank objective, lowered."""
+    X, grades, sizes = _rank_data()
+    ds = lgb.Dataset(X, label=grades, group=sizes).construct()
+    cfg = Config(RANK_PARAMS)
+    obj = create_objective(cfg.objective, cfg)
+    obj.init(ds._handle.metadata, len(grades))
+    return jax.jit(obj.get_gradients).lower(
+        jnp.zeros(len(grades), jnp.float32)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", RANK_SCOPES)
+def test_lambdarank_gradient_program_nests_the_scope_under_gradients(
+        rank_gradient_program, scope):
+    nested = f"{timer.SCOPE_GRADIENTS}/{SCOPES['SCOPE_' + scope.upper()]}/"
+    assert nested in rank_gradient_program
+
+
+def test_validation_update_and_ndcg_programs_carry_their_scopes():
+    text = gbdt_mod._add_valid_delta.lower(
+        jnp.zeros((1, 64), jnp.float32), jnp.zeros((64, 1), jnp.float32),
+        0).as_text(debug_info=True)
+    assert timer.SCOPE_VALID_SCORE in text
+    from lightgbm_tpu.metrics import create_metric
+
+    X, grades, sizes = _rank_data()
+    ds = lgb.Dataset(X, label=grades, group=sizes).construct()
+    metric = create_metric("ndcg", Config(RANK_PARAMS))
+    metric.init(ds._handle.metadata, len(grades))
+    text = jax.jit(lambda s: metric._build_program()(
+        s, metric._per_bucket)).lower(
+        jnp.zeros(len(grades), jnp.float32)).as_text(debug_info=True)
+    assert timer.SCOPE_EVAL_NDCG in text
+
+
 @pytest.mark.parametrize("scope", PREDICT_SCOPES)
 def test_predict_program_carries_the_scope(predict_program, scope):
     assert SCOPES["SCOPE_" + scope.upper()] in predict_program
@@ -244,7 +295,8 @@ def test_the_table_of_scopes_is_the_constants():
     one prefix, and no two are alike."""
     checked = set(TREE_SCOPES + PREDICT_SCOPES + DENSE_PREDICT_SCOPES
                   + ["allreduce", "gradients", "update_score", "quantize",
-                     "renew_leaves"])
+                     "renew_leaves", "valid_score", "eval_ndcg"]
+                  + RANK_SCOPES)
     assert {v for v in SCOPES.values()} == {
         timer.SCOPE_PREFIX + name for name in checked}
     assert len(set(SCOPES.values())) == len(SCOPES)
@@ -463,6 +515,47 @@ def test_a_quantized_tree_opens_one_quantize_span_and_says_so_in_its_note(
     assert pack is learner._gh_int and pack.dtype == jnp.int8
     assert pack.shape == (1501, 3) and scales.shape == (3,)
     assert int(pack[-1].sum()) == 0 and int(pack[:-1, 2].min()) == 1
+
+
+def test_a_lambdarank_iteration_opens_gradients_and_eval_valid_and_notes_it(
+        spans):
+    """Three iterations with a validation set: `gradients` once an
+    iteration inside `boosting`, `eval_valid` once inside the iteration's
+    root and after its `update_score`; one `rank_gradients` note an
+    iteration with the pair counts; the two counters count them."""
+    X, grades, sizes = _rank_data()
+    Xv, grades_v, sizes_v = _rank_data(queries=12, seed=6)
+    ds = lgb.Dataset(X, label=grades, group=sizes)
+    dv = lgb.Dataset(Xv, label=grades_v, group=sizes_v, reference=ds)
+    tracing.recorder().reset()
+    del spans[:]
+    before = {k: global_timer.counters[k]
+              for k in ("rank_queries", "rank_pair_slots")}
+    bst = lgb.train(RANK_PARAMS, ds, num_boost_round=3, valid_sets=[dv])
+    roots = [s for s in spans if s[0] == timer.SPAN_ITERATION]
+    grads = [s for s in spans if s[0] == timer.SPAN_GRADIENTS]
+    evals = [s for s in spans if s[0] == timer.SPAN_EVAL_VALID]
+    boosting = [s for s in spans if s[0] == "boosting"]
+    updates = [s for s in spans if s[0] == "update_score"]
+    assert len(roots) == len(grads) == len(evals) == 3
+    for root, grad, ev, upd in zip(roots, grads, evals, updates):
+        assert _inside(grad, root) and _inside(ev, root)
+        assert any(_inside(grad, b) for b in boosting)
+        assert upd[2] <= ev[1]
+    notes = [n for n in tracing.recorder().snapshot()
+             if n["kind"] == "rank_gradients"]
+    obj = bst._gbdt.objective
+    m = np.minimum(sizes, 30)
+    assert len(notes) == 3
+    for note in notes:
+        assert note["queries"] == len(sizes) and note["rows"] == len(X)
+        assert note["pair_positions"] == int(
+            np.sum(m * sizes - m * (m + 1) // 2)) == obj.pair_positions
+        assert note["pair_slots"] == obj.pair_slots >= note["pair_positions"]
+    assert global_timer.counters["rank_queries"] \
+        - before["rank_queries"] == 3 * len(sizes)
+    assert global_timer.counters["rank_pair_slots"] \
+        - before["rank_pair_slots"] == 3 * obj.pair_slots
 
 
 def test_a_float_learner_has_no_pack_and_its_note_says_hist_int_0(
