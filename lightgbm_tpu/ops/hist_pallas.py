@@ -7,24 +7,33 @@ HBM traffic per histogram — catastrophically bandwidth-bound. These kernels
 generate each one-hot tile INSIDE the kernel (VMEM-resident, never touches
 HBM) and feed the MXU directly, so HBM traffic drops to the irreducible
 G*N*(bins + gh) bytes. The wave kernel (pallas_histogram_slots_ragged: the
-device learner's and the streamed learner's), per grid step (group block,
-row tile) and per REAL group of the block:
+device learner's and the streamed learner's) walks a table of (row tile,
+slot) pairs (tile_slot_pairs); per grid step (group block, pair) and per
+REAL group of the block:
 
+    X[L*CHp, TN]   = bf16_limbs(gh_tile * (slot_tile == slot_of_pair))
     onehot[Bp, TN] = (iota_sublane == bins_tile[g][None, :])  # VPU, bf16
-    acc[L*SCp, Bp] = X[L*SCp, TN] . onehot^T   # MXU: bf16 x bf16 -> f32
-    out[g]        += acc[0:SC] + acc[SCp:SCp+SC] + ...        # [SC, Bp]
+    acc[L*CHp, Bp] = X . onehot^T          # MXU: bf16 x bf16 -> f32
+    out[slot_of_pair, g] += acc[0:CH] + acc[CHp:CHp+CH] + ...  # [CH, Bp]
 
 The bin row is lane-major as it arrives, so the one-hot is a sublane
 broadcast and a compare (no lanes-to-sublanes relayout), and the
 contraction runs over the last dimension of both operands (the q @ k^T
-form, as ops/compact_pallas.py). X is the slot-expanded gradient tile as L
-bfloat16 limbs stacked on the sublanes (bf16_limbs): L = 3 holds a float32
+form, as ops/compact_pallas.py). X is the gradient tile of the pair's ONE
+slot as L bfloat16 limbs stacked on the sublanes (bf16_limbs), CHp = CH up
+to a packed bfloat16 tile's 16 rows: its height is set by the dtype policy
+and the channel count alone, so a wave tile costs what a root tile costs
+(the rows are leaf-contiguous: a tile holds one slot except at a range's
+ends, and is listed once for each it holds). L = 3 holds a float32
 exactly, so f32=True is the float32 histogram of the unrounded gradients in
 ONE MXU pass where float32 operands at Precision.HIGHEST cost six, three of
 them against the one-hot's all-zero low parts; L = 1 is the bfloat16
 default and the quantized path. Bp is num_bins rounded up to whole 128-lane
-tiles. The dense kernel of the host learners (pallas_histogram) still
-builds onehot[TN, B] and contracts gh_tile^T @ onehot, [CH, B].
+tiles. The pairs are slot-major, so the output block (1, GB, CH, Bp) of a
+slot is resident for that slot's run of pairs and written once (the
+grouped-matmul form). The dense kernel of the host learners
+(pallas_histogram) still builds onehot[TN, B] and contracts gh_tile^T @
+onehot, [CH, B].
 
 GB is chosen per call by _prep_bins/_group_block: as large as the output
 block fits comfortably in VMEM (32 -> 16 -> 8; bigger blocks amortize
@@ -33,9 +42,9 @@ block dim to be a multiple of 8 (or the full array dim); a (1, TN) bins
 block fails to lower on real TPU hardware. 8-bit bin planes (uint8) pass
 through unwidened — 4x less HBM traffic for the dominant [G, N] array —
 with GB pinned to 32 (Mosaic tiles 8-bit as (32, 128)) and the group row
-widened to i32 in-register for the compare. The output block for a group
-slab is revisited across the N tiles (TPU grids run sequentially),
-accumulating in VMEM; step 0 zero-initializes.
+widened to i32 in-register for the compare. The dense kernel's output
+block for a group slab is revisited across the N tiles (TPU grids run
+sequentially), accumulating in VMEM; step 0 zero-initializes.
 
 Counterpart of the CUDA shared-memory scatter kernels
 (src/treelearner/cuda/cuda_histogram_constructor.cu:20-513) — same
@@ -70,8 +79,8 @@ MIN_GROUP_BLOCK = 8  # Mosaic minimum for the second-to-last block dim
 def _group_block(n_groups: int, n_channels: int, num_bins: int,
                  acc_bytes: int = 4) -> int:
     """Largest useful group block whose output block stays comfortably in
-    VMEM. Bigger blocks amortize the per-grid-step work (the slot-expanded
-    gradient build runs once per (block, tile)): 8 -> 32 measured +13%
+    VMEM. Bigger blocks amortize the per-grid-step work (the gradient
+    operand's build runs once per (block, tile)): 8 -> 32 measured +13%
     end-to-end training throughput on v5e. Clamped to the group count
     rounded up to 8 so small-G datasets don't pay for dead padded groups."""
     cap = max(-(-n_groups // MIN_GROUP_BLOCK) * MIN_GROUP_BLOCK,
@@ -89,7 +98,7 @@ def _prep_bins(bins: jax.Array, n_channels: int, num_bins: int):
     array moves 4x fewer HBM bytes — and the kernels widen each group row
     to i32 in-register for the one-hot compare (Mosaic has no elementwise
     8-bit vectors). Mosaic tiles 8-bit arrays as (32, 128), so the bins
-    block's group dim is pinned to 32; when the matching (32, SC, B) f32
+    block's group dim is pinned to 32; when the matching (32, CH, B) f32
     output block would blow the VMEM budget, widen to int32 up front and
     let _group_block pick a smaller block instead."""
     if (bins.dtype.itemsize == 1
@@ -203,26 +212,41 @@ def pallas_histogram(bins: jax.Array, gh: jax.Array, num_bins: int,
     return out[:G].transpose(0, 2, 1)  # [G, B, CH]; 172KB, free vs the dot
 
 
-def active_tile_table(starts: jax.Array, ends: jax.Array, valid: jax.Array,
-                      n_tiles: int, tile_rows: int):
-    """Row-tile indirection table for the ragged wave histogram.
+def tile_slot_pairs(starts: jax.Array, ends: jax.Array, valid: jax.Array,
+                    n_tiles: int, tile_rows: int):
+    """(row tile, slot) pair table for the ragged wave histogram.
 
-    starts/ends [K] int32 half-open row ranges (leaf-contiguous layout),
-    valid [K] bool. Returns (tiles [n_tiles] int32, n_active [1] int32):
-    the ascending indices of every tile overlapping a valid range, padded
-    past n_active by repeating the last active tile (same block index =>
-    the kernel pipeline skips the redundant DMA and pl.when skips compute).
+    starts/ends [K] int32 half-open row ranges, DISJOINT (leaf-contiguous
+    layout), valid [K] bool; slot k is range k. Returns (tiles [P], slots
+    [P], n_pairs [1], n_active [1]) int32 with P = n_tiles + 2 * K: every
+    (tile, slot) whose tile overlaps that slot's range, slot-major with the
+    tiles ascending within a slot, so each slot's pairs are one run. A slot
+    whose range is empty or invalid is listed ONCE, with tile 0: the kernel
+    writes an output block only where a pair visits it, and that visit
+    finds no row of the slot and writes zeros. n_active counts the distinct
+    tiles among the live ranges' pairs: disjoint ranges share a tile only
+    where one starts in the tile another ends in, so n_pairs <= n_tiles +
+    K. Entries past n_pairs repeat the last pair (same block indices => the
+    kernel pipeline skips the redundant DMA and pl.when skips compute).
     """
-    t = jnp.arange(n_tiles, dtype=jnp.int32)
-    lo = t * tile_rows
-    act = (((lo[:, None] < ends[None, :])
-            & (lo[:, None] + tile_rows > starts[None, :]))
-           & valid[None, :]).any(axis=1)
-    order = jnp.argsort(~act, stable=True).astype(jnp.int32)  # actives first
-    n_act = act.sum().astype(jnp.int32)
-    last = jnp.take(order, jnp.maximum(n_act - 1, 0))
-    tiles = jnp.where(t < n_act, order, last)
-    return tiles, n_act[None]
+    K = starts.shape[0]
+    live = valid & (ends > starts)
+    first = jnp.where(live, jnp.clip(starts // tile_rows, 0, n_tiles - 1), 0)
+    last = jnp.where(live, jnp.clip((ends - 1) // tile_rows, 0, n_tiles - 1),
+                     0)
+    cnt = last - first + 1  # a dead slot: its one visit
+    off = jnp.cumsum(cnt) - cnt  # [K] where each slot's run begins
+    n_pairs = off[-1] + cnt[-1]
+    p = jnp.minimum(jnp.arange(n_tiles + 2 * K, dtype=jnp.int32),
+                    n_pairs - 1)
+    slots = jnp.searchsorted(off, p, side="right", method="compare_all") - 1
+    tiles = jnp.take(first - off, slots) + p
+    # a live range's first tile is another's last: listed twice, one tile
+    shared = (live[:, None] & live[None, :]
+              & (first[:, None] == last[None, :])
+              & (starts[:, None] > starts[None, :])).any(axis=1)
+    n_active = jnp.sum(jnp.where(live, cnt, 0)) - jnp.sum(shared)
+    return tiles, slots, n_pairs[None], n_active[None]
 
 
 def bf16_limbs(x: jax.Array, n: int) -> jax.Array:
@@ -242,48 +266,49 @@ def bf16_limbs(x: jax.Array, n: int) -> jax.Array:
     return parts[0] if n == 1 else jnp.concatenate(parts, axis=0)
 
 
-def _make_slots_ragged_kernel(bins_p: int, tile_rows: int, n_slots: int,
-                              ch: int, limbs: int, acc_dtype,
-                              group_block: int, n_groups: int):
+def _make_slots_ragged_kernel(bins_p: int, tile_rows: int, ch: int,
+                              limbs: int, acc_dtype, group_block: int,
+                              n_groups: int):
     """bins_p: the bin axis padded to whole 128-lane tiles. limbs: how many
     bfloat16 limbs carry the gradient operand (3 = exact float32, 1 = the
     bfloat16 rounding or the quantized path's small ints).
     n_groups: the plane's real groups; the groups behind them in the last
     group block are padding and get no one-hot and no contraction."""
-    SC = n_slots * ch
     g_blocks = -(-n_groups // group_block)
     last_block_groups = n_groups - (g_blocks - 1) * group_block
-    SCp = -(-SC // 16) * 16  # a limb block starts on a packed bf16 tile
+    chp = -(-ch // 16) * 16  # a limb block starts on a packed bf16 tile
     quantized = jnp.issubdtype(jnp.dtype(acc_dtype), jnp.integer)
 
-    def kernel(tiles_ref, nact_ref, bins_ref, gh_ref, slot_ref, out_ref):
-        t = pl.program_id(1)
+    def kernel(tiles_ref, slots_ref, npairs_ref, bins_ref, gh_ref, slot_ref,
+               out_ref):
+        del tiles_ref  # the index maps' alone
+        p = pl.program_id(1)
         full_block = pl.program_id(0) < g_blocks - 1
+        k = slots_ref[p]
 
-        @pl.when(t == 0)
+        # the output block is slot k's: zeroed where the slot's run of
+        # pairs begins, written back by the pipeline where it ends
+        @pl.when((p == 0) | (slots_ref[jnp.maximum(p - 1, 0)] != k))
         def _init():
             out_ref[...] = jnp.zeros_like(out_ref)
 
-        @pl.when(t < nact_ref[0])
+        @pl.when(p < npairs_ref[0])
         def _acc():
             s = slot_ref[...]  # [1, TN] int32: rows on the lanes
             ghc = gh_ref[...]  # [ch, TN] f32 (quantized: exact small ints)
-            # slot-expanded gradient tile, row j = slot*ch + channel, built
-            # [SCp, TN] f32 in VMEM straight from the lane-major payload
-            # rows (a sublane broadcast each; rows past SC stay zero).
-            # Strictly 2D broadcasts: per-channel masked adds, not a
-            # concat/tile (an n_slots-way concat lowers to a serial copy
-            # chain in Mosaic, ~2x slower end to end; the XLA-side
-            # materialization of this matrix cost ~18 ms a wave).
-            row = jax.lax.broadcasted_iota(jnp.int32, (SCp, 1), 0)
-            rowslot = row // ch  # [SCp, 1]: 8 registers
-            rowch = jnp.where(row < SC, row % ch, -1)
-            gsum = jnp.zeros((SCp, tile_rows), jnp.float32)
+            # this slot's gradient tile, row j = channel, built [chp, TN]
+            # f32 in VMEM straight from the lane-major payload rows (a
+            # sublane broadcast each; rows past ch stay zero). Strictly 2D
+            # broadcasts: per-channel masked adds, not a concat (which
+            # lowers to a serial copy chain in Mosaic).
+            row = jax.lax.broadcasted_iota(jnp.int32, (chp, 1), 0)
+            gsum = jnp.zeros((chp, tile_rows), jnp.float32)
             for c in range(ch):
-                gsum += ghc[c:c + 1, :] * (rowch == c).astype(jnp.float32)
-            # the MXU multiplies bfloat16: the gradients go in as exact
-            # bfloat16 limbs stacked on the sublanes, [limbs * SCp, TN]
-            X = bf16_limbs(gsum * (rowslot == s).astype(jnp.float32), limbs)
+                gsum += ghc[c:c + 1, :] * (row == c).astype(jnp.float32)
+            # the MXU multiplies bfloat16: the slot's rows go in as exact
+            # bfloat16 limbs stacked on the sublanes, [limbs * chp, TN]; a
+            # row of another slot (or the dump slot) is a zero column
+            X = bf16_limbs(gsum * (s == k).astype(jnp.float32), limbs)
             iota = jax.lax.broadcasted_iota(jnp.int32, (bins_p, tile_rows), 0)
 
             def group(gi):
@@ -297,13 +322,13 @@ def _make_slots_ragged_kernel(bins_p: int, tile_rows: int, n_slots: int,
                 # the float32 histogram at the gradients' full 24 bits
                 acc = jax.lax.dot_general(
                     X, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)  # [limbs*SCp, Bp]
-                h = acc[:SC]
+                    preferred_element_type=jnp.float32)  # [limbs*chp, Bp]
+                h = acc[:ch]
                 for i in range(1, limbs):
-                    h = h + acc[i * SCp:i * SCp + SC]
+                    h = h + acc[i * chp:i * chp + ch]
                 # quantized: per-tile partial sums are exact ints in f32
                 # (<= tile_rows * 127 * 255 < 2**24); accumulate int32
-                out_ref[gi] += h.astype(acc_dtype) if quantized else h
+                out_ref[0, gi] += h.astype(acc_dtype) if quantized else h
 
             for gi in range(last_block_groups):
                 group(gi)
@@ -321,26 +346,32 @@ def _make_slots_ragged_kernel(bins_p: int, tile_rows: int, n_slots: int,
                                    "interpret"))
 def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
                                   slot: jax.Array, tiles: jax.Array,
-                                  n_active: jax.Array,
+                                  slots: jax.Array, n_pairs: jax.Array,
                                   num_bins: int, n_slots: int,
                                   tile_rows: int = DEFAULT_TILE_ROWS,
                                   quantized: bool = False,
                                   f32: bool = False,
                                   n_groups: int | None = None,
                                   interpret: bool = False) -> jax.Array:
-    """Slot-expanded histogram over an indirected set of row tiles:
+    """Per-slot histograms over a table of (row tile, slot) pairs:
     [G, N] bins + [CH, N] gh + [N] slot ids -> [G, num_bins, n_slots*CH],
     where row n adds its gh to channel block slot[n] and a row whose slot
     is outside [0, n_slots) adds nowhere.
 
-    The rows-in-leaf wave histogram: `tiles` (from active_tile_table) names
-    the row tiles overlapping the wave's selected leaf ranges; the grid
-    walks ONLY those via scalar-prefetched index maps (MoE-style ragged
-    blocks), so per-wave cost is O(rows in selected leaves) instead of
-    O(N). Rows inside a listed tile but outside every selected range must
-    carry slot >= n_slots (the dump slot). `n_active` is a traced [1]
-    int32 — inactive tail entries of `tiles` repeat the last active tile
-    and are skipped.
+    The rows-in-leaf wave histogram: `tiles`/`slots` (from
+    tile_slot_pairs) name every row tile that overlaps a selected leaf
+    range beside that range's slot; the grid walks ONLY those pairs via
+    scalar-prefetched index maps (the grouped-matmul form: slot-major, the
+    output block a pair accumulates into is its slot's), and a pair
+    contracts the rows of ITS slot alone, an operand one slot high: a tile
+    costs what the slots it holds cost, not what n_slots would. So per-wave
+    cost is O(rows in selected leaves) instead of O(N). A row adds to its
+    slot only under a listed pair of that slot, so every slot < n_slots
+    must appear in `slots` (its block is otherwise never written) and rows
+    outside every selected range carry slot >= n_slots (the dump slot).
+    `n_pairs` is a traced [1] int32 — tail entries of the table repeat the
+    last pair and are skipped. The root pass is the same call with one
+    slot.
 
     gh is ALWAYS [CH, N] f32 here: the gh rows of the leaf-contiguous
     payload, rows on the lanes like bins (block (CH, tile_rows)), and slot
@@ -364,7 +395,6 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
     G, N = bins.shape
     n_groups = G if n_groups is None else n_groups
     CH = gh.shape[0]
-    SC = n_slots * CH
     if N % tile_rows:
         raise ValueError("ragged histogram requires N padded to tile_rows")
     if not 0 < n_groups <= G:
@@ -372,32 +402,33 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
     limbs = 3 if hist_operand(quantized, f32) == "bf16x3" else 1
     acc_dtype = jnp.int32 if quantized else jnp.float32
     bins_p = -(-num_bins // 128) * 128  # whole lane tiles; no bin >= num_bins
-    T = tiles.shape[0]
-    bins, GB = _prep_bins(bins, SC, bins_p)
+    bins, GB = _prep_bins(bins, CH, bins_p)
     slot = slot.reshape(1, N).astype(jnp.int32)
     g_blocks = -(-n_groups // GB)
     g_pad = g_blocks * GB - G  # negative where the caller padded past it
     if g_pad > 0:
         bins = jnp.pad(bins, ((0, g_pad), (0, 0)), constant_values=0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(g_blocks, T),
+        num_scalar_prefetch=3,
+        grid=(g_blocks, tiles.shape[0]),
         in_specs=[
-            pl.BlockSpec((GB, tile_rows), lambda g, t, tr, na: (g, tr[t])),
-            pl.BlockSpec((CH, tile_rows), lambda g, t, tr, na: (0, tr[t])),
-            pl.BlockSpec((1, tile_rows), lambda g, t, tr, na: (0, tr[t])),
+            pl.BlockSpec((GB, tile_rows), lambda g, p, tr, sl, n: (g, tr[p])),
+            pl.BlockSpec((CH, tile_rows), lambda g, p, tr, sl, n: (0, tr[p])),
+            pl.BlockSpec((1, tile_rows), lambda g, p, tr, sl, n: (0, tr[p])),
         ],
-        out_specs=pl.BlockSpec((GB, SC, bins_p),
-                               lambda g, t, tr, na: (g, 0, 0)),
+        out_specs=pl.BlockSpec((1, GB, CH, bins_p),
+                               lambda g, p, tr, sl, n: (sl[p], g, 0, 0)),
     )
     out = pl.pallas_call(
-        _make_slots_ragged_kernel(bins_p, tile_rows, n_slots, CH, limbs,
-                                  acc_dtype, GB, n_groups),
+        _make_slots_ragged_kernel(bins_p, tile_rows, CH, limbs, acc_dtype,
+                                  GB, n_groups),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((g_blocks * GB, SC, bins_p),
+        out_shape=jax.ShapeDtypeStruct((n_slots, g_blocks * GB, CH, bins_p),
                                        acc_dtype),
         interpret=interpret,
         name="pallas_histogram_slots_ragged",
-    )(tiles.astype(jnp.int32), n_active.astype(jnp.int32),
-      bins, gh.astype(jnp.float32), slot)
-    return out[:n_groups, :, :num_bins].transpose(0, 2, 1)  # [G, B, SC]
+    )(tiles.astype(jnp.int32), slots.astype(jnp.int32),
+      n_pairs.astype(jnp.int32), bins, gh.astype(jnp.float32), slot)
+    # [S, G, CH, B] -> [G, B, S*CH]
+    return out[:, :n_groups, :, :num_bins].transpose(1, 3, 0, 2).reshape(
+        n_groups, num_bins, n_slots * CH)

@@ -671,16 +671,16 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
                 self._vslot_arg, self._scan_meta_arg, self._tables_rep,
                 self._params_rep, fmask_sh, scale_rep,
                 *self._extra_grow_args())
-        rec_store, leaf_id, _, hist_rows, n_waves = out[:5]
-        self._note_grow_extras(out[5:])
+        rec_store, leaf_id, _, hist_rows, n_waves, hist_tiles = out[:6]
+        self._note_grow_extras(out[6:])
         with global_timer.scope(SPAN_GATHER_LEAF_IDS):
             leaf_id = self._gather_leaf_ids(leaf_id)
-        for arr in (rec_store, leaf_id, hist_rows, n_waves):
+        for arr in (rec_store, leaf_id, hist_rows, n_waves, hist_tiles):
             start = getattr(arr, "copy_to_host_async", None)
             if start is not None:
                 start()
         return _PendingTree(Tree(cfg.num_leaves), rec_store, leaf_id,
-                            hist_rows, n_waves, n_bag,
+                            hist_rows, n_waves, hist_tiles, n_bag,
                             wave_k=self.wave_k)
 
     def _gather_leaf_ids(self, leaf_id: jax.Array) -> jax.Array:

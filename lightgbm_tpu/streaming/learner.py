@@ -52,9 +52,9 @@ import numpy as np
 
 from ..config import Config
 from ..io.dataset import Dataset
-from ..ops.hist_pallas import (DEFAULT_TILE_ROWS, active_tile_table,
-                               hist_force_f32,
-                               pallas_histogram_slots_ragged)
+from ..ops.hist_pallas import (DEFAULT_TILE_ROWS, hist_force_f32,
+                               pallas_histogram_slots_ragged,
+                               tile_slot_pairs)
 from ..ops.histogram import DEFAULT_ROW_CHUNK, _acc_dtype, _hist_chunk
 from ..ops.partition import pad_indices
 from ..ops.score import binned_leaf_index, binned_tree_arrays
@@ -323,8 +323,8 @@ class StreamedTreeLearner(SerialTreeLearner):
 
         Each cached block slab is fed to pallas_histogram_slots_ragged
         whole (padded to the tile grid) with a 1-slot table: rows of this
-        leaf carry slot 0, every other row the dump slot, and the active-
-        tile table restricts the grid to the tiles the leaf actually
+        leaf carry slot 0, every other row the dump slot, and the pair
+        table restricts the grid to the tiles the leaf actually
         touches — per-block cost is O(tiles overlapping the leaf), not
         O(block_rows). The next block's H2D prefetch is dispatched while
         the current block's kernel is in flight (the same double buffer
@@ -371,12 +371,13 @@ class StreamedTreeLearner(SerialTreeLearner):
                                axis=0).astype(jnp.float32)
             gh = jnp.zeros((CH, padded), jnp.float32).at[:, loc].set(
                 gh_rows.T)  # the kernel takes its per-row operands [k, N]
-            tiles, n_act = active_tile_table(
+            tiles, slots, n_pairs, _ = tile_slot_pairs(
                 jnp.asarray([sel[0] - lo], jnp.int32),
                 jnp.asarray([sel[-1] - lo + 1], jnp.int32),
                 jnp.asarray([True]), padded // tr, tr)
             part = pallas_histogram_slots_ragged(
-                bins_b, gh, slot, tiles, n_act, num_bins, 1, tile_rows=tr,
+                bins_b, gh, slot, tiles, slots, n_pairs, num_bins, 1,
+                tile_rows=tr,
                 quantized=self.quantized, f32=hist_force_f32(),
                 interpret=interpret)
             acc = acc + part.astype(acc_dtype)

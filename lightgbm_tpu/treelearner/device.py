@@ -234,9 +234,9 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
     neg_inf = jnp.float32(-jnp.inf)
     from ..ops.compact_pallas import (COMPACT_TILE, compact_rows,
                                       range_partition_dst)
-    from ..ops.hist_pallas import (DEFAULT_TILE_ROWS, active_tile_table,
-                                   hist_force_f32,
-                                   pallas_histogram_slots_ragged)
+    from ..ops.hist_pallas import (DEFAULT_TILE_ROWS, hist_force_f32,
+                                   pallas_histogram_slots_ragged,
+                                   tile_slot_pairs)
 
     # pad rows ONCE to a common multiple of the histogram and compaction
     # tiles; padded rows carry leaf_id -1 and zero gh and (like bagged-out
@@ -302,24 +302,28 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
 
     def ranged_hist(bins_c, row_c, slot, n_slots, starts, ends, valid):
         """[G, B, n_slots*CH] histogram of the rows inside the given
-        leaf-contiguous ranges (slot must be the dump value outside).
+        leaf-contiguous ranges, one a slot (slot must be the dump value
+        outside), and [2] int32: the (tile, slot) pairs the kernel walked
+        and the distinct tiles among them (zeros off the kernel path).
         bins_c/row_c passed explicitly: inside the wave loop they are the
         CARRY arrays, not the pre-loop closure values."""
         with jax.named_scope(SCOPE_HIST):
             ghc = row_c[:CH]  # the payload's gh rows, f32 [CH, rows]
             if use_kernels:
-                tiles, nact = active_tile_table(starts, ends, valid, T_hist,
-                                                DEFAULT_TILE_ROWS)
-                return pallas_histogram_slots_ragged(
-                    bins_c, ghc, slot, tiles, nact, num_bins,
+                tiles, slots, n_pairs, n_active = tile_slot_pairs(
+                    starts, ends, valid, T_hist, DEFAULT_TILE_ROWS)
+                h = pallas_histogram_slots_ragged(
+                    bins_c, ghc, slot, tiles, slots, n_pairs, num_bins,
                     n_slots, quantized=quantized, f32=hist_force_f32(),
                     n_groups=G, interpret=interp)
+                return h, jnp.concatenate([n_pairs, n_active])
             # XLA fallback: flat slot-expanded build over the full row set
             col_slot = jnp.arange(n_slots * CH, dtype=jnp.int32) // CH
             ghK = jnp.where(slot[:, None] == col_slot[None, :],
                             jnp.tile(ghc.T, (1, n_slots)), 0.0)
             h = build_histogram(bins_c[:G], ghK, num_bins)
-            return h.astype(pool_dtype)  # quantized: exact ints below 2**24
+            # quantized: exact ints below 2**24
+            return h.astype(pool_dtype), jnp.zeros(2, jnp.int32)
 
     if data_par:
         gidx, vslot, sm = meta.gather_index, meta.valid_slot, meta.scan
@@ -521,10 +525,12 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
 
     # --- root histogram through the ragged slots kernel (satellite: the
     # thin-CH masked dot cost ~183 ms/tree; this path is O(n_in) and warm)
-    root_hist = ranged_hist(
+    root_hist, hist_tiles = ranged_hist(
         bins_p, row_p, jnp.where(pos < n_in, 0, 1), 1,
         jnp.zeros(1, jnp.int32), n_in[None], jnp.ones(1, bool))
-    hist_rows = n_in  # instrumentation: rows histogrammed this tree
+    # instrumentation: rows histogrammed this tree, and the tile visits
+    # (pairs walked, distinct tiles) the kernel took for them
+    hist_rows = n_in
 
     with jax.named_scope(SCOPE_TREE_SETUP):
         depth = jnp.zeros(L + 1, jnp.int32)
@@ -582,13 +588,15 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
     def wave(carry):
         if voting:
             (bins_p, row_p, start, count, depth, leaf_best, rec_store, pool,
-             n_cur, t, hist_rows, tpool, count_g, miss, n_waves) = carry
+             n_cur, t, hist_rows, hist_tiles, tpool, count_g, miss,
+             n_waves) = carry
         elif data_par:
             (bins_p, row_p, start, count, depth, leaf_best, rec_store, pool,
-             n_cur, t, hist_rows, tpool, count_g, n_waves) = carry
+             n_cur, t, hist_rows, hist_tiles, tpool, count_g,
+             n_waves) = carry
         else:
             (bins_p, row_p, start, count, depth, leaf_best, rec_store, pool,
-             n_cur, t, hist_rows, n_waves) = carry
+             n_cur, t, hist_rows, hist_tiles, n_waves) = carry
         n_waves = n_waves + 1  # wave-efficiency telemetry (finalize())
         with jax.named_scope(SCOPE_SELECT):
             gains = leaf_best[:L, 0]
@@ -681,8 +689,9 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             slotS = jnp.where(inS.any(axis=0),
                               jnp.argmax(inS, axis=0).astype(jnp.int32), K)
             hist_rows = hist_rows + jnp.sum(jnp.where(sel_ok, sc_k, 0))
-            histS = ranged_hist(bins_p, row_p, slotS, K, ss_k, se_k,
-                                sel_ok & (sc_k > 0))
+            histS, wave_tiles = ranged_hist(bins_p, row_p, slotS, K, ss_k,
+                                            se_k, sel_ok & (sc_k > 0))
+            hist_tiles = hist_tiles + wave_tiles
             histS_k = jnp.moveaxis(
                 histS.reshape(G, num_bins, K, CH), 2, 0)  # [K, G, B, CH]
         with jax.named_scope(SCOPE_SCAN):
@@ -843,14 +852,14 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
                 jnp.where(is_right, rowsP[1], row_p[LEAF_ROW]))
         if voting:
             return (bins_p, row_p, start, count, depth, leaf_best,
-                    rec_store, pool, n_cur, t, hist_rows, tpool, count_g,
-                    miss, n_waves)
+                    rec_store, pool, n_cur, t, hist_rows, hist_tiles, tpool,
+                    count_g, miss, n_waves)
         if data_par:
             return (bins_p, row_p, start, count, depth, leaf_best,
-                    rec_store, pool, n_cur, t, hist_rows, tpool, count_g,
-                    n_waves)
+                    rec_store, pool, n_cur, t, hist_rows, hist_tiles, tpool,
+                    count_g, n_waves)
         return (bins_p, row_p, start, count, depth, leaf_best, rec_store,
-                pool, n_cur, t, hist_rows, n_waves)
+                pool, n_cur, t, hist_rows, hist_tiles, n_waves)
 
     def cond(carry):
         with jax.named_scope(SCOPE_SELECT):
@@ -858,7 +867,7 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             return (t < L - 1) & (jnp.max(leaf_best[:L, 0]) > 0)
 
     carry = (bins_p, row_p, start, count, depth, leaf_best, rec_store, pool,
-             jnp.int32(1), jnp.int32(0), hist_rows)
+             jnp.int32(1), jnp.int32(0), hist_rows, hist_tiles)
     if row_sharded:
         carry = carry + (tpool, count_g)
     if voting:
@@ -866,22 +875,22 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
     carry = carry + (jnp.int32(0),)  # n_waves, last so indices above hold
     if L > 1:
         carry = jax.lax.while_loop(cond, wave, carry)
-    row_p, rec_store, n_cur, hist_rows = carry[1], carry[6], carry[8], \
-        carry[10]
+    row_p, rec_store, n_cur, hist_rows, hist_tiles = (
+        carry[1], carry[6], carry[8], carry[10], carry[11])
     n_waves = carry[-1]
     if row_sharded:
         with jax.named_scope(SCOPE_ALLREDUCE):
             hist_rows = jax.lax.psum(hist_rows, "data")
+            hist_tiles = jax.lax.psum(hist_tiles, "data")
     with jax.named_scope(SCOPE_FINISH):
         # undo the permutation without a TPU scatter: sort leaf ids by the
         # original-position row (both exact small ints in f32)
         _, leaf_sorted = jax.lax.sort_key_val(
             row_p[POS_ROW].astype(jnp.int32),
             row_p[LEAF_ROW].astype(jnp.int32))
-    if voting:
-        return (rec_store[:-1], leaf_sorted[:N], n_cur, hist_rows, n_waves,
-                carry[13])
-    return rec_store[:-1], leaf_sorted[:N], n_cur, hist_rows, n_waves
+    out = (rec_store[:-1], leaf_sorted[:N], n_cur, hist_rows, n_waves,
+           hist_tiles)
+    return out + (carry[14],) if voting else out
 
 
 # bins/gh/leaf_id0 are donated: each is a fresh per-tree buffer (the
@@ -925,7 +934,9 @@ def grow_tree_on_device(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
     — <= ~4N in practice vs O(N * waves) for full-N masked waves.
     Returns (rec_store [L-1, STORE], leaf_id [N] in ORIGINAL row order,
     num_leaves_final, hist_rows — rows histogrammed, the perf counter,
-    n_waves — while_loop trips, for the committed-vs-speculated telemetry).
+    n_waves — while_loop trips, for the committed-vs-speculated telemetry,
+    hist_tiles [2] — the (row tile, slot) pairs the histogram kernel walked
+    for those rows, root and waves, and the distinct tiles among them).
     """
     return _grow_impl(bins, gh, leaf_id0, meta, tables, params, feature_mask,
                       scale_vec, num_leaves=num_leaves, num_bins=num_bins,
@@ -974,8 +985,8 @@ def make_sharded_grow_fn(mesh, *, num_leaves: int, num_bins: int,
     it is ignored). Categorical splits are not supported here (the factory
     routes categorical configs to the host-driven learners). Returns the
     same (rec_store, leaf_id [Np] global original order, n_cur, hist_rows,
-    n_waves) as grow_tree_on_device; rec_store/n_cur/hist_rows/n_waves are
-    replicated.
+    n_waves, hist_tiles) as grow_tree_on_device; all but leaf_id are
+    replicated (hist_rows and hist_tiles summed over the row shards).
     """
     from jax.sharding import PartitionSpec as P
 
@@ -998,7 +1009,7 @@ def make_sharded_grow_fn(mesh, *, num_leaves: int, num_bins: int,
             body, mesh=mesh,
             in_specs=(P(None, "data"), P("data"), P("data"), P(), P(),
                       P(), P(), P(), P(), P(), P(), P()),
-            out_specs=(P(), P("data"), P(), P(), P(), P()),
+            out_specs=(P(), P("data"), P(), P(), P(), P(), P()),
             check_vma=False), donate_argnums=(0, 1, 2))
 
     if mode == "feature":
@@ -1020,7 +1031,7 @@ def make_sharded_grow_fn(mesh, *, num_leaves: int, num_bins: int,
             body, mesh=mesh,
             in_specs=(P(), P(), P(), P("data"), P("data"), P("data"),
                       P(), P(), P("data"), P()),
-            out_specs=(P(), P(), P(), P(), P()),
+            out_specs=(P(), P(), P(), P(), P(), P()),
             check_vma=False))
 
     def body(bins, gh, leaf_id0, gather_index, valid_slot, scan_meta,
@@ -1038,7 +1049,7 @@ def make_sharded_grow_fn(mesh, *, num_leaves: int, num_bins: int,
         body, mesh=mesh,
         in_specs=(P(None, "data"), P("data"), P("data"), P(), P(),
                   P("data"), P(), P(), P("data"), P()),
-        out_specs=(P(), P("data"), P(), P(), P()),
+        out_specs=(P(), P("data"), P(), P(), P(), P()),
         check_vma=False), donate_argnums=(0, 1, 2))
 
 
@@ -1094,6 +1105,7 @@ class _PendingTree(NamedTuple):
     leaf_id: jax.Array
     hist_rows: jax.Array
     n_waves: jax.Array
+    hist_tiles: jax.Array  # [2]: tile visits, distinct tiles
     n_bag: int
     wave_k: int  # wave width this tree was dispatched with
 
@@ -1225,7 +1237,7 @@ class DeviceTreeLearner(SerialTreeLearner):
         with global_timer.scope("tree_device"):
             # bins_dev is COPIED per tree: grow_tree_on_device donates its
             # first three args (gh and leaf_id0 are already fresh buffers)
-            rec_store, leaf_id, _, hist_rows, n_waves = grow(
+            rec_store, leaf_id, _, hist_rows, n_waves, hist_tiles = grow(
                 jnp.copy(self.bins_dev), gh, leaf_id0, self.meta,
                 self.tables, self.params_dev, fmask, num_leaves,
                 self.group_bin_padded,
@@ -1235,12 +1247,12 @@ class DeviceTreeLearner(SerialTreeLearner):
         # start the device->host copies without blocking; finalize() (maybe
         # a full iteration later, under the async pipeline) pays no wait if
         # the transfer already landed
-        for arr in (rec_store, leaf_id, hist_rows, n_waves):
+        for arr in (rec_store, leaf_id, hist_rows, n_waves, hist_tiles):
             start = getattr(arr, "copy_to_host_async", None)
             if start is not None:
                 start()
         return _PendingTree(Tree(num_leaves), rec_store, leaf_id, hist_rows,
-                            n_waves, n_bag, wave_k=self.wave_k)
+                            n_waves, hist_tiles, n_bag, wave_k=self.wave_k)
 
     def finalize(self, pending: _PendingTree) -> Tree:
         cfg = self.config
@@ -1250,6 +1262,10 @@ class DeviceTreeLearner(SerialTreeLearner):
         leaf_id = pending.leaf_id
         self.last_hist_rows = int(pending.hist_rows)
         global_timer.add_count("device_hist_rows", self.last_hist_rows)
+        self.last_hist_tile_visits, self.last_hist_tiles_active = (
+            int(v) for v in np.asarray(pending.hist_tiles))
+        global_timer.add_count("device_hist_tile_visits",
+                               self.last_hist_tile_visits)
 
         counts: Dict[int, int] = {0: int(pending.n_bag)}
         for t in range(rec_np.shape[0]):
@@ -1307,6 +1323,8 @@ class DeviceTreeLearner(SerialTreeLearner):
         tracing.note("tree_wave", waves=n_waves, wave_k=wave_k,
                      committed=committed, speculated=speculated,
                      hist_rows=self.last_hist_rows,
+                     hist_tile_visits=self.last_hist_tile_visits,
+                     hist_tiles_active=self.last_hist_tiles_active,
                      hist_operand=self.hist_operand,
                      hist_int=int(self.hist_operand == "int"),
                      ici_bytes=n_waves * self._ici_bytes_per_wave,

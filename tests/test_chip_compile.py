@@ -73,6 +73,38 @@ def _mosaic_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+# What the four training cells' whole-tree programs ask (AOT, PR 35; the
+# parent's, PR 34, in brackets): temp bytes, then the scoped VMEM of the wave
+# histogram call / the root histogram call / the compaction call.
+#   2^20 x 28 float32      232,384,512 (231,934,976)   65,536 (327,680) / 65,536 (65,536) / 98,304
+#   2^20 x 28 quantized    232,512,512 (231,998,464)   65,536 (323,584) / 65,536 (65,536) / 98,304
+#   10,502,144 x 28 on four chips, a chip
+#                          710,413,824 (710,519,296)  229,376 (716,800) / 229,376 (225,280) / 131,072
+#   10,500,000 x 28 quantized
+#                        2,160,010,240 (2,159,524,864) 98,304 (356,352) / 98,304 (98,304) / 131,072
+#   2,270,296 x 136 float32
+#                        1,035,742,720 (1,035,323,392) 221,184 (4,923,392) / 221,184 (217,088) / 598,016
+# The (tile, slot) pair kernel's operand and output block are one slot
+# high, so the wave call asks what the root call asks.
+def _tree_kernels(compiled, capsys, what: str) -> list:
+    """The whole-tree program's histogram calls, after checking that it
+    holds exactly two (root and waves: one kernel, `n_slots` 1 and WAVE_K)
+    and one compaction call; prints what the program asks of the chip
+    (PERF.md keeps the readings beside the parent's)."""
+    kernels = [ln for ln in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    hist = [ln for ln in kernels if "pallas_histogram_slots_ragged" in ln]
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nAOT {what}: temp {mem.temp_size_in_bytes} arguments "
+              f"{mem.argument_size_in_bytes} output "
+              f"{mem.output_size_in_bytes}; kernels' VMEM "
+              f"{_mosaic_vmem_bytes(compiled)}")
+    assert len(hist) == 2 and len(kernels) - len(hist) == 1
+    assert sum("_pallas_compact_call" in ln for ln in kernels) == 1
+    return hist
+
+
 def _compact_dst_operand(compiled) -> str:
     """The HLO instruction that makes the compaction kernel's last operand,
     dst [1, N]."""
@@ -126,32 +158,61 @@ def _mosaic_matmuls(compiled) -> list:
     return found
 
 
+def _mosaic_vmem_bytes(compiled) -> dict:
+    """What each Mosaic kernel of the compiled program asks of VMEM: the
+    scoped allocation the TPU compiler records on its custom call."""
+    found = {}
+    for ln in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in ln:
+            continue
+        size = re.search(r'"used_scoped_memory_configs":\[\{"memory_space":'
+                         r'"1","offset":"\d+","size":"(\d+)"\}', ln)
+        found[ln.split(" = ")[0].strip().lstrip("%")] = (
+            int(size.group(1)) if size else None)
+    return found
+
+
 @pytest.mark.parametrize("policy", ["f32", "bf16", "int"])
-@pytest.mark.parametrize("n_slots", [1, WAVE_K, 2 * WAVE_K])
-def test_ragged_histogram_kernel_compiles(on_chip, n_slots, policy):
-    """1 slot is the root pass, WAVE_K the wave's smaller children, and
-    2 * WAVE_K the widest slot block the 4 MiB output budget still keeps on
-    the uint8 plane at 255 bins (256 in the kernel). f32 is the path every
-    benchmark cell runs: three bfloat16 limbs of the gradients against a
-    bfloat16 one-hot, so NO policy leaves a float32 x float32 matmul (six
-    MXU passes at Precision.HIGHEST) in the body, and the four groups that
-    pad HIGGS's 28 to 32 get no contraction."""
-    tiles = N // DEFAULT_TILE_ROWS
+@pytest.mark.parametrize("groups", [FEATURES, 136])
+@pytest.mark.parametrize("n_slots", [1, WAVE_K])
+def test_ragged_histogram_kernel_compiles(on_chip, capsys, n_slots, groups,
+                                          policy):
+    """1 slot is the root pass, WAVE_K the wave's smaller children; 28
+    groups are HIGGS's plane (one 32-group block, four of them padding), 136
+    MSLR-WEB30K's (five blocks, the last with 8 real groups). f32 is the
+    path every float benchmark cell runs: three bfloat16 limbs of the
+    gradients against a bfloat16 one-hot, so NO policy leaves a float32 x
+    float32 matmul (six MXU passes at Precision.HIGHEST) in the body. The
+    operand is ONE slot high whatever n_slots is (a pair of the grid
+    contracts its own slot's rows: 16 packed rows a limb), the result a
+    block a slot, and the groups that pad the plane get no contraction."""
+    pairs = N // DEFAULT_TILE_ROWS + 2 * n_slots
+    padded = -(-groups // GROUPS_PADDED) * GROUPS_PADDED
     compiled = pallas_histogram_slots_ragged.lower(
-        on_chip((GROUPS_PADDED, N), jnp.uint8), on_chip((3, N), jnp.float32),
-        on_chip((N,), jnp.int32), on_chip((tiles,), jnp.int32),
-        on_chip((1,), jnp.int32), num_bins=BINS, n_slots=n_slots,
-        quantized=policy == "int", f32=policy == "f32", n_groups=FEATURES,
-        interpret=False).compile()
+        on_chip((padded, N), jnp.uint8), on_chip((3, N), jnp.float32),
+        on_chip((N,), jnp.int32), on_chip((pairs,), jnp.int32),
+        on_chip((pairs,), jnp.int32), on_chip((1,), jnp.int32),
+        num_bins=BINS, n_slots=n_slots, quantized=policy == "int",
+        f32=policy == "f32", n_groups=groups, interpret=False).compile()
     assert _mosaic_calls(compiled) == 1
+    call = next(ln for ln in compiled.as_text().splitlines()
+                if 'custom_call_target="tpu_custom_call"' in ln)
+    acc = "s32" if policy == "int" else "f32"
+    assert f"= {acc}[{n_slots},{padded},3,256]" in call, call[:200]
     matmuls = _mosaic_matmuls(compiled)
-    assert len(matmuls) == FEATURES
-    sc_padded = -(-3 * n_slots // 16) * 16
-    rows = (3 if policy == "f32" else 1) * sc_padded
+    # a whole block's groups, and the last block's real ones where it is
+    # not whole (its own branch of the body)
+    last = groups - (padded // GROUPS_PADDED - 1) * GROUPS_PADDED
+    assert len(matmuls) == (GROUPS_PADDED if padded > GROUPS_PADDED
+                            else last)
+    rows = (3 if policy == "f32" else 1) * 16
     for lhs, rhs, res in matmuls:
         assert lhs == f"{rows}x{DEFAULT_TILE_ROWS}xbf16"
         assert rhs == f"256x{DEFAULT_TILE_ROWS}xbf16"  # one-hot [Bp, TN]
         assert res == f"{rows}x256xf32"
+    with capsys.disabled():
+        print(f"\nAOT histogram kernel n_slots={n_slots} groups={groups} "
+              f"{policy}: VMEM {_mosaic_vmem_bytes(compiled)}")
 
 
 @pytest.mark.parametrize("plane", [jnp.uint8, jnp.int32])
@@ -215,7 +276,8 @@ def test_dense_predict_program_fits_with_room(on_chip):
 
 @pytest.mark.slow
 @pytest.mark.parametrize("quantized", [False, True])
-def test_whole_tree_program_compiles_and_fits(on_chip, monkeypatch, quantized):
+def test_whole_tree_program_compiles_and_fits(on_chip, monkeypatch, capsys,
+                                              quantized):
     """grow_tree_on_device whole, ~45 s a compile. The learner asks
     on_tpu() — the CPU, in this process — so the test answers for it."""
     monkeypatch.setattr(device_mod, "on_tpu", lambda: True)
@@ -243,7 +305,8 @@ def test_whole_tree_program_compiles_and_fits(on_chip, monkeypatch, quantized):
         scale_vec=on_chip((3,), jnp.float32) if quantized else None,
         batch=WAVE_K, bagged=False).compile()
     # root histogram, wave histogram, wave compaction
-    assert _mosaic_calls(compiled) == 3
+    _tree_kernels(compiled, capsys,
+                  f"tree at 2^20 x 28, quantized={quantized}")
     assert _rows_on_sublanes(compiled, N) == []
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
@@ -255,7 +318,7 @@ HIGGS_ROWS = 10_500_000
 
 @pytest.mark.slow
 def test_sharded_whole_tree_program_fits_four_chips_at_higgs_full(
-        topo, monkeypatch):
+        topo, monkeypatch, capsys):
     """`tree_learner=data, num_machines=4` at Higgs's published 10,500,000
     rows (the benchmark's `higgs_full.train_4chip`): the sharded whole-tree
     program, 2,625,536 rows a shard, compiled for the four described chips
@@ -306,7 +369,7 @@ def test_sharded_whole_tree_program_fits_four_chips_at_higgs_full(
         like(learner._tables_rep, P()), like(learner._params_rep, P()),
         on(P("data"), (learner.f_pad,), jnp.bool_),
         on(P(), (3,), jnp.float32)).compile()
-    assert _mosaic_calls(compiled) == 3
+    _tree_kernels(compiled, capsys, f"sharded tree at {n_pad} x 28, a chip")
     assert _rows_on_sublanes(compiled, n_pad // 4) == []
     text = compiled.as_text()
     assert " all-reduce(" in text and " all-gather(" in text
@@ -354,14 +417,10 @@ def test_quantized_whole_tree_program_fits_one_chip_at_higgs_full(
         num_bins=learner.group_bin_padded, max_depth=cfg.max_depth,
         quantized=True, scale_vec=on_chip((3,), jnp.float32),
         batch=WAVE_K, bagged=False).compile()
-    assert _mosaic_calls(compiled) == 3
-    text = compiled.as_text()
-    kernels = [ln for ln in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in ln]
-    hist = [ln for ln in kernels if "pallas_histogram_slots_ragged" in ln]
-    assert len(hist) == 2 and len(kernels) - len(hist) == 1
-    for ln in hist:  # the kernel's result, left of the `=`
-        assert re.search(r"= s32\[32,\d+,256\]", ln), ln[:200]
+    hist = _tree_kernels(compiled, capsys, f"quantized tree at {n} x 28")
+    # the kernel's result, left of the `=`: a (1, 32, 3, 256) block a slot
+    assert sorted(re.search(r"= s32\[(\d+),32,3,256\]", ln).group(1)
+                  for ln in hist) == ["1", str(WAVE_K)]
     n_pad = -(-n // 1024) * 1024
     assert _rows_on_sublanes(compiled, n) == []
     assert _rows_on_sublanes(compiled, n_pad) == []
@@ -382,10 +441,7 @@ def test_quantized_whole_tree_program_fits_one_chip_at_higgs_full(
                   + smem.output_size_in_bytes)
     assert step_bytes < HBM_BYTES
     with capsys.disabled():
-        print(f"\nAOT quantized tree at {n} rows: temp "
-              f"{mem.temp_size_in_bytes} arguments "
-              f"{mem.argument_size_in_bytes} output "
-              f"{mem.output_size_in_bytes}; quantize step: temp "
+        print(f"AOT quantize step at {n} rows: temp "
               f"{smem.temp_size_in_bytes} arguments "
               f"{smem.argument_size_in_bytes} output "
               f"{smem.output_size_in_bytes}")
@@ -440,13 +496,11 @@ def test_whole_tree_and_gradient_programs_fit_one_chip_at_mslr(
         num_bins=learner.group_bin_padded, max_depth=cfg.max_depth,
         quantized=False, scale_vec=learner._scale_vec, batch=WAVE_K,
         bagged=False).compile()
-    assert _mosaic_calls(compiled) == 3
-    kernels = [ln for ln in compiled.as_text().splitlines()
-               if 'custom_call_target="tpu_custom_call"' in ln]
-    hist = [ln for ln in kernels if "pallas_histogram_slots_ragged" in ln]
-    assert len(hist) == 2 and len(kernels) - len(hist) == 1
-    for ln in hist:  # five blocks of 32 groups: the result, left of the `=`
-        assert re.search(r"= f32\[160,\d+,256\]", ln), ln[:200]
+    hist = _tree_kernels(compiled, capsys,
+                         f"MSLR tree at {n} x {MSLR_FEATURES}")
+    # five blocks of 32 groups: the result, left of the `=`
+    assert sorted(re.search(r"= f32\[(\d+),160,3,256\]", ln).group(1)
+                  for ln in hist) == ["1", str(WAVE_K)]
     n_pad = -(-n // 1024) * 1024
     assert _rows_on_sublanes(compiled, n) == []
     assert _rows_on_sublanes(compiled, n_pad) == []
@@ -476,10 +530,7 @@ def test_whole_tree_and_gradient_programs_fit_one_chip_at_mslr(
                   + gmem.output_size_in_bytes)
     assert tree_bytes + grad_bytes < HBM_BYTES
     with capsys.disabled():
-        print(f"\nAOT MSLR tree at {n} x {MSLR_FEATURES}: temp "
-              f"{mem.temp_size_in_bytes} arguments "
-              f"{mem.argument_size_in_bytes} output "
-              f"{mem.output_size_in_bytes}; gradient program: temp "
+        print(f"AOT MSLR gradient program: temp "
               f"{gmem.temp_size_in_bytes} arguments "
               f"{gmem.argument_size_in_bytes} output "
               f"{gmem.output_size_in_bytes}; pair slots {obj.pair_slots}")

@@ -117,6 +117,8 @@ def test_device_hist_rows_counter(rng):
     assert learner.last_hist_rows <= 4 * n, learner.last_hist_rows
     assert global_timer.counters["device_hist_rows"] == learner.last_hist_rows
     assert "device_hist_rows" in global_timer.report()
+    # the XLA body (no kernel here) walks no row tiles
+    assert learner.last_hist_tile_visits == 0
 
 
 @pytest.mark.slow  # tier-1 budget triage: heavy full-training driver, runs in the slow tier

@@ -624,6 +624,7 @@ def test_one_tree_wave_note_per_tree_carries_waves_rows_and_width(
     monkeypatch.setenv("LGBM_TPU_HIST_F32", hist_f32)
     tracing.recorder().reset()
     rows_before = global_timer.counters["device_hist_rows"]
+    visits_before = global_timer.counters["device_hist_tile_visits"]
     # the whole-tree program bakes the operand in as it is traced: no
     # program of another test may stand in for this one, nor this one's
     # for a later test's
@@ -646,8 +647,17 @@ def test_one_tree_wave_note_per_tree_carries_waves_rows_and_width(
         assert note["speculated"] == note["waves"] * note["wave_k"]
         assert note["mesh_devices"] == 1 and note["ici_bytes"] == 0
         assert note["hist_operand"] == operand
+        # the (tile, slot) pairs the histogram kernel walked: every tile
+        # that holds a histogrammed row at least once, and at most once
+        # more a slot of a wave (a range that starts in another's last
+        # tile; a slot with no rows, visited to write its zeros)
+        visits, active = note["hist_tile_visits"], note["hist_tiles_active"]
+        assert note["hist_rows"] <= 1024 * active <= 1024 * visits
+        assert visits <= active + note["speculated"]
     assert sum(n["hist_rows"] for n in notes) \
         == global_timer.counters["device_hist_rows"] - rows_before
+    assert sum(n["hist_tile_visits"] for n in notes) \
+        == global_timer.counters["device_hist_tile_visits"] - visits_before
 
 
 def _compile_notes():
