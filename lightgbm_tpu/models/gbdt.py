@@ -32,8 +32,9 @@ from ..parallel import elastic
 from ..treelearner import create_tree_learner
 from ..utils import faults, sanitize
 from ..utils.log import Log
-from ..utils.timer import (SCOPE_GRADIENTS, SCOPE_UPDATE_SCORE,
-                           SCOPE_VALID_SCORE, SPAN_EVAL_VALID, SPAN_GRADIENTS,
+from ..utils.timer import (SCOPE_GRADIENTS, SCOPE_TREE_SETUP,
+                           SCOPE_UPDATE_SCORE, SCOPE_VALID_SCORE,
+                           SPAN_EVAL_VALID, SPAN_GRADIENTS,
                            SPAN_PREDICT_CALL, SPAN_PREDICT_FETCH,
                            SPAN_PREDICT_TRAVERSE, SPAN_PREDICT_UPLOAD,
                            global_timer)
@@ -44,10 +45,14 @@ from .tree import Tree
 K_EPSILON = 1e-15
 
 
+# graftlint: disable=R6 -- the gradients outlive the call (linear-tree fitting and the health monitor read them after the tree), and no [N] input matches the [N+1, 3] output
+@jax.jit
 def _pack_gh(grad: jax.Array, hess: jax.Array) -> jax.Array:
-    """[N] grad/hess -> [N+1, 3] with count channel and zero sentinel row."""
-    gh = jnp.stack([grad, hess, jnp.ones_like(grad)], axis=1)
-    return jnp.concatenate([gh, jnp.zeros((1, 3), gh.dtype)], axis=0)
+    """[N] grad/hess -> [N+1, 3] with count channel and zero sentinel row:
+    one program a tree, part of the tree's set-up."""
+    with jax.named_scope(SCOPE_TREE_SETUP):
+        gh = jnp.stack([grad, hess, jnp.ones_like(grad)], axis=1)
+        return jnp.concatenate([gh, jnp.zeros((1, 3), gh.dtype)], axis=0)
 
 
 # score is donated: the caller replaces it with the returned array, so XLA
@@ -84,6 +89,20 @@ def _apply_split_log_to_score(score: jax.Array, rec_store: jax.Array,
         lv = lv[:L] * rate
         return score + jnp.where(
             leaf_ids >= 0, lv[jnp.clip(leaf_ids, 0, L - 1)], 0.0)
+
+
+# graftlint: disable=R6 -- the leaf ids are the tree's partition and outlive the call; the score row is left undonated as the eager expression left it (donation is ROADMAP S6's change, not the naming's)
+@jax.jit
+def _add_leaf_values_to_score(score: jax.Array, leaf_ids: jax.Array,
+                              leaf_values: jax.Array) -> jax.Array:
+    """The sync path's score update: score [N] plus each row's leaf value,
+    one float32 add a row; bagged-out rows carry leaf id -1 and add
+    nothing. leaf_values is padded to the configuration's num_leaves, so
+    one program serves every tree."""
+    with jax.named_scope(SCOPE_UPDATE_SCORE):
+        last = leaf_values.shape[0] - 1
+        return score + jnp.where(
+            leaf_ids >= 0, leaf_values[jnp.clip(leaf_ids, 0, last)], 0.0)
 
 
 @partial(jax.jit, static_argnums=(2,), donate_argnums=(0,))
@@ -558,10 +577,10 @@ class GBDT:
             # vectorized path: one gather over the device leaf-id vector
             # (bagged-out rows carry -1 and contribute nothing)
             ids = _colocate(ids_fn(), score)
-            lv = jnp.asarray(tree.leaf_value[: tree.num_leaves],
-                             dtype=jnp.float32)
-            score = score + jnp.where(
-                ids >= 0, lv[jnp.clip(ids, 0, tree.num_leaves - 1)], 0.0)
+            lv = np.zeros(max(self.config.num_leaves, tree.num_leaves),
+                          dtype=np.float32)
+            lv[: tree.num_leaves] = tree.leaf_value[: tree.num_leaves]
+            score = _add_leaf_values_to_score(score, ids, jnp.asarray(lv))
         else:
             for leaf in range(tree.num_leaves):
                 idx = part.indices(leaf)

@@ -269,6 +269,24 @@ def build_pair_tables(lefts: LeftCounts, starts: jax.Array,
     return pair_in, pair_out, pcopy, n_pairs[None]
 
 
+# What one compaction call cost, as compact_rows hands it back beside the
+# arrays: the live pairs of its list, those that are a raw block copy, those
+# that are a one-hot permute (the rest of the live pairs are skipped
+# duplicates), and the static grid length the kernel steps through.
+COMPACT_WORK_FIELDS = ("compact_pairs", "compact_copy_pairs",
+                       "compact_permute_pairs", "compact_grid_steps")
+
+
+def pair_work_counts(pcopy: jax.Array, n_pairs: jax.Array) -> jax.Array:
+    """[4] int32, COMPACT_WORK_FIELDS of one pair list (build_pair_tables'
+    pcopy and n_pairs): two masked sums over the table, nothing per row."""
+    live = jnp.arange(pcopy.shape[0], dtype=jnp.int32) < n_pairs[0]
+    return jnp.stack([
+        n_pairs[0], jnp.sum(live & (pcopy == 1), dtype=jnp.int32),
+        jnp.sum(live & (pcopy == 0), dtype=jnp.int32),
+        jnp.int32(pcopy.shape[0])])
+
+
 def _limbs(x_int: jax.Array, n: int) -> jax.Array:
     """Split int32 values [c, T] into n 8-bit limbs stacked along the rows,
     [n*c, T] (each limb <= 255: exact as a bf16 matmul operand)."""
@@ -389,10 +407,12 @@ def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,
                  lefts: LeftCounts, starts: jax.Array, counts: jax.Array,
                  valid: jax.Array, *, tile: int = COMPACT_TILE,
                  use_pallas: bool = True, interpret: bool = False
-                 ) -> Tuple[jax.Array, jax.Array]:
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Apply the forward permutation dst [N] to bins_p [Gp, N] (uint8, or
     int32 with values < 2**16) and row_p [rc, N] (f32 payload, one row per
     channel, moved bit-exactly). The output bin plane keeps bins_p's dtype.
+    Returns (bins, rows, work): work [4] int32 is COMPACT_WORK_FIELDS of the
+    kernel call, zeros on the XLA path.
 
     dst and lefts are range_partition_dst's, for the same K ranges
     (starts, counts, valid) and the same tile.
@@ -408,7 +428,7 @@ def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,
             bins_p, unique_indices=True)
         row_o = jnp.zeros_like(row_p).at[:, dst].set(
             row_p, unique_indices=True)
-        return bins_o, row_o
+        return bins_o, row_o, jnp.zeros(len(COMPACT_WORK_FIELDS), jnp.int32)
     if row_p.shape[0] % 8:
         raise ValueError("compaction kernel needs the payload's channel "
                          f"count padded to 8, got {row_p.shape[0]}")
@@ -426,5 +446,7 @@ def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,
         perfmodel.note_dispatch("compact", _pallas_compact_call,
                                 bins_p, row_f32, dst_i32, pair_in, pair_out,
                                 is_copy, n_pairs, tile, interpret)
-    return _pallas_compact_call(bins_p, row_f32, dst_i32, pair_in, pair_out,
-                                is_copy, n_pairs, tile, interpret)
+    bins_o, row_o = _pallas_compact_call(
+        bins_p, row_f32, dst_i32, pair_in, pair_out, is_copy, n_pairs, tile,
+        interpret)
+    return bins_o, row_o, pair_work_counts(is_copy, n_pairs)

@@ -53,8 +53,8 @@ from ..treelearner.serial import (SerialTreeLearner, _LeafState,
                                   device_growth_applies)
 from ..utils import sanitize
 from ..utils.log import Log
-from ..utils.timer import (SPAN_GATHER_LEAF_IDS, SPAN_SHARD_INPUTS,
-                           global_timer)
+from ..utils.timer import (SCOPE_FINISH, SPAN_GATHER_LEAF_IDS,
+                           SPAN_SHARD_INPUTS, global_timer)
 from .dist import (host_value, init_distributed, put_global, put_global_tree,
                    put_replicated)
 from .mesh import data_mesh, padded_row_count
@@ -671,16 +671,16 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
                 self._vslot_arg, self._scan_meta_arg, self._tables_rep,
                 self._params_rep, fmask_sh, scale_rep,
                 *self._extra_grow_args())
-        rec_store, leaf_id, _, hist_rows, n_waves, hist_tiles = out[:6]
+        rec_store, leaf_id, _, hist_rows, n_waves, work_counts = out[:6]
         self._note_grow_extras(out[6:])
         with global_timer.scope(SPAN_GATHER_LEAF_IDS):
             leaf_id = self._gather_leaf_ids(leaf_id)
-        for arr in (rec_store, leaf_id, hist_rows, n_waves, hist_tiles):
+        for arr in (rec_store, leaf_id, hist_rows, n_waves, work_counts):
             start = getattr(arr, "copy_to_host_async", None)
             if start is not None:
                 start()
         return _PendingTree(Tree(cfg.num_leaves), rec_store, leaf_id,
-                            hist_rows, n_waves, hist_tiles, n_bag,
+                            hist_rows, n_waves, work_counts, n_bag,
                             wave_k=self.wave_k)
 
     def _gather_leaf_ids(self, leaf_id: jax.Array) -> jax.Array:
@@ -690,7 +690,7 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
         enqueued behind the tree's program and block nothing. A multi-
         process mesh keeps the sharded array: no one process can address
         it whole, and models/gbdt.py `_colocate` allgathers it."""
-        leaf_id = leaf_id[:self.num_data]
+        leaf_id = _without_row_padding(leaf_id, self.num_data)
         if leaf_id.is_fully_addressable:
             leaf_id = jax.device_put(leaf_id, self.mesh.devices.flat[0])
         return leaf_id
@@ -702,6 +702,15 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
         # (sharded scatter-adds may reorder the f32 accumulation)
         super()._renew_quantized_leaves_device(
             tree, jnp.asarray(np.asarray(leaf_id)))
+
+
+@partial(jax.jit, static_argnames=("num_data",))
+def _without_row_padding(leaf_id: jax.Array, num_data: int) -> jax.Array:
+    """The sharded tree's [n_pad] leaf ids cut to the real rows: the slice
+    an eager `leaf_id[:n]` dispatches, as one program under the tree's
+    finishing scope (the output's placement is the compiler's, as it was)."""
+    with jax.named_scope(SCOPE_FINISH):
+        return leaf_id[:num_data]
 
 
 class VotingDataParallelTreeLearner(DeviceDataParallelTreeLearner):

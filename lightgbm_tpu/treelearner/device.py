@@ -61,6 +61,7 @@ from jax import shard_map
 
 from ..models.sample_strategy import DeviceBag
 from ..models.tree import Tree
+from ..ops.compact_pallas import COMPACT_WORK_FIELDS
 from ..ops.histogram import build_histogram
 from ..ops.split import (SPLIT_FIELDS, ScanMeta, SplitInfo, find_best_split,
                          fix_feature_hist, gather_feature_hist_raw,
@@ -87,6 +88,17 @@ STORE = REC + 4
 # lost on the chip (PERF.md, PR 29: trees at K = 16 cost 1.18-1.47 s and at
 # K = 8 1.34-1.42 s against 1.12-1.37 s at 21, 4.19 M rows) and is gone.
 WAVE_K = 21
+
+# What a tree handed its two Mosaic kernels, summed over the tree's calls
+# (root pass, initial compaction and every wave): the one int32 vector
+# `_grow_impl` carries through the wave loop, and the fields of the tree's
+# `tree_wave` note. The (row tile, slot) pairs the histogram kernel walked,
+# the distinct tiles among them and the static pair-axis length T + 2K of
+# its grid; the compaction kernel's COMPACT_WORK_FIELDS. All zeros where
+# the XLA bodies run instead of the kernels.
+HIST_WORK_FIELDS = ("hist_tile_visits", "hist_tiles_active",
+                    "hist_grid_steps")
+WORK_FIELDS = HIST_WORK_FIELDS + COMPACT_WORK_FIELDS
 
 
 class FeatureTables(NamedTuple):
@@ -303,8 +315,9 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
     def ranged_hist(bins_c, row_c, slot, n_slots, starts, ends, valid):
         """[G, B, n_slots*CH] histogram of the rows inside the given
         leaf-contiguous ranges, one a slot (slot must be the dump value
-        outside), and [2] int32: the (tile, slot) pairs the kernel walked
-        and the distinct tiles among them (zeros off the kernel path).
+        outside), and [3] int32, HIST_WORK_FIELDS of the call: the (tile,
+        slot) pairs the kernel walked, the distinct tiles among them and
+        the grid's static pair-axis length (zeros off the kernel path).
         bins_c/row_c passed explicitly: inside the wave loop they are the
         CARRY arrays, not the pre-loop closure values."""
         with jax.named_scope(SCOPE_HIST):
@@ -316,14 +329,17 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
                     bins_c, ghc, slot, tiles, slots, n_pairs, num_bins,
                     n_slots, quantized=quantized, f32=hist_force_f32(),
                     n_groups=G, interpret=interp)
-                return h, jnp.concatenate([n_pairs, n_active])
+                return h, jnp.concatenate([
+                    n_pairs, n_active,
+                    jnp.full(1, tiles.shape[0], jnp.int32)])
             # XLA fallback: flat slot-expanded build over the full row set
             col_slot = jnp.arange(n_slots * CH, dtype=jnp.int32) // CH
             ghK = jnp.where(slot[:, None] == col_slot[None, :],
                             jnp.tile(ghc.T, (1, n_slots)), 0.0)
             h = build_histogram(bins_c[:G], ghK, num_bins)
             # quantized: exact ints below 2**24
-            return h.astype(pool_dtype), jnp.zeros(2, jnp.int32)
+            return h.astype(pool_dtype), jnp.zeros(len(HIST_WORK_FIELDS),
+                                                   jnp.int32)
 
     if data_par:
         gidx, vslot, sm = meta.gather_index, meta.valid_slot, meta.scan
@@ -499,6 +515,8 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
 
     # --- initial compaction: in-bag rows to the front, root = [0, n_in)
     with jax.named_scope(SCOPE_TREE_SETUP):
+        # the kernel work of this set-up: its one compaction when bagged
+        compact_work = jnp.zeros(len(COMPACT_WORK_FIELDS), jnp.int32)
         if bagged:
             in_bag = leaf_id0 == 0
             n_in = in_bag.sum().astype(jnp.int32)
@@ -509,7 +527,7 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             dst0, _, lefts0 = range_partition_dst(
                 in_bag, jnp.ones((1, Np), bool), jnp.ones(Np, bool), *whole,
                 COMPACT_TILE)
-            bins_p, row_p = compact_rows(
+            bins_p, row_p, compact_work = compact_rows(
                 bins_p, row_p, dst0, lefts0, *whole, tile=COMPACT_TILE,
                 use_pallas=use_kernels, interpret=interp)
         elif row_sharded:
@@ -525,12 +543,13 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
 
     # --- root histogram through the ragged slots kernel (satellite: the
     # thin-CH masked dot cost ~183 ms/tree; this path is O(n_in) and warm)
-    root_hist, hist_tiles = ranged_hist(
+    root_hist, hist_work = ranged_hist(
         bins_p, row_p, jnp.where(pos < n_in, 0, 1), 1,
         jnp.zeros(1, jnp.int32), n_in[None], jnp.ones(1, bool))
-    # instrumentation: rows histogrammed this tree, and the tile visits
-    # (pairs walked, distinct tiles) the kernel took for them
+    # instrumentation: rows histogrammed this tree, and what the two
+    # kernels were handed for them (WORK_FIELDS)
     hist_rows = n_in
+    work_counts = jnp.concatenate([hist_work, compact_work])
 
     with jax.named_scope(SCOPE_TREE_SETUP):
         depth = jnp.zeros(L + 1, jnp.int32)
@@ -588,15 +607,15 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
     def wave(carry):
         if voting:
             (bins_p, row_p, start, count, depth, leaf_best, rec_store, pool,
-             n_cur, t, hist_rows, hist_tiles, tpool, count_g, miss,
+             n_cur, t, hist_rows, work_counts, tpool, count_g, miss,
              n_waves) = carry
         elif data_par:
             (bins_p, row_p, start, count, depth, leaf_best, rec_store, pool,
-             n_cur, t, hist_rows, hist_tiles, tpool, count_g,
+             n_cur, t, hist_rows, work_counts, tpool, count_g,
              n_waves) = carry
         else:
             (bins_p, row_p, start, count, depth, leaf_best, rec_store, pool,
-             n_cur, t, hist_rows, hist_tiles, n_waves) = carry
+             n_cur, t, hist_rows, work_counts, n_waves) = carry
         n_waves = n_waves + 1  # wave-efficiency telemetry (finalize())
         with jax.named_scope(SCOPE_SELECT):
             gains = leaf_best[:L, 0]
@@ -660,7 +679,7 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             # uncommitted leaf's range is merely reordered, still contiguous)
             dst, nl_k, lefts = range_partition_dst(
                 go_left, match, kvalid, s_k, c_k, sel_ok, COMPACT_TILE)
-            bins_p, row_p = compact_rows(
+            bins_p, row_p, compact_work = compact_rows(
                 bins_p, row_p, dst, lefts, s_k, c_k, sel_ok,
                 tile=COMPACT_TILE, use_pallas=use_kernels, interpret=interp)
 
@@ -689,9 +708,10 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             slotS = jnp.where(inS.any(axis=0),
                               jnp.argmax(inS, axis=0).astype(jnp.int32), K)
             hist_rows = hist_rows + jnp.sum(jnp.where(sel_ok, sc_k, 0))
-            histS, wave_tiles = ranged_hist(bins_p, row_p, slotS, K, ss_k,
-                                            se_k, sel_ok & (sc_k > 0))
-            hist_tiles = hist_tiles + wave_tiles
+            histS, hist_work = ranged_hist(bins_p, row_p, slotS, K, ss_k,
+                                           se_k, sel_ok & (sc_k > 0))
+            work_counts = work_counts + jnp.concatenate(
+                [hist_work, compact_work])
             histS_k = jnp.moveaxis(
                 histS.reshape(G, num_bins, K, CH), 2, 0)  # [K, G, B, CH]
         with jax.named_scope(SCOPE_SCAN):
@@ -852,14 +872,14 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
                 jnp.where(is_right, rowsP[1], row_p[LEAF_ROW]))
         if voting:
             return (bins_p, row_p, start, count, depth, leaf_best,
-                    rec_store, pool, n_cur, t, hist_rows, hist_tiles, tpool,
+                    rec_store, pool, n_cur, t, hist_rows, work_counts, tpool,
                     count_g, miss, n_waves)
         if data_par:
             return (bins_p, row_p, start, count, depth, leaf_best,
-                    rec_store, pool, n_cur, t, hist_rows, hist_tiles, tpool,
+                    rec_store, pool, n_cur, t, hist_rows, work_counts, tpool,
                     count_g, n_waves)
         return (bins_p, row_p, start, count, depth, leaf_best, rec_store,
-                pool, n_cur, t, hist_rows, hist_tiles, n_waves)
+                pool, n_cur, t, hist_rows, work_counts, n_waves)
 
     def cond(carry):
         with jax.named_scope(SCOPE_SELECT):
@@ -867,7 +887,7 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             return (t < L - 1) & (jnp.max(leaf_best[:L, 0]) > 0)
 
     carry = (bins_p, row_p, start, count, depth, leaf_best, rec_store, pool,
-             jnp.int32(1), jnp.int32(0), hist_rows, hist_tiles)
+             jnp.int32(1), jnp.int32(0), hist_rows, work_counts)
     if row_sharded:
         carry = carry + (tpool, count_g)
     if voting:
@@ -875,13 +895,13 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
     carry = carry + (jnp.int32(0),)  # n_waves, last so indices above hold
     if L > 1:
         carry = jax.lax.while_loop(cond, wave, carry)
-    row_p, rec_store, n_cur, hist_rows, hist_tiles = (
+    row_p, rec_store, n_cur, hist_rows, work_counts = (
         carry[1], carry[6], carry[8], carry[10], carry[11])
     n_waves = carry[-1]
     if row_sharded:
         with jax.named_scope(SCOPE_ALLREDUCE):
             hist_rows = jax.lax.psum(hist_rows, "data")
-            hist_tiles = jax.lax.psum(hist_tiles, "data")
+            work_counts = jax.lax.psum(work_counts, "data")
     with jax.named_scope(SCOPE_FINISH):
         # undo the permutation without a TPU scatter: sort leaf ids by the
         # original-position row (both exact small ints in f32)
@@ -889,7 +909,7 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
             row_p[POS_ROW].astype(jnp.int32),
             row_p[LEAF_ROW].astype(jnp.int32))
     out = (rec_store[:-1], leaf_sorted[:N], n_cur, hist_rows, n_waves,
-           hist_tiles)
+           work_counts)
     return out + (carry[14],) if voting else out
 
 
@@ -935,8 +955,8 @@ def grow_tree_on_device(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
     Returns (rec_store [L-1, STORE], leaf_id [N] in ORIGINAL row order,
     num_leaves_final, hist_rows — rows histogrammed, the perf counter,
     n_waves — while_loop trips, for the committed-vs-speculated telemetry,
-    hist_tiles [2] — the (row tile, slot) pairs the histogram kernel walked
-    for those rows, root and waves, and the distinct tiles among them).
+    work_counts [len(WORK_FIELDS)] int32 — what the two Mosaic kernels
+    were handed over the tree's calls: pairs, tiles and grid steps).
     """
     return _grow_impl(bins, gh, leaf_id0, meta, tables, params, feature_mask,
                       scale_vec, num_leaves=num_leaves, num_bins=num_bins,
@@ -985,8 +1005,8 @@ def make_sharded_grow_fn(mesh, *, num_leaves: int, num_bins: int,
     it is ignored). Categorical splits are not supported here (the factory
     routes categorical configs to the host-driven learners). Returns the
     same (rec_store, leaf_id [Np] global original order, n_cur, hist_rows,
-    n_waves, hist_tiles) as grow_tree_on_device; all but leaf_id are
-    replicated (hist_rows and hist_tiles summed over the row shards).
+    n_waves, work_counts) as grow_tree_on_device; all but leaf_id are
+    replicated (hist_rows and work_counts summed over the row shards).
     """
     from jax.sharding import PartitionSpec as P
 
@@ -1105,7 +1125,7 @@ class _PendingTree(NamedTuple):
     leaf_id: jax.Array
     hist_rows: jax.Array
     n_waves: jax.Array
-    hist_tiles: jax.Array  # [2]: tile visits, distinct tiles
+    work_counts: jax.Array  # [len(WORK_FIELDS)] int32
     n_bag: int
     wave_k: int  # wave width this tree was dispatched with
 
@@ -1236,8 +1256,11 @@ class DeviceTreeLearner(SerialTreeLearner):
                 bagged=bag_indices is not None)
         with global_timer.scope("tree_device"):
             # bins_dev is COPIED per tree: grow_tree_on_device donates its
-            # first three args (gh and leaf_id0 are already fresh buffers)
-            rec_store, leaf_id, _, hist_rows, n_waves, hist_tiles = grow(
+            # first three args (gh and leaf_id0 are already fresh buffers).
+            # The copy stays eager: inside a jitted function jnp.copy lowers
+            # to nothing and the compiler's own copy of the result carries
+            # no scope either (PERF.md, PR 36)
+            rec_store, leaf_id, _, hist_rows, n_waves, work_counts = grow(
                 jnp.copy(self.bins_dev), gh, leaf_id0, self.meta,
                 self.tables, self.params_dev, fmask, num_leaves,
                 self.group_bin_padded,
@@ -1247,12 +1270,12 @@ class DeviceTreeLearner(SerialTreeLearner):
         # start the device->host copies without blocking; finalize() (maybe
         # a full iteration later, under the async pipeline) pays no wait if
         # the transfer already landed
-        for arr in (rec_store, leaf_id, hist_rows, n_waves, hist_tiles):
+        for arr in (rec_store, leaf_id, hist_rows, n_waves, work_counts):
             start = getattr(arr, "copy_to_host_async", None)
             if start is not None:
                 start()
         return _PendingTree(Tree(num_leaves), rec_store, leaf_id, hist_rows,
-                            n_waves, hist_tiles, n_bag, wave_k=self.wave_k)
+                            n_waves, work_counts, n_bag, wave_k=self.wave_k)
 
     def finalize(self, pending: _PendingTree) -> Tree:
         cfg = self.config
@@ -1262,10 +1285,8 @@ class DeviceTreeLearner(SerialTreeLearner):
         leaf_id = pending.leaf_id
         self.last_hist_rows = int(pending.hist_rows)
         global_timer.add_count("device_hist_rows", self.last_hist_rows)
-        self.last_hist_tile_visits, self.last_hist_tiles_active = (
-            int(v) for v in np.asarray(pending.hist_tiles))
-        global_timer.add_count("device_hist_tile_visits",
-                               self.last_hist_tile_visits)
+        self.last_work = dict(zip(
+            WORK_FIELDS, (int(v) for v in np.asarray(pending.work_counts))))
 
         counts: Dict[int, int] = {0: int(pending.n_bag)}
         for t in range(rec_np.shape[0]):
@@ -1323,8 +1344,7 @@ class DeviceTreeLearner(SerialTreeLearner):
         tracing.note("tree_wave", waves=n_waves, wave_k=wave_k,
                      committed=committed, speculated=speculated,
                      hist_rows=self.last_hist_rows,
-                     hist_tile_visits=self.last_hist_tile_visits,
-                     hist_tiles_active=self.last_hist_tiles_active,
+                     **self.last_work,
                      hist_operand=self.hist_operand,
                      hist_int=int(self.hist_operand == "int"),
                      ici_bytes=n_waves * self._ici_bytes_per_wave,

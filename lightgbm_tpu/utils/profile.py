@@ -64,10 +64,3 @@ def _check_writable(target: str) -> None:
                   "ancestor: %s) — fix LGBM_TPU_PROFILE or the trace_dir "
                   "argument", target, probe or "<none>")
 
-
-def annotate(name: str):
-    """Named sub-span inside a capture (jax.profiler.TraceAnnotation), for
-    marking phases (binning, tree N, eval) in the device timeline."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)

@@ -9,8 +9,8 @@ import jax
 import jax.numpy as jnp
 
 from lightgbm_tpu.ops.compact_pallas import (
-    COMPACT_TILE, build_pair_tables, compact_rows, exclusive_cumsum,
-    max_pairs_bound, range_partition_dst)
+    COMPACT_TILE, COMPACT_WORK_FIELDS, build_pair_tables, compact_rows,
+    exclusive_cumsum, max_pairs_bound, pair_work_counts, range_partition_dst)
 
 
 def _np_dst(go_left, ranges, n):
@@ -224,7 +224,7 @@ def test_compact_pallas_bit_exact(rng, name, ranges, tile):
     row[3] = np.arange(n)  # a perm-style integer row rides along
     # bit patterns a float accumulate would not carry: the kernel ORs bits
     row[0, ::5], row[0, 1::5] = -0.0, 1e-39
-    ours_b, ours_r = compact_rows(
+    ours_b, ours_r, _ = compact_rows(
         jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst), lefts, *args,
         tile=tile, use_pallas=True, interpret=True)
     ref_b, ref_r = _permuted(bins, row, dst)
@@ -248,7 +248,7 @@ def test_pair_list_holds_a_range_spanning_many_tiles(rng):
     bins = rng.randint(0, 256, size=(gp, n)).astype(np.uint8)
     row = rng.randn(rc, n).astype(np.float32)
     row[3] = np.arange(n)
-    ours_b, ours_r = compact_rows(
+    ours_b, ours_r, _ = compact_rows(
         jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst), lefts, *args,
         tile=tile, use_pallas=True, interpret=True)
     ref_b, ref_r = _permuted(bins, row, dst)
@@ -266,13 +266,13 @@ def test_compact_pallas_uint8_plane(rng, name, ranges):
     dst, _, lefts, args = _partition(go_left, ranges, n, tile)
     bins8 = rng.randint(0, 256, size=(gp, n)).astype(np.uint8)
     row = rng.randn(rc, n).astype(np.float32)
-    b8, r8 = compact_rows(
+    b8, r8, _ = compact_rows(
         jnp.asarray(bins8), jnp.asarray(row), jnp.asarray(dst), lefts, *args,
         tile=tile, use_pallas=True, interpret=True)
     assert np.asarray(b8).dtype == np.uint8
     ref_b, _ = _permuted(bins8, row, dst)
     np.testing.assert_array_equal(np.asarray(b8), ref_b)
-    b32, r32 = compact_rows(
+    b32, r32, _ = compact_rows(
         jnp.asarray(bins8.astype(np.int32)), jnp.asarray(row),
         jnp.asarray(dst), lefts, *args, tile=tile, use_pallas=True,
         interpret=True)
@@ -289,7 +289,7 @@ def test_compact_xla_fallback_uint8(rng):
     dst, _, lefts, args = _partition(go_left, ranges, n, COMPACT_TILE)
     bins = rng.randint(0, 256, size=(gp, n)).astype(np.uint8)
     row = rng.randn(3, n).astype(np.float32)
-    ours_b, _ = compact_rows(
+    ours_b, *_ = compact_rows(
         jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst), lefts, *args,
         use_pallas=False)
     assert np.asarray(ours_b).dtype == np.uint8
@@ -304,7 +304,7 @@ def test_compact_xla_fallback_exact(rng):
     dst, _, lefts, args = _partition(go_left, ranges, n, COMPACT_TILE)
     bins = rng.randint(0, 256, size=(gp, n)).astype(np.int32)
     row = rng.randn(rc, n).astype(np.float32)
-    ours_b, ours_r = compact_rows(
+    ours_b, ours_r, _ = compact_rows(
         jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst), lefts, *args,
         use_pallas=False)
     ref_b, ref_r = _permuted(bins, row, dst)
@@ -324,7 +324,7 @@ def test_compact_one_sided(rng):
         bins = np.arange(2 * n, dtype=np.int32).reshape(2, n) % 256
         bins = np.vstack([bins] * 4)  # gp=8
         row = np.arange(n * 8, dtype=np.float32).reshape(8, n)
-        ob, orr = compact_rows(
+        ob, orr, _ = compact_rows(
             jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst), lefts,
             *args, tile=tile, use_pallas=True, interpret=True)
         np.testing.assert_array_equal(np.asarray(ob), bins)
@@ -391,3 +391,55 @@ def test_pair_list_overflow_is_loud_under_sanitize(rng, monkeypatch):
     monkeypatch.setenv("LGBM_TPU_SANITIZE", "1")
     with pytest.raises(Exception, match="the truncated list drops rows"):
         jax.block_until_ready(build_pair_tables(lefts, *args, tile))
+
+
+# ------------------------------------------------------------- the pair counts
+# What compact_rows hands back beside the arrays (COMPACT_WORK_FIELDS): the
+# tree program sums it into the `tree_wave` note, and the chip benchmark reads
+# the kernel's time per pair from it.
+
+@pytest.mark.parametrize("name,ranges,valid,p_left", GLUE_CASES,
+                         ids=[c[0] for c in GLUE_CASES])
+def test_pair_counts_are_a_recount_of_the_pair_tables(rng, name, ranges,
+                                                      valid, p_left):
+    n, tile = 2048, 256
+    go_left = rng.rand(n) < p_left
+    dst, _, lefts, args = _partition(go_left, ranges, n, tile, valid)
+    _, _, pcopy, n_pairs = build_pair_tables(lefts, *args, tile)
+    bins = rng.randint(0, 256, size=(32, n)).astype(np.uint8)
+    row = rng.randn(8, n).astype(np.float32)
+    *_, work = compact_rows(
+        jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst), lefts, *args,
+        tile=tile, use_pallas=True, interpret=True)
+    assert work.shape == (len(COMPACT_WORK_FIELDS),) and work.dtype == jnp.int32
+    got = dict(zip(COMPACT_WORK_FIELDS, np.asarray(work).tolist()))
+    np.testing.assert_array_equal(
+        np.asarray(work), np.asarray(pair_work_counts(pcopy, n_pairs)))
+    live = np.asarray(pcopy)[:int(n_pairs[0])]
+    assert got["compact_pairs"] == live.size
+    assert got["compact_copy_pairs"] == int((live == 1).sum())
+    assert got["compact_permute_pairs"] == int((live == 0).sum())
+    duplicates = int((live == 2).sum())
+    assert got["compact_pairs"] == (got["compact_copy_pairs"]
+                                    + got["compact_permute_pairs"]
+                                    + duplicates)
+    assert got["compact_grid_steps"] == max_pairs_bound(n // tile,
+                                                        2 * len(ranges))
+    assert 0 < got["compact_pairs"] <= got["compact_grid_steps"]
+    # every tile is written once at least: a raw copy where no range
+    # touches it, a permute where one does
+    touched = _match(ranges, n, valid).any(axis=0).reshape(-1, tile).any(
+        axis=1)
+    assert got["compact_copy_pairs"] == int((~touched).sum())
+    assert got["compact_permute_pairs"] >= int(touched.sum())
+
+
+def test_the_xla_fallback_counts_no_pair(rng):
+    n = 1024
+    go_left = rng.rand(n) < 0.3
+    dst, _, lefts, args = _partition(go_left, [(100, 500)], n, COMPACT_TILE)
+    *_, work = compact_rows(
+        jnp.zeros((4, n), jnp.uint8), jnp.zeros((3, n), jnp.float32),
+        jnp.asarray(dst), lefts, *args, use_pallas=False)
+    np.testing.assert_array_equal(np.asarray(work),
+                                  np.zeros(len(COMPACT_WORK_FIELDS)))

@@ -12,7 +12,7 @@ from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.dataset import Dataset as CoreDataset
 from lightgbm_tpu.models.gbdt import GBDT
 from lightgbm_tpu.objectives import create_objective
-from lightgbm_tpu.treelearner.device import DeviceTreeLearner
+from lightgbm_tpu.treelearner.device import WORK_FIELDS, DeviceTreeLearner
 from lightgbm_tpu.treelearner.serial import SerialTreeLearner
 
 
@@ -117,8 +117,9 @@ def test_device_hist_rows_counter(rng):
     assert learner.last_hist_rows <= 4 * n, learner.last_hist_rows
     assert global_timer.counters["device_hist_rows"] == learner.last_hist_rows
     assert "device_hist_rows" in global_timer.report()
-    # the XLA body (no kernel here) walks no row tiles
-    assert learner.last_hist_tile_visits == 0
+    # the XLA bodies (no kernel here) walk no row tile and move no pair
+    assert set(learner.last_work) == set(WORK_FIELDS)
+    assert not any(learner.last_work.values())
 
 
 @pytest.mark.slow  # tier-1 budget triage: heavy full-training driver, runs in the slow tier

@@ -24,6 +24,8 @@ from lightgbm_tpu.models import gbdt as gbdt_mod
 from lightgbm_tpu.models.gbdt import GBDT
 from lightgbm_tpu.objectives import create_objective
 from lightgbm_tpu.ops import predict as predict_mod
+from lightgbm_tpu.ops.compact_pallas import COMPACT_TILE, max_pairs_bound
+from lightgbm_tpu.ops.hist_pallas import DEFAULT_TILE_ROWS
 from lightgbm_tpu.parallel import learners as learners_mod
 from lightgbm_tpu.parallel.learners import DeviceDataParallelTreeLearner
 from lightgbm_tpu.treelearner import device as device_mod
@@ -198,6 +200,110 @@ def test_score_update_program_carries_its_scope():
         jnp.zeros(n, jnp.int32), jnp.float32(0.1),
         num_leaves=L).as_text(debug_info=True)
     assert timer.SCOPE_UPDATE_SCORE in text
+
+
+# The sync path's per-tree dispatches outside the whole-tree program: each an
+# eager sequence until PR 36, whose device time ran under no scope.
+PER_TREE_PROGRAMS = {
+    "sync_score_update": (
+        lambda: gbdt_mod._add_leaf_values_to_score.lower(
+            jnp.zeros(64, jnp.float32), jnp.zeros(64, jnp.int32),
+            jnp.zeros(15, jnp.float32)), timer.SCOPE_UPDATE_SCORE),
+    "pack_gh": (
+        lambda: gbdt_mod._pack_gh.lower(
+            jnp.zeros(64, jnp.float32), jnp.zeros(64, jnp.float32)),
+        timer.SCOPE_TREE_SETUP),
+    "leaf_ids_without_padding": (
+        lambda: learners_mod._without_row_padding.lower(
+            jnp.zeros(64, jnp.int32), num_data=60), timer.SCOPE_FINISH),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PER_TREE_PROGRAMS))
+def test_a_per_tree_dispatch_is_one_program_under_a_scope(name):
+    lower, scope = PER_TREE_PROGRAMS[name]
+    assert scope in lower().as_text(debug_info=True)
+
+
+def _scoped_copy(plane):
+    with jax.named_scope(timer.SCOPE_TREE_SETUP):
+        return jnp.copy(plane)
+
+
+def _eager_score_update(score, ids, leaf_values, num_leaves):
+    """The sync path's update as it was dispatched until PR 36, primitive
+    by primitive, on the tree's own leaf count."""
+    lv = jnp.asarray(leaf_values[:num_leaves], dtype=jnp.float32)
+    return score + jnp.where(
+        ids >= 0, lv[jnp.clip(ids, 0, num_leaves - 1)], 0.0)
+
+
+def test_sync_trees_score_bit_equal_to_the_eager_update_and_compile_once(
+        monkeypatch):
+    """learning_rate 0.1 is not exact in float32, so every tree takes the
+    sync path (as in every benchmark cell); bagging leaves rows with leaf
+    id -1, which add nothing."""
+    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
+    X, y = _data(1500)
+    cfg = Config(dict(PARAMS, learning_rate=0.1, bagging_fraction=0.7,
+                      bagging_freq=1))
+    ds = CoreDataset.from_matrix(X, label=y, config=cfg)
+    bst = GBDT(cfg, ds, create_objective(cfg.objective, cfg))
+    bst.tree_learner = DeviceTreeLearner(cfg, ds)
+    assert not bst._async_enabled()
+    program = gbdt_mod._add_leaf_values_to_score
+    calls = []
+
+    def recorded(score, ids, lv):
+        out = program(score, ids, lv)
+        calls.append((score, ids, np.asarray(lv), out))
+        return out
+
+    monkeypatch.setattr(gbdt_mod, "_add_leaf_values_to_score", recorded)
+    for it in range(3):
+        compiled = [program._cache_size(), gbdt_mod._pack_gh._cache_size()]
+        notes = len(_compile_notes())
+        assert not bst.train_one_iter()
+        if it == 2:  # one program each, whatever the tree's leaf count
+            assert [program._cache_size(),
+                    gbdt_mod._pack_gh._cache_size()] == compiled
+            assert len(_compile_notes()) == notes
+    assert len(calls) == 3
+    for (score, ids, lv, out), tree in zip(calls, bst.models):
+        assert lv.shape == (cfg.num_leaves,) and lv.dtype == np.float32
+        bagged_out = np.asarray(ids) < 0
+        assert 0 < bagged_out.sum() < bagged_out.size
+        want = _eager_score_update(score, ids, lv, tree.num_leaves)
+        np.testing.assert_array_equal(np.asarray(out).view(np.uint32),
+                                      np.asarray(want).view(np.uint32))
+        np.testing.assert_array_equal(np.asarray(out)[bagged_out],
+                                      np.asarray(score)[bagged_out])
+        assert (np.asarray(out) != np.asarray(score)).any()
+
+
+def test_the_plane_survives_the_tree_that_donates_its_copy(monkeypatch):
+    """The whole-tree program donates its plane argument, so the learner
+    hands it a per-tree copy. The copy stays an eager jnp.copy: in a jitted
+    function it lowers to nothing, and the copy the compiler then makes of
+    the result has no name stack for a scope to be in."""
+    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
+    bst, _ = _booster()
+    plane = bst.tree_learner.bins_dev
+    was = np.asarray(plane).copy()
+    handed = []
+    grow = device_mod.grow_tree_on_device
+    monkeypatch.setattr(
+        device_mod, "grow_tree_on_device",
+        lambda bins, *a, **k: handed.append(bins) or grow(bins, *a, **k))
+    scoped = jax.jit(_scoped_copy).lower(plane).as_text(debug_info=True)
+    assert timer.SCOPE_TREE_SETUP not in scoped
+    assert not bst.train_one_iter()
+    # a second buffer, not the resident one handed over
+    assert handed[0] is not plane
+    assert handed[0].is_deleted() or (
+        handed[0].unsafe_buffer_pointer() != plane.unsafe_buffer_pointer())
+    assert bst.tree_learner.bins_dev is plane and not plane.is_deleted()
+    np.testing.assert_array_equal(np.asarray(plane), was)
 
 
 @pytest.mark.parametrize("stochastic", [True, False])
@@ -460,10 +566,16 @@ def test_a_sharded_tree_opens_its_two_host_spans_once_each(spans,
     assert len(notes) == 3
     per_wave = global_timer.counters["device_ici_bytes_per_wave"]
     assert per_wave > 0
+    shard_rows = learner.n_pad // 4
     for note in notes:
         assert note["mesh_devices"] == 4
         assert note["ici_bytes"] == note["waves"] * per_wave
         assert note["wave_k"] == min(learner.wave, PARAMS["num_leaves"])
+        # the kernels' work is the four shards' summed: every shard steps
+        # through its own grids, whatever rows of a leaf it holds
+        for field, steps in _grid_steps(shard_rows, note, shards=4).items():
+            assert note[field] == steps, field
+        _check_work_fields(note)
 
 
 def test_a_one_chip_tree_opens_neither_sharded_span(spans, monkeypatch):
@@ -624,7 +736,6 @@ def test_one_tree_wave_note_per_tree_carries_waves_rows_and_width(
     monkeypatch.setenv("LGBM_TPU_HIST_F32", hist_f32)
     tracing.recorder().reset()
     rows_before = global_timer.counters["device_hist_rows"]
-    visits_before = global_timer.counters["device_hist_tile_visits"]
     # the whole-tree program bakes the operand in as it is traced: no
     # program of another test may stand in for this one, nor this one's
     # for a later test's
@@ -654,10 +765,38 @@ def test_one_tree_wave_note_per_tree_carries_waves_rows_and_width(
         visits, active = note["hist_tile_visits"], note["hist_tiles_active"]
         assert note["hist_rows"] <= 1024 * active <= 1024 * visits
         assert visits <= active + note["speculated"]
+        # the grids the two kernels stepped through are the shapes': the
+        # root's one-slot call and a call a wave; a compaction a wave
+        for field, steps in _grid_steps(2048, note).items():
+            assert note[field] == steps, field
+        _check_work_fields(note)
     assert sum(n["hist_rows"] for n in notes) \
         == global_timer.counters["device_hist_rows"] - rows_before
-    assert sum(n["hist_tile_visits"] for n in notes) \
-        == global_timer.counters["device_hist_tile_visits"] - visits_before
+    # the note is the one record of the kernels' work: no counter twin
+    assert not [c for c in global_timer.counters
+                if c.startswith(("device_hist_tile", "device_compact"))]
+
+
+def _grid_steps(rows: int, note: dict, shards: int = 1) -> dict:
+    """The two static grid lengths of a tree of `note["waves"]` waves over
+    `rows` padded rows (a shard's): what the program must have summed."""
+    t_hist, t_compact = rows // DEFAULT_TILE_ROWS, rows // COMPACT_TILE
+    k = note["wave_k"]
+    return {"hist_grid_steps": shards * (
+                (t_hist + 2) + note["waves"] * (t_hist + 2 * k)),
+            "compact_grid_steps": shards * note["waves"] * max_pairs_bound(
+                t_compact, 2 * k)}
+
+
+def _check_work_fields(note: dict) -> None:
+    assert set(device_mod.WORK_FIELDS) <= set(note)
+    assert len(device_mod.WORK_FIELDS) == 7
+    assert 0 < note["hist_tiles_active"] <= note["hist_tile_visits"] \
+        <= note["hist_grid_steps"]
+    moved = note["compact_copy_pairs"] + note["compact_permute_pairs"]
+    assert 0 < moved <= note["compact_pairs"] <= note["compact_grid_steps"]
+    # every wave writes every tile of every shard once at least
+    assert moved * COMPACT_TILE >= note["waves"] * 2048
 
 
 def _compile_notes():
