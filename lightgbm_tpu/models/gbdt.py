@@ -38,6 +38,7 @@ from ..utils.timer import (SCOPE_GRADIENTS, SCOPE_TREE_SETUP,
                            SPAN_PREDICT_CALL, SPAN_PREDICT_FETCH,
                            SPAN_PREDICT_TRAVERSE, SPAN_PREDICT_UPLOAD,
                            global_timer)
+from .resident import ResidentRows
 from .sample_strategy import DeviceBag, create_sample_strategy
 from .serialize import GBDTModel
 from .tree import Tree
@@ -151,6 +152,24 @@ class _ValidData:
 class GBDT:
     """The training driver. One instance per Booster."""
 
+    # the per-row state in the sharded learner's row layout, where the run
+    # is eligible (`_resident_rows`); None: the score is `_score`, [C, N]
+    _rows: Optional[ResidentRows] = None
+
+    @property
+    def score(self) -> jax.Array:
+        """The training scores [C, N]. A reader of scores or leaf ids
+        outside an iteration takes this N-row view (cut on demand where the
+        rows are resident) and never places a per-row array itself."""
+        return self._score if self._rows is None else self._rows.view()
+
+    @score.setter
+    def score(self, value: jax.Array) -> None:
+        if self._rows is None:
+            self._score = value
+        else:  # a restore, the first iteration's average: off the tree path
+            self._rows.score = self._rows.place(value)
+
     def __init__(self, config: Config, train_set: Optional[Dataset],
                  objective: Optional[ObjectiveFunction],
                  train_raw: Optional[np.ndarray] = None) -> None:
@@ -214,6 +233,7 @@ class GBDT:
             else:
                 self._grad_fn = self._compute_gh
             self.train_raw = train_raw
+            self._rows = self._resident_rows()
 
     # ------------------------------------------------------------------ valid
 
@@ -232,6 +252,45 @@ class GBDT:
         self.valid_names.append(name)
 
     # --------------------------------------------------------------- boosting
+
+    def _resident_rows(self) -> Optional[ResidentRows]:
+        """Decided once, here, from what the run shows: the scores, the
+        objective's per-row constants, the gradients and each tree's leaf
+        ids stay in the learner's row layout (models/resident.py) when
+
+        * the learner offers one (rows sharded over a one-process mesh:
+          `DeviceDataParallelTreeLearner.row_layout`; the one-chip and the
+          host-driven learners have none);
+        * the objective's gradient of a row reads that row alone
+          (`row_constants`), inside one jitted program, and it refits no
+          leaf on the host (`renew_tree_output` is the base's);
+        * every tree is grown from all rows by plain GBDT, one tree an
+          iteration: no bagging or GOSS (their bags are host index sets),
+          no linear leaves or health monitor (both read the gradients as
+          [N] host rows), no DART / RF (they rewrite the score by
+          traversal). Custom gradients come without an objective
+          (`Booster.update`), so such a run is never here.
+
+        Anything else keeps the score on the default device, [C, N]."""
+        offer = getattr(self.tree_learner, "row_layout", None)
+        layout = offer() if offer is not None else None
+        obj = self.objective
+        if (layout is None or type(self) is not GBDT or obj is None
+                or not obj.row_constants or not obj.jit_gradients
+                or (type(obj).renew_tree_output
+                    is not ObjectiveFunction.renew_tree_output)
+                or self.num_tree_per_iteration != 1
+                or self.sample_strategy.samples_rows
+                or self.config.linear_tree or self._health is not None):
+            return None
+        return ResidentRows(layout, obj, self._score)
+
+    def _pack(self, grad: jax.Array, hess: jax.Array) -> jax.Array:
+        """One tree's gradients as its learner takes them: [N+1, 3] with
+        the sentinel row, or [n_pad, 3] in the row layout."""
+        if self._rows is None:
+            return _pack_gh(grad, hess)
+        return self._rows.programs.pack(grad, hess)
 
     def _compute_gh(self, score):
         """score [N] (C==1) or [C, N] -> (grad, hess) matching shapes — the
@@ -350,6 +409,7 @@ class GBDT:
         C = self.num_tree_per_iteration
         init_scores = [0.0] * C
         custom = gradients is not None
+        rows = self._rows  # never with custom gradients: no objective
         if not custom:
             if self.objective is None:
                 Log.fatal("No object function provided")
@@ -366,8 +426,11 @@ class GBDT:
                     grads, hesses = grads[0], hesses[0]
             else:
                 with global_timer.scope(SPAN_GRADIENTS):
-                    grads, hesses = self._grad_fn(
-                        self.score if C > 1 else self.score[0])
+                    if rows is not None:
+                        grads, hesses = rows.gradients()
+                    else:
+                        grads, hesses = self._grad_fn(
+                            self.score if C > 1 else self.score[0])
         grads, hesses = faults.maybe_poison_gh(grads, hesses, self.iter_)
         if self._health is not None:
             grads, hesses = self._health.admit(self, grads, hesses)
@@ -392,11 +455,13 @@ class GBDT:
                 if C > 1:
                     gh_ext = _pack_gh(grads[c], hesses[c])
                 else:
-                    gh_ext = _pack_gh(grads, hesses)
+                    gh_ext = self._pack(grads, hesses)
             new_tree = Tree(2)
             if self.class_need_train[c] and self.train_set.num_features > 0:
                 with global_timer.scope("tree_train"):
-                    new_tree = self.tree_learner.train(gh_ext, bag)
+                    new_tree = (self.tree_learner.train(gh_ext, bag)
+                                if rows is None
+                                else self.tree_learner.train_rows(gh_ext))
             if new_tree.num_leaves > 1:
                 should_continue = True
                 if self._health is not None:
@@ -413,7 +478,9 @@ class GBDT:
                             np.asarray(gvec), np.asarray(hvec),
                             self.config.linear_lambda,
                             is_first_tree=len(self.models) < C)
-                if self.objective is not None:
+                # resident rows: the objective's hook is the base's no-op
+                # (`_resident_rows`), and its argument would cut a view
+                if self.objective is not None and rows is None:
                     self.objective.renew_tree_output(
                         new_tree, self.score[c], self.tree_learner.partition)
                 new_tree.shrink(self.shrinkage_rate)
@@ -457,18 +524,27 @@ class GBDT:
         sync path; only the stop on a no-split tree lands one iteration
         late (the extra dispatched tree is provably the same stub with a
         zero score delta, and is dropped)."""
+        rows = self._rows
         with global_timer.scope("boosting"):
-            gh_ext = _pack_gh(grads, hesses)
+            gh_ext = self._pack(grads, hesses)
         with global_timer.scope("tree_train"):
-            pending = self.tree_learner.train_async(gh_ext, None)
+            pending = (self.tree_learner.train_async(gh_ext, None)
+                       if rows is None
+                       else self.tree_learner.train_rows_async(gh_ext))
         apply_log = sanitize.guard(
             _apply_split_log_to_score, (0,),
             "_apply_split_log_to_score (models/gbdt.py async score update)")
+        rate = jnp.float32(self.shrinkage_rate)
         with global_timer.scope("update_score"):
-            self.score = self.score.at[0].set(apply_log(
-                self.score[0], _colocate(pending.rec_store, self.score),
-                _colocate(pending.leaf_id, self.score),
-                jnp.float32(self.shrinkage_rate), self.config.num_leaves))
+            if rows is None:
+                self.score = self.score.at[0].set(apply_log(
+                    self.score[0], _colocate(pending.rec_store, self.score),
+                    _colocate(pending.leaf_id, self.score), rate,
+                    self.config.num_leaves))
+            else:  # the log is on every chip, the ids where the tree wrote
+                rows.score = apply_log(rows.score, pending.rec_store,
+                                       pending.leaf_id, rate,
+                                       self.config.num_leaves)
         self.models.append(pending.tree)
         self._predictor.invalidate()
         self._flush_pending()  # overlaps t-1's replay with t's growth
@@ -566,21 +642,34 @@ class GBDT:
         delta = predict_raw(packed, self._train_raw_dev())[:, 0]
         self.score = self.score.at[class_id].add(delta)
 
+    def _leaf_values(self, tree: Tree) -> jax.Array:
+        """The tree's leaf values padded to the configuration's num_leaves:
+        one update program serves every tree."""
+        lv = np.zeros(max(self.config.num_leaves, tree.num_leaves),
+                      dtype=np.float32)
+        lv[: tree.num_leaves] = tree.leaf_value[: tree.num_leaves]
+        return jnp.asarray(lv)
+
     def _update_train_score(self, tree: Tree, class_id: int) -> None:
         if tree.is_linear:
             self._add_linear_tree_score(tree, class_id)
             return
         part = self.tree_learner.partition
+        rows = self._rows
+        if rows is not None:
+            # every tree of the run left its [n_pad] ids in the layout:
+            # each chip adds to its own rows (pad rows carry -1)
+            rows.score = _add_leaf_values_to_score(
+                rows.score, part.leaf_ids_dev(), self._leaf_values(tree))
+            return
         score = self.score[class_id]
         ids_fn = getattr(part, "leaf_ids_dev", None)
         if ids_fn is not None:
             # vectorized path: one gather over the device leaf-id vector
             # (bagged-out rows carry -1 and contribute nothing)
             ids = _colocate(ids_fn(), score)
-            lv = np.zeros(max(self.config.num_leaves, tree.num_leaves),
-                          dtype=np.float32)
-            lv[: tree.num_leaves] = tree.leaf_value[: tree.num_leaves]
-            score = _add_leaf_values_to_score(score, ids, jnp.asarray(lv))
+            score = _add_leaf_values_to_score(score, ids,
+                                              self._leaf_values(tree))
         else:
             for leaf in range(tree.num_leaves):
                 idx = part.indices(leaf)

@@ -36,6 +36,8 @@ class SampleStrategy:
     """Base: no sampling (full data every iteration)."""
 
     is_use_subset = False
+    # whether any iteration of the run grows its tree from a row subset
+    samples_rows = False
 
     def __init__(self, config: Config, num_data: int, metadata,
                  num_tree_per_iteration: int) -> None:
@@ -67,6 +69,7 @@ class BaggingSampleStrategy(SampleStrategy):
             Log.warning("Only can use pos/neg bagging with binary objective")
             self.balanced = False
             self.need = config.bagging_freq > 0 and config.bagging_fraction < 1.0
+        self.samples_rows = self.need
         self._bag: Optional[np.ndarray] = None
 
     def bagging(self, iteration: int, grad, hess):
@@ -174,6 +177,8 @@ class GOSSStrategy(SampleStrategy):
     grad/hess by (1-top_rate)/other_rate. Inactive during the warm-up
     (iteration < 1/learning_rate, goss.hpp) like the reference.
     """
+
+    samples_rows = True
 
     def __init__(self, config: Config, num_data: int, metadata,
                  num_tree_per_iteration: int) -> None:

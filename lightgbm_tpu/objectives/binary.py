@@ -19,6 +19,8 @@ K_EPS = 1e-15
 
 @register_objective("binary")
 class BinaryLogloss(ObjectiveFunction):
+    row_constants = ("_sign", "_lw")
+
     def __init__(self, config):
         super().__init__(config)
         self.sigmoid = config.sigmoid

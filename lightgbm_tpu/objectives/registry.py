@@ -8,7 +8,7 @@ kernels in src/objective/cuda/).
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional, Type
+from typing import Callable, Dict, Optional, Tuple, Type
 
 from ..config import Config
 from ..io.metadata import Metadata
@@ -35,6 +35,12 @@ class ObjectiveFunction:
     # whether get_gradients is a pure traceable function safe to wrap in an
     # outer jit (stateful objectives like rank_xendcg manage their own jits)
     jit_gradients = True
+    # names of the per-row device constants get_gradients reads besides the
+    # score (labels, weights; an attribute may hold None), declared by an
+    # objective whose gradient of a row depends on that row alone: a driver
+    # that keeps its rows in a sharded learner's layout places them there
+    # once (models/resident.py). Empty: not known to be per-row
+    row_constants: Tuple[str, ...] = ()
 
     def __init__(self, config: Config) -> None:
         self.config = config

@@ -66,6 +66,7 @@ def _weighted_percentile(values: np.ndarray, weights, alpha: float) -> float:
 @register_objective("regression", "regression_l2", "l2", "mean_squared_error", "mse")
 class RegressionL2(ObjectiveFunction):
     is_constant_hessian = True
+    row_constants = ("_label_dev", "_w_dev")
 
     def __init__(self, config):
         super().__init__(config)
@@ -228,6 +229,7 @@ class RegressionQuantile(RegressionL2):
 @register_objective("mape", "mean_absolute_percentage_error")
 class RegressionMAPE(RegressionL2):
     is_constant_hessian = True
+    row_constants = ("_label_dev", "_lw_dev")
 
     def __init__(self, config):
         super().__init__(config)

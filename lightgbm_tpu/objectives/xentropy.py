@@ -19,6 +19,8 @@ K_EPS = 1e-15
 
 
 class _XentBase(ObjectiveFunction):
+    row_constants = ("_label_dev", "_w_dev")
+
     def init(self, metadata, num_data):
         super().init(metadata, num_data)
         label = metadata.label.astype(np.float64)

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import os
 from functools import partial
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,8 +53,9 @@ from ..treelearner.serial import (SerialTreeLearner, _LeafState,
                                   device_growth_applies)
 from ..utils import sanitize
 from ..utils.log import Log
-from ..utils.timer import (SCOPE_FINISH, SPAN_GATHER_LEAF_IDS,
-                           SPAN_SHARD_INPUTS, global_timer)
+from ..utils.timer import (SCOPE_FINISH, SCOPE_TREE_SETUP,
+                           SPAN_GATHER_LEAF_IDS, SPAN_SHARD_INPUTS,
+                           global_timer)
 from .dist import (host_value, init_distributed, put_global, put_global_tree,
                    put_replicated)
 from .mesh import data_mesh, padded_row_count
@@ -63,6 +64,17 @@ from .mesh import data_mesh, padded_row_count
 def _ceil_to(n: int, d: int) -> int:
     return -(-n // d) * d
 
+
+class RowLayout(NamedTuple):
+    """Where a row-sharded learner keeps a tree's per-row arrays: `n_pad`
+    rows in the data set's order, the `n_pad - num_data` pad rows at the
+    end, split on the mesh's `data` axis as `rows` says (the sharding of an
+    `[n_pad]` vector). A driver whose scores and gradients live in this
+    layout hands the learner its rows in place (`train_rows_async`)."""
+
+    num_data: int
+    n_pad: int
+    rows: NamedSharding
 
 
 def _better_record(recs: jax.Array, other: jax.Array) -> jax.Array:
@@ -505,6 +517,7 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
             self.n_pad = padded_row_count(dataset.num_data, self.D,
                                           self._row_unit)
             self._row_spec = P("data")
+        self._row_sharding = NamedSharding(self.mesh, self._row_spec)
         super().__init__(config, dataset)
         F = len(self.meta.real_feature)
         self.f_pad = _ceil_to(max(F, self.D), self.D)
@@ -614,11 +627,21 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
         self._ici_bytes_per_wave = int(bytes_w)
         global_timer.set_count("device_ici_bytes_per_wave", bytes_w)
 
-    def _shard_inputs(self, gh_ext: jax.Array,
-                      bag_indices: Optional[np.ndarray]) -> tuple:
-        """The tree's inputs across the mesh: gradients (computed on one
-        chip) and initial leaf ids padded to the sharded row count and
-        split on `data`, the feature mask, the quantization scales."""
+    def row_layout(self) -> Optional[RowLayout]:
+        """The layout this learner takes a tree's rows in without moving
+        them, or None where it has none to offer: replicated rows (feature-
+        parallel), a mesh of several processes (no one process addresses a
+        whole per-row array), and quantized training, whose per-tree pack
+        and stochastic rounding are drawn over the `[N + 1, 3]` pack."""
+        if (self._replicate_rows or self.quantized
+                or not self.bins_dev.is_fully_addressable):
+            return None
+        return RowLayout(self.num_data, self.n_pad, self._row_sharding)
+
+    def _shard_rows(self, gh_ext: jax.Array,
+                    bag_indices: Optional[np.ndarray]) -> tuple:
+        """A tree's gradients (computed on one chip) and initial leaf ids
+        padded to the sharded row count and split on `data`."""
         n, npad = self.num_data, self.n_pad
         gh = gh_ext[:-1]
         if bag_indices is not None:
@@ -638,7 +661,11 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
             [gh, jnp.zeros((npad - n, gh.shape[1]), gh.dtype)])
         gh_sh = put_global(gh_pad, self.mesh, self._row_spec)
         leaf_sh = put_global(ids_pad, self.mesh, self._row_spec)
+        return gh_sh, leaf_sh, n_bag
 
+    def _tree_constants(self) -> tuple:
+        """The feature mask and the quantization scales of one tree, on the
+        mesh: bytes, whatever the row count."""
         F = len(self.meta.real_feature)
         mask = np.ones(self.f_pad, dtype=bool)
         if self.col_sampler.active:
@@ -646,25 +673,46 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
         fmask_sh = put_global(mask, self.mesh, self._fmask_spec)
         scale = (self._scale_vec if self.quantized
                  else jnp.ones(3, jnp.float32))
-        scale_rep = put_global(scale, self.mesh, P())
-
-        return gh_sh, leaf_sh, fmask_sh, scale_rep, n_bag
+        return fmask_sh, put_global(scale, self.mesh, P())
 
     def train_async(self, gh_ext: jax.Array,
                     bag_indices: Optional[np.ndarray] = None) -> _PendingTree:
-        cfg = self.config
         bag_indices = host_bag_indices(bag_indices)
         if self.quantized:
             gh_ext = self._prepare_gh(gh_ext)  # int8 rows + scales
         with global_timer.scope(SPAN_SHARD_INPUTS):
-            gh_sh, leaf_sh, fmask_sh, scale_rep, n_bag = self._shard_inputs(
-                gh_ext, bag_indices)
+            gh_sh, leaf_sh, n_bag = self._shard_rows(gh_ext, bag_indices)
+            constants = self._tree_constants()
+        return self._grow(gh_sh, leaf_sh, constants, n_bag,
+                          bagged=bag_indices is not None,
+                          rows_resident=False)
+
+    def train_rows(self, gh_rows: jax.Array) -> Tree:
+        return self.finalize(self.train_rows_async(gh_rows))
+
+    def train_rows_async(self, gh_rows: jax.Array) -> _PendingTree:
+        """`train_async` for a driver that keeps its rows in `row_layout()`:
+        `gh_rows` is the tree's `[n_pad, 3]` pack where the tree reads it
+        (pad rows all zero; donated to the tree), the initial leaf ids are
+        made on the mesh, and the tree's leaf ids stay where the tree wrote
+        them: nothing per-row is padded, moved or pulled. Every tree is
+        grown from all rows: there is no bag to hand over."""
+        with global_timer.scope(SPAN_SHARD_INPUTS):
+            leaf_sh = _root_leaf_ids(self.num_data, self.n_pad,
+                                     self._row_sharding)
+            constants = self._tree_constants()
+        return self._grow(gh_rows, leaf_sh, constants, self.num_data,
+                          bagged=False, rows_resident=True)
+
+    def _grow(self, gh_sh: jax.Array, leaf_sh: jax.Array, constants: tuple,
+              n_bag: int, bagged: bool, rows_resident: bool) -> _PendingTree:
+        fmask_sh, scale_rep = constants
         narrow = self._narrow(leaf_sh)
         self._record_carry_bytes()
         self._record_ici_bytes(narrow)
         grow = sanitize.guard(
-            self._grow_fn(bag_indices is not None, narrow), (0, 1, 2),
-            "the sharded grow dispatch (parallel/learners.py train_async)")
+            self._grow_fn(bagged, narrow), (0, 1, 2),
+            "the sharded grow dispatch (parallel/learners.py _grow)")
         with global_timer.scope("tree_device"):
             out = grow(
                 jnp.copy(self.bins_dev), gh_sh, leaf_sh, self._gidx_arg,
@@ -673,23 +721,26 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
                 *self._extra_grow_args())
         rec_store, leaf_id, _, hist_rows, n_waves, work_counts = out[:6]
         self._note_grow_extras(out[6:])
+        to_host = [rec_store, hist_rows, n_waves, work_counts]
         with global_timer.scope(SPAN_GATHER_LEAF_IDS):
-            leaf_id = self._gather_leaf_ids(leaf_id)
-        for arr in (rec_store, leaf_id, hist_rows, n_waves, work_counts):
-            start = getattr(arr, "copy_to_host_async", None)
-            if start is not None:
-                start()
-        return _PendingTree(Tree(cfg.num_leaves), rec_store, leaf_id,
+            if not rows_resident:
+                leaf_id = self._gather_leaf_ids(leaf_id)
+                to_host.append(leaf_id)
+            for arr in to_host:
+                start = getattr(arr, "copy_to_host_async", None)
+                if start is not None:
+                    start()
+        return _PendingTree(Tree(self.config.num_leaves), rec_store, leaf_id,
                             hist_rows, n_waves, work_counts, n_bag,
-                            wave_k=self.wave_k)
+                            wave_k=self.wave_k, rows_resident=rows_resident)
 
     def _gather_leaf_ids(self, leaf_id: jax.Array) -> jax.Array:
         """The tree's per-row leaf ids without the row padding, on the
-        mesh's first chip, where the scores and gradients of a one-process
-        run live (the score update reads them there). Both steps are
-        enqueued behind the tree's program and block nothing. A multi-
-        process mesh keeps the sharded array: no one process can address
-        it whole, and models/gbdt.py `_colocate` allgathers it."""
+        mesh's first chip, where the scores and gradients of a run outside
+        the row layout live (the score update reads them there). Both
+        steps are enqueued behind the tree's program and block nothing. A
+        multi-process mesh keeps the sharded array: no one process can
+        address it whole, and models/gbdt.py `_colocate` allgathers it."""
         leaf_id = _without_row_padding(leaf_id, self.num_data)
         if leaf_id.is_fully_addressable:
             leaf_id = jax.device_put(leaf_id, self.mesh.devices.flat[0])
@@ -711,6 +762,16 @@ def _without_row_padding(leaf_id: jax.Array, num_data: int) -> jax.Array:
     finishing scope (the output's placement is the compiler's, as it was)."""
     with jax.named_scope(SCOPE_FINISH):
         return leaf_id[:num_data]
+
+
+@partial(jax.jit, static_argnames=("num_data", "n_pad", "rows"))
+def _root_leaf_ids(num_data: int, n_pad: int, rows: NamedSharding
+                   ) -> jax.Array:
+    """A full-data tree's initial leaf ids in the row layout, made where
+    they are read: 0 on the real rows, -1 on the pad."""
+    with jax.named_scope(SCOPE_TREE_SETUP):
+        ids = jnp.where(jnp.arange(n_pad, dtype=jnp.int32) < num_data, 0, -1)
+        return jax.lax.with_sharding_constraint(ids.astype(jnp.int32), rows)
 
 
 class VotingDataParallelTreeLearner(DeviceDataParallelTreeLearner):

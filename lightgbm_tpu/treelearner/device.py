@@ -1076,10 +1076,15 @@ def make_sharded_grow_fn(mesh, *, num_leaves: int, num_bins: int,
 class DevicePartition:
     """Partition view over the final leaf-id vector (indices()/count()
     surface shared with ops.partition.RowPartition, plus the vectorized
-    leaf_ids_dev fast path for score updates)."""
+    leaf_ids_dev fast path for score updates). The device vector may be
+    the sharded learner's `[n_pad]` row layout (pad rows -1 at the end),
+    left where the tree wrote it for the score update to read there; the
+    host view is cut to the `num_data` real rows."""
 
-    def __init__(self, leaf_ids_dev: jax.Array, counts: Dict[int, int]) -> None:
+    def __init__(self, leaf_ids_dev: jax.Array, counts: Dict[int, int],
+                 num_data: int) -> None:
         self._ids_dev = leaf_ids_dev
+        self._num_data = num_data
         self._ids: Optional[np.ndarray] = None
         self._order: Optional[np.ndarray] = None
         self._sorted: Optional[np.ndarray] = None
@@ -1091,7 +1096,7 @@ class DevicePartition:
     @property
     def ids_host(self) -> np.ndarray:
         if self._ids is None:
-            self._ids = np.asarray(self._ids_dev)
+            self._ids = np.asarray(self._ids_dev)[: self._num_data]
         return self._ids
 
     def count(self, leaf: int) -> int:
@@ -1128,6 +1133,8 @@ class _PendingTree(NamedTuple):
     work_counts: jax.Array  # [len(WORK_FIELDS)] int32
     n_bag: int
     wave_k: int  # wave width this tree was dispatched with
+    # leaf_id is the sharded learner's [n_pad] row layout, never moved
+    rows_resident: bool = False
 
 
 class DeviceTreeLearner(SerialTreeLearner):
@@ -1312,7 +1319,7 @@ class DeviceTreeLearner(SerialTreeLearner):
             counts[tree.num_leaves - 1] = split.right_count
 
         self._record_wave_efficiency(pending, tree)
-        self.partition = DevicePartition(leaf_id, counts)
+        self.partition = DevicePartition(leaf_id, counts, self.num_data)
         if tree.num_leaves == 1:
             tree.as_constant_tree(0.0)
         elif self.quantized and cfg.quant_train_renew_leaf:
@@ -1348,7 +1355,8 @@ class DeviceTreeLearner(SerialTreeLearner):
                      hist_operand=self.hist_operand,
                      hist_int=int(self.hist_operand == "int"),
                      ici_bytes=n_waves * self._ici_bytes_per_wave,
-                     mesh_devices=self.D)
+                     mesh_devices=self.D,
+                     rows_resident=int(pending.rows_resident))
         if telemetry.enabled():
             telemetry.emit(
                 "tree_wave", waves=n_waves, wave_width=wave_k,

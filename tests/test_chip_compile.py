@@ -380,6 +380,68 @@ def test_sharded_whole_tree_program_fits_four_chips_at_higgs_full(
 
 
 @pytest.mark.slow
+def test_resident_row_programs_hold_no_cross_chip_collective_at_higgs_full(
+        topo):
+    """What runs between two sharded trees since PR 37, at the cell's
+    10,502,144 rows on the four described chips: the gradients, the pack,
+    the initial leaf ids and both score updates over operands in the
+    learner's row layout compile to programs without one collective (each
+    chip its own 2,625,536 rows), and the pack and the leaf ids come out
+    in the shardings the tree program above is lowered with; only the
+    N-row view, which no tree reads, gathers."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from lightgbm_tpu.models import gbdt as gbdt_mod
+    from lightgbm_tpu.models.resident import row_programs
+    from lightgbm_tpu.objectives import create_objective
+    from lightgbm_tpu.parallel import learners as learners_mod
+
+    n_pad = 10_502_144
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    rows = NamedSharding(mesh, P("data"))
+    layout = learners_mod.RowLayout(HIGGS_ROWS, n_pad, rows)
+    cfg = Config({"objective": "binary", "verbosity": -1})
+    programs = row_programs(layout, create_objective("binary", cfg))
+
+    def vec(dtype):
+        return jax.ShapeDtypeStruct((n_pad,), dtype, sharding=rows)
+
+    def rep(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32,
+                                    sharding=NamedSharding(mesh, P()))
+
+    f32, i32 = vec(jnp.float32), vec(jnp.int32)
+    compiled = {
+        "gradients": programs.gradients.lower(
+            f32, {"_sign": f32, "_lw": f32}).compile(),
+        "pack": programs.pack.lower(f32, f32).compile(),
+        "leaf ids": learners_mod._root_leaf_ids.lower(
+            HIGGS_ROWS, n_pad, rows).compile(),
+        "update": gbdt_mod._add_leaf_values_to_score.lower(
+            f32, i32, rep((255,))).compile(),
+        "update from the log": gbdt_mod._apply_split_log_to_score.lower(
+            f32, rep((254, device_mod.STORE)), i32, rep(()),
+            num_leaves=255).compile(),
+    }
+    def collectives(program) -> list:
+        text = program.as_text()
+        return [op for op in ("all-gather", "all-to-all", "all-reduce",
+                              "collective-permute", "reduce-scatter")
+                if f" {op}(" in text or f" {op}-start(" in text]
+
+    for name, program in compiled.items():
+        assert collectives(program) == [], name
+    gh = NamedSharding(mesh, P("data"))  # as the tree program takes [n, 3]
+    assert compiled["pack"].output_shardings.is_equivalent_to(gh, 2)
+    assert compiled["leaf ids"].output_shardings.is_equivalent_to(rows, 1)
+    for name in ("update", "update from the log"):
+        assert compiled[name].output_shardings.is_equivalent_to(rows, 1)
+    for out in compiled["gradients"].output_shardings:
+        assert out.is_equivalent_to(rows, 1)
+    assert collectives(programs.cut.lower(f32).compile()) != []
+
+
+@pytest.mark.slow
 def test_quantized_whole_tree_program_fits_one_chip_at_higgs_full(
         on_chip, monkeypatch, capsys):
     """`use_quantized_grad` at Higgs's published 10,500,000 rows on ONE

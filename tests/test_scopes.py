@@ -21,6 +21,7 @@ from lightgbm_tpu import tracing
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.dataset import Dataset as CoreDataset
 from lightgbm_tpu.models import gbdt as gbdt_mod
+from lightgbm_tpu.models import resident as resident_mod
 from lightgbm_tpu.models.gbdt import GBDT
 from lightgbm_tpu.objectives import create_objective
 from lightgbm_tpu.ops import predict as predict_mod
@@ -216,7 +217,35 @@ PER_TREE_PROGRAMS = {
     "leaf_ids_without_padding": (
         lambda: learners_mod._without_row_padding.lower(
             jnp.zeros(64, jnp.int32), num_data=60), timer.SCOPE_FINISH),
+    # rows resident in the sharded learner's layout (PR 37): the update is
+    # the two programs above over sharded operands; these are the rest
+    "resident_gradients": (
+        lambda: _row_programs()[1].gradients.lower(
+            jnp.zeros(64, jnp.float32),
+            {"_sign": jnp.ones(64, jnp.float32),
+             "_lw": jnp.ones(64, jnp.float32)}), timer.SCOPE_GRADIENTS),
+    "resident_pack": (
+        lambda: _row_programs()[1].pack.lower(
+            jnp.zeros(64, jnp.float32), jnp.zeros(64, jnp.float32)),
+        timer.SCOPE_TREE_SETUP),
+    "resident_root_leaf_ids": (
+        lambda: learners_mod._root_leaf_ids.lower(
+            60, 64, _row_programs()[0].rows), timer.SCOPE_TREE_SETUP),
+    "resident_score_view": (
+        lambda: _row_programs()[1].cut.lower(jnp.zeros(64, jnp.float32)),
+        timer.SCOPE_FINISH),
 }
+
+
+def _row_programs():
+    """60 rows padded to 64 over four of the CPU devices, and the binary
+    objective's programs in that layout."""
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("data",))
+    layout = learners_mod.RowLayout(60, 64, jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec("data")))
+    cfg = Config(PARAMS)
+    return layout, resident_mod.row_programs(
+        layout, create_objective(cfg.objective, cfg))
 
 
 @pytest.mark.parametrize("name", sorted(PER_TREE_PROGRAMS))
@@ -538,8 +567,9 @@ def test_three_trees_give_three_iteration_spans_that_hold_their_children(
 def test_a_sharded_tree_opens_its_two_host_spans_once_each(spans,
                                                            monkeypatch):
     """`shard_inputs`, `tree_device`, `gather_leaf_ids`, in that order and
-    apart, inside the iteration's `tree_train`, once a tree; the tree's
-    flight note says how wide the mesh was and what crossed it."""
+    apart, inside the iteration's `tree_train`, once a tree, around what is
+    left of either step now that the run's rows stay on the mesh; the
+    tree's flight note says so, how wide the mesh was and what crossed it."""
     monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
     monkeypatch.setattr(serial_mod, "on_tpu", lambda: True)
     X, y = _data(1500)
@@ -568,7 +598,7 @@ def test_a_sharded_tree_opens_its_two_host_spans_once_each(spans,
     assert per_wave > 0
     shard_rows = learner.n_pad // 4
     for note in notes:
-        assert note["mesh_devices"] == 4
+        assert note["mesh_devices"] == 4 and note["rows_resident"] == 1
         assert note["ici_bytes"] == note["waves"] * per_wave
         assert note["wave_k"] == min(learner.wave, PARAMS["num_leaves"])
         # the kernels' work is the four shards' summed: every shard steps

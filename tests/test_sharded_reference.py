@@ -121,14 +121,14 @@ def test_control_a_shards_rows_left_out_of_the_reduction_fails(
     """The first shard's gradients, hessians and counts zeroed on their
     way to the mesh: its rows reach no histogram, as if its block were
     missing from the psum_scatter."""
-    real = learners_mod.DeviceDataParallelTreeLearner._shard_inputs
+    real = learners_mod.DeviceDataParallelTreeLearner._grow
 
-    def without_first_shard(self, gh_ext, bag_indices):
+    def without_first_shard(self, gh_sh, *args, **kwargs):
         hi = self.n_pad // self.D
-        return real(self, gh_ext.at[:hi].set(0.0), bag_indices)
+        return real(self, gh_sh.at[:hi].set(0.0), *args, **kwargs)
 
     monkeypatch.setattr(learners_mod.DeviceDataParallelTreeLearner,
-                        "_shard_inputs", without_first_shard)
+                        "_grow", without_first_shard)
     readings, _ = _readings(monkeypatch, plain)
     assert readings["count_mismatch"] > 0
     for name in ("leaf_value_gap", "split_gain_gap", "split_shortfall",
