@@ -52,24 +52,22 @@ def _auc(score, is_pos, weights):
 
 
 def _auc_body(score, is_pos, weights):
-    order = jnp.argsort(score)
-    s = score[order]
-    y = is_pos[order]
-    w = weights[order] if weights is not None else jnp.ones_like(s)
+    w = jnp.ones_like(score) if weights is None else weights
+    # one stable sort carries the labels and weights with the scores: no
+    # row-sized gather or scatter anywhere in the program
+    s, y, w = jax.lax.sort((score, is_pos, w), num_keys=1, is_stable=True)
     wneg = w * (1.0 - y)
     wpos = w * y
     cum_neg = jnp.cumsum(wneg)  # negatives with score <= s_i (inclusive)
-    # tie handling: within equal-score runs use (neg_below + neg_tied/2)
-    # compute run boundaries
-    neg_below_excl = cum_neg - wneg
-    # for ties: segment by equal score values
+    # tie handling: within a run of equal scores (a segment) a positive
+    # gets the negatives before the run and half of those in it; both are
+    # prefix sums read at the run's ends, carried over the run by scans
+    below = jnp.concatenate([jnp.zeros(1, cum_neg.dtype), cum_neg[:-1]])
     is_new = jnp.concatenate([jnp.array([True]), s[1:] > s[:-1]])
-    seg_id = jnp.cumsum(is_new.astype(jnp.int32)) - 1
-    n_seg = s.shape[0]
-    seg_neg = jax.ops.segment_sum(wneg, seg_id, num_segments=n_seg)
-    seg_cum = jnp.cumsum(seg_neg)
-    neg_in_seg = seg_neg[seg_id]
-    neg_before_seg = seg_cum[seg_id] - neg_in_seg
+    is_end = jnp.concatenate([s[1:] > s[:-1], jnp.array([True])])
+    neg_before_seg = jax.lax.cummax(jnp.where(is_new, below, 0.0))
+    neg_in_seg = jax.lax.cummin(jnp.where(is_end, cum_neg, jnp.inf),
+                                reverse=True) - neg_before_seg
     credit = neg_before_seg + 0.5 * neg_in_seg
     total_pos = jnp.sum(wpos)
     total_neg = jnp.sum(wneg)

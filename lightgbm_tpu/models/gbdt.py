@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import telemetry
+from .. import telemetry, tracing
 from ..config import Config
 from ..health import create_monitor
 from ..io.dataset import Dataset
@@ -27,7 +27,9 @@ from ..ops.partition import bucket_size, pad_indices
 from ..ops.predict import (PredictorCache, pack_ensemble, predict_dtype,
                            predict_raw, predict_raw_streamed,
                            stream_chunk_rows)
-from ..ops.score import add_tree_to_score
+from ..ops.score import (add_tree_to_score, cat_table_words,
+                         feature_split_fields, replay_tree_to_score,
+                         split_table)
 from ..parallel import elastic
 from ..treelearner import create_tree_learner
 from ..utils import faults, sanitize
@@ -142,11 +144,15 @@ def _colocate(arr: jax.Array, ref: jax.Array) -> jax.Array:
 
 
 class _ValidData:
-    """Holds one validation set's device raw matrix, metadata, score."""
+    """Holds one validation set's device raw matrix, metadata, score and,
+    where its trees are added in bin space (`GBDT._valid_plane`), its
+    binned plane [G, Nv] at the plane's own width."""
 
-    def __init__(self, dataset: Dataset, raw: np.ndarray, metrics) -> None:
+    def __init__(self, dataset: Dataset, raw: np.ndarray, metrics,
+                 plane: Optional[jax.Array] = None) -> None:
         self.dataset = dataset
         self.raw = jnp.asarray(raw, dtype=jnp.float32)
+        self.plane = plane
         self.metrics = metrics
         self.score: Optional[jax.Array] = None
 
@@ -157,6 +163,9 @@ class GBDT:
     # the per-row state in the sharded learner's row layout, where the run
     # is eligible (`_resident_rows`); None: the score is `_score`, [C, N]
     _rows: Optional[ResidentRows] = None
+    # (feature_split_fields, cat_table_words) of the training set, made with
+    # the first validation plane (`_valid_plane`)
+    _split_fields: Optional[Tuple[np.ndarray, int]] = None
 
     @property
     def score(self) -> jax.Array:
@@ -244,7 +253,7 @@ class GBDT:
                    if m is not None]
         for m in metrics:
             m.init(valid.metadata, valid.num_data)
-        vd = _ValidData(valid, raw, metrics)
+        vd = _ValidData(valid, raw, metrics, self._valid_plane(valid))
         vd.score = jnp.zeros((self.num_tree_per_iteration, valid.num_data),
                              dtype=jnp.float32)
         if valid.metadata.init_score is not None:
@@ -252,6 +261,31 @@ class GBDT:
                                    .reshape(self.num_tree_per_iteration, valid.num_data))
         self.valid_sets.append(vd)
         self.valid_names.append(name)
+
+    def _has_categorical(self) -> bool:
+        ds = self.train_set
+        return ds is not None and any(ds.mappers[f].bin_type == 1
+                                      for f in ds.used_features)
+
+    def _valid_plane(self, valid: Dataset) -> Optional[jax.Array]:
+        """Decided once a set, from what the run shows: a validation set
+        takes each tree in bin space (`_update_valid_scores`) when the
+        training set has a categorical used feature (whose trees the dense
+        predict program cannot score) and the set was binned with the
+        training set's mappers into its groups (constructed with
+        `reference=`). Its plane is then placed on the device once, rows on
+        the lanes. A numerical run keeps the one-tree pack: the dense
+        program on the TPU, the gather traversal off it."""
+        ds = self.train_set
+        if (not self._has_categorical() or valid.bins is None
+                or valid.mappers is not ds.mappers
+                or [g.feature_indices for g in valid.groups]
+                != [g.feature_indices for g in ds.groups]):
+            return None
+        if self._split_fields is None:
+            self._split_fields = (feature_split_fields(ds),
+                                  cat_table_words(ds))
+        return jnp.asarray(valid.bins, dtype=valid.bins.dtype)
 
     # --------------------------------------------------------------- boosting
 
@@ -690,21 +724,41 @@ class GBDT:
         pack). A few shapes, and so a few programs, serve every tree, and
         the pack grows with the words the tree's nodes hold, never with the
         size of the category codes alone."""
-        ds = self.train_set
-        if not any(ds.mappers[f].bin_type == 1 for f in ds.used_features):
+        if not self._has_categorical():
             return 0
         return max(VALID_CAT_WORDS,
                    1 << (len(tree.cat_threshold) - 1).bit_length())
 
     def _update_valid_scores(self, tree: Tree, class_id: int) -> None:
-        if not self.valid_sets:
-            return
-        depth_bound = (self.config.max_depth if self.config.max_depth > 0
-                       else self.config.num_leaves - 1)
-        packed = pack_ensemble([tree], fixed_leaves=self.config.num_leaves,
-                               fixed_depth=depth_bound,
-                               fixed_cat_words=self._valid_cat_words(tree))
+        """Add one tree to every validation set's scores. A set with a
+        plane (`_valid_plane`) takes a tree whose bin fields are its own
+        (grown in this process; not linear) by replaying its splits over
+        the plane (ops/score.py `replay_tree_to_score`): the decisions
+        LightGBM's ScoreUpdater makes, the same float32 add. Every other
+        (set, tree) scores the one-tree pack over the raw matrix. One
+        `valid_update` note a set and tree says which."""
+        table = packed = None
+        splits = max(tree.num_leaves - 1, 0)
         for vd in self.valid_sets:
+            binned = (vd.plane is not None and tree.has_bin_thresholds
+                      and not tree.is_linear)
+            tracing.note("valid_update", binned=int(binned),
+                         rows=int(vd.raw.shape[0]), splits=splits)
+            if binned:
+                if table is None:
+                    fields, n_words = self._split_fields
+                    table = jnp.asarray(split_table(
+                        tree, fields, n_words, self.config.num_leaves - 1))
+                global_timer.add_count("valid_binned_updates", 1)
+                vd.score = replay_tree_to_score(
+                    vd.score, vd.plane, table, np.int32(splits),
+                    self._leaf_values(tree), class_id)
+                continue
+            if packed is None:
+                packed = pack_ensemble(
+                    [tree], fixed_leaves=self.config.num_leaves,
+                    fixed_depth=self._depth_bound,
+                    fixed_cat_words=self._valid_cat_words(tree))
             vd.score = _add_valid_delta(vd.score, predict_raw(packed, vd.raw),
                                         class_id)
 

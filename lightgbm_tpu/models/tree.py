@@ -68,6 +68,10 @@ class Tree:
         self.cat_boundaries_inner: List[int] = [0]
         self.cat_threshold_inner: List[int] = []  # bitset words over bins
         self.shrinkage = 1.0
+        # threshold_in_bin / cat_threshold_inner hold the bins of the data
+        # set the tree was grown on: set by split / split_categorical, never
+        # by a tree read from model text (which has no bins of its own)
+        self.has_bin_thresholds = False
         self.is_linear = is_linear
         self.track_branch_features = track_branch_features
         self.branch_features: List[List[int]] = [[] for _ in range(max_leaves)] if track_branch_features else []
@@ -109,6 +113,7 @@ class Tree:
             dt |= _DEFAULT_LEFT_MASK
         dt |= np.int8((missing_type & 3) << 2)
         self.decision_type[new_node] = dt
+        self.has_bin_thresholds = True
         self._finish_split(new_node, leaf, new_leaf, left_value, right_value,
                            left_count, right_count, left_weight, right_weight,
                            parent_value, real_feature)
@@ -143,6 +148,7 @@ class Tree:
         self.cat_boundaries.append(self.cat_boundaries[-1] + len(value_bitset))
         self.cat_threshold.extend(int(w) for w in value_bitset)
         self.num_cat += 1
+        self.has_bin_thresholds = True
         self._finish_split(new_node, leaf, new_leaf, left_value, right_value,
                            left_count, right_count, left_weight, right_weight,
                            parent_value, real_feature)

@@ -96,3 +96,24 @@ def test_categorical_with_numerical_mix():
                      "verbosity": -1}, ds, num_boost_round=20)
     acc = np.mean((bst.predict(X) > 0.5) == y)
     assert acc > 0.93, acc
+
+
+@pytest.mark.parametrize("boosting", ["gbdt", "rf"])
+def test_bagged_out_of_bag_rows_follow_the_categorical_sets(boosting):
+    """Under bagging the out-of-bag rows' training scores come from the
+    bin-space traversal of each tree (ops/score.py add_tree_to_score):
+    categorical nodes route them by the bit of their bin in the left set,
+    as prediction does by category, never numerically by bin <= k."""
+    X, y, _ = _cat_data(n=4000, n_cats=20)
+    ds = lgb.Dataset(X, label=y, categorical_feature=[0])
+    bst = lgb.train({"objective": "binary", "num_leaves": 7,
+                     "boosting": boosting, "bagging_fraction": 0.5,
+                     "bagging_freq": 1, "min_data_per_group": 5,
+                     "cat_smooth": 1, "verbosity": -1},
+                    ds, num_boost_round=5)
+    g = bst._gbdt
+    assert any((t.decision_type[:t.num_leaves - 1] & 1).any()
+               for t in g.models)
+    np.testing.assert_allclose(np.asarray(g.score[0]),
+                               bst.predict(X, raw_score=True),
+                               rtol=1e-5, atol=1e-5)

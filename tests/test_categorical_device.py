@@ -385,12 +385,18 @@ def test_a_mapper_that_bins_other_categories_fails_the_map_check(
         "bin_map_mismatch"] == 0
 
 
-def test_the_validation_pack_grows_with_the_tree_s_words_not_the_codes():
+def test_the_validation_pack_grows_with_the_tree_s_words_not_the_codes(
+        monkeypatch):
     """Category codes in the millions: each per-tree
     validation pack holds the tree's own bitset words rounded up to a power
     of two (at least gbdt.VALID_CAT_WORDS), not num_leaves - 1 times the
-    words of the largest code, and scores the test set as predict does."""
+    words of the largest code, and scores the test set as predict does.
+    The pack is the fallback of a set without a plane (one not binned with
+    the training set's mappers) and of a tree read from model text; the
+    set here is given none."""
     from lightgbm_tpu.models import gbdt as gbdt_mod
+
+    monkeypatch.setattr(gbdt_mod.GBDT, "_valid_plane", lambda self, v: None)
 
     rng = np.random.default_rng(5)
     X, y = _table(rng, 5000, False)

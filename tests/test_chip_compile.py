@@ -678,3 +678,29 @@ def test_categorical_whole_tree_program_fits_one_chip_at_expo(
     test_bytes = EXPO_TEST_ROWS * (8 + 1) * 4
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + test_bytes < HBM_BYTES
+
+
+@pytest.mark.slow
+def test_binned_validation_update_fits_one_chip_at_expo(on_chip, capsys):
+    """The Expo cell's test-set update (models/gbdt.py
+    `_update_valid_scores` on a categorical run): one tree of 255 leaves
+    replayed over the 1,000,000-row uint16 plane [8, N], rows on the lanes,
+    the score donated. What it asks of the chip beside the tree program:
+    a few row vectors of temp, and no array with the rows on the sublanes."""
+    from lightgbm_tpu.ops.score import SPLIT_TABLE_FIELDS, replay_tree_to_score
+
+    n, words = EXPO_TEST_ROWS, 9  # ~276 bins a group: 9 words of 32
+    compiled = replay_tree_to_score.lower(
+        on_chip((1, n), jnp.float32), on_chip((8, n), jnp.uint16),
+        on_chip((254, len(SPLIT_TABLE_FIELDS) + words), jnp.int32),
+        on_chip((), jnp.int32), on_chip((255,), jnp.float32),
+        class_id=0).compile()
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nAOT binned validation update at {n} x 8 uint16: temp "
+              f"{mem.temp_size_in_bytes} arguments "
+              f"{mem.argument_size_in_bytes} output "
+              f"{mem.output_size_in_bytes}")
+    assert _rows_on_sublanes(compiled, n) == []
+    assert " gather(" not in compiled.as_text()
+    assert mem.temp_size_in_bytes < 64 * n
