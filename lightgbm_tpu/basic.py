@@ -430,13 +430,12 @@ class Booster:
             Log.fatal("Validation-set evaluation needs raw feature values; "
                       "this Dataset has none (e.g. reloaded from a binary "
                       "cache of sparse input)")
-        if raw is None and getattr(data, "_sparse_raw", None) is not None:
-            # valid-set eval traverses raw feature values on device; a
-            # sparse VALID set densifies here (valid << train in practice —
-            # the train matrix itself is never densified). astype BEFORE
-            # toarray: the f32 conversion on the sparse side halves the
-            # transient peak vs densify-then-cast.
-            raw = data._sparse_raw.astype(np.float32).toarray()
+        if raw is None:
+            # a sparse set stays sparse: the booster makes it dense only if
+            # a tree needs the one-tree pack over raw values
+            # (models/gbdt.py _ValidData.raw); a set binned with the
+            # training mappers of a bundled or categorical run never does
+            raw = data._sparse_raw
         self._gbdt.add_valid(data._handle, raw, name)
         self.name_valid_sets.append(name)
         return self

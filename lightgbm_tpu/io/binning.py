@@ -18,7 +18,7 @@ arrays ship to device as part of the binned matrix build.
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -38,6 +38,38 @@ def _next_after_up(a: float) -> float:
 
 def _check_double_equal_ordered(a: float, b: float) -> bool:
     return b <= _next_after_up(a)
+
+
+def _distinct(sorted_vals: np.ndarray, zero_cnt: int
+              ) -> Tuple[List[float], List[int]]:
+    """bin.cpp:339-361 over sorted non-NaN values: (distinct values,
+    counts), a value within one float64 step above its predecessor merged
+    into its run (the run keeps its last value), and (0.0, zero_cnt) put
+    before the first value, between the negative and the positive values,
+    or after the last, where the source puts it."""
+    n = sorted_vals.size
+    if n == 0:
+        return [0.0], [zero_cnt]
+    # a run starts where a value is more than one step above the one before
+    starts = np.flatnonzero(np.concatenate((
+        [True], sorted_vals[1:] > np.nextafter(sorted_vals[:-1], np.inf))))
+    ends = np.append(starts[1:], n)
+    values = sorted_vals[ends - 1].astype(np.float64).tolist()
+    counts = (ends - starts).tolist()
+    # the one break where the values cross zero (a negative run's last
+    # value before a positive run's first) takes the zeros, however many
+    cross = np.flatnonzero((sorted_vals[starts[1:] - 1] < 0.0)
+                           & (sorted_vals[starts[1:]] > 0.0))
+    for k in cross[::-1].tolist():
+        values.insert(k + 1, 0.0)
+        counts.insert(k + 1, zero_cnt)
+    if sorted_vals[0] > 0.0 and zero_cnt > 0:
+        values.insert(0, 0.0)
+        counts.insert(0, zero_cnt)
+    if sorted_vals[-1] < 0.0 and zero_cnt > 0:
+        values.append(0.0)
+        counts.append(zero_cnt)
+    return values, counts
 
 
 def greedy_find_bin(distinct_values: Sequence[float], counts: Sequence[int],
@@ -263,28 +295,7 @@ class BinMapper:
 
         # distinct values with zero folded in at the right place
         sorted_vals = np.sort(non_na, kind="stable")
-        distinct_values: List[float] = []
-        counts: List[int] = []
-        if len(sorted_vals) == 0 or (sorted_vals[0] > 0.0 and zero_cnt > 0):
-            distinct_values.append(0.0)
-            counts.append(zero_cnt)
-        if len(sorted_vals) > 0:
-            distinct_values.append(float(sorted_vals[0]))
-            counts.append(1)
-        for i in range(1, len(sorted_vals)):
-            prev, cur = float(sorted_vals[i - 1]), float(sorted_vals[i])
-            if not _check_double_equal_ordered(prev, cur):
-                if prev < 0.0 and cur > 0.0:
-                    distinct_values.append(0.0)
-                    counts.append(zero_cnt)
-                distinct_values.append(cur)
-                counts.append(1)
-            else:
-                distinct_values[-1] = cur
-                counts[-1] += 1
-        if len(sorted_vals) > 0 and sorted_vals[-1] < 0.0 and zero_cnt > 0:
-            distinct_values.append(0.0)
-            counts.append(zero_cnt)
+        distinct_values, counts = _distinct(sorted_vals, zero_cnt)
 
         self.min_val = distinct_values[0]
         self.max_val = distinct_values[-1]

@@ -23,6 +23,8 @@ or text files (io/parser.py).
 """
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -30,8 +32,10 @@ import numpy as np
 from .binning import (BIN_TYPE_CATEGORICAL, BIN_TYPE_NUMERICAL, BinMapper)
 from .metadata import Metadata
 from ..common import MISSING_NAN, MISSING_NONE, MISSING_ZERO
+from .. import tracing
 from ..config import Config
 from ..utils.log import Log
+from ..utils.timer import global_timer
 
 
 class FeatureGroup:
@@ -81,19 +85,34 @@ class FeatureGroup:
         return off, off + m.num_bin - 1, m.default_bin
 
 
+BIN_THREADS = min(8, os.cpu_count() or 1)  # threads binning sparse groups
+
+
 def _is_sparse(data) -> bool:
     """scipy.sparse duck-check (no hard scipy dependency)."""
     return hasattr(data, "tocsc") and hasattr(data, "nnz")
 
 
-def _column(data, j: int) -> np.ndarray:
-    """Dense f64 view of column j for ndarray or CSC input — sparse stays
-    sparse end to end except for one transient column at a time. Slice
-    syntax (not getcol) so both spmatrix and the newer sparse-array classes
-    (csc_array has no getcol) work."""
-    if _is_sparse(data):
-        return data[:, [j]].toarray().ravel().astype(np.float64)
+def _canonical(mat):
+    """A CSC or CSR matrix with sorted indices and no duplicate entries
+    (duplicates summed, as toarray() sums them); the input is not edited."""
+    if not mat.has_canonical_format:
+        mat = mat.copy()
+        mat.sum_duplicates()
+    return mat
+
+
+def _column(data: np.ndarray, j: int) -> np.ndarray:
+    """Column j of a dense matrix. Sparse input never comes here: it is
+    read through its entries (`_csc_column`)."""
     return data[:, j]
+
+
+def _csc_column(csc, j: int) -> Tuple[np.ndarray, np.ndarray]:
+    """(rows, float64 values) of the entries column j of a canonical CSC
+    matrix stores: O(its entries), never a dense column."""
+    s, e = csc.indptr[j], csc.indptr[j + 1]
+    return csc.indices[s:e], csc.data[s:e].astype(np.float64)
 
 
 def _sample_for_binning(col: np.ndarray, sample_cnt: int, rng: np.random.RandomState) -> Tuple[np.ndarray, int]:
@@ -114,14 +133,21 @@ def _sample_for_binning(col: np.ndarray, sample_cnt: int, rng: np.random.RandomS
 def find_feature_groups(mappers: List[BinMapper], sample_nonzero: List[np.ndarray],
                         sample_total: int, used_features: List[int],
                         max_conflict_rate: float, enable_bundle: bool,
-                        max_bin_per_group: int = 256) -> List[List[int]]:
+                        max_bin_per_group: int = 256,
+                        stats: Optional[dict] = None) -> List[List[int]]:
     """Exclusive Feature Bundling — greedy conflict-bounded grouping.
 
     Behavioral counterpart of GetConflictCount/FindGroups (dataset.cpp:64-249):
-    features are visited in descending non-zero count; each joins the first
-    existing bundle whose accumulated conflicts stay under
-    max_conflict_rate * sample_total, else starts a new bundle. Conflicts are
-    computed on boolean non-default masks over the binning sample.
+    sparse numerical features are visited in feature order; each joins the
+    first existing bundle whose accumulated conflicts stay under
+    max_conflict_rate * sample_total, else starts a new bundle. Conflicts
+    are counted over the binning sample: `sample_nonzero[f]` holds the
+    sample positions where feature f is non-zero and not NaN, and a bundle
+    keeps a mark a position, so a feature costs O(its non-zeros) a bundle
+    it tries. A row on which two members of a bundle are non-default
+    keeps the LATER member's bin (Dataset._bin_rows): the earlier member
+    reads as its default there. `stats`, where given, receives the
+    sample's conflicts the bundles admitted (`sample_conflicts`).
     """
     if not enable_bundle or len(used_features) <= 1:
         return [[f] for f in used_features]
@@ -135,32 +161,34 @@ def find_feature_groups(mappers: List[BinMapper], sample_nonzero: List[np.ndarra
             dense.append(f)
     if len(sparse_feats) <= 1:
         return [[f] for f in used_features]
-    order = sorted(sparse_feats, key=lambda f: -len(sample_nonzero[f]))
     max_conflicts = int(max_conflict_rate * sample_total)
     groups: List[List[int]] = []
-    group_masks: List[np.ndarray] = []
+    group_marks: List[np.ndarray] = []
     group_conflicts: List[int] = []
     group_bins: List[int] = []
-    for f in order:
-        mask = sample_nonzero[f]
-        nnz = int(mask.sum())
+    for f in sparse_feats:
+        rows = sample_nonzero[f]
         placed = False
         for gi in range(len(groups)):
             if group_bins[gi] + mappers[f].num_bin - 1 > max_bin_per_group:
                 continue
-            conflict = int(np.count_nonzero(group_masks[gi] & mask))
+            conflict = int(np.count_nonzero(group_marks[gi][rows]))
             if group_conflicts[gi] + conflict <= max_conflicts:
                 groups[gi].append(f)
-                group_masks[gi] |= mask
+                group_marks[gi][rows] = True
                 group_conflicts[gi] += conflict
                 group_bins[gi] += mappers[f].num_bin - 1
                 placed = True
                 break
         if not placed:
             groups.append([f])
-            group_masks.append(mask.copy())
+            mark = np.zeros(sample_total, dtype=bool)
+            mark[rows] = True
+            group_marks.append(mark)
             group_conflicts.append(0)
             group_bins.append(1 + mappers[f].num_bin - 1)
+    if stats is not None:
+        stats["sample_conflicts"] = int(sum(group_conflicts))
     out = [[f] for f in dense]
     out.extend(g for g in groups)
     # keep original feature order inside each bundle for determinism
@@ -208,10 +236,10 @@ class Dataset:
         self = cls(config)
         if _is_sparse(data):
             # sparse path (DatasetLoader::ConstructFromSampleData with CSR
-            # input, c_api.cpp LGBM_DatasetCreateFromCSR): keep the matrix
-            # CSC and densify ONE COLUMN at a time — peak memory is the
-            # uint8 bin matrix + a single f64 column, never the dense raw
-            data = data.tocsc()
+            # input, c_api.cpp LGBM_DatasetCreateFromCSR): the matrix stays
+            # sparse and construction costs O(entries): the binning sample
+            # is a row slice of the CSR, each group is binned from its
+            # members' CSC entries, and no column is ever made dense
             if data.dtype not in (np.float32, np.float64):
                 data = data.astype(np.float64)
         else:
@@ -241,7 +269,8 @@ class Dataset:
             self._align_with(reference, data)
             return self
 
-        group_lists = self._fit_layout(data, categorical_feature)
+        with global_timer.scope("construct_fit"):
+            group_lists = self._fit_layout(data, categorical_feature)
         self._build_groups_and_bins(group_lists, data)
         return self
 
@@ -268,9 +297,13 @@ class Dataset:
                 Log.fatal("max_bin_by_feature should be greater than 1")
 
         self.mappers = []
-        sample_nonzero_masks: List[np.ndarray] = []
+        sample_nonzero_rows: List[np.ndarray] = []
         sample_idx = (rng.choice(n, sample_cnt, replace=False)
                       if n > sample_cnt else np.arange(n))
+        # sparse input: the sampled rows, sliced from the CSR and turned
+        # into columns (O(the sample's entries))
+        sample = (_canonical(data.tocsr()[sample_idx].tocsc())
+                  if _is_sparse(data) else None)
         # forcedbins_filename (DatasetLoader::GetForcedBins,
         # src/io/dataset_loader.cpp): JSON [{"feature", "bin_upper_bound"}]
         forced_by_feature: Dict[int, List[float]] = {}
@@ -285,14 +318,20 @@ class Dataset:
             except OSError:
                 Log.warning("Could not open %s", config.forcedbins_filename)
         for j in range(f):
-            col = _column(data, j)[sample_idx]
-            nonzero = col[(col != 0) | np.isnan(col)]
+            if sample is None:
+                col = _column(data, j)[sample_idx]
+                nonzero = col[(col != 0) | np.isnan(col)]
+                nz_rows = np.flatnonzero((col != 0) & ~np.isnan(col))
+            else:  # the same values: find_bin sorts them
+                rows, vals = _csc_column(sample, j)
+                nonzero = vals[(vals != 0) | np.isnan(vals)]
+                nz_rows = rows[(vals != 0) & ~np.isnan(vals)]
             mapper = BinMapper()
             bt = BIN_TYPE_CATEGORICAL if j in cat_set else BIN_TYPE_NUMERICAL
             mb = config.max_bin
             if config.max_bin_by_feature and j < len(config.max_bin_by_feature):
                 mb = config.max_bin_by_feature[j]
-            mapper.find_bin(nonzero, len(col), mb,
+            mapper.find_bin(nonzero, len(sample_idx), mb,
                             min_data_in_bin=config.min_data_in_bin,
                             min_split_data=config.min_data_in_leaf,
                             pre_filter=config.feature_pre_filter,
@@ -301,7 +340,7 @@ class Dataset:
                             zero_as_missing=config.zero_as_missing,
                             forced_upper_bounds=forced_by_feature.get(j, ()))
             self.mappers.append(mapper)
-            sample_nonzero_masks.append((col != 0) & ~np.isnan(col))
+            sample_nonzero_rows.append(nz_rows)
 
         self.used_features = [j for j in range(f) if not self.mappers[j].is_trivial]
         if not self.used_features:
@@ -310,15 +349,36 @@ class Dataset:
                         "min_data_in_bin or min_data_in_leaf and re-constructing "
                         "Dataset might resolve this warning.")
 
+        stats = {"sample_conflicts": 0}
         group_lists = find_feature_groups(
-            self.mappers, sample_nonzero_masks, len(sample_idx),
-            self.used_features, self.config.max_conflict_rate if hasattr(self.config, "max_conflict_rate") else 0.0,
-            enable_bundle=self.config.enable_bundle)
+            self.mappers, sample_nonzero_rows, len(sample_idx),
+            self.used_features, self.config.max_conflict_rate,
+            enable_bundle=self.config.enable_bundle, stats=stats)
+        self._sample_conflicts = stats["sample_conflicts"]
         return group_lists
 
     def _build_groups_and_bins(self, group_lists: List[List[int]], data: np.ndarray) -> None:
         self._make_groups(group_lists)
-        self.bins = self._bin_rows(data)
+        with global_timer.scope("construct_bin"):
+            self.bins = self._bin_rows(data)
+        self._note_layout(data)
+
+    def _note_layout(self, data) -> None:
+        """The `efb_layout` flight note of a construction, and the
+        `efb_conflict_rows` counter: what bundling made of the table."""
+        bundles = [g for g in self.groups if g.is_multi]
+        global_timer.add_count("efb_conflict_rows", self.conflict_rows)
+        tracing.note(
+            "efb_layout", used_features=len(self.used_features),
+            groups=len(self.groups), bundles=len(bundles),
+            bundled_features=sum(len(g.feature_indices) for g in bundles),
+            max_group_bins=max((g.num_total_bin for g in self.groups),
+                               default=0),
+            nnz=int(data.nnz if _is_sparse(data)
+                    else data.shape[0] * data.shape[1]),
+            sample_conflicts=int(getattr(self, "_sample_conflicts", 0)),
+            conflict_rows=self.conflict_rows,
+            aligned=int(self._reference is not None))
 
     def _make_groups(self, group_lists: List[List[int]]) -> None:
         """Materialize FeatureGroups + the feature->(group, member) map from
@@ -340,10 +400,27 @@ class Dataset:
         """Bin an arbitrary row matrix against the FITTED layout into a
         [num_groups, n_rows] plane. Binning is per-row independent, so
         concatenating per-block planes equals one one-shot plane exactly —
-        the invariant streaming ingest relies on."""
+        the invariant streaming ingest relies on. A bundle's row keeps the
+        later member's bin where two members are non-default (the rows
+        where that happened: `conflict_rows`). Sparse input is binned from
+        its CSC entries alone: a group's rows start at the bin of 0 and
+        only the stored entries are binned and placed."""
         n_rows = data.shape[0]
         dtype = self.bins_dtype()
         bins = np.zeros((len(self.groups), n_rows), dtype=dtype)
+        self.conflict_rows = 0
+        if _is_sparse(data):
+            csc = _canonical(data.tocsc())
+            # groups are independent: each binned into its own row of the
+            # plane on a thread (numpy lets go of the GIL in the lookups
+            # and the scatters); the counts are summed in the groups' order
+            with ThreadPoolExecutor(min(BIN_THREADS, len(self.groups)) or 1
+                                    ) as pool:
+                self.conflict_rows = sum(pool.map(
+                    lambda gi: self._bin_group_sparse(
+                        self.groups[gi], csc, bins[gi]),
+                    range(len(self.groups))))
+            return bins
         for gi, fg in enumerate(self.groups):
             if not fg.is_multi:
                 j = fg.feature_indices[0]
@@ -357,9 +434,34 @@ class Dataset:
                     # exclusivity: at most one member non-default per row;
                     # on conflict the later feature wins (matches bundle
                     # push order semantics)
+                    self.conflict_rows += int(np.count_nonzero(
+                        (gb != 0) & (acc != 0)))
                     acc = np.where(gb != 0, gb, acc)
                 bins[gi] = acc.astype(dtype)
         return bins
+
+    def _bin_group_sparse(self, fg: FeatureGroup, csc,
+                          acc: np.ndarray) -> int:
+        """One group's row of the plane, `acc` (zeros), from the members'
+        stored entries: what the dense loop above makes of the same matrix,
+        bit for bit. Returns the group's count of conflicting rows."""
+        conflicts = 0
+        for mi, j in enumerate(fg.feature_indices):
+            rows, vals = _csc_column(csc, j)
+            mapper = self.mappers[j]
+            zero_bin = fg.bin_for_feature(
+                mi, mapper.values_to_bins(np.zeros(1)))[0]
+            gb = fg.bin_for_feature(mi, mapper.values_to_bins(vals))
+            if not fg.is_multi:
+                acc[:] = zero_bin
+                acc[rows] = gb
+                continue
+            before = acc[rows]
+            if zero_bin != 0:
+                acc[:] = zero_bin
+            conflicts += int(np.count_nonzero((gb != 0) & (before != 0)))
+            acc[rows] = np.where(gb != 0, gb, before)
+        return conflicts
 
     @classmethod
     def from_layout(cls, layout: "Dataset", bins: np.ndarray, num_data: int,
@@ -440,7 +542,7 @@ class Dataset:
         self._loaded_raw = raw if raw.size else None  # single npz read
         return self
 
-    def _align_with(self, reference: "Dataset", data: np.ndarray) -> None:
+    def _align_with(self, reference: "Dataset", data) -> None:
         self._reference = reference
         self.mappers = reference.mappers
         self.used_features = reference.used_features

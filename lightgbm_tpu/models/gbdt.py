@@ -148,13 +148,32 @@ class _ValidData:
     where its trees are added in bin space (`GBDT._valid_plane`), its
     binned plane [G, Nv] at the plane's own width."""
 
-    def __init__(self, dataset: Dataset, raw: np.ndarray, metrics,
+    def __init__(self, dataset: Dataset, raw, metrics,
                  plane: Optional[jax.Array] = None) -> None:
         self.dataset = dataset
-        self.raw = jnp.asarray(raw, dtype=jnp.float32)
+        self._raw_host = raw
+        self._raw: Optional[jax.Array] = None
         self.plane = plane
         self.metrics = metrics
         self.score: Optional[jax.Array] = None
+        if plane is None:  # every tree takes the one-tree pack
+            _ = self.raw
+
+    @property
+    def raw(self) -> jax.Array:
+        """The set's raw float32 matrix on the device, placed when a tree
+        first needs the one-tree pack (at `add_valid` for a set without a
+        plane). A sparse set is made dense here and nowhere else, so a set
+        whose every tree takes the bin-space update never is."""
+        if self._raw is None:
+            raw = self._raw_host
+            if hasattr(raw, "toarray"):
+                # astype BEFORE toarray: the f32 conversion on the sparse
+                # side halves the transient peak vs densify-then-cast
+                raw = raw.astype(np.float32).toarray()
+            self._raw = jnp.asarray(raw, dtype=jnp.float32)
+            self._raw_host = None
+        return self._raw
 
 
 class GBDT:
@@ -271,20 +290,26 @@ class GBDT:
         """Decided once a set, from what the run shows: a validation set
         takes each tree in bin space (`_update_valid_scores`) when the
         training set has a categorical used feature (whose trees the dense
-        predict program cannot score) and the set was binned with the
+        predict program cannot score) or an EFB bundle (whose raw matrix
+        is as wide as the table: thousands of one-hot columns, in the
+        tables bundling is for), and the set was binned with the
         training set's mappers into its groups (constructed with
         `reference=`). Its plane is then placed on the device once, rows on
-        the lanes. A numerical run keeps the one-tree pack: the dense
-        program on the TPU, the gather traversal off it."""
+        the lanes. A run with neither a categorical feature nor a bundle
+        keeps the one-tree pack: the dense program on the TPU, the gather
+        traversal off it."""
         ds = self.train_set
-        if (not self._has_categorical() or valid.bins is None
+        bundled = any(g.is_multi for g in ds.groups)
+        if (not (self._has_categorical() or bundled) or valid.bins is None
                 or valid.mappers is not ds.mappers
                 or [g.feature_indices for g in valid.groups]
                 != [g.feature_indices for g in ds.groups]):
             return None
         if self._split_fields is None:
-            self._split_fields = (feature_split_fields(ds),
-                                  cat_table_words(ds))
+            # no categorical node, no bitset words in the table
+            self._split_fields = (
+                feature_split_fields(ds),
+                cat_table_words(ds) if self._has_categorical() else 0)
         return jnp.asarray(valid.bins, dtype=valid.bins.dtype)
 
     # --------------------------------------------------------------- boosting
@@ -743,7 +768,7 @@ class GBDT:
             binned = (vd.plane is not None and tree.has_bin_thresholds
                       and not tree.is_linear)
             tracing.note("valid_update", binned=int(binned),
-                         rows=int(vd.raw.shape[0]), splits=splits)
+                         rows=int(vd.dataset.num_data), splits=splits)
             if binned:
                 if table is None:
                     fields, n_words = self._split_fields

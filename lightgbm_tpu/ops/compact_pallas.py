@@ -295,23 +295,30 @@ def _limbs(x_int: jax.Array, n: int) -> jax.Array:
     return jnp.concatenate(parts, axis=0)
 
 
-def _make_compact_kernel(tile: int, gp: int, rc: int, plane8: bool):
+def _make_compact_kernel(tile: int, gp: int, rc: int, plane8: bool,
+                         packed: bool):
     """plane8: the bin plane is an 8-bit dtype (uint8). Its values fit one
     bf16 limb, so the plane rides the transport matmul as Gp rows instead
     of 2*Gp, and the accumulate widens to i32 in-register (Mosaic has no
     elementwise 8-bit vectors) before narrowing back to the 8-bit output
-    block."""
+    block. packed: the pair's kind rides the input tile's table word
+    (`pair_tables_packed`), else it is a table of its own."""
 
-    def kernel(pin_ref, pout_ref, pcopy_ref, npair_ref,
-               bins_ref, row_ref, dst_ref, bins_out, row_out):
+    def kernel(*refs):
+        if packed:
+            pin_ref, pout_ref, npair_ref = refs[:3]
+        else:
+            pin_ref, pout_ref, pcopy_ref, npair_ref = refs[:4]
+        bins_ref, row_ref, dst_ref, bins_out, row_out = refs[-5:]
         p = pl.program_id(0)
         out_t = pout_ref[p]
         first = (p == 0) | (out_t != pout_ref[jnp.maximum(p - 1, 0)])
+        pcopy = pin_ref[p] >> PCOPY_SHIFT if packed else pcopy_ref[p]
         # pcopy == 2: duplicate pair demoted to a skip by build_pair_tables
         # (a duplicate is never the first pair of its output block, so the
         # zero-init below cannot be skipped by accident)
-        active = (p < npair_ref[0]) & (pcopy_ref[p] < 2)
-        is_copy = pcopy_ref[p] == 1
+        active = (p < npair_ref[0]) & (pcopy < 2)
+        is_copy = pcopy == 1
 
         @pl.when(active & is_copy)
         def _copy():  # untouched tile: single pair for this block, plain copy
@@ -367,6 +374,25 @@ def _make_compact_kernel(tile: int, gp: int, rc: int, plane8: bool):
     return kernel
 
 
+# The kernel's pair tables are scalar-prefetched into SMEM, one int32 a pair
+# each: the input tile, the output tile and the pair's kind. The v5e's SMEM
+# holds 1 MiB; three tables of max_pairs_bound(T, 2K) words (T = N / 512
+# tiles) fit to ~11 M rows a chip (11,000,000 ran on the chip) and take
+# 1.18 MiB at 13,184,290 (AOT, the compiler's own refusal). Past the budget
+# below the kind rides the input tile's word above PCOPY_SHIFT: two tables,
+# to ~16.7 M rows. Three where they fit: the packed word costs a grid step
+# a shift and three masks, 0.5-1.5 % of the kernel's time at 4,194,304 rows
+# on a v5e.
+PCOPY_SHIFT = 28
+SMEM_TABLE_BYTES = (1 << 20) - (8 << 10)
+
+
+def pair_tables_packed(max_pairs: int) -> bool:
+    """Whether the kind rides the input tile's word: three tables of
+    max_pairs int32 words would not fit SMEM_TABLE_BYTES."""
+    return 3 * 4 * max_pairs > SMEM_TABLE_BYTES
+
+
 @partial(jax.jit, static_argnames=("tile", "interpret"))
 def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
                          n_pairs, tile: int, interpret: bool):
@@ -374,21 +400,38 @@ def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
     rc = row_p.shape[0]
     mp = pair_in.shape[0]
     plane8 = bins_p.dtype.itemsize == 1
+    packed = pair_tables_packed(mp)
+    if packed:
+        if N // tile >= 1 << PCOPY_SHIFT:
+            raise ValueError(f"{N // tile} compaction tiles do not leave "
+                             "the pair table's kind its bits")
+        in_mask = (1 << PCOPY_SHIFT) - 1
+        tables = (pair_in | (is_copy << PCOPY_SHIFT), pair_out, n_pairs)
+
+        def in_map(p, pi, po, npr):
+            return 0, pi[p] & in_mask
+
+        def out_map(p, pi, po, npr):
+            return 0, po[p]
+    else:
+        tables = (pair_in, pair_out, is_copy, n_pairs)
+
+        def in_map(p, pi, po, pc, npr):
+            return 0, pi[p]
+
+        def out_map(p, pi, po, pc, npr):
+            return 0, po[p]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=len(tables),
         grid=(mp,),
-        in_specs=[
-            pl.BlockSpec((Gp, tile), lambda p, pi, po, pc, npr: (0, pi[p])),
-            pl.BlockSpec((rc, tile), lambda p, pi, po, pc, npr: (0, pi[p])),
-            pl.BlockSpec((1, tile), lambda p, pi, po, pc, npr: (0, pi[p])),
-        ],
-        out_specs=[
-            pl.BlockSpec((Gp, tile), lambda p, pi, po, pc, npr: (0, po[p])),
-            pl.BlockSpec((rc, tile), lambda p, pi, po, pc, npr: (0, po[p])),
-        ],
+        in_specs=[pl.BlockSpec((Gp, tile), in_map),
+                  pl.BlockSpec((rc, tile), in_map),
+                  pl.BlockSpec((1, tile), in_map)],
+        out_specs=[pl.BlockSpec((Gp, tile), out_map),
+                   pl.BlockSpec((rc, tile), out_map)],
     )
     return pl.pallas_call(
-        _make_compact_kernel(tile, Gp, rc, plane8),
+        _make_compact_kernel(tile, Gp, rc, plane8, packed),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((Gp, N), bins_p.dtype),
@@ -399,8 +442,7 @@ def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
         # the custom call after it, and the benchmark's
         # train.compact_kernel_ms_per_tree finds it by ^_pallas_compact_call
         name="_pallas_compact_call",
-    )(pair_in, pair_out, is_copy, n_pairs, bins_p, row_p,
-      dst.reshape(1, N))
+    )(*tables, bins_p, row_p, dst.reshape(1, N))
 
 
 def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,

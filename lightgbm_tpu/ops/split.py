@@ -19,7 +19,9 @@ ordered scan and added to the left side in the "default-left" lane only.
 
 Bundled features (EFB) omit their default bin in group storage; it is
 reconstructed here from the leaf totals exactly like Dataset::FixHistogram
-(include/LightGBM/dataset.h:770).
+(include/LightGBM/dataset.h:770). A data set with a bundle gathers its
+features in width classes (`WidthClasses`): a bundle's one-hot members at
+their own two bins, not padded to the widest feature's.
 
 Gain/output formulas mirror feature_histogram.hpp GetSplitGains /
 CalculateSplittedLeafOutput: L1 soft-thresholding, L2 shrinkage,
@@ -36,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common import MISSING_NAN, MISSING_NONE, MISSING_ZERO
-from ..utils.timer import SCOPE_CAT_SCAN
+from ..utils.timer import SCOPE_BUNDLE_SCAN, SCOPE_CAT_SCAN
 
 K_EPSILON = 1e-15
 K_MIN_GAIN = -np.inf
@@ -62,11 +64,15 @@ class FeatureMeta:
     # static: the longest prefix the categorical scan evaluates,
     # min(max_cat_threshold, max_bins // 2); 0 = max_bins // 2
     cat_scan_len: int = 0
+    # where the data set has an EFB bundle and no categorical feature
+    # (decided once, from the layout): the scan's features in classes of
+    # their own gather width; None: one [F, Bmax] gather
+    width_classes: Optional["WidthClasses"] = None
 
     def tree_flatten(self):
         return ((self.gather_index, self.valid_slot, self.default_bin,
                  self.efb_omitted, self.missing_type, self.nbins,
-                 self.is_categorical, self.monotone),
+                 self.is_categorical, self.monotone, self.width_classes),
                 (self.real_feature, self.max_bins, self.hist_rows,
                  self.has_categorical, self.cat_scan_len))
 
@@ -74,10 +80,29 @@ class FeatureMeta:
 jax.tree_util.register_pytree_node(
     FeatureMeta,
     FeatureMeta.tree_flatten,
-    lambda aux, ch: FeatureMeta(*ch, real_feature=aux[0], max_bins=aux[1],
-                                hist_rows=aux[2], has_categorical=aux[3],
-                                cat_scan_len=aux[4]),
+    lambda aux, ch: FeatureMeta(*ch[:8], real_feature=aux[0],
+                                max_bins=aux[1], hist_rows=aux[2],
+                                has_categorical=aux[3], cat_scan_len=aux[4],
+                                width_classes=ch[8]),
 )
+
+
+class WidthClass(NamedTuple):
+    """Features of at most `meta.max_bins` bins, gathered at that width:
+    `meta` holds their rows of the whole data set's FeatureMeta, cut to
+    the width, and `ids` their dense feature indices."""
+
+    meta: FeatureMeta
+    ids: jax.Array  # [F_c] int32
+
+
+class WidthClasses(NamedTuple):
+    """The split scan's features in classes by their bin count, made once
+    from the layout (`width_classes`); `order[k]` is dense feature k's row
+    in the classes' records laid end to end."""
+
+    classes: Tuple[WidthClass, ...]
+    order: jax.Array  # [F] int32
 
 
 def make_feature_meta(dataset, group_bin_padded: int,
@@ -130,22 +155,52 @@ def make_feature_meta(dataset, group_bin_padded: int,
                 slot = lo + b - (1 if b > dbin else 0)
                 gather[k, b] = gi * group_bin_padded + slot
             efb_omitted[k] = True
+    has_bundle = any(g.is_multi for g in dataset.groups)
+    arrays = dict(gather_index=gather, valid_slot=valid,
+                  default_bin=default_bin, efb_omitted=efb_omitted,
+                  missing_type=missing, nbins=nbins, is_categorical=is_cat,
+                  monotone=mono)
     return FeatureMeta(
-        gather_index=jnp.asarray(gather, dtype=jnp.int32),
-        valid_slot=jnp.asarray(valid, dtype=jnp.bool_),
-        default_bin=jnp.asarray(default_bin, dtype=jnp.int32),
-        efb_omitted=jnp.asarray(efb_omitted, dtype=jnp.bool_),
-        missing_type=jnp.asarray(missing, dtype=jnp.int32),
-        nbins=jnp.asarray(nbins, dtype=jnp.int32),
-        is_categorical=jnp.asarray(is_cat, dtype=jnp.bool_),
-        monotone=jnp.asarray(mono, dtype=jnp.int32),
+        **{k: jnp.asarray(v, dtype=v.dtype) for k, v in arrays.items()},
         real_feature=list(feats),
         max_bins=Bmax,
         hist_rows=G * group_bin_padded,
         has_categorical=bool(is_cat.any()),
         cat_scan_len=(min(int(max_cat_threshold), Bmax // 2)
                       if max_cat_threshold > 0 else 0),
+        width_classes=(width_classes(arrays, list(feats), G * group_bin_padded)
+                       if has_bundle and not is_cat.any() else None),
     )
+
+
+def width_classes(arrays: dict, feats: list,
+                  hist_rows: int) -> Optional[WidthClasses]:
+    """Two classes of features by bin count, split where the gather is
+    smallest: the features of at most w bins gathered [F_n, w], the rest
+    [F_w, Bmax] (a bundled one-hot table's members at 2 bins beside its
+    numerical columns at 255). None where no split is smaller than one
+    [F, Bmax] gather. `arrays` are make_feature_meta's [F, ...] tables."""
+    nbins = arrays["nbins"]
+    F, Bmax = arrays["gather_index"].shape
+    cost = {int(w): int((nbins <= w).sum()) * int(w)
+            + int((nbins > w).sum()) * Bmax for w in np.unique(nbins)}
+    w = min(cost, key=lambda k: (cost[k], k))
+    if cost[w] >= F * Bmax:
+        return None
+    classes = []
+    for ids, width in ((np.flatnonzero(nbins <= w), w),
+                       (np.flatnonzero(nbins > w), Bmax)):
+        if ids.size == 0:
+            continue
+        sub = {k: jnp.asarray(v[ids, :width] if v.ndim == 2 else v[ids],
+                              dtype=v.dtype) for k, v in arrays.items()}
+        classes.append(WidthClass(FeatureMeta(
+            **sub, real_feature=[feats[i] for i in ids], max_bins=int(width),
+            hist_rows=hist_rows), jnp.asarray(ids, jnp.int32)))
+    order = np.empty(F, np.int32)
+    order[np.concatenate([np.asarray(c.ids) for c in classes])] = \
+        np.arange(F, dtype=np.int32)
+    return WidthClasses(tuple(classes), jnp.asarray(order, dtype=jnp.int32))
 
 
 class ScanMeta(NamedTuple):
@@ -435,6 +490,21 @@ def per_feature_best(fh: jax.Array, totals: jax.Array, meta: FeatureMeta,
     ], axis=1)
 
 
+def class_records(hist: jax.Array, totals: jax.Array, wc: WidthClass,
+                  params: jax.Array, feature_mask: Optional[jax.Array] = None,
+                  constraint: Optional[jax.Array] = None,
+                  penalty: Optional[jax.Array] = None) -> jax.Array:
+    """per_feature_best's records of one width class: its features gathered
+    and fixed at the class's width, the `feature` field the dense index."""
+    fh = gather_feature_hist(hist, wc.meta, totals)  # [F_c, w, 3]
+    recs = per_feature_best(
+        fh, totals, wc.meta, params,
+        None if feature_mask is None else feature_mask[wc.ids], constraint,
+        None if penalty is None else penalty[wc.ids])
+    return recs.at[:, 1].set(jnp.where(recs[:, 1] >= 0,
+                                       wc.ids.astype(jnp.float32), -1.0))
+
+
 # A categorical split's left set travels in its record as bin-space bitset
 # words of CAT_WORD_BITS bits each, appended after SPLIT_FIELDS: a word is
 # below 2**16, exact in float32 and through a HIGHEST-precision matmul, so
@@ -685,6 +755,13 @@ def find_best_split(hist: jax.Array, totals: jax.Array, meta: FeatureMeta,
     penalty: optional [F] per-feature gain penalty (CEGB)
     Returns packed split record [split_record_width(meta)] float32.
     """
+    if meta.width_classes is not None:  # static: the layout has a bundle
+        with jax.named_scope(SCOPE_BUNDLE_SCAN):
+            wcs = meta.width_classes
+            recs = jnp.concatenate([
+                class_records(hist, totals, wc, params, feature_mask,
+                              constraint, penalty) for wc in wcs.classes])
+            return reduce_best_record(recs[wcs.order])
     fh = gather_feature_hist(hist, meta, totals)  # [F, Bmax, 3]
     recs = per_feature_best(fh, totals, meta, params, feature_mask,
                             constraint, penalty)

@@ -1199,6 +1199,9 @@ class DeviceTreeLearner(SerialTreeLearner):
     def __init__(self, config, dataset) -> None:
         super().__init__(config, dataset)
         self.tables = _feature_tables(dataset, dataset.used_features)
+        # which dense features are EFB bundle members (the `tree_wave`
+        # note's efb_splits counts the splits on them)
+        self._is_efb = np.asarray(self.tables.is_efb)
         self._row_arange = np.arange(self.num_data, dtype=np.int32)
         # `wave` is the width asked for, `wave_k` the width a wave can use
         # (a tree of L leaves never holds more than L candidates) and the
@@ -1339,8 +1342,10 @@ class DeviceTreeLearner(SerialTreeLearner):
             WORK_FIELDS, (int(v) for v in np.asarray(pending.work_counts))))
 
         counts: Dict[int, int] = {0: int(pending.n_bag)}
-        # categorical splits of this tree and the bins they sent left
+        # categorical splits of this tree and the bins they sent left;
+        # splits on EFB bundle members
         self.last_cat = {"cat_splits": 0, "cat_left_bins": 0}
+        efb_splits = 0
         for t in range(rec_np.shape[0]):
             row = rec_np[t]
             if row[3] < 0.5:  # valid flag: growth stopped here
@@ -1372,7 +1377,9 @@ class DeviceTreeLearner(SerialTreeLearner):
                     parent_value=float(row[1]))
             counts[leaf] = split.left_count
             counts[tree.num_leaves - 1] = split.right_count
+            efb_splits += int(self._is_efb[dense_f])
 
+        self.last_efb = {"efb_splits": efb_splits}
         self._record_wave_efficiency(pending, tree)
         self.partition = DevicePartition(leaf_id, counts, self.num_data)
         if tree.num_leaves == 1:
@@ -1405,13 +1412,16 @@ class DeviceTreeLearner(SerialTreeLearner):
                                    self.last_cat["cat_splits"])
             global_timer.add_count("device_cat_trees",
                                    int(self.last_cat["cat_splits"] > 0))
+        if self.meta.width_classes is not None:
+            global_timer.add_count("device_efb_splits",
+                                   self.last_efb["efb_splits"])
         # flight-recorder mirror: plain already-computed ints, O(1), no
         # sync — the one in-memory record per tree (a postmortem sees the
         # last trees' wave shape even with telemetry off)
         tracing.note("tree_wave", waves=n_waves, wave_k=wave_k,
                      committed=committed, speculated=speculated,
                      hist_rows=self.last_hist_rows,
-                     **self.last_work, **self.last_cat,
+                     **self.last_work, **self.last_cat, **self.last_efb,
                      hist_operand=self.hist_operand,
                      hist_int=int(self.hist_operand == "int"),
                      ici_bytes=n_waves * self._ici_bytes_per_wave,

@@ -81,6 +81,9 @@ SCOPE_RENEW_LEAVES = SCOPE_PREFIX + "renew_leaves"
 # and the categorical decision of the wave's routing (inside lgbm.route)
 SCOPE_CAT_SCAN = SCOPE_PREFIX + "cat_scan"
 SCOPE_CAT_ROUTE = SCOPE_PREFIX + "cat_route"
+# the split scan of a data set with an EFB bundle, its features gathered
+# in width classes (ops/split.py, inside lgbm.scan)
+SCOPE_BUNDLE_SCAN = SCOPE_PREFIX + "bundle_scan"
 # the binary AUC metric's one program (metrics/binary.py)
 SCOPE_EVAL_AUC = SCOPE_PREFIX + "eval_auc"
 SCOPE_NODE_GATHER = SCOPE_PREFIX + "node_gather"

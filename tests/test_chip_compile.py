@@ -704,3 +704,69 @@ def test_binned_validation_update_fits_one_chip_at_expo(on_chip, capsys):
     assert _rows_on_sublanes(compiled, n) == []
     assert " gather(" not in compiled.as_text()
     assert mem.temp_size_in_bytes < 64 * n
+
+
+ALLSTATE_ROWS = 13_184_290
+ALLSTATE_TEST_ROWS = 1_000_000
+
+
+def _allstate_learner():
+    """A DeviceTreeLearner over a 250,000-row table of the Allstate
+    configuration (benchmark/data_allstate.py, loaded by file): 4,228
+    columns as CSR, the one-hot levels bundled. The layout comes from the
+    binning's 200,000-row sample, as at the cell's size."""
+    import importlib.util
+    import json
+    import pathlib
+
+    bench = pathlib.Path(__file__).resolve().parent.parent / "benchmark"
+    spec = importlib.util.spec_from_file_location(
+        "data_allstate", bench / "data_allstate.py")
+    data = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(data)
+    cfg_file = json.loads((bench / "configs" / "allstate_efb.json").read_text())
+    sets = data.make_allstate_data(dict(cfg_file, rows=250_000,
+                                        test_rows=1000), 3640000101)
+    X, y = sets["train"]
+    cfg = Config(dict(cfg_file["params"], verbosity=-1))
+    ds = CoreDataset.from_matrix(X, label=y, config=cfg)
+    learner = device_mod.DeviceTreeLearner(cfg, ds)
+    assert learner.meta.width_classes is not None
+    return learner, cfg
+
+
+@pytest.mark.slow
+def test_bundled_whole_tree_program_fits_one_chip_at_allstate(
+        on_chip, monkeypatch, capsys):
+    """The benchmark's `allstate_efb.train` on ONE described v5e: the
+    whole-tree program at the Allstate row's 13,184,290 training rows on
+    the bundled plane, its split scan in width classes under
+    lgbm.bundle_scan, with room left for the test set's plane and score."""
+    monkeypatch.setattr(device_mod, "on_tpu", lambda: True)
+    monkeypatch.delenv("LGBM_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setenv("LGBM_TPU_HIST_F32", "1")
+    learner, cfg = _allstate_learner()
+    G = learner.bins_dev.shape[0]
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: on_chip(a.shape, a.dtype),
+                                      tree)
+
+    n = ALLSTATE_ROWS
+    lowered = device_mod.grow_tree_on_device.lower(
+        on_chip((G, n), learner.bins_dev.dtype), on_chip((n, 3), jnp.float32),
+        on_chip((n,), jnp.int32), abstract(learner.meta),
+        abstract(learner.tables), abstract(learner.params_dev),
+        on_chip((len(learner.meta.real_feature),), jnp.bool_),
+        num_leaves=255, num_bins=learner.group_bin_padded,
+        max_depth=cfg.max_depth, quantized=False, scale_vec=None,
+        batch=WAVE_K, bagged=False)
+    assert "lgbm.bundle_scan" in lowered.as_text(debug_info=True)
+    compiled = lowered.compile()
+    _tree_kernels(compiled, capsys, f"Allstate tree at {n} x {G} groups")
+    n_pad = -(-n // 1024) * 1024
+    assert _rows_on_sublanes(compiled, n_pad) == []
+    mem = compiled.memory_analysis()
+    test_bytes = ALLSTATE_TEST_ROWS * (G + 4)
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + test_bytes < HBM_BYTES

@@ -8,9 +8,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from lightgbm_tpu.ops import compact_pallas
 from lightgbm_tpu.ops.compact_pallas import (
     COMPACT_TILE, COMPACT_WORK_FIELDS, build_pair_tables, compact_rows,
-    exclusive_cumsum, max_pairs_bound, pair_work_counts, range_partition_dst)
+    exclusive_cumsum, max_pairs_bound, pair_tables_packed, pair_work_counts,
+    range_partition_dst)
 
 
 def _np_dst(go_left, ranges, n):
@@ -232,6 +234,47 @@ def test_compact_pallas_bit_exact(rng, name, ranges, tile):
     # bit-exact: limb transport must preserve f32 payloads exactly
     np.testing.assert_array_equal(
         np.asarray(ours_r).view(np.uint32), ref_r.view(np.uint32))
+
+
+@pytest.mark.parametrize("name,ranges", CASES)
+def test_the_packed_pair_word_moves_the_same_rows(rng, name, ranges,
+                                                  monkeypatch):
+    """Where three pair tables would not fit SMEM the pair's kind rides the
+    input tile's word: the same rows land in the same places, uint8 and
+    int32 planes, copy, permute and skip pairs."""
+    n, gp, rc, tile = 2048, 32, 8, 256
+    go_left = rng.rand(n) < 0.5
+    dst, _, lefts, args = _partition(go_left, ranges, n, tile)
+    row = rng.randn(rc, n).astype(np.float32)
+    row[0, ::5] = -0.0
+    monkeypatch.setattr(compact_pallas, "SMEM_TABLE_BYTES", 0)
+    jax.clear_caches()
+    try:
+        for bins in (rng.randint(0, 256, size=(gp, n)).astype(np.uint8),
+                     rng.randint(0, 60000, size=(gp, n)).astype(np.int32)):
+            ours_b, ours_r, _ = compact_rows(
+                jnp.asarray(bins), jnp.asarray(row), jnp.asarray(dst), lefts,
+                *args, tile=tile, use_pallas=True, interpret=True)
+            ref_b, ref_r = _permuted(bins, row, dst)
+            np.testing.assert_array_equal(np.asarray(ours_b), ref_b)
+            np.testing.assert_array_equal(
+                np.asarray(ours_r).view(np.uint32), ref_r.view(np.uint32))
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+
+
+@pytest.mark.parametrize("rows,packed", [(4_194_304, False),
+                                         (10_500_000, False),
+                                         (11_000_000, False),
+                                         (13_184_290, True)])
+def test_three_pair_tables_wherever_they_fit(rows, packed):
+    """The benchmark's one-chip row counts at a wave of 21 slots: the
+    tables stay three up to the Expo cell's 11,000,000 rows (as they ran on
+    the chip) and pack at the Allstate cell's 13,184,290, where three take
+    1.18 MiB of the v5e's 1 MiB SMEM."""
+    tiles = -(-rows // 1024) * 1024 // COMPACT_TILE
+    assert pair_tables_packed(max_pairs_bound(tiles, 2 * 21)) == packed
 
 
 def test_pair_list_holds_a_range_spanning_many_tiles(rng):

@@ -444,7 +444,7 @@ def test_the_table_of_scopes_is_the_constants():
     checked = set(TREE_SCOPES + PREDICT_SCOPES + DENSE_PREDICT_SCOPES
                   + ["allreduce", "gradients", "update_score", "quantize",
                      "renew_leaves", "valid_score", "eval_ndcg", "cat_scan",
-                     "cat_route", "eval_auc"]
+                     "cat_route", "eval_auc", "bundle_scan"]
                   + RANK_SCOPES)
     assert {v for v in SCOPES.values()} == {
         timer.SCOPE_PREFIX + name for name in checked}

@@ -234,10 +234,47 @@ def test_a_tree_read_from_model_text_takes_the_raw_path():
 
 
 def test_a_numerical_run_keeps_predict_raw():
+    """A run with neither a categorical feature nor a bundle (the sparse
+    pair kept apart: enable_bundle off) scores its set by the one-tree
+    pack."""
     sets = _sets(40)
-    notes, moved = _moved(lambda: _train(sets, {}, cats=[]))
+    notes, moved = _moved(lambda: _train(sets, {"enable_bundle": False},
+                                         cats=[]))
     assert [n["binned"] for n in notes] == [0] * TREES
     assert moved == [0, TREES, 0]  # off the TPU a numerical pack traverses
+
+
+def test_a_bundled_numerical_run_takes_the_bin_space_update(checked):
+    """The same numerical run with its sparse pair bundled: the set takes
+    every tree in bin space, bit for bit the raw traversal's numbers, its
+    table carrying no bitset word; its raw matrix is placed only for the
+    check's own traversal."""
+    sets = _sets(40)
+    notes, moved = _moved(lambda: _train(sets, {}, cats=[]))
+    assert [n["binned"] for n in notes] == [1] * TREES
+    assert moved[0] == TREES and moved[2] == 0
+    assert [b for b, _ in checked] == [1] * TREES
+    g = checked[0][1]
+    assert g.num_leaves > 1
+
+
+def test_a_sparse_bundled_set_is_never_made_dense():
+    """A CSR validation set of a bundled run keeps its entries on the host:
+    every tree takes the bin-space update, so no raw matrix is placed."""
+    import scipy.sparse as sp
+
+    sets = _sets(40)
+    X, y = sets["train"]
+    ds = lgb.Dataset(sp.csr_matrix(X[:, 3:]), label=y)
+    valid = lgb.Dataset(sp.csr_matrix(sets["valid"][0][:, 3:]),
+                        label=sets["valid"][1], reference=ds)
+    bst = lgb.train(PARAMS, ds, num_boost_round=2, valid_sets=[valid])
+    vd = bst._gbdt.valid_sets[0]
+    assert vd.plane is not None and vd._raw is None
+    np.testing.assert_allclose(
+        np.asarray(vd.score[0]),
+        bst.predict(sets["valid"][0][:, 3:], raw_score=True),
+        rtol=1e-6, atol=1e-6)
 
 
 def test_a_linear_tree_falls_back(checked):
