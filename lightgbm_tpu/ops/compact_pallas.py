@@ -191,8 +191,8 @@ def build_pair_tables(lefts: LeftCounts, starts: jax.Array,
     or range ends, so every lext[.] is one of lefts' samples.
 
     Returns (pair_in, pair_out, pcopy, n_pairs[1]) with static length
-    max_pairs_bound(T, 2 * K); entries past n_pairs repeat the last real
-    pair (same blocks -> the kernel skips DMA and compute for them). pcopy
+    max_pairs_bound(T, 2 * K); the kernel's grid is n_pairs long, and the
+    entries past it, never walked, repeat the last real pair. pcopy
     per pair: 0 = one-hot permute, 1 = raw block copy (untouched identity
     tile), 2 = SKIP (duplicate of the previous pair — processing it would
     double-count rows). Sorted by out_tile so the kernel revisits each
@@ -272,7 +272,8 @@ def build_pair_tables(lefts: LeftCounts, starts: jax.Array,
 # What one compaction call cost, as compact_rows hands it back beside the
 # arrays: the live pairs of its list, those that are a raw block copy, those
 # that are a one-hot permute (the rest of the live pairs are skipped
-# duplicates), and the static grid length the kernel steps through.
+# duplicates), and the steps the kernel's grid walks: the live pairs too,
+# since the grid's bound is n_pairs.
 COMPACT_WORK_FIELDS = ("compact_pairs", "compact_copy_pairs",
                        "compact_permute_pairs", "compact_grid_steps")
 
@@ -283,8 +284,7 @@ def pair_work_counts(pcopy: jax.Array, n_pairs: jax.Array) -> jax.Array:
     live = jnp.arange(pcopy.shape[0], dtype=jnp.int32) < n_pairs[0]
     return jnp.stack([
         n_pairs[0], jnp.sum(live & (pcopy == 1), dtype=jnp.int32),
-        jnp.sum(live & (pcopy == 0), dtype=jnp.int32),
-        jnp.int32(pcopy.shape[0])])
+        jnp.sum(live & (pcopy == 0), dtype=jnp.int32), n_pairs[0]])
 
 
 def _limbs(x_int: jax.Array, n: int) -> jax.Array:
@@ -306,9 +306,9 @@ def _make_compact_kernel(tile: int, gp: int, rc: int, plane8: bool,
 
     def kernel(*refs):
         if packed:
-            pin_ref, pout_ref, npair_ref = refs[:3]
+            pin_ref, pout_ref = refs[:2]
         else:
-            pin_ref, pout_ref, pcopy_ref, npair_ref = refs[:4]
+            pin_ref, pout_ref, pcopy_ref = refs[:3]
         bins_ref, row_ref, dst_ref, bins_out, row_out = refs[-5:]
         p = pl.program_id(0)
         out_t = pout_ref[p]
@@ -317,7 +317,7 @@ def _make_compact_kernel(tile: int, gp: int, rc: int, plane8: bool,
         # pcopy == 2: duplicate pair demoted to a skip by build_pair_tables
         # (a duplicate is never the first pair of its output block, so the
         # zero-init below cannot be skipped by accident)
-        active = (p < npair_ref[0]) & (pcopy < 2)
+        active = pcopy < 2
         is_copy = pcopy == 1
 
         @pl.when(active & is_copy)
@@ -406,24 +406,27 @@ def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
             raise ValueError(f"{N // tile} compaction tiles do not leave "
                              "the pair table's kind its bits")
         in_mask = (1 << PCOPY_SHIFT) - 1
-        tables = (pair_in | (is_copy << PCOPY_SHIFT), pair_out, n_pairs)
+        tables = (pair_in | (is_copy << PCOPY_SHIFT), pair_out)
 
-        def in_map(p, pi, po, npr):
+        def in_map(p, pi, po):
             return 0, pi[p] & in_mask
 
-        def out_map(p, pi, po, npr):
+        def out_map(p, pi, po):
             return 0, po[p]
     else:
-        tables = (pair_in, pair_out, is_copy, n_pairs)
+        tables = (pair_in, pair_out, is_copy)
 
-        def in_map(p, pi, po, pc, npr):
+        def in_map(p, pi, po, pc):
             return 0, pi[p]
 
-        def out_map(p, pi, po, pc, npr):
+        def out_map(p, pi, po, pc):
             return 0, po[p]
+    # the grid walks the live pairs alone: its bound is the traced n_pairs,
+    # an operand of the kernel call (every tile's identity pair is live, so
+    # n_pairs >= T and every output block is visited)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(tables),
-        grid=(mp,),
+        grid=(jnp.maximum(n_pairs[0], 1),),
         in_specs=[pl.BlockSpec((Gp, tile), in_map),
                   pl.BlockSpec((rc, tile), in_map),
                   pl.BlockSpec((1, tile), in_map)],

@@ -226,8 +226,8 @@ def tile_slot_pairs(starts: jax.Array, ends: jax.Array, valid: jax.Array,
     finds no row of the slot and writes zeros. n_active counts the distinct
     tiles among the live ranges' pairs: disjoint ranges share a tile only
     where one starts in the tile another ends in, so n_pairs <= n_tiles +
-    K. Entries past n_pairs repeat the last pair (same block indices => the
-    kernel pipeline skips the redundant DMA and pl.when skips compute).
+    K. The kernel's pair axis is n_pairs long; the entries past it, never
+    walked, repeat the last pair.
     """
     K = starts.shape[0]
     live = valid & (ends > starts)
@@ -279,8 +279,7 @@ def _make_slots_ragged_kernel(bins_p: int, tile_rows: int, ch: int,
     chp = -(-ch // 16) * 16  # a limb block starts on a packed bf16 tile
     quantized = jnp.issubdtype(jnp.dtype(acc_dtype), jnp.integer)
 
-    def kernel(tiles_ref, slots_ref, npairs_ref, bins_ref, gh_ref, slot_ref,
-               out_ref):
+    def kernel(tiles_ref, slots_ref, bins_ref, gh_ref, slot_ref, out_ref):
         del tiles_ref  # the index maps' alone
         p = pl.program_id(1)
         full_block = pl.program_id(0) < g_blocks - 1
@@ -292,51 +291,49 @@ def _make_slots_ragged_kernel(bins_p: int, tile_rows: int, ch: int,
         def _init():
             out_ref[...] = jnp.zeros_like(out_ref)
 
-        @pl.when(p < npairs_ref[0])
-        def _acc():
-            s = slot_ref[...]  # [1, TN] int32: rows on the lanes
-            ghc = gh_ref[...]  # [ch, TN] f32 (quantized: exact small ints)
-            # this slot's gradient tile, row j = channel, built [chp, TN]
-            # f32 in VMEM straight from the lane-major payload rows (a
-            # sublane broadcast each; rows past ch stay zero). Strictly 2D
-            # broadcasts: per-channel masked adds, not a concat (which
-            # lowers to a serial copy chain in Mosaic).
-            row = jax.lax.broadcasted_iota(jnp.int32, (chp, 1), 0)
-            gsum = jnp.zeros((chp, tile_rows), jnp.float32)
-            for c in range(ch):
-                gsum += ghc[c:c + 1, :] * (row == c).astype(jnp.float32)
-            # the MXU multiplies bfloat16: the slot's rows go in as exact
-            # bfloat16 limbs stacked on the sublanes, [limbs * chp, TN]; a
-            # row of another slot (or the dump slot) is a zero column
-            X = bf16_limbs(gsum * (s == k).astype(jnp.float32), limbs)
-            iota = jax.lax.broadcasted_iota(jnp.int32, (bins_p, tile_rows), 0)
+        s = slot_ref[...]  # [1, TN] int32: rows on the lanes
+        ghc = gh_ref[...]  # [ch, TN] f32 (quantized: exact small ints)
+        # this slot's gradient tile, row j = channel, built [chp, TN] f32 in
+        # VMEM straight from the lane-major payload rows (a sublane
+        # broadcast each; rows past ch stay zero). Strictly 2D broadcasts:
+        # per-channel masked adds, not a concat (which lowers to a serial
+        # copy chain in Mosaic).
+        row = jax.lax.broadcasted_iota(jnp.int32, (chp, 1), 0)
+        gsum = jnp.zeros((chp, tile_rows), jnp.float32)
+        for c in range(ch):
+            gsum += ghc[c:c + 1, :] * (row == c).astype(jnp.float32)
+        # the MXU multiplies bfloat16: the slot's rows go in as exact
+        # bfloat16 limbs stacked on the sublanes, [limbs * chp, TN]; a row
+        # of another slot (or the dump slot) is a zero column
+        X = bf16_limbs(gsum * (s == k).astype(jnp.float32), limbs)
+        iota = jax.lax.broadcasted_iota(jnp.int32, (bins_p, tile_rows), 0)
 
-            def group(gi):
-                # onehot[b, n] from the lane-major bin row: a sublane
-                # broadcast, no relayout; 0 and 1 are exact in bfloat16
-                b = bins_ref[gi:gi + 1, :].astype(jnp.int32)  # [1, TN]
-                onehot = (iota == b).astype(jnp.bfloat16)
-                # one MXU pass contracting the last dimension of both (the
-                # q @ k^T form): a limb times 0 or 1 is exact and the
-                # accumulation is float32, so the limb blocks of acc sum to
-                # the float32 histogram at the gradients' full 24 bits
-                acc = jax.lax.dot_general(
-                    X, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)  # [limbs*chp, Bp]
-                h = acc[:ch]
-                for i in range(1, limbs):
-                    h = h + acc[i * chp:i * chp + ch]
-                # quantized: per-tile partial sums are exact ints in f32
-                # (<= tile_rows * 127 * 255 < 2**24); accumulate int32
-                out_ref[0, gi] += h.astype(acc_dtype) if quantized else h
+        def group(gi):
+            # onehot[b, n] from the lane-major bin row: a sublane broadcast,
+            # no relayout; 0 and 1 are exact in bfloat16
+            b = bins_ref[gi:gi + 1, :].astype(jnp.int32)  # [1, TN]
+            onehot = (iota == b).astype(jnp.bfloat16)
+            # one MXU pass contracting the last dimension of both (the
+            # q @ k^T form): a limb times 0 or 1 is exact and the
+            # accumulation is float32, so the limb blocks of acc sum to the
+            # float32 histogram at the gradients' full 24 bits
+            acc = jax.lax.dot_general(
+                X, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [limbs*chp, Bp]
+            h = acc[:ch]
+            for i in range(1, limbs):
+                h = h + acc[i * chp:i * chp + ch]
+            # quantized: per-tile partial sums are exact ints in f32
+            # (<= tile_rows * 127 * 255 < 2**24); accumulate int32
+            out_ref[0, gi] += h.astype(acc_dtype) if quantized else h
 
-            for gi in range(last_block_groups):
-                group(gi)
-            if g_blocks > 1 and last_block_groups < group_block:
-                @pl.when(full_block)
-                def _rest():
-                    for gi in range(last_block_groups, group_block):
-                        group(gi)
+        for gi in range(last_block_groups):
+            group(gi)
+        if g_blocks > 1 and last_block_groups < group_block:
+            @pl.when(full_block)
+            def _rest():
+                for gi in range(last_block_groups, group_block):
+                    group(gi)
 
     return kernel
 
@@ -369,9 +366,9 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
     slot only under a listed pair of that slot, so every slot < n_slots
     must appear in `slots` (its block is otherwise never written) and rows
     outside every selected range carry slot >= n_slots (the dump slot).
-    `n_pairs` is a traced [1] int32 — tail entries of the table repeat the
-    last pair and are skipped. The root pass is the same call with one
-    slot.
+    `n_pairs` is a traced [1] int32, the pair axis's bound: the grid
+    walks the first n_pairs entries of the tables and no others. The root
+    pass is the same call with one slot.
 
     gh is ALWAYS [CH, N] f32 here: the gh rows of the leaf-contiguous
     payload, rows on the lanes like bins (block (CH, tile_rows)), and slot
@@ -408,16 +405,19 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
     g_pad = g_blocks * GB - G  # negative where the caller padded past it
     if g_pad > 0:
         bins = jnp.pad(bins, ((0, g_pad), (0, 0)), constant_values=0)
+    # the pair axis walks the live pairs alone: its bound is the traced
+    # n_pairs, an operand of the kernel call (every slot is listed, so
+    # n_pairs >= n_slots and every slot's block is written)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(g_blocks, tiles.shape[0]),
+        num_scalar_prefetch=2,
+        grid=(g_blocks, jnp.maximum(n_pairs[0], 1)),
         in_specs=[
-            pl.BlockSpec((GB, tile_rows), lambda g, p, tr, sl, n: (g, tr[p])),
-            pl.BlockSpec((CH, tile_rows), lambda g, p, tr, sl, n: (0, tr[p])),
-            pl.BlockSpec((1, tile_rows), lambda g, p, tr, sl, n: (0, tr[p])),
+            pl.BlockSpec((GB, tile_rows), lambda g, p, tr, sl: (g, tr[p])),
+            pl.BlockSpec((CH, tile_rows), lambda g, p, tr, sl: (0, tr[p])),
+            pl.BlockSpec((1, tile_rows), lambda g, p, tr, sl: (0, tr[p])),
         ],
         out_specs=pl.BlockSpec((1, GB, CH, bins_p),
-                               lambda g, p, tr, sl, n: (sl[p], g, 0, 0)),
+                               lambda g, p, tr, sl: (sl[p], g, 0, 0)),
     )
     out = pl.pallas_call(
         _make_slots_ragged_kernel(bins_p, tile_rows, CH, limbs, acc_dtype,
@@ -427,8 +427,8 @@ def pallas_histogram_slots_ragged(bins: jax.Array, gh: jax.Array,
                                        acc_dtype),
         interpret=interpret,
         name="pallas_histogram_slots_ragged",
-    )(tiles.astype(jnp.int32), slots.astype(jnp.int32),
-      n_pairs.astype(jnp.int32), bins, gh.astype(jnp.float32), slot)
+    )(tiles.astype(jnp.int32), slots.astype(jnp.int32), bins,
+      gh.astype(jnp.float32), slot)
     # [S, G, CH, B] -> [G, B, S*CH]
     return out[:, :n_groups, :, :num_bins].transpose(1, 3, 0, 2).reshape(
         n_groups, num_bins, n_slots * CH)

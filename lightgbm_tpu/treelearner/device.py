@@ -99,9 +99,10 @@ WAVE_K = 21
 # (root pass, initial compaction and every wave): the one int32 vector
 # `_grow_impl` carries through the wave loop, and the fields of the tree's
 # `tree_wave` note. The (row tile, slot) pairs the histogram kernel walked,
-# the distinct tiles among them and the static pair-axis length T + 2K of
-# its grid; the compaction kernel's COMPACT_WORK_FIELDS. All zeros where
-# the XLA bodies run instead of the kernels.
+# the distinct tiles among them and the steps of its grid's pair axis,
+# which is bounded by the pairs; the compaction kernel's
+# COMPACT_WORK_FIELDS. All zeros where the XLA bodies run instead of the
+# kernels.
 HIST_WORK_FIELDS = ("hist_tile_visits", "hist_tiles_active",
                     "hist_grid_steps")
 WORK_FIELDS = HIST_WORK_FIELDS + COMPACT_WORK_FIELDS
@@ -344,7 +345,8 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
         leaf-contiguous ranges, one a slot (slot must be the dump value
         outside), and [3] int32, HIST_WORK_FIELDS of the call: the (tile,
         slot) pairs the kernel walked, the distinct tiles among them and
-        the grid's static pair-axis length (zeros off the kernel path).
+        the steps of the grid's pair axis, which is as long as the pairs
+        (zeros off the kernel path).
         bins_c/row_c passed explicitly: inside the wave loop they are the
         CARRY arrays, not the pre-loop closure values."""
         with jax.named_scope(SCOPE_HIST):
@@ -356,9 +358,7 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
                     bins_c, ghc, slot, tiles, slots, n_pairs, num_bins,
                     n_slots, quantized=quantized, f32=hist_force_f32(),
                     n_groups=G, interpret=interp)
-                return h, jnp.concatenate([
-                    n_pairs, n_active,
-                    jnp.full(1, tiles.shape[0], jnp.int32)])
+                return h, jnp.concatenate([n_pairs, n_active, n_pairs])
             # XLA fallback: flat slot-expanded build over the full row set
             col_slot = jnp.arange(n_slots * CH, dtype=jnp.int32) // CH
             ghK = jnp.where(slot[:, None] == col_slot[None, :],

@@ -73,24 +73,33 @@ def _mosaic_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
-# What the four training cells' whole-tree programs ask (AOT, PR 35; the
-# parent's, PR 34, in brackets): temp bytes, then the scoped VMEM of the wave
-# histogram call / the root histogram call / the compaction call.
-#   2^20 x 28 float32      232,384,512 (231,934,976)   65,536 (327,680) / 65,536 (65,536) / 98,304
-#   2^20 x 28 quantized    232,512,512 (231,998,464)   65,536 (323,584) / 65,536 (65,536) / 98,304
+# What the training cells' whole-tree programs ask with both kernels' grids
+# bounded by the call's traced pair count (with the static grids of the
+# table's whole length in brackets): temp bytes, then the scoped VMEM of the
+# wave histogram call / the root histogram call / the compaction call, equal
+# under both grids.
+#   2^20 x 28 float32      232,415,232 (232,415,232)   65,536 / 65,536 / 98,304
+#   2^20 x 28 quantized    232,543,232 (232,543,232)   65,536 / 65,536 / 98,304
 #   10,502,144 x 28 on four chips, a chip
-#                          710,413,824 (710,519,296)  229,376 (716,800) / 229,376 (225,280) / 131,072
+#                          710,413,824 (710,413,824)  229,376 / 229,376 / 131,072
 #   10,500,000 x 28 quantized
-#                        2,160,010,240 (2,159,524,864) 98,304 (356,352) / 98,304 (98,304) / 131,072
+#                        2,160,010,240 (2,160,010,240) 98,304 / 98,304 / 131,072
 #   2,270,296 x 136 float32
-#                        1,035,742,720 (1,035,323,392) 221,184 (4,923,392) / 221,184 (217,088) / 598,016
+#                        1,035,774,976 (1,035,742,720) 221,184 / 221,184 / 598,016
+#   11,000,000 x 8 float32, int32 plane
+#                        3,709,595,136 (3,709,595,136) 286,720 / 286,720 / 131,072
+#   13,184,290 x 69 float32, two pair tables
+#                        3,677,789,184 (3,677,756,928) 253,952 / 253,952 / 323,584
 # The (tile, slot) pair kernel's operand and output block are one slot
 # high, so the wave call asks what the root call asks.
-def _tree_kernels(compiled, capsys, what: str) -> list:
+def _tree_kernels(compiled, capsys, what: str,
+                  compact_tables: int = 3) -> list:
     """The whole-tree program's histogram calls, after checking that it
     holds exactly two (root and waves: one kernel, `n_slots` 1 and WAVE_K)
-    and one compaction call; prints what the program asks of the chip
-    (PERF.md keeps the readings beside the parent's)."""
+    and one compaction call, each walking a grid bounded by its pair count
+    (`compact_tables`: 2 where the pair's kind rides the input tile's
+    word); prints what the program asks of the chip (PERF.md keeps the
+    readings beside the parent's)."""
     kernels = [ln for ln in compiled.as_text().splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     hist = [ln for ln in kernels if "pallas_histogram_slots_ragged" in ln]
@@ -102,7 +111,24 @@ def _tree_kernels(compiled, capsys, what: str) -> list:
               f"{_mosaic_vmem_bytes(compiled)}")
     assert len(hist) == 2 and len(kernels) - len(hist) == 1
     assert sum("_pallas_compact_call" in ln for ln in kernels) == 1
+    for ln in kernels:
+        _assert_grid_bound_operand(
+            ln, 2 if ln in hist else compact_tables)
     return hist
+
+
+def _assert_grid_bound_operand(call: str, tables: int) -> None:
+    """A Mosaic call whose grid is bounded by a traced pair count takes that
+    bound as an s32[] operand in front of its scalar-prefetched pair
+    tables: one more scalar operand than the tables, each of them an s32
+    vector of the same static length."""
+    constraints = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
+                            call).group(1)
+    operands = re.findall(r"[a-z]\w*\[[\d,]*\]", constraints)
+    assert operands[0] == "s32[]", operands
+    assert len(set(operands[1:1 + tables])) == 1, operands
+    assert re.fullmatch(r"s32\[\d+\]", operands[1]), operands
+    assert not re.fullmatch(r"s32\[\d+\]", operands[1 + tables]), operands
 
 
 def _compact_dst_operand(compiled) -> str:
@@ -197,6 +223,7 @@ def test_ragged_histogram_kernel_compiles(on_chip, capsys, n_slots, groups,
     assert _mosaic_calls(compiled) == 1
     call = next(ln for ln in compiled.as_text().splitlines()
                 if 'custom_call_target="tpu_custom_call"' in ln)
+    _assert_grid_bound_operand(call, 2)
     acc = "s32" if policy == "int" else "f32"
     assert f"= {acc}[{n_slots},{padded},3,256]" in call, call[:200]
     matmuls = _mosaic_matmuls(compiled)
@@ -227,6 +254,9 @@ def test_compaction_kernel_compiles(on_chip, plane):
         on_chip((1,), jnp.int32), tile=COMPACT_TILE,
         interpret=False).compile()
     assert _mosaic_calls(compiled) == 1
+    _assert_grid_bound_operand(
+        next(ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln), 3)
 
 
 def _packed_500x255(on_chip) -> PackedEnsemble:
@@ -763,7 +793,8 @@ def test_bundled_whole_tree_program_fits_one_chip_at_allstate(
         batch=WAVE_K, bagged=False)
     assert "lgbm.bundle_scan" in lowered.as_text(debug_info=True)
     compiled = lowered.compile()
-    _tree_kernels(compiled, capsys, f"Allstate tree at {n} x {G} groups")
+    _tree_kernels(compiled, capsys, f"Allstate tree at {n} x {G} groups",
+                  compact_tables=2)
     n_pad = -(-n // 1024) * 1024
     assert _rows_on_sublanes(compiled, n_pad) == []
     mem = compiled.memory_analysis()

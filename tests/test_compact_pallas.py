@@ -10,9 +10,9 @@ import jax.numpy as jnp
 
 from lightgbm_tpu.ops import compact_pallas
 from lightgbm_tpu.ops.compact_pallas import (
-    COMPACT_TILE, COMPACT_WORK_FIELDS, build_pair_tables, compact_rows,
-    exclusive_cumsum, max_pairs_bound, pair_tables_packed, pair_work_counts,
-    range_partition_dst)
+    COMPACT_TILE, COMPACT_WORK_FIELDS, _pallas_compact_call, build_pair_tables,
+    compact_rows, exclusive_cumsum, max_pairs_bound, pair_tables_packed,
+    pair_work_counts, range_partition_dst)
 
 
 def _np_dst(go_left, ranges, n):
@@ -325,6 +325,75 @@ def test_compact_pallas_uint8_plane(rng, name, ranges):
         np.asarray(r8).view(np.uint32), np.asarray(r32).view(np.uint32))
 
 
+# ------------------------------------------------------------ the grid's bound
+# The kernel's grid is n_pairs steps long, a traced bound. Interpret mode
+# leaves a block that no step visits unwritten (NaN payload rows), so a bound
+# that stops short of a live pair shows below as a mismatch.
+
+def _kernel_and_xla(rng, ranges, valid, n=2048, tile=256):
+    """compact_rows' operands for `ranges` over an int32 plane and a
+    payload with -0.0 among its values, and what the XLA body makes of
+    them."""
+    go_left = rng.rand(n) < 0.5
+    dst, _, lefts, args = _partition(go_left, ranges, n, tile, valid)
+    bins = jnp.asarray(rng.randint(0, 60000, size=(8, n)).astype(np.int32))
+    row = rng.randn(8, n).astype(np.float32)
+    row[0, ::5] = -0.0
+    ops = (bins, jnp.asarray(row), jnp.asarray(dst), lefts, args)
+    xla = compact_rows(*ops[:4], *args, tile=tile, use_pallas=False)[:2]
+    return ops, xla
+
+
+def _bit_equal(got, want) -> bool:
+    return (np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+            and np.array_equal(np.asarray(got[1]).view(np.uint32),
+                               np.asarray(want[1]).view(np.uint32)))
+
+
+@pytest.mark.parametrize("ranges,valid", [
+    ([(0, 700), (900, 400)], [False, False]),
+    ([(300, 0), (2048, 0)], None),
+], ids=["every_range_invalid", "every_range_empty"])
+def test_a_compaction_that_moves_no_row_walks_one_copy_a_tile(rng, ranges,
+                                                              valid):
+    """No valid range: n_pairs == T, every pair a tile's raw copy of
+    itself, and the grid walks those T steps and no more."""
+    n, tile = 2048, 256
+    (bins, row, dst, lefts, args), xla = _kernel_and_xla(rng, ranges, valid)
+    _, _, pcopy, n_pairs = build_pair_tables(lefts, *args, tile)
+    assert int(n_pairs[0]) == n // tile
+    assert (np.asarray(pcopy)[:n // tile] == 1).all()
+    *got, work = compact_rows(bins, row, dst, lefts, *args, tile=tile,
+                              use_pallas=True, interpret=True)
+    assert _bit_equal(got, xla) and _bit_equal(got, (bins, row))
+    counts = dict(zip(COMPACT_WORK_FIELDS, np.asarray(work).tolist()))
+    assert counts["compact_grid_steps"] == counts["compact_pairs"] \
+        == counts["compact_copy_pairs"] == n // tile
+
+
+@pytest.mark.parametrize("name,ranges,valid,p_left", GLUE_CASES,
+                         ids=[c[0] for c in GLUE_CASES])
+def test_the_grid_bound_is_the_live_pair_count(rng, name, ranges, valid,
+                                               p_left):
+    """The kernel called with its tables cut to the live pairs (n_pairs
+    then IS the tables' length) moves what the XLA body moves, bit for
+    bit; stopped before the last pair that moves rows, it does not."""
+    n, tile = 2048, 256
+    (bins, row, dst, lefts, args), xla = _kernel_and_xla(rng, ranges, valid)
+    pair_in, pair_out, pcopy, n_pairs = build_pair_tables(lefts, *args, tile)
+    live = int(n_pairs[0])
+
+    def kernel(tables, bound):
+        return _pallas_compact_call(bins, row, dst, *tables,
+                                    jnp.full(1, bound, jnp.int32), tile, True)
+
+    tables = (pair_in, pair_out, pcopy)
+    assert _bit_equal(kernel(tables, live), xla)
+    assert _bit_equal(kernel([t[:live] for t in tables], live), xla)
+    last_mover = int(np.flatnonzero(np.asarray(pcopy)[:live] < 2)[-1])
+    assert not _bit_equal(kernel(tables, last_mover), xla)
+
+
 def test_compact_xla_fallback_uint8(rng):
     n, gp = 1024, 4
     ranges = [(100, 500)]
@@ -466,9 +535,10 @@ def test_pair_counts_are_a_recount_of_the_pair_tables(rng, name, ranges,
     assert got["compact_pairs"] == (got["compact_copy_pairs"]
                                     + got["compact_permute_pairs"]
                                     + duplicates)
-    assert got["compact_grid_steps"] == max_pairs_bound(n // tile,
-                                                        2 * len(ranges))
-    assert 0 < got["compact_pairs"] <= got["compact_grid_steps"]
+    # the grid walks the live pairs and no other step: its bound is n_pairs
+    assert got["compact_grid_steps"] == got["compact_pairs"] > 0
+    assert got["compact_pairs"] <= max_pairs_bound(n // tile,
+                                                   2 * len(ranges))
     # every tile is written once at least: a raw copy where no range
     # touches it, a permute where one does
     touched = _match(ranges, n, valid).any(axis=0).reshape(-1, tile).any(

@@ -409,6 +409,72 @@ def test_a_slot_no_pair_lists_adds_nowhere(rng):
     assert both[..., 3:].any() and not only0[..., 3:].any()
 
 
+# ------------------------------------------------------------ the grid's bound
+# The pair axis is n_pairs steps long, a traced bound. Interpret mode leaves a
+# block that no step visits unwritten (NaN, or the integer minimum), so a bound
+# that stops short shows below as a mismatch. The gradients are the quantized
+# path's small ints or lie on an exactly summable grid, so the XLA body's sums
+# equal the kernel's bit for bit whatever the order.
+
+def _xla_slots(bins, gh, slot, num_bins, n_slots):
+    """The device learner's XLA body (treelearner/device.py ranged_hist):
+    the slot-expanded [N, n_slots * CH] gradients through build_histogram."""
+    col = np.arange(n_slots * 3) // 3
+    ghK = np.where(slot[:, None] == col[None, :],
+                   np.tile(gh, (1, n_slots)), 0.0).astype(np.float32)
+    return np.asarray(build_histogram(jnp.asarray(bins), jnp.asarray(ghK),
+                                      num_bins))
+
+
+GRID_CASES = {
+    # name: (ranges, valid, n, tile)
+    "one_slot_one_tile": ([(0, 512)], None, 512, 512),
+    "one_slot_inside_one_tile": ([(37, 300)], None, 512, 512),
+    "a_wave_with_an_empty_slot": (
+        [(0, 700), (900, 900), (1024, 2500), (3000, 4096)], None, 4096, 512),
+    "a_wave_with_an_invalid_slot": (
+        [(0, 700), (800, 1500), (2000, 4000)], [True, False, True], 4096,
+        512),
+}
+
+
+@pytest.mark.parametrize("policy", ["bf16x3", "int"])
+@pytest.mark.parametrize("case", sorted(GRID_CASES))
+def test_the_pair_axis_walks_the_live_pairs_alone(rng, case, policy):
+    """Bit-equal to the XLA body with the tables as tile_slot_pairs makes
+    them and cut to the live pairs (n_pairs then IS the tables' length); a
+    slot with no row gets its block once, zeros; a bound one pair short
+    leaves a mismatch wherever there is more than one pair."""
+    ranges, valid, n, tile = GRID_CASES[case]
+    S, G, B = len(ranges), 3, 16
+    bins = rng.randint(0, B, size=(G, n)).astype(np.int32)
+    gh = _quantized_gh(rng, n) if policy == "int" else _coarse_grid_gh(rng, n)
+    slot = np.full(n, S, dtype=np.int32)  # dump by default
+    for k, (s, e) in enumerate(ranges):
+        if valid is None or valid[k]:
+            slot[s:e] = k
+    tiles, slots, n_pairs = _pairs(ranges, S, n // tile, tile, valid)
+    live = int(n_pairs[0])
+
+    def kernel(tiles, slots, bound):
+        return np.asarray(pallas_histogram_slots_ragged(
+            jnp.asarray(bins), jnp.asarray(gh.T), jnp.asarray(slot), tiles,
+            slots, jnp.full(1, bound, jnp.int32), B, S, tile_rows=tile,
+            quantized=policy == "int", f32=policy == "bf16x3",
+            interpret=True))
+
+    got = kernel(tiles, slots, live)
+    want = _xla_slots(bins, gh, slot, B, S).astype(got.dtype)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(kernel(tiles[:live], slots[:live], live),
+                                  want)
+    for k, (s, e) in enumerate(ranges):
+        if e == s or (valid is not None and not valid[k]):
+            assert not got[..., 3 * k:3 * k + 3].any()
+    if live > 1:
+        assert not np.array_equal(kernel(tiles, slots, live - 1), want)
+
+
 def test_pallas_histogram_uint8_bins_bit_identical(rng):
     """The 8-bit plane path (uint8 bins pass through unwidened, kernel
     widens the group row in-register) is bit-identical to int32 bins."""

@@ -615,10 +615,9 @@ def test_a_sharded_tree_opens_its_two_host_spans_once_each(spans,
         assert note["mesh_devices"] == 4 and note["rows_resident"] == 1
         assert note["ici_bytes"] == note["waves"] * per_wave
         assert note["wave_k"] == min(learner.wave, PARAMS["num_leaves"])
-        # the kernels' work is the four shards' summed: every shard steps
-        # through its own grids, whatever rows of a leaf it holds
-        for field, steps in _grid_steps(shard_rows, note, shards=4).items():
-            assert note[field] == steps, field
+        # the kernels' work is the four shards' summed: every shard walks
+        # its own pairs, whatever rows of a leaf it holds
+        _check_grid_steps(shard_rows, note, shards=4)
         _check_work_fields(note)
 
 
@@ -809,10 +808,9 @@ def test_one_tree_wave_note_per_tree_carries_waves_rows_and_width(
         visits, active = note["hist_tile_visits"], note["hist_tiles_active"]
         assert note["hist_rows"] <= 1024 * active <= 1024 * visits
         assert visits <= active + note["speculated"]
-        # the grids the two kernels stepped through are the shapes': the
-        # root's one-slot call and a call a wave; a compaction a wave
-        for field, steps in _grid_steps(2048, note).items():
-            assert note[field] == steps, field
+        # the root's one-slot call and a call a wave; a compaction a wave:
+        # each grid walked its call's live pairs
+        _check_grid_steps(2048, note)
         _check_work_fields(note)
     assert sum(n["hist_rows"] for n in notes) \
         == global_timer.counters["device_hist_rows"] - rows_before
@@ -821,15 +819,19 @@ def test_one_tree_wave_note_per_tree_carries_waves_rows_and_width(
                 if c.startswith(("device_hist_tile", "device_compact"))]
 
 
-def _grid_steps(rows: int, note: dict, shards: int = 1) -> dict:
-    """The two static grid lengths of a tree of `note["waves"]` waves over
-    `rows` padded rows (a shard's): what the program must have summed."""
+def _check_grid_steps(rows: int, note: dict, shards: int = 1) -> None:
+    """Both kernels' grids walk their live pairs alone: the steps the
+    program summed are the pairs, under the pair tables' static lengths
+    (T + 2K a histogram call, max_pairs_bound a compaction) of a tree of
+    `note["waves"]` waves over `rows` padded rows (a shard's)."""
     t_hist, t_compact = rows // DEFAULT_TILE_ROWS, rows // COMPACT_TILE
     k = note["wave_k"]
-    return {"hist_grid_steps": shards * (
-                (t_hist + 2) + note["waves"] * (t_hist + 2 * k)),
-            "compact_grid_steps": shards * note["waves"] * max_pairs_bound(
-                t_compact, 2 * k)}
+    assert note["hist_grid_steps"] == note["hist_tile_visits"]
+    assert note["compact_grid_steps"] == note["compact_pairs"]
+    assert note["hist_grid_steps"] <= shards * (
+        (t_hist + 2) + note["waves"] * (t_hist + 2 * k))
+    assert note["compact_grid_steps"] <= shards * note["waves"] \
+        * max_pairs_bound(t_compact, 2 * k)
 
 
 def _check_work_fields(note: dict) -> None:
