@@ -26,24 +26,33 @@ global scatter, so the same data movement is phrased as dense tile algebra:
      contiguous, so a tile's class rows span <= 2 output tiles. Both ends of
      that run follow from the samples of lext alone, so the tables are
      [K, T] integer arithmetic and read no per-row array. The pair list
-     (in_tile -> out_tile), sorted by out_tile, is the kernel's grid.
-  3. Pallas kernel (_pallas_compact_call): sequential grid over pairs.
-     Every per-row operand has the ROWS ON THE LANES: the bin plane
-     [Gp, N] (block (Gp, T)), the f32 payload [rc, N] (block (rc, T), rc a
-     multiple of 8) and dst [1, N] (block (1, T)). A custom call's operand
-     layout is fixed, and XLA carries it back into the glue that makes the
-     operand: an [N, 1] or [N, rc] operand pads its minor dimension to 128
-     lanes (2.15 GB for 4M int32, not 16 MB) and the whole wave's routing
-     arithmetic then runs at 8 useful values a vector register. Per pair
-     the kernel builds the one-hot PT[o, i] = (o == dst[i] - out*T) from
-     the lane-major dst (a sublane broadcast, no relayout), stacks the
-     payload's four limbs and the plane's limb(s) into ONE operand
-     X [4*rc + Gp*(1|2), T] and accumulates
+     (in_tile -> out_tile) is sorted by out_tile; one searchsorted of the
+     boundaries of the runs of R consecutive output tiles over it gives the
+     kernel's run offsets (run_offsets, [T/R + 1]).
+  3. Pallas kernel (_pallas_compact_call): a sequential grid over the runs
+     of output tiles, ceil(T / R) steps for T tiles of `tile` rows; R
+     (compact_blocks) is 8 wherever the run's output blocks fit the VMEM
+     budget. Every per-row operand has
+     the ROWS ON THE LANES: the bin plane [Gp, N], the f32 payload [rc, N]
+     (rc a multiple of 8) and dst [1, N]. A custom call's operand layout is
+     fixed, and XLA carries it back into the glue that makes the operand:
+     an [N, 1] or [N, rc] operand pads its minor dimension to 128 lanes
+     (2.15 GB for 4M int32, not 16 MB) and the whole wave's routing
+     arithmetic then runs at 8 useful values a vector register. A step's
+     output blocks, (Gp, R*tile) and (rc, R*tile), are zeroed, filled and written
+     back once. The inputs stay in HBM (pl.ANY): a fori_loop inside the
+     step walks the run's pairs, and the kernel's own DMAs copy each live
+     pair's input tiles into a VMEM ring, ring - 1 pairs ahead, across the
+     step boundaries too, so no pair pays a pipeline step or waits on its
+     fetch. A duplicate pair is neither fetched nor worked; a copy pair
+     (a tile no range touches) is a plain VPU copy into its tile of the
+     run; a permute pair builds the one-hot PT[o, i] = (o == dst[i] -
+     out*tile) from the lane-major dst (a sublane broadcast, no relayout),
+     stacks the payload's four limbs and the plane's limb(s) into ONE
+     operand X [4*rc + Gp*(1|2), tile] and accumulates
      out[c, o] += sum_i X[c, i] * PT[o, i] — one matmul contracting the
      last dimension of both (the q @ k^T form), so payload and plane move
-     the same way. Consecutive pairs share the output block (sorted
-     order), so accumulation stays in VMEM; a scalar-prefetched copy flag
-     routes untouched tiles through a plain VPU copy with no matmul.
+     the same way — into its tile of the run.
 
 Exactness: values transit the MXU as 8-bit limbs of their raw bits (bf16
 operands — 0/1 one-hot and limbs <= 255 are exact in bf16, and each output
@@ -139,11 +148,12 @@ def range_partition_dst(go_left: jax.Array, match: jax.Array,
     dst = jnp.where(
         lmask, bases[0].astype(jnp.int32) + lcum,
         jnp.where(in_any, pos + bases[1].astype(jnp.int32) - lcum, pos))
-    # dst before the pair tables: they no longer read it, and a scheduler
-    # left free makes it last, straight into the kernel from HBM. Made
-    # first, the compiler has the tables' sort to prefetch it into fast
-    # memory (its layout gains S(1)), where the kernel's per-pair (1, tile)
-    # block DMAs cost 11 % less of the whole kernel (PERF.md, PR 33).
+    # dst and the left counts as one step before the pair tables and the
+    # kernel. The kernel reads dst by its own DMAs, so where dst lives no
+    # longer matters; the order does: without the barrier the scheduler
+    # keeps more of the wave's arrays alive around the kernel (AOT for a
+    # described v5e: temp 1.04 -> 1.60 GB in MSLR's tree program, 3.71 ->
+    # 6.07 GB in Expo's; PERF.md, PR 42).
     dst, lefts = jax.lax.optimization_barrier((dst, lefts))
     return dst, jnp.where(valid, n_left, 0), lefts
 
@@ -191,12 +201,12 @@ def build_pair_tables(lefts: LeftCounts, starts: jax.Array,
     or range ends, so every lext[.] is one of lefts' samples.
 
     Returns (pair_in, pair_out, pcopy, n_pairs[1]) with static length
-    max_pairs_bound(T, 2 * K); the kernel's grid is n_pairs long, and the
-    entries past it, never walked, repeat the last real pair. pcopy
+    max_pairs_bound(T, 2 * K); the kernel walks the first n_pairs, and the
+    entries past them, never walked, repeat the last real pair. pcopy
     per pair: 0 = one-hot permute, 1 = raw block copy (untouched identity
     tile), 2 = SKIP (duplicate of the previous pair — processing it would
-    double-count rows). Sorted by out_tile so the kernel revisits each
-    output block in one consecutive run.
+    double-count rows). Sorted by out_tile, so the pairs of each run of
+    output tiles the kernel's grid step writes are one contiguous slice.
 
     One fused lax.sort: candidate pairs (with duplicates still in) are
     sorted by the composite key out_tile*T + in_tile, so duplicates —
@@ -272,19 +282,22 @@ def build_pair_tables(lefts: LeftCounts, starts: jax.Array,
 # What one compaction call cost, as compact_rows hands it back beside the
 # arrays: the live pairs of its list, those that are a raw block copy, those
 # that are a one-hot permute (the rest of the live pairs are skipped
-# duplicates), and the steps the kernel's grid walks: the live pairs too,
-# since the grid's bound is n_pairs.
+# duplicates), and the steps the kernel's grid walks: one a run of output
+# tiles, ceil(T / run_tiles), whatever the pairs.
 COMPACT_WORK_FIELDS = ("compact_pairs", "compact_copy_pairs",
                        "compact_permute_pairs", "compact_grid_steps")
 
 
-def pair_work_counts(pcopy: jax.Array, n_pairs: jax.Array) -> jax.Array:
+def pair_work_counts(pcopy: jax.Array, n_pairs: jax.Array,
+                     grid_steps: int) -> jax.Array:
     """[4] int32, COMPACT_WORK_FIELDS of one pair list (build_pair_tables'
-    pcopy and n_pairs): two masked sums over the table, nothing per row."""
+    pcopy and n_pairs) and the kernel's grid_steps: two masked sums over
+    the table, nothing per row."""
     live = jnp.arange(pcopy.shape[0], dtype=jnp.int32) < n_pairs[0]
     return jnp.stack([
         n_pairs[0], jnp.sum(live & (pcopy == 1), dtype=jnp.int32),
-        jnp.sum(live & (pcopy == 0), dtype=jnp.int32), n_pairs[0]])
+        jnp.sum(live & (pcopy == 0), dtype=jnp.int32),
+        jnp.int32(grid_steps)])
 
 
 def _limbs(x_int: jax.Array, n: int) -> jax.Array:
@@ -295,151 +308,254 @@ def _limbs(x_int: jax.Array, n: int) -> jax.Array:
     return jnp.concatenate(parts, axis=0)
 
 
+# A grid step writes a run of up to RUN_TILES_MAX consecutive output tiles,
+# and the kernel's own DMAs keep up to RING_MAX pairs' input tiles in VMEM
+# (RING_MAX - 1 in flight while one is worked on). Each halves, down to 1
+# and 2, until the run's double-buffered output blocks and the ring each
+# fit half of BLOCK_VMEM_BYTES, beside the permute's [tile, tile] one-hot.
+RUN_TILES_MAX = 8
+RING_MAX = 8
+BLOCK_VMEM_BYTES = 6 << 20
+
+
+def compact_blocks(gp: int, plane_bytes: int, rc: int,
+                   tile: int) -> Tuple[int, int]:
+    """(run_tiles, ring): the output tiles of a grid step and the pairs'
+    input tiles the kernel keeps in VMEM, for a plane of gp rows of
+    plane_bytes bytes and a float32 payload of rc rows."""
+    col = gp * plane_bytes + 4 * rc  # a row's bytes across plane and payload
+    run, ring = RUN_TILES_MAX, RING_MAX
+    while run > 1 and 2 * run * tile * col > BLOCK_VMEM_BYTES // 2:
+        run //= 2
+    # an input slot also holds the pair's dst, [1, tile] padded to 8 rows
+    while ring > 2 and ring * tile * (col + 32) > BLOCK_VMEM_BYTES // 2:
+        ring //= 2
+    return run, ring
+
+
 def _make_compact_kernel(tile: int, gp: int, rc: int, plane8: bool,
-                         packed: bool):
-    """plane8: the bin plane is an 8-bit dtype (uint8). Its values fit one
-    bf16 limb, so the plane rides the transport matmul as Gp rows instead
-    of 2*Gp, and the accumulate widens to i32 in-register (Mosaic has no
-    elementwise 8-bit vectors) before narrowing back to the 8-bit output
-    block. packed: the pair's kind rides the input tile's table word
-    (`pair_tables_packed`), else it is a table of its own."""
+                         packed: bool, run_tiles: int, ring: int,
+                         n_runs: int):
+    """Grid step b writes output tiles [b * run_tiles, (b + 1) * run_tiles):
+    it zeroes its output blocks, then a fori_loop walks the run's pairs,
+    [off[b], off[b + 1]) of the out-sorted table. Pair p's input tiles
+    (plane, payload and, for a permute, dst) are copied by the kernel's
+    own DMAs into ring slot p % ring, started ring - 1 pairs ahead; the
+    sequential grid keeps the ring and its semaphores from one step to the
+    next, so the last pairs of run b start the fetches of run b + 1's
+    first. A duplicate pair is neither fetched nor worked; a copy pair
+    writes its input tile into its tile of the run; a permute pair moves
+    its rows by the one-hot limb product. plane8: the bin plane is an
+    8-bit dtype (uint8). Its values fit one bf16 limb, so the plane rides
+    the transport matmul as Gp rows instead of 2*Gp, and the accumulate
+    widens to i32 in-register (Mosaic has no elementwise 8-bit vectors)
+    before narrowing back to the 8-bit output block. packed: the pair's
+    kind rides the input tile's table word (`pair_tables_packed`), else it
+    is a table of its own."""
+    in_mask = (1 << PCOPY_SHIFT) - 1
 
     def kernel(*refs):
         if packed:
-            pin_ref, pout_ref = refs[:2]
+            pin_ref, pout_ref, off_ref = refs[:3]
         else:
-            pin_ref, pout_ref, pcopy_ref = refs[:3]
-        bins_ref, row_ref, dst_ref, bins_out, row_out = refs[-5:]
-        p = pl.program_id(0)
-        out_t = pout_ref[p]
-        first = (p == 0) | (out_t != pout_ref[jnp.maximum(p - 1, 0)])
-        pcopy = pin_ref[p] >> PCOPY_SHIFT if packed else pcopy_ref[p]
-        # pcopy == 2: duplicate pair demoted to a skip by build_pair_tables
-        # (a duplicate is never the first pair of its output block, so the
-        # zero-init below cannot be skipped by accident)
-        active = pcopy < 2
-        is_copy = pcopy == 1
+            pin_ref, pout_ref, pcopy_ref, off_ref = refs[:4]
+        (bins_hbm, row_hbm, dst_hbm, bins_out, row_out,
+         bins_buf, row_buf, dst_buf, sems) = refs[-9:]
+        b = pl.program_id(0)
+        n_live = off_ref[n_runs]
 
-        @pl.when(active & is_copy)
-        def _copy():  # untouched tile: single pair for this block, plain copy
-            bins_out[...] = bins_ref[...]
-            row_out[...] = row_ref[...]
+        def kind(p):
+            # 0 = one-hot permute, 1 = raw copy, 2 = duplicate (skipped)
+            return pin_ref[p] >> PCOPY_SHIFT if packed else pcopy_ref[p]
 
-        @pl.when(active & jnp.logical_not(is_copy))
-        def _permute():
-            @pl.when(first)
-            def _zero():
-                bins_out[...] = jnp.zeros_like(bins_out)
-                row_out[...] = jnp.zeros_like(row_out)
+        def fetches(p):
+            slot = p % ring
+            cols = pl.ds(pl.multiple_of(
+                (pin_ref[p] & in_mask if packed else pin_ref[p]) * tile,
+                tile), tile)
+            return [pltpu.make_async_copy(src.at[:, cols], buf.at[slot],
+                                          sems.at[i, slot])
+                    for i, (src, buf) in enumerate(
+                        ((bins_hbm, bins_buf), (row_hbm, row_buf),
+                         (dst_hbm, dst_buf)))]
 
-            rel = dst_ref[...] - out_t * tile  # [1, tile]: in-rows on lanes
-            iota = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
-            # PT[o, i] = 1 iff in-row i lands at out-row o of this block.
-            # dst is injective => every row has at most one 1, so each
-            # output row below receives exactly one source row: the limb
-            # matmul is exact bit transport, not a sum.
-            PT = (iota == rel).astype(jnp.bfloat16)
-            rbits = jax.lax.bitcast_convert_type(row_ref[...], jnp.int32)
-            # single limb for an 8-bit plane: values <= 255 are exact bf16
-            plane = (bins_ref[...].astype(jnp.int32) if plane8
-                     else _limbs(bins_ref[...], 2))
-            X = jnp.concatenate([_limbs(rbits, 4), plane],
-                                axis=0).astype(jnp.bfloat16)
-            out = jax.lax.dot_general(
-                X, PT, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [4*rc + planes, tile]
-            # The low three limbs recombine in f32 (a sum below 2**24 is
-            # exact), the top one by the only integer shift. NOT
-            # `limb2 << 16`: on a v5e (my chip run, PR 22) Mosaic's i32
-            # shift-left by 16 zeroes every value under 128 — what a
-            # bf16 -> f32 widening does to a bf16 denormal — so payloads
-            # lost bits 16..22 wherever bit 23 was clear, and interpret
-            # mode never showed it.
-            low = (out[:rc] + 256.0 * out[rc:2 * rc]
-                   + 65536.0 * out[2 * rc:3 * rc]).astype(jnp.int32)
-            obits = low | (out[3 * rc:4 * rc].astype(jnp.int32) << 24)
-            # rows not sourced by this pair recombine to bits 0, and the
-            # accumulate ORs bits, so no float operation ever touches a
-            # payload: -0.0 and denormals ride along exactly
-            row_out[...] = jax.lax.bitcast_convert_type(
-                jax.lax.bitcast_convert_type(row_out[...], jnp.int32) | obits,
-                jnp.float32)
-            obl = out[4 * rc:].astype(jnp.int32)
-            if plane8:
-                bins_out[...] = (bins_out[...].astype(jnp.int32)
-                                 + obl).astype(bins_out.dtype)
-            else:
-                bins_out[...] += obl[:gp] | (obl[gp:] << 8)
+        def start(p):
+            k = kind(p)
+            plane, payload, dst = fetches(p)
+
+            @pl.when(k < 2)
+            def _():
+                plane.start()
+                payload.start()
+
+            @pl.when(k == 0)  # a copy pair needs no destinations
+            def _():
+                dst.start()
+
+        @pl.when(b == 0)
+        def _prime():
+            for q in range(ring - 1):
+                @pl.when(q < n_live)
+                def _():
+                    start(q)
+
+        bins_out[...] = jnp.zeros_like(bins_out)
+        row_out[...] = jnp.zeros_like(row_out)
+
+        def pair(p, carry):
+            ahead = p + (ring - 1)  # its slot held pair p - 1, now done
+
+            @pl.when(ahead < n_live)
+            def _():
+                start(ahead)
+
+            k = kind(p)
+            slot = p % ring
+            out_t = pout_ref[p]
+            sub = pl.ds(pl.multiple_of((out_t - b * run_tiles) * tile, tile),
+                        tile)
+            plane, payload, dst = fetches(p)
+
+            @pl.when(k == 1)
+            def _copy():  # untouched tile: the one pair of its output tile
+                plane.wait()
+                payload.wait()
+                bins_out[:, sub] = bins_buf[slot]
+                row_out[:, sub] = row_buf[slot]
+
+            @pl.when(k == 0)
+            def _permute():
+                plane.wait()
+                payload.wait()
+                dst.wait()
+                rel = dst_buf[slot] - out_t * tile  # [1, tile]: in-rows on lanes
+                iota = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+                # PT[o, i] = 1 iff in-row i lands at out-row o of this tile.
+                # dst is injective => every row has at most one 1, so each
+                # output row below receives exactly one source row: the
+                # limb matmul is exact bit transport, not a sum.
+                PT = (iota == rel).astype(jnp.bfloat16)
+                rbits = jax.lax.bitcast_convert_type(row_buf[slot], jnp.int32)
+                # single limb for an 8-bit plane: values <= 255 are exact bf16
+                plane_in = bins_buf[slot]
+                plane_x = (plane_in.astype(jnp.int32) if plane8
+                           else _limbs(plane_in, 2))
+                X = jnp.concatenate([_limbs(rbits, 4), plane_x],
+                                    axis=0).astype(jnp.bfloat16)
+                out = jax.lax.dot_general(
+                    X, PT, dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [4*rc + planes, tile]
+                # The low three limbs recombine in f32 (a sum below 2**24 is
+                # exact), the top one by the only integer shift. NOT
+                # `limb2 << 16`: on a v5e (my chip run, PR 22) Mosaic's i32
+                # shift-left by 16 zeroes every value under 128 — what a
+                # bf16 -> f32 widening does to a bf16 denormal — so payloads
+                # lost bits 16..22 wherever bit 23 was clear, and interpret
+                # mode never showed it.
+                low = (out[:rc] + 256.0 * out[rc:2 * rc]
+                       + 65536.0 * out[2 * rc:3 * rc]).astype(jnp.int32)
+                obits = low | (out[3 * rc:4 * rc].astype(jnp.int32) << 24)
+                # rows not sourced by this pair recombine to bits 0, and the
+                # accumulate ORs bits, so no float operation ever touches a
+                # payload: -0.0 and denormals ride along exactly
+                row_out[:, sub] = jax.lax.bitcast_convert_type(
+                    jax.lax.bitcast_convert_type(row_out[:, sub], jnp.int32)
+                    | obits, jnp.float32)
+                obl = out[4 * rc:].astype(jnp.int32)
+                if plane8:
+                    bins_out[:, sub] = (bins_out[:, sub].astype(jnp.int32)
+                                        + obl).astype(bins_out.dtype)
+                else:
+                    bins_out[:, sub] += obl[:gp] | (obl[gp:] << 8)
+
+            return carry
+
+        jax.lax.fori_loop(off_ref[b], off_ref[b + 1], pair, 0)
 
     return kernel
 
 
-# The kernel's pair tables are scalar-prefetched into SMEM, one int32 a pair
-# each: the input tile, the output tile and the pair's kind. The v5e's SMEM
-# holds 1 MiB; three tables of max_pairs_bound(T, 2K) words (T = N / 512
-# tiles) fit to ~11 M rows a chip (11,000,000 ran on the chip) and take
-# 1.18 MiB at 13,184,290 (AOT, the compiler's own refusal). Past the budget
-# below the kind rides the input tile's word above PCOPY_SHIFT: two tables,
-# to ~16.7 M rows. Three where they fit: the packed word costs a grid step
-# a shift and three masks, 0.5-1.5 % of the kernel's time at 4,194,304 rows
-# on a v5e.
+# The kernel's tables are scalar-prefetched into SMEM: one int32 a pair
+# each for the input tile, the output tile and the pair's kind, and the run
+# offsets, one int32 a grid step and one more. The v5e's SMEM holds 1 MiB;
+# three pair tables of max_pairs_bound(T, 2K) words (T = N / 512 tiles) and
+# the offsets fit to ~10.9 M rows a chip, and three alone take 1.18 MiB at
+# 13,184,290 (AOT, the compiler's own refusal). Past the budget below the
+# kind rides the input tile's word above PCOPY_SHIFT: two tables, to
+# ~16.7 M rows. Three where they fit: the packed word costs a pair a shift
+# and a mask.
 PCOPY_SHIFT = 28
 SMEM_TABLE_BYTES = (1 << 20) - (8 << 10)
 
 
-def pair_tables_packed(max_pairs: int) -> bool:
+def pair_tables_packed(max_pairs: int, n_runs: int) -> bool:
     """Whether the kind rides the input tile's word: three tables of
-    max_pairs int32 words would not fit SMEM_TABLE_BYTES."""
-    return 3 * 4 * max_pairs > SMEM_TABLE_BYTES
+    max_pairs int32 words and the n_runs + 1 run offsets would not fit
+    SMEM_TABLE_BYTES."""
+    return 4 * (3 * max_pairs + n_runs + 1) > SMEM_TABLE_BYTES
 
 
-@partial(jax.jit, static_argnames=("tile", "interpret"))
+def run_offsets(pair_out: jax.Array, n_pairs: jax.Array, run_tiles: int,
+                n_runs: int) -> jax.Array:
+    """[n_runs + 1] int32: the first pair of each run of run_tiles output
+    tiles in the out-sorted table, and n_pairs last. One searchsorted of
+    the run boundaries: the table's tail repeats its last live pair, whose
+    output tile lies below the last boundary, so the clamp to n_pairs
+    holds every offset inside the live pairs."""
+    bounds = jnp.arange(n_runs + 1, dtype=jnp.int32) * run_tiles
+    return jnp.minimum(jnp.searchsorted(pair_out, bounds).astype(jnp.int32),
+                       n_pairs[0])
+
+
+@partial(jax.jit, static_argnames=("tile", "interpret", "run_tiles", "ring"))
 def _pallas_compact_call(bins_p, row_p, dst, pair_in, pair_out, is_copy,
-                         n_pairs, tile: int, interpret: bool):
+                         n_pairs, tile: int, interpret: bool,
+                         run_tiles: int = 0, ring: int = 0):
+    """run_tiles, ring: compact_blocks' choice where 0."""
     Gp, N = bins_p.shape
     rc = row_p.shape[0]
     mp = pair_in.shape[0]
     plane8 = bins_p.dtype.itemsize == 1
-    packed = pair_tables_packed(mp)
+    auto_run, auto_ring = compact_blocks(Gp, bins_p.dtype.itemsize, rc, tile)
+    run_tiles, ring = run_tiles or auto_run, ring or auto_ring
+    n_runs = -(-(N // tile) // run_tiles)
+    off = run_offsets(pair_out, n_pairs, run_tiles, n_runs)
+    packed = pair_tables_packed(mp, n_runs)
     if packed:
         if N // tile >= 1 << PCOPY_SHIFT:
             raise ValueError(f"{N // tile} compaction tiles do not leave "
                              "the pair table's kind its bits")
-        in_mask = (1 << PCOPY_SHIFT) - 1
-        tables = (pair_in | (is_copy << PCOPY_SHIFT), pair_out)
-
-        def in_map(p, pi, po):
-            return 0, pi[p] & in_mask
-
-        def out_map(p, pi, po):
-            return 0, po[p]
+        tables = (pair_in | (is_copy << PCOPY_SHIFT), pair_out, off)
     else:
-        tables = (pair_in, pair_out, is_copy)
+        tables = (pair_in, pair_out, is_copy, off)
 
-        def in_map(p, pi, po, pc):
-            return 0, pi[p]
-
-        def out_map(p, pi, po, pc):
-            return 0, po[p]
-    # the grid walks the live pairs alone: its bound is the traced n_pairs,
-    # an operand of the kernel call (every tile's identity pair is live, so
-    # n_pairs >= T and every output block is visited)
+    def out_map(b, *_):
+        return 0, b
+    # the grid walks the runs of output tiles, each visited once (every
+    # tile's identity pair is live, so no run is empty); the last run is
+    # short where T % run_tiles != 0, and its block's tail is not written
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(tables),
-        grid=(jnp.maximum(n_pairs[0], 1),),
-        in_specs=[pl.BlockSpec((Gp, tile), in_map),
-                  pl.BlockSpec((rc, tile), in_map),
-                  pl.BlockSpec((1, tile), in_map)],
-        out_specs=[pl.BlockSpec((Gp, tile), out_map),
-                   pl.BlockSpec((rc, tile), out_map)],
+        grid=(n_runs,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        out_specs=[pl.BlockSpec((Gp, run_tiles * tile), out_map),
+                   pl.BlockSpec((rc, run_tiles * tile), out_map)],
+        scratch_shapes=[pltpu.VMEM((ring, Gp, tile), bins_p.dtype),
+                        pltpu.VMEM((ring, rc, tile), jnp.float32),
+                        pltpu.VMEM((ring, 1, tile), jnp.int32),
+                        pltpu.SemaphoreType.DMA((3, ring))],
     )
     return pl.pallas_call(
-        _make_compact_kernel(tile, Gp, rc, plane8, packed),
+        _make_compact_kernel(tile, Gp, rc, plane8, packed, run_tiles, ring,
+                             n_runs),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((Gp, N), bins_p.dtype),
             jax.ShapeDtypeStruct((rc, N), jnp.float32),
         ],
+        # the ring and its semaphores carry from one step to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         # the jitted wrapper's own name, not compact_rows': the trace names
         # the custom call after it, and the benchmark's
@@ -494,4 +610,7 @@ def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,
     bins_o, row_o = _pallas_compact_call(
         bins_p, row_f32, dst_i32, pair_in, pair_out, is_copy, n_pairs, tile,
         interpret)
-    return bins_o, row_o, pair_work_counts(is_copy, n_pairs)
+    run_tiles, _ = compact_blocks(bins_p.shape[0], bins_p.dtype.itemsize,
+                                  row_p.shape[0], tile)
+    n_runs = -(-(dst.shape[0] // tile) // run_tiles)
+    return bins_o, row_o, pair_work_counts(is_copy, n_pairs, n_runs)

@@ -24,7 +24,8 @@ import pytest
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.dataset import Dataset as CoreDataset
 from lightgbm_tpu.ops.compact_pallas import (COMPACT_TILE, _pallas_compact_call,
-                                             max_pairs_bound)
+                                             compact_blocks, max_pairs_bound,
+                                             pair_tables_packed)
 from lightgbm_tpu.ops.hist_pallas import (DEFAULT_TILE_ROWS, pallas_histogram,
                                           pallas_histogram_slots_ragged)
 from lightgbm_tpu.ops.predict import (PackedEnsemble, _predict_raw_dense,
@@ -34,6 +35,8 @@ from lightgbm_tpu.treelearner import device as device_mod
 N = 1 << 20
 FEATURES, GROUPS_PADDED, BINS, WAVE_K = 28, 32, 255, 21
 HBM_BYTES = int(15.75 * 2 ** 30)  # what the v5e compiler admits a program
+# the scoped VMEM a Mosaic kernel may ask of a v5e without its own limit
+V5E_SCOPED_VMEM = 16 << 20
 # The whole-tree program's temp at N = 2^20 read 307,058,688 B (float32) and
 # 307,123,200 B (quantized) once every per-row carry was [k, N] (AOT, PR 29);
 # with rows on the sublanes one [N, k] float32 carry alone was 0.54 GB and
@@ -73,33 +76,40 @@ def _mosaic_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
-# What the training cells' whole-tree programs ask with both kernels' grids
-# bounded by the call's traced pair count (with the static grids of the
-# table's whole length in brackets): temp bytes, then the scoped VMEM of the
-# wave histogram call / the root histogram call / the compaction call, equal
-# under both grids.
-#   2^20 x 28 float32      232,415,232 (232,415,232)   65,536 / 65,536 / 98,304
-#   2^20 x 28 quantized    232,543,232 (232,543,232)   65,536 / 65,536 / 98,304
+# What the training cells' whole-tree programs ask with the compaction grid
+# over runs of eight output tiles, the pairs walked by a loop inside the
+# kernel and read by its own DMAs (with PR 41's grid over the live pairs in
+# brackets): temp bytes, then the scoped VMEM of the wave histogram call /
+# the root histogram call / the compaction call (PR 41's in brackets).
+#   2^20 x 28 float32      232,479,744 (232,415,232)
+#                          65,536 / 65,536 / 540,672 (98,304)
+#   2^20 x 28 quantized    232,607,744 (232,543,232)
+#                          65,536 / 65,536 / 540,672 (98,304)
 #   10,502,144 x 28 on four chips, a chip
-#                          710,413,824 (710,413,824)  229,376 / 229,376 / 131,072
+#                          710,825,984 (710,413,824)
+#                          229,376 / 229,376 / 802,816 (131,072)
 #   10,500,000 x 28 quantized
-#                        2,160,010,240 (2,160,010,240) 98,304 / 98,304 / 131,072
+#                        2,159,986,688 (2,160,010,240)
+#                          98,304 / 98,304 / 802,816 (131,072)
 #   2,270,296 x 136 float32
-#                        1,035,774,976 (1,035,742,720) 221,184 / 221,184 / 598,016
-#   11,000,000 x 8 float32, int32 plane
-#                        3,709,595,136 (3,709,595,136) 286,720 / 286,720 / 131,072
+#                        1,035,904,000 (1,035,774,976)
+#                          221,184 / 221,184 / 2,613,248 (598,016)
+#   11,000,000 x 8 float32, int32 plane, two pair tables (three until PR 42)
+#                        3,709,861,888 (3,709,595,136)
+#                          286,720 / 286,720 / 802,816 (131,072)
 #   13,184,290 x 69 float32, two pair tables
-#                        3,677,789,184 (3,677,756,928) 253,952 / 253,952 / 323,584
+#                        3,677,983,744 (3,677,789,184)
+#                          253,952 / 253,952 / 1,650,688 (323,584)
 # The (tile, slot) pair kernel's operand and output block are one slot
 # high, so the wave call asks what the root call asks.
 def _tree_kernels(compiled, capsys, what: str,
                   compact_tables: int = 3) -> list:
     """The whole-tree program's histogram calls, after checking that it
-    holds exactly two (root and waves: one kernel, `n_slots` 1 and WAVE_K)
-    and one compaction call, each walking a grid bounded by its pair count
-    (`compact_tables`: 2 where the pair's kind rides the input tile's
-    word); prints what the program asks of the chip (PERF.md keeps the
-    readings beside the parent's)."""
+    holds exactly two (root and waves: one kernel, `n_slots` 1 and WAVE_K),
+    each walking a grid bounded by its pair count, and one compaction call
+    whose grid walks runs of output tiles (`compact_tables`: 2 where the
+    pair's kind rides the input tile's word); prints what the program asks
+    of the chip (PERF.md keeps the readings beside the parent's)."""
     kernels = [ln for ln in compiled.as_text().splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     hist = [ln for ln in kernels if "pallas_histogram_slots_ragged" in ln]
@@ -112,9 +122,18 @@ def _tree_kernels(compiled, capsys, what: str,
     assert len(hist) == 2 and len(kernels) - len(hist) == 1
     assert sum("_pallas_compact_call" in ln for ln in kernels) == 1
     for ln in kernels:
-        _assert_grid_bound_operand(
-            ln, 2 if ln in hist else compact_tables)
+        if ln in hist:
+            _assert_grid_bound_operand(ln, 2)
+        else:
+            _assert_compact_operands(ln, compact_tables)
     return hist
+
+
+def _operands(call: str) -> list:
+    """The operand types of a custom call, in order."""
+    constraints = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
+                            call).group(1)
+    return re.findall(r"[a-z]\w*\[[\d,]*\]", constraints)
 
 
 def _assert_grid_bound_operand(call: str, tables: int) -> None:
@@ -122,24 +141,48 @@ def _assert_grid_bound_operand(call: str, tables: int) -> None:
     bound as an s32[] operand in front of its scalar-prefetched pair
     tables: one more scalar operand than the tables, each of them an s32
     vector of the same static length."""
-    constraints = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
-                            call).group(1)
-    operands = re.findall(r"[a-z]\w*\[[\d,]*\]", constraints)
+    operands = _operands(call)
     assert operands[0] == "s32[]", operands
     assert len(set(operands[1:1 + tables])) == 1, operands
     assert re.fullmatch(r"s32\[\d+\]", operands[1]), operands
     assert not re.fullmatch(r"s32\[\d+\]", operands[1 + tables]), operands
 
 
-def _compact_dst_operand(compiled) -> str:
-    """The HLO instruction that makes the compaction kernel's last operand,
-    dst [1, N]."""
-    text = compiled.as_text()
-    call = next(ln for ln in text.splitlines()
-                if "_pallas_compact_call" in ln and " custom-call(" in ln)
-    name = call.split(" custom-call(")[1].split(")")[0].split(", ")[-1]
-    return next(ln for ln in text.splitlines()
-                if ln.lstrip().startswith(name + " = "))
+def _assert_compact_operands(call: str, tables: int) -> None:
+    """The compaction call's grid is static, one step a run of output
+    tiles: no bound in front of its scalar-prefetched pair tables, each an
+    s32 vector of the same length, then the run offsets, a shorter s32
+    vector, all in SMEM; then the three per-row operands (plane, payload,
+    dst), which the kernel reads by its own DMAs: memory space ANY in its
+    body."""
+    operands = _operands(call)
+    pairs = int(re.fullmatch(r"s32\[(\d+)\]", operands[0]).group(1))
+    assert operands[:tables] == [f"s32[{pairs}]"] * tables, operands
+    runs = int(re.fullmatch(r"s32\[(\d+)\]", operands[tables]).group(1))
+    assert runs < pairs, operands
+    assert len(operands) == tables + 1 + 3, operands
+    spaces = [re.search(r"#tpu\.memory_space<(\w+)>", a).group(1)
+              for a in _mosaic_args(call)[1:]]  # past the grid index
+    assert spaces[:tables + 1] == ["smem"] * (tables + 1), spaces
+    assert spaces[tables + 1:tables + 4] == ["any"] * 3, spaces
+
+
+def _mosaic_args(call: str) -> list:
+    """The argument types of a Mosaic custom call's kernel body (the grid
+    index first), decoded from the call's MLIR bytecode."""
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    body = re.search(r'"custom_call_config":\{"body":"([^"]+)"',
+                     call).group(1)
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        text = str(ir.Module.parse(base64.b64decode(body)))
+    block = re.search(r"\^bb0\((.*?)\):", text).group(1)
+    return re.split(r", (?=%arg)", block)
 
 
 def _rows_on_sublanes(compiled, n_rows: int) -> list:
@@ -254,9 +297,51 @@ def test_compaction_kernel_compiles(on_chip, plane):
         on_chip((1,), jnp.int32), tile=COMPACT_TILE,
         interpret=False).compile()
     assert _mosaic_calls(compiled) == 1
-    _assert_grid_bound_operand(
+    _assert_compact_operands(
         next(ln for ln in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln), 3)
+
+
+# Each training cell's compaction call: rows a chip (padded to whole
+# 1,024-row histogram tiles), the plane's rows and dtype.
+COMPACT_CELLS = [
+    ("higgs_binary", 4_194_304, 32, jnp.uint8),
+    ("higgs_full_dp4_a_chip", 2_625_536, 32, jnp.uint8),
+    ("higgs_binary_quant", 10_500_096, 32, jnp.uint8),
+    ("mslr_lambdarank", 2_271_232, 160, jnp.uint8),
+    ("expo_airline_cat", 11_000_832, 8, jnp.int32),
+    ("allstate_efb", 13_185_024, 96, jnp.uint8),
+]
+
+
+@pytest.mark.parametrize("name,rows,gp,plane", COMPACT_CELLS,
+                         ids=[c[0] for c in COMPACT_CELLS])
+def test_compaction_kernel_fits_at_every_cell(on_chip, capsys, name, rows,
+                                              gp, plane):
+    """The kernel alone at each training cell's rows and plane: its tables
+    (packed where the run offsets tip them past the SMEM budget), the run
+    offsets and the three per-row operands as `_assert_compact_operands`
+    has them, and the scoped VMEM its output blocks, ring and one-hot ask
+    within the v5e's limit."""
+    tiles = rows // COMPACT_TILE
+    pairs = max_pairs_bound(tiles, 2 * WAVE_K)
+    run_tiles, ring = compact_blocks(gp, jnp.dtype(plane).itemsize, 8,
+                                     COMPACT_TILE)
+    packed = pair_tables_packed(pairs, -(-tiles // run_tiles))
+    compiled = _pallas_compact_call.lower(
+        on_chip((gp, rows), plane), on_chip((8, rows), jnp.float32),
+        on_chip((rows,), jnp.int32), on_chip((pairs,), jnp.int32),
+        on_chip((pairs,), jnp.int32), on_chip((pairs,), jnp.int32),
+        on_chip((1,), jnp.int32), tile=COMPACT_TILE,
+        interpret=False).compile()
+    call = next(ln for ln in compiled.as_text().splitlines()
+                if 'custom_call_target="tpu_custom_call"' in ln)
+    _assert_compact_operands(call, 2 if packed else 3)
+    (vmem,) = _mosaic_vmem_bytes(compiled).values()
+    with capsys.disabled():
+        print(f"\nAOT compaction kernel {name}: R {run_tiles} ring {ring} "
+              f"packed {packed} VMEM {vmem}")
+    assert vmem <= V5E_SCOPED_VMEM
 
 
 def _packed_500x255(on_chip) -> PackedEnsemble:
@@ -516,11 +601,6 @@ def test_quantized_whole_tree_program_fits_one_chip_at_higgs_full(
     n_pad = -(-n // 1024) * 1024
     assert _rows_on_sublanes(compiled, n) == []
     assert _rows_on_sublanes(compiled, n_pad) == []
-    # the destinations are made before the pair tables, so the compiler
-    # prefetches them into fast memory under the tables' sort; made last
-    # they stay in HBM and the kernel reads 11 % slower (PERF.md, PR 33)
-    assert re.search(r"s32\[1,\d+\]\{1,0:T\(1,128\)S\(1\)\}",
-                     _compact_dst_operand(compiled))
     mem = compiled.memory_analysis()
     tree_bytes = mem.temp_size_in_bytes + mem.argument_size_in_bytes
     assert tree_bytes < HBM_BYTES
@@ -701,7 +781,9 @@ def test_categorical_whole_tree_program_fits_one_chip_at_expo(
     monkeypatch.setenv("LGBM_TPU_HIST_F32", "1")
     learner, cfg = _expo_learner()
     compiled = _lower_expo(learner, cfg, on_chip, EXPO_ROWS).compile()
-    _tree_kernels(compiled, capsys, f"Expo tree at {EXPO_ROWS} x 8")
+    # two pair tables: with the run offsets, three outgrow the SMEM budget
+    _tree_kernels(compiled, capsys, f"Expo tree at {EXPO_ROWS} x 8",
+                  compact_tables=2)
     n_pad = -(-EXPO_ROWS // 1024) * 1024
     assert _rows_on_sublanes(compiled, n_pad) == []
     mem = compiled.memory_analysis()

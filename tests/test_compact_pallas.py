@@ -10,9 +10,10 @@ import jax.numpy as jnp
 
 from lightgbm_tpu.ops import compact_pallas
 from lightgbm_tpu.ops.compact_pallas import (
-    COMPACT_TILE, COMPACT_WORK_FIELDS, _pallas_compact_call, build_pair_tables,
-    compact_rows, exclusive_cumsum, max_pairs_bound, pair_tables_packed,
-    pair_work_counts, range_partition_dst)
+    COMPACT_TILE, COMPACT_WORK_FIELDS, SMEM_TABLE_BYTES, _pallas_compact_call,
+    build_pair_tables, compact_blocks, compact_rows, exclusive_cumsum,
+    max_pairs_bound, pair_tables_packed, pair_work_counts,
+    range_partition_dst, run_offsets)
 
 
 def _np_dst(go_left, ranges, n):
@@ -264,17 +265,34 @@ def test_the_packed_pair_word_moves_the_same_rows(rng, name, ranges,
         jax.clear_caches()
 
 
-@pytest.mark.parametrize("rows,packed", [(4_194_304, False),
-                                         (10_500_000, False),
-                                         (11_000_000, False),
-                                         (13_184_290, True)])
-def test_three_pair_tables_wherever_they_fit(rows, packed):
-    """The benchmark's one-chip row counts at a wave of 21 slots: the
-    tables stay three up to the Expo cell's 11,000,000 rows (as they ran on
-    the chip) and pack at the Allstate cell's 13,184,290, where three take
-    1.18 MiB of the v5e's 1 MiB SMEM."""
+# Each training cell's rows a chip and plane (groups padded, bytes a value),
+# and whether its tables pack once the run offsets sit beside them.
+CELL_TABLES = [
+    ("higgs_binary", 4_194_304, 32, 1, False),
+    ("higgs_full_dp4_a_chip", 2_625_536, 32, 1, False),
+    ("higgs_binary_quant", 10_500_000, 32, 1, False),
+    ("mslr_lambdarank", 2_270_296, 160, 1, False),
+    ("expo_airline_cat", 11_000_000, 8, 4, True),
+    ("allstate_efb", 13_184_290, 96, 1, True),
+]
+
+
+@pytest.mark.parametrize("name,rows,gp,plane_bytes,packed", CELL_TABLES,
+                         ids=[c[0] for c in CELL_TABLES])
+def test_three_pair_tables_wherever_they_fit(name, rows, gp, plane_bytes,
+                                             packed):
+    """The benchmark's row counts a chip at a wave of 21 slots, with the
+    run offsets counted: the tables stay three up to the quantized cell's
+    10,500,000 rows and pack at the Expo cell's 11,000,000, where three
+    alone fit but not with the offsets' 2,687 words, and at the Allstate
+    cell's 13,184,290, where three alone take 1.18 MiB of the v5e's
+    1 MiB SMEM. Every cell's plane takes runs of eight output tiles."""
     tiles = -(-rows // 1024) * 1024 // COMPACT_TILE
-    assert pair_tables_packed(max_pairs_bound(tiles, 2 * 21)) == packed
+    run_tiles, _ = compact_blocks(gp, plane_bytes, 8, COMPACT_TILE)
+    assert run_tiles == 8
+    mp = max_pairs_bound(tiles, 2 * 21)
+    assert pair_tables_packed(mp, -(-tiles // run_tiles)) == packed
+    assert (3 * 4 * mp > SMEM_TABLE_BYTES) == (name == "allstate_efb")
 
 
 def test_pair_list_holds_a_range_spanning_many_tiles(rng):
@@ -325,10 +343,11 @@ def test_compact_pallas_uint8_plane(rng, name, ranges):
         np.asarray(r8).view(np.uint32), np.asarray(r32).view(np.uint32))
 
 
-# ------------------------------------------------------------ the grid's bound
-# The kernel's grid is n_pairs steps long, a traced bound. Interpret mode
-# leaves a block that no step visits unwritten (NaN payload rows), so a bound
-# that stops short of a live pair shows below as a mismatch.
+# ------------------------------------------------------ the runs and the ring
+# The kernel's grid walks runs of output tiles; a loop inside each step walks
+# the run's pairs, its bounds the run offsets, which end at n_pairs. A step
+# zeroes its output blocks first, so a pair the loop does not reach leaves
+# its rows zero and a bound that stops short shows below as a mismatch.
 
 def _kernel_and_xla(rng, ranges, valid, n=2048, tile=256):
     """compact_rows' operands for `ranges` over an int32 plane and a
@@ -350,6 +369,114 @@ def _bit_equal(got, want) -> bool:
                                np.asarray(want[1]).view(np.uint32)))
 
 
+# (ranges, valid): a wave that permutes and leaves duplicates, the root
+# split (one range over every row: the most pairs a run), and a wave of
+# invalid ranges, whose runs hold raw copies alone
+RUN_CASES = {
+    "multi": ([(64, 300), (512, 512), (1100, 180), (1280, 250)], None),
+    "root": ([(0, 2048)], None),
+    "only_copies": ([(0, 700), (900, 400)], [False, False]),
+}
+# (run_tiles, ring) over T = 8 tiles: a tile a run; runs of three, the last
+# of two, each holding more pairs than the two-deep ring; one run of every
+# pair; runs of four with a ring deeper than some of them
+RUN_BLOCKS = [(1, 2), (3, 2), (8, 3), (4, 8)]
+# plane dtype and groups, and whether the kind rides the input tile's word
+RUN_LAYOUTS = [(np.uint8, 32, False), (np.int32, 8, False),
+               (np.uint8, 32, True), (np.int32, 8, True)]
+
+
+def _run_case(case, plane, gp, n=2048, tile=256):
+    """compact_rows' operands for RUN_CASES[case] over a `plane` of gp rows
+    and a payload with -0.0 among its values, the pair tables, and what the
+    XLA body makes of the operands."""
+    ranges, valid = RUN_CASES[case]
+    rng = np.random.RandomState(4200)
+    go_left = rng.rand(n) < 0.5
+    dst, _, lefts, args = _partition(go_left, ranges, n, tile, valid)
+    hi = 256 if plane == np.uint8 else 60000
+    bins = jnp.asarray(rng.randint(0, hi, size=(gp, n)).astype(plane))
+    row = rng.randn(8, n).astype(np.float32)
+    row[0, ::5] = -0.0
+    row = jnp.asarray(row)
+    xla = compact_rows(bins, row, jnp.asarray(dst), lefts, *args, tile=tile,
+                       use_pallas=False)[:2]
+    return ((bins, row, jnp.asarray(dst)),
+            build_pair_tables(lefts, *args, tile), xla)
+
+
+@pytest.mark.parametrize("plane,gp,packed", RUN_LAYOUTS,
+                         ids=["uint8", "int32", "uint8_packed",
+                              "int32_packed"])
+@pytest.mark.parametrize("run_tiles,ring", RUN_BLOCKS,
+                         ids=[f"R{r}_ring{g}" for r, g in RUN_BLOCKS])
+@pytest.mark.parametrize("case", sorted(RUN_CASES))
+def test_runs_and_ring_move_what_the_xla_body_moves(case, run_tiles, ring,
+                                                    plane, gp, packed,
+                                                    monkeypatch):
+    """Whatever the run length and the ring's depth, over a uint8 or an
+    int32 plane, with the kind in a table of its own or in the input
+    tile's word: the rows land where the XLA scatter puts them, bit for
+    bit."""
+    if packed:
+        monkeypatch.setattr(compact_pallas, "SMEM_TABLE_BYTES", 0)
+    (bins, row, dst), tables, xla = _run_case(case, plane, gp)
+    got = _pallas_compact_call(bins, row, dst, *tables, 256, True,
+                               run_tiles=run_tiles, ring=ring)
+    assert _bit_equal(got, xla)
+
+
+def test_the_run_cases_hold_every_kind_of_run():
+    """RUN_CASES x RUN_BLOCKS hold runs of raw copies alone, runs of more
+    pairs than the ring is deep, duplicates inside a run, and a last run
+    shorter than the others (8 tiles in runs of 3)."""
+    seen = set()
+    for case in RUN_CASES:
+        _, (pair_in, pair_out, pcopy, n_pairs), _ = _run_case(case, np.uint8,
+                                                              32)
+        live = int(n_pairs[0])
+        kinds = np.asarray(pcopy)[:live]
+        for run_tiles, ring in RUN_BLOCKS:
+            n_runs = -(-8 // run_tiles)
+            off = np.asarray(run_offsets(pair_out, n_pairs, run_tiles,
+                                         n_runs))
+            assert off[0] == 0 and off[-1] == live
+            assert (np.diff(off) > 0).all()  # no run without its pairs
+            for a, b in zip(off[:-1], off[1:]):
+                outs = np.asarray(pair_out)[a:b] // run_tiles
+                assert (outs == outs[0]).all()  # the run's pairs alone
+                if (kinds[a:b] == 1).all():
+                    seen.add("only copies")
+                if b - a > ring:
+                    seen.add("more pairs than the ring")
+                if (kinds[a:b] == 2).any():
+                    seen.add("duplicates")
+            if 8 % run_tiles:
+                seen.add("short last run")
+    assert seen == {"only copies", "more pairs than the ring", "duplicates",
+                    "short last run"}
+
+
+@pytest.mark.parametrize("n,tile", [(2048, 256), (5120, 512), (16384, 256)])
+def test_the_grid_walks_one_step_a_run_of_output_tiles(rng, n, tile):
+    """compact_grid_steps is ceil(T / R), R the run compact_blocks takes
+    for the plane (8 tiles; the 10 tiles of 5,120 rows end in a run of
+    two), and compact_pairs the tables' live pairs, as before the loop."""
+    go_left = rng.rand(n) < 0.5
+    ranges = [(0, n // 3), (n // 2, n // 4)]
+    dst, _, lefts, args = _partition(go_left, ranges, n, tile)
+    bins = jnp.asarray(rng.randint(0, 256, size=(32, n)).astype(np.uint8))
+    row = jnp.asarray(rng.randn(8, n).astype(np.float32))
+    *got, work = compact_rows(bins, row, jnp.asarray(dst), lefts, *args,
+                              tile=tile, use_pallas=True, interpret=True)
+    assert _bit_equal(got, _permuted(np.asarray(bins), np.asarray(row), dst))
+    run_tiles, _ = compact_blocks(32, 1, 8, tile)
+    counts = dict(zip(COMPACT_WORK_FIELDS, np.asarray(work).tolist()))
+    *_, n_pairs = build_pair_tables(lefts, *args, tile)
+    assert counts["compact_grid_steps"] == -(-(n // tile) // run_tiles)
+    assert counts["compact_pairs"] == int(n_pairs[0])
+
+
 @pytest.mark.parametrize("ranges,valid", [
     ([(0, 700), (900, 400)], [False, False]),
     ([(300, 0), (2048, 0)], None),
@@ -357,7 +484,7 @@ def _bit_equal(got, want) -> bool:
 def test_a_compaction_that_moves_no_row_walks_one_copy_a_tile(rng, ranges,
                                                               valid):
     """No valid range: n_pairs == T, every pair a tile's raw copy of
-    itself, and the grid walks those T steps and no more."""
+    itself, and the grid walks one step a run of output tiles."""
     n, tile = 2048, 256
     (bins, row, dst, lefts, args), xla = _kernel_and_xla(rng, ranges, valid)
     _, _, pcopy, n_pairs = build_pair_tables(lefts, *args, tile)
@@ -367,8 +494,10 @@ def test_a_compaction_that_moves_no_row_walks_one_copy_a_tile(rng, ranges,
                               use_pallas=True, interpret=True)
     assert _bit_equal(got, xla) and _bit_equal(got, (bins, row))
     counts = dict(zip(COMPACT_WORK_FIELDS, np.asarray(work).tolist()))
-    assert counts["compact_grid_steps"] == counts["compact_pairs"] \
-        == counts["compact_copy_pairs"] == n // tile
+    assert counts["compact_pairs"] == counts["compact_copy_pairs"] \
+        == n // tile
+    run_tiles, _ = compact_blocks(8, 4, 8, tile)
+    assert counts["compact_grid_steps"] == -(-(n // tile) // run_tiles)
 
 
 @pytest.mark.parametrize("name,ranges,valid,p_left", GLUE_CASES,
@@ -377,7 +506,8 @@ def test_the_grid_bound_is_the_live_pair_count(rng, name, ranges, valid,
                                                p_left):
     """The kernel called with its tables cut to the live pairs (n_pairs
     then IS the tables' length) moves what the XLA body moves, bit for
-    bit; stopped before the last pair that moves rows, it does not."""
+    bit; its pair loops stopped before the last pair that moves rows, it
+    does not."""
     n, tile = 2048, 256
     (bins, row, dst, lefts, args), xla = _kernel_and_xla(rng, ranges, valid)
     pair_in, pair_out, pcopy, n_pairs = build_pair_tables(lefts, *args, tile)
@@ -525,8 +655,9 @@ def test_pair_counts_are_a_recount_of_the_pair_tables(rng, name, ranges,
         tile=tile, use_pallas=True, interpret=True)
     assert work.shape == (len(COMPACT_WORK_FIELDS),) and work.dtype == jnp.int32
     got = dict(zip(COMPACT_WORK_FIELDS, np.asarray(work).tolist()))
+    steps = -(-(n // tile) // compact_blocks(32, 1, 8, tile)[0])
     np.testing.assert_array_equal(
-        np.asarray(work), np.asarray(pair_work_counts(pcopy, n_pairs)))
+        np.asarray(work), np.asarray(pair_work_counts(pcopy, n_pairs, steps)))
     live = np.asarray(pcopy)[:int(n_pairs[0])]
     assert got["compact_pairs"] == live.size
     assert got["compact_copy_pairs"] == int((live == 1).sum())
@@ -535,8 +666,9 @@ def test_pair_counts_are_a_recount_of_the_pair_tables(rng, name, ranges,
     assert got["compact_pairs"] == (got["compact_copy_pairs"]
                                     + got["compact_permute_pairs"]
                                     + duplicates)
-    # the grid walks the live pairs and no other step: its bound is n_pairs
-    assert got["compact_grid_steps"] == got["compact_pairs"] > 0
+    # the grid walks one step a run of output tiles, the pairs inside it
+    assert got["compact_grid_steps"] == steps
+    assert got["compact_pairs"] > 0
     assert got["compact_pairs"] <= max_pairs_bound(n // tile,
                                                    2 * len(ranges))
     # every tile is written once at least: a raw copy where no range

@@ -25,7 +25,8 @@ from lightgbm_tpu.models import resident as resident_mod
 from lightgbm_tpu.models.gbdt import GBDT
 from lightgbm_tpu.objectives import create_objective
 from lightgbm_tpu.ops import predict as predict_mod
-from lightgbm_tpu.ops.compact_pallas import COMPACT_TILE, max_pairs_bound
+from lightgbm_tpu.ops.compact_pallas import (COMPACT_TILE, RUN_TILES_MAX,
+                                             max_pairs_bound)
 from lightgbm_tpu.ops.hist_pallas import DEFAULT_TILE_ROWS
 from lightgbm_tpu.parallel import learners as learners_mod
 from lightgbm_tpu.parallel.learners import DeviceDataParallelTreeLearner
@@ -808,8 +809,8 @@ def test_one_tree_wave_note_per_tree_carries_waves_rows_and_width(
         visits, active = note["hist_tile_visits"], note["hist_tiles_active"]
         assert note["hist_rows"] <= 1024 * active <= 1024 * visits
         assert visits <= active + note["speculated"]
-        # the root's one-slot call and a call a wave; a compaction a wave:
-        # each grid walked its call's live pairs
+        # the root's one-slot call and a call a wave, each grid walking its
+        # call's live pairs; a compaction a wave, a step a run of tiles
         _check_grid_steps(2048, note)
         _check_work_fields(note)
     assert sum(n["hist_rows"] for n in notes) \
@@ -820,17 +821,20 @@ def test_one_tree_wave_note_per_tree_carries_waves_rows_and_width(
 
 
 def _check_grid_steps(rows: int, note: dict, shards: int = 1) -> None:
-    """Both kernels' grids walk their live pairs alone: the steps the
-    program summed are the pairs, under the pair tables' static lengths
-    (T + 2K a histogram call, max_pairs_bound a compaction) of a tree of
-    `note["waves"]` waves over `rows` padded rows (a shard's)."""
+    """The histogram kernel's grid walks its live pairs alone, under the
+    pair table's static length T + 2K a call; the compaction kernel's walks
+    one step a run of output tiles (RUN_TILES_MAX of them at these planes'
+    few groups) and its live pairs, under max_pairs_bound a call, in a loop
+    inside each step; for a tree of `note["waves"]` waves over `rows`
+    padded rows (a shard's)."""
     t_hist, t_compact = rows // DEFAULT_TILE_ROWS, rows // COMPACT_TILE
     k = note["wave_k"]
     assert note["hist_grid_steps"] == note["hist_tile_visits"]
-    assert note["compact_grid_steps"] == note["compact_pairs"]
     assert note["hist_grid_steps"] <= shards * (
         (t_hist + 2) + note["waves"] * (t_hist + 2 * k))
-    assert note["compact_grid_steps"] <= shards * note["waves"] \
+    assert note["compact_grid_steps"] == shards * note["waves"] \
+        * -(-t_compact // RUN_TILES_MAX)
+    assert note["compact_pairs"] <= shards * note["waves"] \
         * max_pairs_bound(t_compact, 2 * k)
 
 
@@ -840,7 +844,9 @@ def _check_work_fields(note: dict) -> None:
     assert 0 < note["hist_tiles_active"] <= note["hist_tile_visits"] \
         <= note["hist_grid_steps"]
     moved = note["compact_copy_pairs"] + note["compact_permute_pairs"]
-    assert 0 < moved <= note["compact_pairs"] <= note["compact_grid_steps"]
+    assert 0 < moved <= note["compact_pairs"]
+    # a compaction step walks one pair at least: every output tile's own
+    assert 0 < note["compact_grid_steps"] <= note["compact_pairs"]
     # every wave writes every tile of every shard once at least
     assert moved * COMPACT_TILE >= note["waves"] * 2048
 

@@ -11,21 +11,26 @@ cell's rows and plane width (the ranges split `cover` of the rows, each
 range's rows going left with its own probability) and prints one JSON line
 a measurement:
 
-- `compact`: the compaction kernel over the wave's pair tables;
+- `compact`: the compaction kernel over the wave's pair tables, with the
+  run of output tiles a grid step writes and the ring of input tiles its
+  DMAs keep (`compact_blocks`' choice at the cell's plane);
+- `compact_blocks_sweep`: the same call at each run length of
+  SWEEP_RUN_TILES and each ring depth of SWEEP_RINGS, each output checked
+  against the chosen one's;
 - `compact_pair_kinds`: T identity pairs each a raw block copy, each a
   one-hot permute, and each a permute followed by its duplicate, so the
-  price of a copy pair, a permute pair and a duplicate (live, skipped) pair;
+  price of a copy pair, a permute pair and a duplicate (live, skipped) pair
+  (and the baseline's, where one is given);
 - `hist_wave`: the histogram kernel over the wave's smaller children;
 - `hist_root`: the histogram kernel's one-slot root pass over every tile.
 
 `--baseline DIR` loads another checkout's two kernel modules
 (`DIR/lightgbm_tpu/ops/{compact,hist}_pallas.py`, their package imports
 resolved against this checkout) and times them beside this checkout's on
-the same operands, interleaved (A B B A), checking the outputs bit for bit.
-Where the baseline's grid walks the tables' whole static length and this
-checkout's walks the live pairs, `us_per_step_past_pairs` is the price of
-one step of the tail. Times are host-clock milliseconds a call, the median
-of back-to-back calls ended by `block_until_ready`: a chip's numbers only.
+the same operands, interleaved (A B B A), checking the outputs bit for bit;
+both take the same pair tables, and each builds its own kernel operands
+from them. Times are host-clock milliseconds a call, the median of
+back-to-back calls ended by `block_until_ready`: a chip's numbers only.
 """
 from __future__ import annotations
 
@@ -49,6 +54,10 @@ TINY_ROWS = 16384
 # seed of its synthetic wave (the next cell's is SEED + 1).
 ROUNDS = 4
 SEED = 41
+# The compaction kernel's run lengths and ring depths timed beside the ones
+# compact_blocks chooses.
+SWEEP_RUN_TILES = (1, 4, 8)
+SWEEP_RINGS = (4, 8, 16)
 
 
 def _rows(cell: str) -> int:
@@ -162,29 +171,39 @@ def run_cell(b: Bench, cell: str, n: int, seed: int, base) -> None:
     live = int(tables[3][0])
     kinds = np.asarray(tables[2])[:live]
     mp = tables[0].shape[0]
+    run_tiles, ring = C.compact_blocks(gp, 1, 8, tile)
     _emit(cell=cell, rows=n, plane=[gp, n], tiles=T, table_steps=mp,
           pairs=live, copy=int((kinds == 1).sum()),
           permute=int((kinds == 0).sum()), duplicate=int((kinds == 2).sum()),
-          packed=C.pair_tables_packed(mp))
+          packed=C.pair_tables_packed(mp, -(-T // run_tiles)))
 
-    def compact(mod, d, *t):
+    def compact(mod, d, *t, **blocks):
         return lambda: mod._pallas_compact_call(bins, row, d, *t, tile,
-                                                interp)
+                                                interp, **blocks)
 
     fns = {"this": compact(C, dst, *tables)}
     if base:
         fns["baseline"] = compact(base[0], dst, *tables)
     res = b.interleave(fns, reps_c)
-    line = dict(kernel="compact", cell=cell, ms=res["this"][0],
+    line = dict(kernel="compact", cell=cell, run_tiles=run_tiles, ring=ring,
+                grid_steps=-(-T // run_tiles), ms=res["this"][0],
                 us_per_live_pair=res["this"][0] * 1e3 / live,
                 runs={k: v[1] for k, v in res.items()})
     if base:
         line.update(bit_equal=b.bit_equal(fns["this"](), fns["baseline"]()),
                     ms_baseline=res["baseline"][0],
-                    us_per_step_past_pairs=(res["baseline"][0]
-                                            - res["this"][0]) * 1e3
-                    / (mp - live))
+                    us_per_live_pair_baseline=res["baseline"][0] * 1e3 / live)
     _emit(**line)
+    # the run lengths and ring depths tried beside compact_blocks' choice
+    sweep = {f"R{r}_ring{g}": compact(C, dst, *tables, run_tiles=r, ring=g)
+             for r, g in sorted({(r, ring) for r in SWEEP_RUN_TILES}
+                                | {(run_tiles, g) for g in SWEEP_RINGS})}
+    want = fns["this"]()
+    res = b.interleave(sweep, reps_c)
+    _emit(kernel="compact_blocks_sweep", cell=cell,
+          **{k: dict(ms=v[0], us_per_live_pair=v[0] * 1e3 / live,
+                     bit_equal=b.bit_equal(sweep[k](), want))
+             for k, v in res.items()})
 
     ident = jnp.arange(n, dtype=jnp.int32)
     ids = jnp.arange(T, dtype=jnp.int32)
@@ -195,30 +214,31 @@ def run_cell(b: Bench, cell: str, n: int, seed: int, base) -> None:
 
     n_t, n_2t = jnp.full(1, T, jnp.int32), jnp.full(1, 2 * T, jnp.int32)
     two = jnp.repeat(ids, 2)
-    copies = (padded(ids), padded(ids), padded(jnp.ones(T, jnp.int32)), n_t)
-    permutes = (padded(ids), padded(ids), padded(jnp.zeros(T, jnp.int32)),
-                n_t)
-    dups = (padded(two), padded(two),
-            padded(jnp.tile(jnp.asarray([0, 2], jnp.int32), T)), n_2t)
+    kind_tables = {
+        "copy": (padded(ids), padded(ids), padded(jnp.ones(T, jnp.int32)),
+                 n_t),
+        "permute": (padded(ids), padded(ids),
+                    padded(jnp.zeros(T, jnp.int32)), n_t),
+        "permute_dup": (padded(two), padded(two),
+                        padded(jnp.tile(jnp.asarray([0, 2], jnp.int32), T)),
+                        n_2t)}
     identity = all(b.bit_equal(compact(C, ident, *t)(), (bins, row))
-                   for t in (copies, permutes, dups))
-    fns = {"copy": compact(C, ident, *copies)}
-    if base:
-        fns["copy_baseline"] = compact(base[0], ident, *copies)
-    res = b.interleave(fns, reps_c)
-    res.update(b.interleave({"permute": compact(C, ident, *permutes),
-                             "permute_dup": compact(C, ident, *dups)},
-                            reps_c))
+                   for t in kind_tables.values())
+    sides = {"": C, "_baseline": base[0]} if base else {"": C}
+    res = {}
+    for name, t in kind_tables.items():
+        res.update(b.interleave({name + side: compact(mod, ident, *t)
+                                 for side, mod in sides.items()}, reps_c))
     line = dict(kernel="compact_pair_kinds", cell=cell,
                 outputs_identity=identity,
-                us_per_copy_pair=res["copy"][0] * 1e3 / T,
-                us_per_permute_pair=res["permute"][0] * 1e3 / T,
-                us_per_duplicate_pair=(res["permute_dup"][0]
-                                       - res["permute"][0]) * 1e3 / T,
                 runs={k: v[1] for k, v in res.items()})
-    if base:
-        line["us_per_step_past_pairs"] = (res["copy_baseline"][0]
-                                          - res["copy"][0]) * 1e3 / (mp - T)
+    for side in sides:
+        line.update({
+            "us_per_copy_pair" + side: res["copy" + side][0] * 1e3 / T,
+            "us_per_permute_pair" + side: res["permute" + side][0] * 1e3 / T,
+            "us_per_duplicate_pair" + side: (res["permute_dup" + side][0]
+                                             - res["permute" + side][0])
+            * 1e3 / T})
     _emit(**line)
     del row
 
@@ -260,9 +280,7 @@ def run_cell(b: Bench, cell: str, n: int, seed: int, base) -> None:
         if base:
             line.update(
                 bit_equal=b.bit_equal(fns["this"](), fns["baseline"]()),
-                ms_baseline=res["baseline"][0],
-                us_per_step_past_pairs=(res["baseline"][0] - res["this"][0])
-                * 1e3 / max(g_blocks * (steps - visits), 1))
+                ms_baseline=res["baseline"][0])
         _emit(**line)
 
 
