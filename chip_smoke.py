@@ -6,7 +6,7 @@
 
 One process, the normal entry points, HIGGS's widths (28 dense f32 features,
 max_bin=255, num_leaves=255, learning_rate=0.1) on the seeded synthetic data
-bench.py generates, at a row count the whole-tree program fits in 16 GB
+`make_data` generates, at a row count the whole-tree program fits in 16 GB
 (CHANGES.md PR 22 says why it is not HIGGS's own 10.5M):
 
   agree   at a small N, the device learner against the host-driven learner
@@ -41,14 +41,13 @@ import time
 
 import numpy as np
 
-from bench import _auc as auc, make_data
-
 ROWS = 1 << 21        # training rows, one chip (CHANGES.md PR 22, step 2)
 ROWS_4CHIPS = 1 << 20  # training rows across the four-chip mesh
 SMALL_ROWS = 1 << 15  # the agreement check's training rows
 TREES = 8             # per timed run: WARMUP untimed, the rest timed
 WARMUP = 3
-AUC_FLOOR = 0.85      # at full N (bench.py's data: 28 features, unit noise)
+AUC_FLOOR = 0.85      # at full N (make_data: 28 features, unit noise)
+N_FEATURES = 28
 # device-vs-serial tolerance of tests/test_device_learner.py, and the
 # sharded-vs-single one of tests/test_sharded_device.py's ULP fields
 RTOL, ATOL = 1e-4, 1e-5
@@ -65,6 +64,33 @@ def emit(**fields) -> None:
 def fail(why: str) -> None:
     print(f"chip_smoke: FAILED: {why}", file=sys.stderr, flush=True)
     sys.exit(1)
+
+
+def make_data(n_rows: int, seed: int):
+    """Dense standard-normal float32 features, labels from a seeded linear
+    logit plus unit noise."""
+    rng = np.random.default_rng(seed)
+    X = rng.standard_normal((n_rows, N_FEATURES), dtype=np.float32)
+    w = rng.standard_normal(N_FEATURES, dtype=np.float32)
+    noise = rng.standard_normal(n_rows, dtype=np.float32)
+    return X, (X @ w + noise > 0).astype(np.float64)
+
+
+def auc(y, score) -> float:
+    """Mann-Whitney AUC with midranks for ties (tree scores tie often;
+    ordinal ranks would make the number order-dependent)."""
+    score = np.asarray(score)
+    order = np.argsort(score, kind="stable")
+    _, start, counts = np.unique(score[order], return_index=True,
+                                 return_counts=True)
+    ranks = np.empty(len(score))
+    ranks[order] = np.repeat(start + (counts + 1) / 2.0, counts)
+    pos = y > 0.5
+    n_pos, n_neg = int(pos.sum()), int((~pos).sum())
+    if n_pos == 0 or n_neg == 0:
+        return 0.5
+    return float((ranks[pos].sum() - n_pos * (n_pos + 1) / 2)
+                 / (n_pos * n_neg))
 
 
 def split_data(n_train: int, n_valid: int, n_hold: int, seed: int):

@@ -13,7 +13,7 @@ from .utils.backend import configure_compile_cache
 from .utils.log import register_log_callback, LightGBMError
 
 # the one place every entry point passes through (lgb.train, the CLI,
-# bench.py, the serving layer): see utils/backend.py for the rule
+# the benchmark, the serving layer): see utils/backend.py for the rule
 configure_compile_cache()
 # every compile and cache load of the process becomes a `compile` flight note
 install_compile_listener()

@@ -53,7 +53,8 @@ def train(params: Dict[str, Any], train_set: Dataset,
     # process stop conflating totals (work counters survive — see timer.py)
     global_timer.new_epoch()
     # telemetry session from the `telemetry_dir` param / $LGBM_TPU_TELEMETRY;
-    # a session already active (e.g. bench.py's) is left alone and reused
+    # a session already active (a caller's `telemetry.capture`) is left
+    # alone and reused
     own_tel = None
     tel_dir = telemetry.resolve_dir(params)
     if tel_dir and telemetry.session() is None:

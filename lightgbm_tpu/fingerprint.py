@@ -1,30 +1,18 @@
-"""Environment fingerprint + the append-only bench ledger.
+"""Environment fingerprints: who produced a result, and on what world.
 
-A bench number with no provenance is noise: 2.38M row-iters/s means
-nothing until you know which commit, which jax, which device, and which
-`LGBM_TPU_*` kernel flags produced it. `fingerprint()` captures exactly
-that — cheaply and without ever raising (a capture must not die because
-git is absent) — and bench.py stamps it into every record.
+`fingerprint()` names the commit, the jax/jaxlib versions, the device
+kind and count and every set `LGBM_TPU_*` flag — cheaply and without ever
+raising (a caller must not die because git is absent). A path choice
+(HIST_F32, BINS_I32, ...) is an experiment axis, so it belongs in it.
 
-`append_ledger()` is the durable trail: one fingerprinted record per
-line in BENCH_LEDGER.jsonl, appended via checkpoint.py's atomic
-read-modify-replace so a crash mid-capture never tears the file.
-tools/benchdiff.py reads the ledger back and gates PRs on it; the record
-schema is documented in docs/OBSERVABILITY.md and versioned by
-``LEDGER_SCHEMA_VERSION`` so readers can reject records they predate.
+`world_fingerprint()` is the distributed-world block checkpoint.py stamps
+into every sidecar.
 """
 from __future__ import annotations
 
 import os
 import subprocess
 from typing import Any, Dict, Optional
-
-# bump on any breaking change to the bench-record key set; benchdiff
-# refuses to compare records across major schema versions
-LEDGER_SCHEMA_VERSION = 1
-
-DEFAULT_LEDGER = "BENCH_LEDGER.jsonl"
-ENV_LEDGER = "BENCH_LEDGER"  # path override; "0"/"off" disables appends
 
 
 def _git_sha(repo_dir: Optional[str] = None) -> str:
@@ -42,9 +30,7 @@ def _git_sha(repo_dir: Optional[str] = None) -> str:
 
 
 def _flag_env() -> Dict[str, str]:
-    """Every set LGBM_TPU_* flag plus the jax/bench knobs that change what
-    a capture measures — a path choice (HIST_F32, BINS_I32, ...) is an
-    experiment axis, so it belongs in the fingerprint."""
+    """Every set LGBM_TPU_* flag plus JAX_PLATFORMS."""
     keep_exact = ("JAX_PLATFORMS",)
     out = {k: v for k, v in os.environ.items()
            if k.startswith("LGBM_TPU_") or k in keep_exact}
@@ -52,12 +38,11 @@ def _flag_env() -> Dict[str, str]:
 
 
 def fingerprint(repo_dir: Optional[str] = None) -> Dict[str, Any]:
-    """The environment identity block stamped on every bench record.
-    Pure observation, never raises; unknown fields degrade to "unknown"
-    (no jax on the path, no git checkout) rather than failing a capture."""
+    """The environment identity block. Pure observation, never raises;
+    unknown fields degrade to "unknown" (no jax on the path, no git
+    checkout) rather than failing the caller."""
     fp: Dict[str, Any] = {
         "git_sha": _git_sha(repo_dir),
-        "schema_version": LEDGER_SCHEMA_VERSION,
         "flags": _flag_env(),
     }
     try:
@@ -119,45 +104,3 @@ def world_fingerprint() -> Dict[str, Any]:
     except Exception:
         pass
     return fp
-
-
-def ledger_path(repo_dir: Optional[str] = None) -> Optional[str]:
-    """Resolved ledger file path, or None when appends are disabled via
-    $BENCH_LEDGER=0/off/empty-string-sentinel."""
-    env = os.environ.get(ENV_LEDGER)
-    if env is not None:
-        if env.strip().lower() in ("0", "off", "none", ""):
-            return None
-        return env
-    base = repo_dir or os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))
-    return os.path.join(base, DEFAULT_LEDGER)
-
-
-def append_ledger(record: Dict[str, Any],
-                  path: Optional[str] = None) -> Optional[str]:
-    """Append one JSON record line to the ledger (atomic whole-file
-    replace — the ledger stays a few thousand lines, so rewrite cost is
-    irrelevant next to crash consistency). Returns the path written, or
-    None when the ledger is disabled."""
-    import json
-
-    from .checkpoint import atomic_write_text
-
-    if path is None:
-        path = ledger_path()
-    if path is None:
-        return None
-    prior = ""
-    try:
-        with open(path, "r", encoding="utf-8") as fh:
-            prior = fh.read()
-        if prior and not prior.endswith("\n"):
-            prior += "\n"
-    except FileNotFoundError:
-        pass
-    parent = os.path.dirname(os.path.abspath(path))
-    os.makedirs(parent, exist_ok=True)
-    line = json.dumps(record, sort_keys=True) + "\n"
-    atomic_write_text(path, prior + line)
-    return path

@@ -78,7 +78,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import perfmodel, telemetry
+from .. import telemetry
 from ..utils import sanitize
 
 # Compaction tile: independent of the histogram tile (DEFAULT_TILE_ROWS);
@@ -601,12 +601,6 @@ def compact_rows(bins_p: jax.Array, row_p: jax.Array, dst: jax.Array,
         lefts, starts, counts, valid, tile)
     row_f32 = row_p.astype(jnp.float32)
     dst_i32 = dst.astype(jnp.int32)
-    if telemetry.enabled():
-        # one-time capture (works at trace time too: tracers carry the
-        # shape/dtype perfmodel's AOT cost_analysis re-lower needs)
-        perfmodel.note_dispatch("compact", _pallas_compact_call,
-                                bins_p, row_f32, dst_i32, pair_in, pair_out,
-                                is_copy, n_pairs, tile, interpret)
     bins_o, row_o = _pallas_compact_call(
         bins_p, row_f32, dst_i32, pair_in, pair_out, is_copy, n_pairs, tile,
         interpret)

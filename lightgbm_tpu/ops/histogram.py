@@ -29,7 +29,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .. import perfmodel, telemetry
 from ..utils.backend import on_tpu
 
 DEFAULT_ROW_CHUNK = 16384
@@ -135,18 +134,8 @@ def build_histogram_rows(bins: jax.Array, gh_ext: jax.Array, row_idx: jax.Array,
         gh_leaf = jnp.take(gh_ext, row_idx, axis=0)
         quantized = jnp.issubdtype(jnp.dtype(compute_dtype), jnp.integer)
         f32 = hist_force_f32()
-        if telemetry.enabled():
-            # one-time capture for perfmodel's AOT cost_analysis; the dict
-            # check keeps the per-leaf hot path O(1) afterwards
-            perfmodel.note_dispatch("histogram", pallas_histogram,
-                                    bins_leaf, gh_leaf, num_bins,
-                                    quantized=quantized, f32=f32)
         return pallas_histogram(bins_leaf, gh_leaf, num_bins,
                                 quantized=quantized, f32=f32)
-    if telemetry.enabled():
-        perfmodel.note_dispatch("histogram", _build_histogram_rows_xla,
-                                bins, gh_ext, row_idx, num_bins,
-                                row_chunk, compute_dtype)
     return _build_histogram_rows_xla(bins, gh_ext, row_idx, num_bins,
                                      row_chunk, compute_dtype)
 

@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import perfmodel, telemetry, tracing
+from .. import telemetry, tracing
 from ..common import MISSING_NAN, MISSING_ZERO, K_ZERO_THRESHOLD
 from ..models.tree import Tree
 from ..utils.backend import on_tpu
@@ -593,12 +593,7 @@ def predict_raw(packed: PackedEnsemble, X: jax.Array,
             n, T = vals.shape
             return vals.reshape(n, T // num_tree_per_iteration,
                                 num_tree_per_iteration).sum(axis=1)
-        program = fused_program(packed)
-        if telemetry.enabled():
-            # one-time dispatch capture for perfmodel's AOT cost_analysis
-            perfmodel.note_dispatch("predict", program,
-                                    packed, X, num_tree_per_iteration)
-        return program(packed, X, num_tree_per_iteration)
+        return fused_program(packed)(packed, X, num_tree_per_iteration)
 
 
 # --------------------------------------------------------------------- aot

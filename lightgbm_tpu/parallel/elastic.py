@@ -30,7 +30,7 @@ pieces (docs/ROBUSTNESS.md, "Distributed fault domain"):
   resume bit-identical), or at the surviving world size with
   ``--allow-shrink`` (shrink-to-fit; see the checkpoint world fingerprint).
 
-Module import stays jax-free: launch.py and bench.py drive GangSupervisor
+Module import stays jax-free: launch.py and the tests drive GangSupervisor
 without paying a backend init; jax loads lazily on the first heartbeat.
 """
 from __future__ import annotations
@@ -208,7 +208,7 @@ class CollectiveWatchdog:
 class ElasticRuntime:
     """Per-process elastic state: the watchdog, the heartbeat collective,
     and the liveness file the gang supervisor reads. Obtained via
-    :func:`active` (env-configured) or :func:`install` (tests/bench)."""
+    :func:`active` (env-configured) or :func:`install` (tests)."""
 
     def __init__(self, timeout_s: Optional[float] = None,
                  heartbeat_every: int = _DEF_HEARTBEAT_EVERY,
@@ -381,7 +381,7 @@ def install(timeout_s: Optional[float] = None,
             heartbeat_every: int = _DEF_HEARTBEAT_EVERY,
             rank: Optional[int] = None,
             gang_dir: Optional[str] = None) -> ElasticRuntime:
-    """Arm an elastic runtime programmatically (tests, bench)."""
+    """Arm an elastic runtime programmatically (tests)."""
     global _runtime, _runtime_key, _installed
     clear()
     _runtime = ElasticRuntime(timeout_s=timeout_s,
@@ -442,8 +442,8 @@ def latest_snapshot(output_model: str) -> Optional[str]:
 class GangSupervisor:
     """Watch a gang of worker processes; reap on first loss; optionally
     relaunch. ``spawn(world_size, rank, attempt)`` -> subprocess.Popen is
-    supplied by the caller (launch.py builds CLI workers; bench.py drives
-    stub commands to measure detect->reap->respawn latency in isolation).
+    supplied by the caller (launch.py builds CLI workers; the tests drive
+    stub commands to check detect->reap->respawn in isolation).
 
     Loss detection: any nonzero exit, or — when ``liveness_timeout_s`` is
     set — a rank whose liveness file under ``gang_dir`` goes stale (the

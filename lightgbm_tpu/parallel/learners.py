@@ -46,9 +46,9 @@ from ..ops.split import (SplitInfo, gather_feature_hist, pad_cat_words,
                          pad_feature_meta, per_feature_best,
                          per_feature_best_categorical, reduce_best_record,
                          scan_meta_of)
-from ..perfmodel import (feature_ici_bytes_per_wave, ici_overlap_pct,
-                         voting_ici_bytes_per_wave)
-from ..treelearner.device import (REC, DeviceTreeLearner, _PendingTree,
+from ..perfmodel import (feature_ici_bytes_per_wave, ici_bytes_per_wave,
+                         ici_overlap_pct, voting_ici_bytes_per_wave)
+from ..treelearner.device import (DeviceTreeLearner, _PendingTree,
                                   make_sharded_grow_fn)
 from ..treelearner.serial import (SerialTreeLearner, _LeafState,
                                   device_growth_applies)
@@ -606,11 +606,9 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
         records. O(K*F*Bmax*CH): independent of the row count
         (docs/PERF_NOTES.md comm-volume model); tests assert the
         N-independence."""
-        K = self.wave_k
-        pool_bytes = 2 if narrow else 4
-        self._set_ici_bytes_per_wave(
-            K * self.f_pad * self.meta.max_bins * 3 * pool_bytes
-            + 2 * K * self.f_pad * REC * 4)
+        self._set_ici_bytes_per_wave(ici_bytes_per_wave(
+            self.wave_k, self.f_pad, self.meta.max_bins,
+            pool_bytes=2 if narrow else 4))
 
     def _set_ici_bytes_per_wave(self, bytes_w: int) -> None:
         """The gauge, and the learner's own copy of it for the tree's
@@ -699,7 +697,6 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
               n_bag: int, bagged: bool, rows_resident: bool) -> _PendingTree:
         fmask_sh, scale_rep = constants
         narrow = self._narrow(leaf_sh)
-        self._record_carry_bytes()
         self._record_ici_bytes(narrow)
         grow = sanitize.guard(
             self._grow_fn(bagged, narrow), (0, 1, 2),

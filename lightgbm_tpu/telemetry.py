@@ -4,8 +4,8 @@ The reference's observability story is a timer table printed at exit under
 -DUSE_TIMETAG (include/LightGBM/utils/common.h:979-1063). A TPU-native stack
 needs machine-readable, per-iteration data because XLA adds failure modes the
 reference never had — shape-driven recompile churn, HBM high-water blowups,
-host<->device sync stalls — and "bench before/after" needs more than one
-end-of-run text dump. This module is the event bus:
+host<->device sync stalls — and a before/after comparison needs more than
+one end-of-run text dump. This module is the event bus:
 
   * In-process aggregator — always on while a session is active: every event
     type counted, every `global_timer.scope` span captured via `span_hook`.
@@ -28,8 +28,8 @@ Two watchers with no reference counterpart:
     memory stats (CPU).
 
 Enable with the `telemetry_dir` param, $LGBM_TPU_TELEMETRY, or the CLI;
-`start(None)` runs an aggregate-only session (no files — bench.py uses this
-to read compile/HBM figures without touching disk). Emission is a single
+`start(None)` runs an aggregate-only session (no files: a caller reads
+compile/HBM figures without touching disk). Emission is a single
 module-global None-check when no session is active, so the disabled path
 costs <1% (asserted by tests/test_telemetry.py) and changes no model output.
 Hot-path call sites must guard `emit()` behind `telemetry.enabled()` —

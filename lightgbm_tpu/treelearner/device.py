@@ -67,7 +67,6 @@ from ..ops.split import (CAT_WORD_BITS, SPLIT_FIELDS, ScanMeta, SplitInfo,
                          find_best_split, fix_feature_hist,
                          gather_feature_hist_raw, per_feature_best,
                          reduce_best_record, split_record_width)
-from .. import perfmodel, telemetry
 from ..utils import sanitize
 from ..utils.backend import on_tpu, pallas_interpret
 from ..utils.log import Log
@@ -644,7 +643,7 @@ def _grow_impl(bins: jax.Array, gh: jax.Array, leaf_id0: jax.Array,
         else:
             (bins_p, row_p, start, count, depth, leaf_best, rec_store, pool,
              n_cur, t, hist_rows, work_counts, n_waves) = carry
-        n_waves = n_waves + 1  # wave-efficiency telemetry (finalize())
+        n_waves = n_waves + 1  # the tree_wave note's wave count (finalize())
         with jax.named_scope(SCOPE_SELECT):
             gains = leaf_best[:L, 0]
             sel_gain, sel = jax.lax.top_k(gains, K)  # [K] distinct leaves
@@ -1231,33 +1230,6 @@ class DeviceTreeLearner(SerialTreeLearner):
                       "bit-identical resume", want, self.bins_dev.dtype)
         super().restore_snapshot_state(st)
 
-    def _record_carry_bytes(self) -> None:
-        """Gauges for the analytic bandwidth model (docs/PERF_NOTES.md,
-        executable form in perfmodel.py): HBM bytes of the per-wave loop
-        carry, bytes the ragged histogram kernel streams per row, and the
-        gain-scan read volume per wave — perfmodel.attribution() reads
-        these back to attribute the fused `tree_device` wall."""
-        from .. import perfmodel
-        from ..ops.compact_pallas import COMPACT_TILE
-        from ..ops.hist_pallas import DEFAULT_TILE_ROWS
-        unit = max(DEFAULT_TILE_ROWS, COMPACT_TILE)
-        G = self.bins_dev.shape[0]
-        plane_b = self.bins_dev.dtype.itemsize
-        plane_b = plane_b if plane_b == 1 else 4
-        global_timer.set_count(
-            "device_carry_bytes_per_wave",
-            perfmodel.carry_bytes_per_wave(self.num_data, G, plane_b, unit))
-        global_timer.set_count(
-            "device_hist_bytes_per_row",
-            perfmodel.hist_bytes_per_row(G, plane_b))
-        # the replay scan sweeps the [K, G, Bpad, CH] pool block and writes
-        # the [2K, G, REC] best-record store; the pool is 4-byte in both the
-        # float and quantized (int32) regimes
-        global_timer.set_count(
-            "device_scan_bytes_per_wave",
-            perfmodel.scan_bytes_per_wave(self.wave_k, G,
-                                          self.group_bin_padded))
-
     def train(self, gh_ext: jax.Array,
               bag_indices: Optional[np.ndarray] = None) -> Tree:
         return self.finalize(self.train_async(gh_ext, bag_indices))
@@ -1293,20 +1265,9 @@ class DeviceTreeLearner(SerialTreeLearner):
                                 dtype=jnp.bool_)
         else:
             fmask = jnp.ones(len(self.meta.real_feature), dtype=bool)
-        self._record_carry_bytes()
         grow = sanitize.guard(
             grow_tree_on_device, (0, 1, 2),
             "grow_tree_on_device (treelearner/device.py train_async)")
-        if telemetry.enabled():
-            # one-time dispatch capture: perfmodel AOT-relowers this exact
-            # signature for cost_analysis() (dict-check no-op afterwards)
-            perfmodel.note_dispatch(
-                "grow_fused", grow_tree_on_device,
-                self.bins_dev, gh, leaf_id0, self.meta, self.tables,
-                self.params_dev, fmask, num_leaves, self.group_bin_padded,
-                cfg.max_depth, quantized=self.quantized,
-                scale_vec=self._scale_vec, batch=self.wave_k,
-                bagged=bag_indices is not None)
         with global_timer.scope("tree_device"):
             # bins_dev is COPIED per tree: grow_tree_on_device donates its
             # first three args (gh and leaf_id0 are already fresh buffers).
@@ -1403,7 +1364,6 @@ class DeviceTreeLearner(SerialTreeLearner):
         wave_k = pending.wave_k
         committed = tree.num_leaves - 1
         speculated = n_waves * wave_k
-        commit_rate = committed / speculated if speculated else 1.0
         global_timer.add_count("device_waves", n_waves)
         global_timer.add_count("wave_splits_committed", committed)
         global_timer.add_count("wave_splits_speculated", speculated)
@@ -1431,12 +1391,9 @@ class DeviceTreeLearner(SerialTreeLearner):
             telemetry.emit(
                 "tree_wave", waves=n_waves, wave_width=wave_k,
                 committed=committed, speculated=speculated,
-                efficiency=round(commit_rate, 4) if speculated else 1.0,
                 hist_rows=self.last_hist_rows,
                 ici_bytes_per_wave=int(global_timer.counters.get(
-                    "device_ici_bytes_per_wave", 0)),
-                carry_bytes_per_wave=int(global_timer.counters.get(
-                    "device_carry_bytes_per_wave", 0)))
+                    "device_ici_bytes_per_wave", 0)))
         global_timer.set_count("wave_k", self.wave_k)
 
     def _renew_quantized_leaves_device(self, tree: Tree,

@@ -38,7 +38,6 @@ from ..ops.split import (FeatureMeta, SplitInfo, bins_to_bitset,
                          find_best_split, make_feature_meta)
 from .cegb import CEGB
 from .col_sampler import ColSampler
-from .. import perfmodel, telemetry
 from ..utils.backend import on_tpu
 from ..utils.log import Log
 from ..utils.timer import SPAN_QUANTIZE, global_timer
@@ -322,10 +321,6 @@ class SerialTreeLearner:
                 jnp.asarray(state.totals, dtype=jnp.float32),
                 self.meta, self.params_dev, self._node_feature_mask(state),
                 self._constraint_of(state), self._penalty_of(state, leaf))
-        if telemetry.enabled():
-            # one-time capture of the gain-scan dispatch signature for
-            # perfmodel's AOT cost_analysis (dict-check no-op afterwards)
-            perfmodel.note_dispatch("scan", find_best_split, *args)
         rec = find_best_split(*args)
         return SplitInfo.from_packed(np.asarray(rec))
 

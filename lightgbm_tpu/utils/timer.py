@@ -152,8 +152,8 @@ class GlobalTimer:
 
     def set_count(self, label: str, n: int) -> None:
         """Set a gauge counter (a level, not an accumulation): idempotent,
-        so per-tree code can re-publish a static figure — e.g. the device
-        learner's `device_carry_bytes_per_wave` — without inflating it."""
+        so per-tree code can re-publish a static figure — e.g. the sharded
+        learner's `device_ici_bytes_per_wave` — without inflating it."""
         self.counters[label] = int(n)
         self.gauges.add(label)
 
@@ -173,8 +173,8 @@ class GlobalTimer:
 
     def new_epoch(self) -> int:
         """Start a fresh per-run accumulation window: wall-clock totals and
-        call counts reset; work counters SURVIVE (bench.py and the learner
-        perf tests read them after training returns). Returns the new epoch
+        call counts reset; work counters SURVIVE (the learner perf tests
+        read them after training returns). Returns the new epoch
         id so telemetry records can name the run they belong to."""
         self.totals.clear()
         self.counts.clear()

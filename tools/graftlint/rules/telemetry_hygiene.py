@@ -3,7 +3,7 @@
 `telemetry.emit()` is a single module-global None-check when no session is
 active — but only AFTER its arguments are evaluated. An unguarded
 
-    telemetry.emit("tree_wave", efficiency=committed / speculated, ...)
+    telemetry.emit("tree_wave", waves=int(n_waves), ...)
 
 in a per-wave or per-chunk loop builds the whole payload dict (and any
 device syncs hiding in the field expressions) on EVERY trip, telemetry on
@@ -62,14 +62,13 @@ class TelemetryHygieneRule(Rule):
                    "even with telemetry off (use the counter APIs or guard "
                    "the emission)")
     scope_prefixes = ("treelearner/", "parallel/", "serving/", "streaming/")
-    # perfmodel/exposition sit on the scrape path: a /metrics render or a
-    # per-dispatch capture hook runs with telemetry off too, so unguarded
-    # emits there cost every caller, not just telemetry users. tracing.py
+    # exposition sits on the scrape path: a /metrics render runs with
+    # telemetry off too, so unguarded emits there cost every caller, not
+    # just telemetry users. tracing.py
     # is IN scope on purpose: its recorder append (tracing.note) is the
     # one sanctioned unguarded hot-path emit — O(1), allocation-bounded,
     # no I/O — so any telemetry.emit added there must still be guarded.
-    scope_exact = ("ops/predict.py", "perfmodel.py", "exposition.py",
-                   "tracing.py")
+    scope_exact = ("ops/predict.py", "exposition.py", "tracing.py")
 
     def check(self, pkg: Package) -> Iterable[Violation]:
         out: List[Violation] = []

@@ -52,9 +52,6 @@ COUNTER_DIRECTIONS: Dict[str, str] = {
     "hbm_high_water_bytes": "lower",
     "device_hist_rows": "lower",
     "device_ici_bytes_per_wave": "lower",
-    "device_carry_bytes_per_wave": "lower",
-    "device_scan_bytes_per_wave": "lower",
-    "device_hist_bytes_per_row": "lower",
     "wave_splits_speculated": "lower",
     "device_waves": "lower",
     "wave_splits_committed": "higher",
